@@ -22,6 +22,7 @@ import numpy as np
 from kmamiz_tpu.api.router import IRequestHandler, Request, Response
 from kmamiz_tpu.domain.endpoint_data_type import EndpointDataType
 from kmamiz_tpu.domain.endpoint_dependencies import EndpointDependencies
+from kmamiz_tpu.resilience import metrics as res_metrics
 from kmamiz_tpu.server.initializer import AppContext
 
 logger = logging.getLogger("kmamiz_tpu.api.graph")
@@ -420,6 +421,7 @@ class GraphHandler(IRequestHandler):
                 usage_cohesions = self._device_usage_cohesion(graph, namespace)
             except Exception:  # noqa: BLE001 - host fallback
                 logger.exception("device cohesion failed; host fallback")
+                res_metrics.incr("scorerHostFallback")
 
         if usage_cohesions is None:
             # host oracle path only: relabeling the whole record set is the
@@ -506,6 +508,7 @@ class GraphHandler(IRequestHandler):
                 return out
             except Exception:  # noqa: BLE001 - host fallback
                 logger.exception("device instability failed; host fallback")
+                res_metrics.incr("scorerHostFallback")
         dependencies = self._labeled_dependencies(namespace)
         if not dependencies:
             return []
@@ -549,6 +552,7 @@ class GraphHandler(IRequestHandler):
                 return out
             except Exception:  # noqa: BLE001 - host fallback
                 logger.exception("device coupling failed; host fallback")
+                res_metrics.incr("scorerHostFallback")
         dependencies = self._labeled_dependencies(namespace)
         if not dependencies:
             return []

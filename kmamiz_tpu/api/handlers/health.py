@@ -44,11 +44,55 @@ class HealthHandler(IRequestHandler):
                 "status": "UP",
                 "serverTime": int(time.time() * 1000),
                 "prewarm": warm,
+                "device": self._device(),
                 # resilience at a glance: breaker states, scheduler-job
                 # failure streaks, quarantine totals, watchdog trips
                 "resilience": res_metrics.resilience_summary(),
             }
         )
+
+    def _device(self) -> Optional[dict]:
+        """The accelerator behind the in-process DataProcessor; None for
+        the modes that run none (serve-only, simulator) and never
+        import jax."""
+        if getattr(self._ctx, "processor", None) is None:
+            return None
+        from kmamiz_tpu.telemetry import device as tel_device
+
+        return tel_device.device_block()
+
+    def _graph_sizes(self, graph) -> dict:
+        """Edge counts of the two graphs a realtime tick writes: the
+        device store (the scorer routes as served) and the host
+        dependency cache (what `?scorer=host` is labeled from).
+        chip_smoke.py asserts that the ticks grew both, by the same
+        number."""
+        cache = getattr(self._ctx, "cache", None)
+        dep = (
+            cache.get_all().get("EndpointDependencies")
+            if cache is not None
+            else None
+        )
+        data = dep.get_data() if dep is not None else None
+        records = data.dependencies if data is not None else []
+        # distinct triples: a window leaves one record per SERVER span,
+        # and the cache folds same-endpoint records only on its next merge
+        host_edges = {
+            (
+                by["endpoint"]["uniqueEndpointName"],
+                d["endpoint"]["uniqueEndpointName"],
+                by["distance"],
+            )
+            for d in records
+            for by in d["dependingBy"]
+        }
+        return {
+            "deviceEdges": graph.n_edges,
+            "hostEdges": len(host_edges),
+            "hostEndpoints": len(
+                {d["endpoint"]["uniqueEndpointName"] for d in records}
+            ),
+        }
 
     def _timings(self, req: Request) -> Response:
         payload = {
@@ -60,6 +104,7 @@ class HealthHandler(IRequestHandler):
         )
         if graph is not None and hasattr(graph, "scorer_cache_stats"):
             payload["scorerCache"] = graph.scorer_cache_stats()
+            payload["graph"] = self._graph_sizes(graph)
         from kmamiz_tpu.models import serving
 
         payload["modelServe"] = serving.serve_stats()
@@ -69,4 +114,11 @@ class HealthHandler(IRequestHandler):
         # ingestDropped (ring backpressure), dpFallback, breakers, WAL,
         # quarantine, watchdog — the fault-layer counters (ISSUE 5)
         payload["resilience"] = res_metrics.resilience_summary()
+        if getattr(self._ctx, "processor", None) is None:
+            payload["device"] = None
+        else:
+            from kmamiz_tpu.telemetry import device as tel_device
+
+            # device, native, compileCache, sparse
+            payload.update(tel_device.runtime_report())
         return Response(payload=payload)

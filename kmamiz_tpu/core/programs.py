@@ -27,9 +27,9 @@ readiness gate (503 + status "WARMING" until done, see
 api/handlers/health.py and deploy/kmamiz-tpu.yaml's readinessProbe).
 
 Env:
-- ``KMAMIZ_SHAPE_HINTS``: hint-file path (default
-  ``$KMAMIZ_COMPILE_CACHE_DIR/shape_hints.json``; hints are disabled
-  when neither is set).
+- ``KMAMIZ_SHAPE_HINTS``: hint-file path (default ``shape_hints.json``
+  beside the persistent cache, wherever core.compile_cache placed it;
+  a process that never enabled the cache keeps no hints).
 - ``KMAMIZ_PREWARM``: "0" disables boot prewarm, "sync" blocks boot on
   it, anything else (default "1") prewarms on a background thread.
 - ``KMAMIZ_PREWARM_READY_GATE``: "0" keeps /health answering 200 while
@@ -44,6 +44,8 @@ import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from kmamiz_tpu.core import compile_cache
 
 logger = logging.getLogger("kmamiz_tpu.programs")
 
@@ -244,13 +246,10 @@ class Program:
 
     # -- shape hints --------------------------------------------------------
     def _record_spec(self, args, kwargs, compile_ms: float = 0.0) -> None:
-        try:
-            import jax
+        import jax
 
-            if not jax.core.trace_state_clean():
-                return  # inner-jit retrace: not a top-level dispatch shape
-        except Exception:  # noqa: BLE001 - private API moved: record anyway
-            pass
+        if not jax.core.trace_ctx.is_top_level():
+            return  # inner-jit retrace: not a top-level dispatch shape
         try:
             spec = (
                 [_encode(a) for a in args],
@@ -523,9 +522,8 @@ def hints_path() -> Optional[str]:
     path = os.environ.get("KMAMIZ_SHAPE_HINTS")
     if path:
         return path
-    cache_dir = os.environ.get("KMAMIZ_COMPILE_CACHE_DIR")
-    if cache_dir:
-        return os.path.join(cache_dir, "shape_hints.json")
+    if compile_cache.enabled():
+        return os.path.join(compile_cache.cache_dir(), "shape_hints.json")
     return None
 
 
@@ -725,7 +723,11 @@ def run_prewarm(
         try:
             report["defaultGraphPrograms"] = graph.prewarm_compile()
         except Exception as e:  # noqa: BLE001 - boot must survive
-            logger.warning("default graph prewarm failed: %s", e)
+            # survive, but visibly: /health's prewarm report carries the
+            # failure, so a probe (chip_smoke.py) can refuse the boot
+            logger.exception("default graph prewarm failed")
+            report["failed"] += 1
+            report["defaultGraphError"] = f"{type(e).__name__}: {e}"[:500]
     report["elapsedS"] = round(time.perf_counter() - t0, 2)  # graftlint: disable=hot-path-clock -- boot-time prewarm accounting, off the tick
     return report
 
@@ -804,6 +806,18 @@ REGISTERED_JIT_SITES: Dict[str, set] = {
         "fused_gated_bias",
         "fused_neighbor_sums",
     },
+    # multi-chip programs: registered for their call/compile counters
+    # (chip_smoke.py reads them to see the mesh path was taken). Their
+    # specs carry a Mesh, which no hint can encode, so they stay out of
+    # the hint file and are prewarmed via the sharded branch of
+    # EndpointGraph.prewarm_compile.
+    "kmamiz_tpu/parallel/mesh.py": {
+        "sharded_window_stats",
+        "sharded_dependency_edges",
+        "sharded_dependency_edges_packed",
+        "sharded_window_edges_compact",
+        "sharded_service_scores",
+    },
     "kmamiz_tpu/ops/window.py": {
         "skip_client_parents",
         "dependency_edges",
@@ -835,14 +849,6 @@ REGISTERED_JIT_SITES: Dict[str, set] = {
 }
 
 ALLOWLISTED_JIT_SITES: Dict[str, Dict[str, str]] = {
-    "kmamiz_tpu/parallel/mesh.py": {
-        "sharded_window_stats": "multi-chip only; prewarmed via the "
-        "sharded branch of EndpointGraph.prewarm_compile",
-        "sharded_dependency_edges": "multi-chip only (see above)",
-        "sharded_dependency_edges_packed": "multi-chip only (see above)",
-        "sharded_window_edges_compact": "multi-chip only (see above)",
-        "sharded_service_scores": "multi-chip only (see above)",
-    },
     "kmamiz_tpu/ops/pallas_kernels.py": {
         "segment_stats_matmul": "inner kernel: dispatched only inside "
         "window_stats' trace (registered there)",

@@ -15,8 +15,8 @@ compares identical work.
     python -m kmamiz_tpu.cost.growth_probe --prewarm on
     python -m kmamiz_tpu.cost.growth_probe --prewarm off --capacity 256
 
-prints one JSON line: {"stall_ms", "steady_ms", "mid_compiles",
-"signature", "crossed", "hit", ...}.
+prints one JSON line: {"platform", "stall_ms", "steady_ms",
+"mid_compiles", "signature", "crossed", "hit", ...}.
 """
 from __future__ import annotations
 
@@ -107,6 +107,9 @@ def run_probe(
     )
     if steady:
         report["steady_ms"] = round(steady[len(steady) // 2], 2)
+    import jax
+
+    report["platform"] = jax.default_backend()
     report["n_edges"] = gg.n_edges
     report["signature"] = graph_signature(gg)
     if prewarm_on:

@@ -233,6 +233,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     import argparse
     import logging
 
+    from kmamiz_tpu.core import compile_cache
     from kmamiz_tpu.server.dp_server import DataProcessorServer
     from kmamiz_tpu.server.processor import DataProcessor
 
@@ -243,6 +244,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     args = ap.parse_args(argv)
 
     logging.basicConfig(level=os.environ.get("LOG_LEVEL", "WARNING").upper())
+    compile_cache.enable()  # before the first jit dispatch
     processor = DataProcessor(_stub_source, use_device_stats=False)
     recovered = processor.replay_wal()
     if recovered["replayed"]:

@@ -168,7 +168,7 @@ def neighbor_mean(
     table fits the VMEM budget; the division stays out here so the
     normalization matches the XLA path exactly."""
     n = h.shape[0]
-    if sparse.fused_enabled() and sparse.fused_fits(n):
+    if sparse.fused_route(n):
         agg, fused_deg = sparse.fused_neighbor_sums(
             h.astype(jnp.float32),
             src_ep,

@@ -144,7 +144,7 @@ def encode(
     # Under the pallas backends the SDDMM gate + bidirectional gated SpMM
     # run as one fused kernel (ops/sparse.py) when the node table fits
     # the VMEM budget; the deg normalization stays out here either way.
-    if sparse.fused_enabled() and sparse.fused_fits(n):
+    if sparse.fused_route(n):
         bias, deg, gate = sparse.fused_gated_bias(
             q,
             k,

@@ -5,8 +5,12 @@ C++ twin of the reference's Rust log parser (log_matcher.rs) — and exposes
 drop-in equivalents of the Python implementations in
 `kmamiz_tpu.core.envoy`. Every entry point degrades to the pure-Python
 path when the toolchain or library is unavailable, so the framework never
-hard-requires the extension. Call `available()` once at startup to keep
-the one-time compile off the request path.
+hard-requires the extension. The library is keyed on a content hash of the
+committed sources plus the compiler flags (build_info.json): any
+difference rebuilds, and a library whose record does not match is never
+loaded — what runs was built here, from the files beside it. Call
+`available()` once at startup to keep the one-time compile off the
+request path.
 """
 from __future__ import annotations
 
@@ -39,12 +43,16 @@ _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
+#: compiler flags common to both arch variants; part of the build key
+_BASE_FLAGS = ("-O3", "-shared", "-fPIC", "-pthread", "-std=c++17")
+_ARCH_FLAGS = {"native": ("-march=native",), "generic": ()}
+
 
 def _cpu_signature() -> str:
     """Stable fingerprint of this host's ISA (the cpu flags line): a
-    -march=native .so restored from a build cache onto a smaller-ISA
-    host would SIGILL on first call — no symbol/mtime check can catch
-    that, so the loader compares this signature instead."""
+    -march=native .so copied onto a smaller-ISA host would SIGILL on
+    first call — no content hash can catch that, so the build key of a
+    native build carries this signature too."""
     import hashlib
 
     try:
@@ -59,39 +67,57 @@ def _cpu_signature() -> str:
     return platform.machine()
 
 
-def _isa_mismatch() -> bool:
-    """True when build_info POSITIVELY says the .so was -march=native
-    compiled for a different cpu (a restored cache from another host):
-    loading such a library risks SIGILL, so it must never load as-is."""
+def source_hash() -> str:
+    """sha256 over the committed sources (name + bytes, in order)."""
+    import hashlib
+
+    digest = hashlib.sha256()
+    for src in _SOURCES:
+        digest.update(src.name.encode())
+        digest.update(b"\0")
+        digest.update(src.read_bytes())
+        digest.update(b"\0")
+    return digest.hexdigest()
+
+
+def _build_key(march: str) -> dict:
+    """What a library must have been built from to be loadable here:
+    these sources, these flags, and — for a -march=native build — this
+    host's ISA."""
+    return {
+        "sources": source_hash(),
+        "compiler": os.environ.get("CXX", "g++"),
+        "flags": list(_BASE_FLAGS + _ARCH_FLAGS[march]),
+        "march": march,
+        "cpu": _cpu_signature() if march == "native" else None,
+    }
+
+
+def build_info() -> Optional[dict]:
+    """The provenance record written next to the library, or None."""
     import json
 
     try:
         info = json.loads(_BUILD_INFO_PATH.read_text())
     except (OSError, ValueError):
-        return False  # unknown provenance: prefer rebuild, allow load
-    return info.get("march") == "native" and info.get("cpu") != _cpu_signature()
+        return None
+    return info if isinstance(info, dict) else None
 
 
-def _build_is_stale() -> bool:
-    """True when the cached .so should rebuild: missing, older than a
-    source, compiled for a different host ISA (restored caches), or of
-    unknown provenance (no build_info — rebuild pins it to THIS host)."""
+def _build_matches() -> bool:
+    """True only when a library exists AND its provenance record equals
+    the key of the files on disk. A missing record, an edited source, a
+    different flag set or another host's -march=native build all fail —
+    such a library is never loaded, it is rebuilt."""
     if not _LIB_PATH.exists():
-        return True
-    if any(
-        src.exists() and src.stat().st_mtime > _LIB_PATH.stat().st_mtime
-        for src in _SOURCES
-    ):
-        return True
-    if not _BUILD_INFO_PATH.exists():
-        return True
-    return _isa_mismatch()
-
-
-def _src_mtimes() -> dict:
-    return {
-        src.name: src.stat().st_mtime for src in _SOURCES if src.exists()
-    }
+        return False
+    info = build_info()
+    if info is None or info.get("march") not in _ARCH_FLAGS:
+        return False
+    try:
+        return info == _build_key(info["march"])
+    except OSError:  # sources unreadable: nothing to match against
+        return False
 
 
 def _build_known_failed() -> bool:
@@ -103,12 +129,12 @@ def _build_known_failed() -> bool:
 
     try:
         info = json.loads(_FAIL_INFO_PATH.read_text())
+        return (
+            info.get("cpu") == _cpu_signature()
+            and info.get("sources") == source_hash()
+        )
     except (OSError, ValueError):
         return False
-    return (
-        info.get("cpu") == _cpu_signature()
-        and info.get("mtimes") == _src_mtimes()
-    )
 
 
 def _build() -> bool:
@@ -119,47 +145,48 @@ def _build() -> bool:
     if _build_known_failed():
         return False
     _BUILD_DIR.mkdir(parents=True, exist_ok=True)
-
-    def cmd_for(arch_flags):
-        return [
-            os.environ.get("CXX", "g++"),
-            "-O3",
-            *arch_flags,
-            "-shared",
-            "-fPIC",
-            "-pthread",
-            "-std=c++17",
-            "-o",
-            str(_LIB_PATH),
-            *[str(src) for src in _SOURCES],
-        ]
+    # the old record goes first: a build that dies half-way must not
+    # leave a new .so under the previous key
+    _BUILD_INFO_PATH.unlink(missing_ok=True)
 
     # -march=native first: the .so is built on the host that runs it (the
     # DP deployment builds in its own image), and the hash/number/memcpy
     # paths gain a few percent beyond the hand-dispatched AVX2 scans.
-    # Portable fallback when the toolchain rejects it. The build records
-    # its ISA so a cache-restored .so never runs on a smaller host.
-    for arch, label in ((["-march=native"], "native"), ([], "generic")):
+    # Portable fallback when the toolchain rejects it.
+    # compile beside the target and rename into place: concurrent cold
+    # processes (fleet workers, soak pool) may all build at once, and a
+    # loader must never map a half-written library
+    tmp_lib = _LIB_PATH.with_name(f"{_LIB_PATH.name}.{os.getpid()}.tmp")
+    last_err: Optional[BaseException] = None
+    for march in ("native", "generic"):
+        key = _build_key(march)
+        cmd = [
+            key["compiler"],
+            *key["flags"],
+            "-o",
+            str(tmp_lib),
+            *[str(src) for src in _SOURCES],
+        ]
         try:
-            subprocess.run(
-                cmd_for(arch), check=True, capture_output=True, timeout=120
-            )
-            try:
-                _BUILD_INFO_PATH.write_text(
-                    json.dumps({"march": label, "cpu": _cpu_signature()})
-                )
-                _FAIL_INFO_PATH.unlink(missing_ok=True)
-            except OSError:
-                pass
-            return True
+            subprocess.run(cmd, check=True, capture_output=True, timeout=300)
         except (subprocess.SubprocessError, OSError) as err:
             last_err = err
+            tmp_lib.unlink(missing_ok=True)
+            continue
+        try:
+            os.replace(tmp_lib, _LIB_PATH)
+            _BUILD_INFO_PATH.write_text(json.dumps(key))
+            _FAIL_INFO_PATH.unlink(missing_ok=True)
+        except OSError as err:
+            last_err = err
+            break  # unrecordable build: unloadable by the rule above
+        return True
     logger.warning(
         "native build failed, using pure-Python path: %s", last_err
     )
     try:  # negative-cache the failure so the next process skips the wall
         _FAIL_INFO_PATH.write_text(
-            json.dumps({"cpu": _cpu_signature(), "mtimes": _src_mtimes()})
+            json.dumps({"cpu": _cpu_signature(), "sources": source_hash()})
         )
     except OSError:
         pass
@@ -173,30 +200,31 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if _build_is_stale():
-            if not _build():
-                # rebuild impossible (no toolchain, stripped sources):
-                # a merely stale or unknown-provenance .so still LOADS
-                # — staleness prefers a rebuild but must not veto the
-                # native path (missing symbols are caught below). Only
-                # a positive ISA mismatch refuses: that .so can SIGILL.
-                if not _LIB_PATH.exists() or _isa_mismatch():
-                    _load_failed = True
-                    return None
-                logger.warning(
-                    "native rebuild unavailable; loading existing "
-                    "libkmamiz_native.so as-is"
-                )
+        # the library on disk is whatever a previous process (or a disk
+        # copy from another checkout) left there: load it only when its
+        # record matches the sources beside it, else rebuild — and when
+        # that is impossible (no toolchain), degrade to the Python path
+        # rather than run code of unknown provenance
+        if not _build_matches() and not _build():
+            _load_failed = True
+            return None
         lib = _open_and_bind()
-        if lib is None and _build():
-            # a stale prebuilt .so can miss newer symbols even when the
-            # mtime check passed (restored build caches); rebuild once
-            lib = _open_and_bind()
         if lib is None:
             _load_failed = True
             return None
         _lib = lib
         return _lib
+
+
+def build_report() -> dict:
+    """Native-loader state for /timings and the chip smoke: whether the
+    library is loaded and the record it was built under."""
+    loaded = available()
+    return {
+        "available": loaded,
+        "sourceHash": source_hash(),
+        "buildInfo": build_info() if loaded else None,
+    }
 
 
 def _open_and_bind() -> Optional[ctypes.CDLL]:

@@ -34,18 +34,21 @@ Backend knob (mirrored in config.Settings):
   >=50x faster); GraphSAGE/STLGT keep their gather/segment-sum XLA code,
   which already IS the sparse formulation for those shapes.
 - ``KMAMIZ_SPARSE=pallas``: additionally routes the STLGT bias and
-  GraphSAGE neighbor sums through the fused Pallas kernel (auto-falls
-  back to interpret mode off-TPU, and to XLA when the node table
-  exceeds the VMEM budget — see ``fused_fits``).
-- ``KMAMIZ_SPARSE=pallas_interpret``: fused kernels in interpret mode
-  everywhere (CI/CPU parity testing).
+  GraphSAGE neighbor sums through the fused Pallas kernel, compiled by
+  Mosaic — on a backend Mosaic cannot target the kernel RAISES; it never
+  runs interpreted under this name. A node table past the VMEM budget
+  (``fused_route``) gives way to the XLA formulation, and every such
+  give-way is counted (``route_stats``, shown in /timings).
+- ``KMAMIZ_SPARSE=pallas_interpret``: the same kernels in interpret mode
+  (CI/CPU parity testing) — the ONLY setting that interprets.
 - ``KMAMIZ_SPARSE=xla``: every consumer keeps the legacy dense/XLA path
   bit-for-bit (the fallback the parity tests pin against).
 
-``KMAMIZ_SPARSE_TILE`` sets the edge-tile block (default 256, f32
-(8, 128)-aligned); ``KMAMIZ_SPARSE_NODE_MAX`` bounds the VMEM-resident
-node table for the fused kernels (default 2048 rows; at tile=256 that is
-two 2 MB one-hot tiles + three node tables well inside 16 MB VMEM).
+``KMAMIZ_SPARSE_TILE`` sets the edge-tile block (default 256, a
+multiple of the 128-lane width); ``KMAMIZ_SPARSE_NODE_MAX`` bounds the
+VMEM-resident node table for the fused kernels (default 2048 rows; at
+tile=256 that is four ~2 MB one-hot tiles plus the double-buffered node
+tables — ``_fused_call`` sizes the kernel's VMEM limit from the shapes).
 
 Parity contract (pinned by tests/test_ops_sparse.py and the per-consumer
 parity tests): integer-derived lanes are bit-exact across backends;
@@ -55,6 +58,7 @@ matmul accumulation) are pinned at fp32 tolerance.
 from __future__ import annotations
 
 import os
+import threading
 from functools import partial
 from typing import Optional, Tuple
 
@@ -65,17 +69,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kmamiz_tpu.core import programs
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.6); take whichever
-# this jax ships
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 _VALID_BACKENDS = ("xla", "sparse", "pallas", "pallas_interpret")
 
 _backend_cache: Optional[str] = None
 _tile_cache: Optional[int] = None
 _node_max_cache: Optional[int] = None
+
+_route_lock = threading.Lock()
+#: fused-kernel routing decisions since process start (trace-time
+#: counts: the consumers decide inside their jit traces)
+_route_counts = {"fused": 0, "gaveWay": 0, "lastGaveWayNodes": 0}
 
 
 def backend() -> str:
@@ -98,8 +101,10 @@ def tile_size() -> int:
     global _tile_cache
     if _tile_cache is None:
         t = int(os.environ.get("KMAMIZ_SPARSE_TILE", "256"))
-        if t < 8 or t % 8:
-            raise ValueError(f"KMAMIZ_SPARSE_TILE={t} must be a multiple of 8")
+        if t < 128 or t % 128:
+            raise ValueError(
+                f"KMAMIZ_SPARSE_TILE={t} must be a multiple of 128"
+            )
         _tile_cache = t
     return _tile_cache
 
@@ -113,11 +118,14 @@ def node_budget() -> int:
 
 
 def reset_for_tests() -> None:
-    """Drop the cached knob reads (tests monkeypatching KMAMIZ_SPARSE*)."""
+    """Drop the cached knob reads (tests monkeypatching KMAMIZ_SPARSE*)
+    and the routing counters."""
     global _backend_cache, _tile_cache, _node_max_cache
     _backend_cache = None
     _tile_cache = None
     _node_max_cache = None
+    with _route_lock:
+        _route_counts.update(fused=0, gaveWay=0, lastGaveWayNodes=0)
 
 
 def use_sparse() -> bool:
@@ -131,16 +139,41 @@ def fused_enabled() -> bool:
 
 
 def fused_interpret() -> bool:
-    """Interpret-mode flag for the fused kernels: forced by the
-    pallas_interpret backend, and automatic off-TPU (Mosaic kernels only
-    compile for TPU; CPU CI runs the same kernel interpreted)."""
-    return backend() == "pallas_interpret" or jax.default_backend() != "tpu"
+    """Interpret-mode flag for the fused kernels: only the
+    pallas_interpret backend interprets. ``pallas`` always hands the
+    kernel to Mosaic, so selecting it where Mosaic cannot compile is an
+    error the caller sees, not a silent change of what runs."""
+    return backend() == "pallas_interpret"
 
 
-def fused_fits(num_nodes: int) -> bool:
-    """Whether the node table fits the fused kernels' VMEM budget; larger
-    windows fall back to the XLA gather/segment-sum path."""
-    return num_nodes <= node_budget()
+def fused_route(num_nodes: int) -> bool:
+    """Whether a model consumer takes the fused kernel for a node table
+    of ``num_nodes`` rows. Under a pallas backend a table past the VMEM
+    budget gives way to the XLA gather/segment-sum formulation; both
+    outcomes are counted so the give-way is visible (``route_stats``)."""
+    if not fused_enabled():
+        return False
+    fits = num_nodes <= node_budget()
+    with _route_lock:
+        if fits:
+            _route_counts["fused"] += 1
+        else:
+            _route_counts["gaveWay"] += 1
+            _route_counts["lastGaveWayNodes"] = int(num_nodes)
+    return fits
+
+
+def route_stats() -> dict:
+    """Backend selection and fused-kernel routing counters (/timings)."""
+    with _route_lock:
+        counts = dict(_route_counts)
+    return {
+        "backend": backend(),
+        "interpret": fused_interpret(),
+        "tile": tile_size(),
+        "nodeBudget": node_budget(),
+        **counts,
+    }
 
 
 def _pad_to(n: int, mult: int) -> int:
@@ -151,17 +184,36 @@ def _pad_to(n: int, mult: int) -> int:
 # fused SDDMM/SpMM kernel (edge-tile grid, VMEM-resident node table)
 # ---------------------------------------------------------------------------
 #
-# grid = (e_pad // tile,), "arbitrary": the bias/degree outputs accumulate
-# across every edge tile into the same [N, H] / [1, N] VMEM block
-# (initialized at tile 0), while the per-edge gate writes one [1, tile]
-# block per step. Gathers and scatters both ride the MXU as one-hot
-# matmuls over [tile, N] masks built in-kernel from broadcasted_iota —
-# the only O(E*N) object is a single VMEM tile, never an HBM array.
+# grid = (e_pad // tile,), "arbitrary": the bias output accumulates across
+# every edge tile into the same [N, H] VMEM block (initialized at tile 0),
+# while the per-edge gate writes one [tile, 1] block per step. Gathers and
+# scatters both ride the MXU as one-hot matmuls over masks built in-kernel
+# from broadcasted_iota — the only O(E*N) objects are VMEM tiles, never an
+# HBM array.
+#
+# Layout: nothing moves between the lane and the sublane axis. Mosaic does
+# not require that — the PR 13 form (1-D edge vectors, one pair of one-hots
+# contracted over the edge axis, M=1 degree products) compiles and agrees
+# with XLA too, with half the one-hot tiles and no ones column (PERF.md,
+# PR 21). This form stays because it is the one chip_smoke.py phase D has
+# run; time both before preferring either.
+# Everything per-edge is a COLUMN ([tile, 1], edges on sublanes) — the
+# gather one-hots [tile, N] compare an id column against a lane iota, and
+# the gate falls out of a lane reduction as a column. The
+# scatter needs the transposed one-hots [N, tile]; those are built directly
+# from the same ids passed a second time as a ROW ([1, tile]) against a
+# sublane iota, so the scatter is a plain [N, tile] @ [tile, H] matmul
+# rather than a contraction over the leading axis of both operands. The
+# degree reduction rides the same matmul: the value table carries a column
+# of ones at index h, so column h of the scattered sum IS the gate-weighted
+# degree (a whole extra 128-lane block when h is a multiple of 128).
 
 
 def _fused_kernel(
-    src_ref,
-    dst_ref,
+    src_row_ref,
+    dst_row_ref,
+    src_col_ref,
+    dst_col_ref,
     mask_ref,
     v_ref,
     *rest,
@@ -169,58 +221,60 @@ def _fused_kernel(
     inv_sqrt_h: float,
 ):
     if gated:
-        q_ref, k_ref, b_ref, bias_ref, deg_ref, gate_ref = rest
+        q_ref, k_ref, b_ref, bias_ref, gate_ref = rest
     else:
-        bias_ref, deg_ref, gate_ref = rest
+        bias_ref, gate_ref = rest
 
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        bias_ref[:, :] = jnp.zeros_like(bias_ref)
-        deg_ref[:, :] = jnp.zeros_like(deg_ref)
+        bias_ref[...] = jnp.zeros_like(bias_ref)
 
-    src = src_ref[0, :]  # [T] int32, parked at n_pad when invalid
-    dst = dst_ref[0, :]
-    m = mask_ref[0, :]  # [T] f32
-
-    tile = src.shape[0]
+    tile = src_col_ref.shape[0]
     n_pad = v_ref.shape[0]
-    local = jax.lax.broadcasted_iota(jnp.int32, (tile, n_pad), 1)
-    # parked ids (n_pad) match no iota column -> all-zero one-hot rows,
-    # so invalid edges gather zeros and scatter nothing
-    oh_src = (src[:, None] == local).astype(jnp.float32)
-    oh_dst = (dst[:, None] == local).astype(jnp.float32)
+    lane_ids = jax.lax.broadcasted_iota(jnp.int32, (tile, n_pad), 1)
+    row_ids = jax.lax.broadcasted_iota(jnp.int32, (n_pad, tile), 0)
+    # parked ids (n_pad) match no iota entry -> all-zero one-hot rows, so
+    # invalid edges gather zeros and scatter nothing
+    oh_src = (src_col_ref[...] == lane_ids).astype(jnp.float32)  # [T, N]
+    oh_dst = (dst_col_ref[...] == lane_ids).astype(jnp.float32)
+    oh_src_t = (src_row_ref[...] == row_ids).astype(jnp.float32)  # [N, T]
+    oh_dst_t = (dst_row_ref[...] == row_ids).astype(jnp.float32)
 
-    _dot = partial(
-        jax.lax.dot_general,
+    # f32 tables through the MXU: HIGHEST keeps the one-hot extraction
+    # f32-exact (the default would round table values to bf16)
+    dot = partial(
+        jnp.dot,
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST,
     )
-    row_dot = partial(_dot, dimension_numbers=(((1,), (0,)), ((), ())))
-    # contract the EDGE axis of both operands: [T, N] x [T, H] -> [N, H]
-    scatter_dot = partial(_dot, dimension_numbers=(((0,), (0,)), ((), ())))
+    v_src = dot(oh_src, v_ref[...])  # [T, H] edge-gather (SpMM in)
+    v_dst = dot(oh_dst, v_ref[...])
 
-    v_src = row_dot(oh_src, v_ref[:, :])  # [T, H] edge-gather (SpMM in)
-    v_dst = row_dot(oh_dst, v_ref[:, :])
-
+    m = mask_ref[...]  # [T, 1] f32
     if gated:
-        q_e = row_dot(oh_src, q_ref[:, :])
-        k_e = row_dot(oh_dst, k_ref[:, :])
+        q_e = dot(oh_src, q_ref[...])
+        k_e = dot(oh_dst, k_ref[...])
         # SDDMM half: per-edge scaled dot + sigmoid gate on the VPU
-        aff = jnp.sum(q_e * k_e, axis=1) * inv_sqrt_h
+        aff = jnp.sum(q_e * k_e, axis=1, keepdims=True) * inv_sqrt_h
         g = jax.nn.sigmoid(aff + b_ref[0, 0]) * m
     else:
         g = m
-    gate_ref[0, :] = g
+    gate_ref[...] = g
 
-    gv_src = g[:, None] * v_src
-    gv_dst = g[:, None] * v_dst
     # SpMM half: segment-reduce both directions back to endpoint rows
-    bias_ref[:, :] += scatter_dot(oh_dst, gv_src) + scatter_dot(oh_src, gv_dst)
-    deg_ref[0, :] += (
-        row_dot(g[None, :], oh_dst)[0, :] + row_dot(g[None, :], oh_src)[0, :]
-    )
+    bias_ref[...] += dot(oh_dst_t, g * v_src) + dot(oh_src_t, g * v_dst)
+
+
+def _fused_vmem_bytes(tile: int, n_pad: int, h_pad: int, gated: bool) -> int:
+    """VMEM the fused kernel needs, from its shapes: four f32 one-hot
+    tiles, the node tables (inputs are double-buffered by the pipeline
+    even at a constant block index), the resident accumulator, and the
+    [tile, h_pad] gathered/gated intermediates."""
+    one_hots = 4 * tile * n_pad * 4
+    tables = (3 if gated else 1) * 2 * n_pad * h_pad * 4
+    accumulator = 2 * n_pad * h_pad * 4
+    edge_values = 8 * tile * h_pad * 4
+    return one_hots + tables + accumulator + edge_values
 
 
 def _fused_call(
@@ -239,31 +293,54 @@ def _fused_call(
     e = src_ep.shape[0]
     e_pad = _pad_to(max(e, 1), tile)
     n_pad = _pad_to(n + 1, 128)  # +1 spill column keeps the park id in-grid
-    h_pad = _pad_to(max(h, 1), 128)
+    h_pad = _pad_to(h + 1, 128)  # +1: the ones column that yields the degree
 
     def _park(ep):
         ep = jnp.where(edge_mask, jnp.clip(ep, 0, n - 1), n_pad)
         return jnp.pad(
             ep.astype(jnp.int32), (0, e_pad - e), constant_values=n_pad
-        )[None, :]
+        )
 
     src_p = _park(src_ep)
     dst_p = _park(dst_ep)
-    mask_p = jnp.pad(edge_mask.astype(jnp.float32), (0, e_pad - e))[None, :]
+    mask_p = jnp.pad(edge_mask.astype(jnp.float32), (0, e_pad - e))
 
-    def _table(t):
-        return jnp.pad(t.astype(jnp.float32), ((0, n_pad - n), (0, h_pad - h)))
+    def _table(t, ones_col: bool = False):
+        t = t.astype(jnp.float32)
+        if ones_col:
+            t = jnp.concatenate([t, jnp.ones((n, 1), jnp.float32)], axis=1)
+        return jnp.pad(t, ((0, n_pad - n), (0, h_pad - t.shape[1])))
 
-    edge_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
+    row_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
+    col_spec = pl.BlockSpec((tile, 1), lambda i: (i, 0))
     table_spec = pl.BlockSpec((n_pad, h_pad), lambda i: (0, 0))
 
-    in_specs = [edge_spec, edge_spec, edge_spec, table_spec]
-    operands = [src_p, dst_p, mask_p, _table(v)]
+    in_specs = [row_spec, row_spec, col_spec, col_spec, col_spec, table_spec]
+    operands = [
+        src_p[None, :],
+        dst_p[None, :],
+        src_p[:, None],
+        dst_p[:, None],
+        mask_p[:, None],
+        _table(v, ones_col=True),
+    ]
     if gated:
-        in_specs += [table_spec, table_spec, pl.BlockSpec((1, 1), lambda i: (0, 0))]
-        operands += [_table(q), _table(k), b_edge.reshape(1, 1).astype(jnp.float32)]
+        in_specs += [
+            table_spec,
+            table_spec,
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ]
+        operands += [
+            _table(q),
+            _table(k),
+            b_edge.reshape(1, 1).astype(jnp.float32),
+        ]
 
-    bias, deg, gate = pl.pallas_call(
+    vmem_limit = min(
+        100 << 20,
+        max(32 << 20, 2 * _fused_vmem_bytes(tile, n_pad, h_pad, gated)),
+    )
+    bias, gate = pl.pallas_call(
         partial(
             _fused_kernel,
             gated=gated,
@@ -273,18 +350,19 @@ def _fused_call(
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((n_pad, h_pad), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_pad), lambda i: (0, 0)),
-            pl.BlockSpec((1, tile), lambda i: (0, i)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pad, h_pad), jnp.float32),
-            jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
-            jax.ShapeDtypeStruct((1, e_pad), jnp.float32),
+            jax.ShapeDtypeStruct((e_pad, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit,
+        ),
         interpret=interpret,
     )(*operands)
-    return bias[:n, :h], deg[0, :n], gate[0, :e]
+    return bias[:n, :h], bias[:n, h], gate[:e, 0]
 
 
 @programs.register("sparse.fused_gated_bias")

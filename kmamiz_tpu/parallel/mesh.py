@@ -26,33 +26,10 @@ from typing import List, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # jax<0.5 keeps it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-# jax renamed the replication/varying-axes check kwarg (check_rep ->
-# check_vma around 0.6); dispatch to whichever this jax understands
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
-    )
-
+from kmamiz_tpu.core import programs
 from kmamiz_tpu.core.spans import KIND_SERVER, SpanBatch, spans_to_batch
 from kmamiz_tpu.ops import window as window_ops
 
@@ -266,6 +243,7 @@ def shard_window(
     )
 
 
+@programs.register("mesh.sharded_window_stats")
 @partial(
     jax.jit,
     static_argnames=(
@@ -448,6 +426,7 @@ def sharded_window_stats(
     )
 
 
+@programs.register("mesh.sharded_dependency_edges")
 @partial(
     jax.jit,
     static_argnames=("mesh", "max_depth", "axis"),
@@ -535,6 +514,7 @@ def shard_window_packed(sharded: ShardedWindow):
     return flat(pslot2), flat(kind2), flat(valid2), flat(ep2), depth
 
 
+@programs.register("mesh.sharded_dependency_edges_packed")
 @partial(
     jax.jit,
     static_argnames=("mesh", "max_depth", "axis"),
@@ -569,6 +549,7 @@ def sharded_dependency_edges_packed(
     )(parent_slot, kind, valid, endpoint_id)
 
 
+@programs.register("mesh.sharded_window_edges_compact")
 @partial(
     jax.jit,
     static_argnames=("mesh", "max_depth", "stage_cap", "packed_key", "axis"),
@@ -655,8 +636,18 @@ def make_sharded_slot_grad(mesh: Mesh, grad_fn, axis: str = "slots"):
     spec = P(axis)
 
     def local(params, feats, tl, ta, nm, src, dst, em, w):
+        # differentiate w.r.t. a per-device (varying) view of the
+        # replicated params: the gradient of an UNVARYING input is
+        # reduced over the axis by shard_map's transpose itself, which
+        # would sum the per-slot grads across devices BEFORE the local
+        # slot weights apply, and the psum below would then count them
+        # n times over
+        local_params = jax.lax.pcast(params, axis, to="varying")
+
         def per_slot(f, l, a, m, wi):
-            (loss, (lat_l, ano_l)), g = grad_fn(params, f, src, dst, em, l, a, m)
+            (loss, (lat_l, ano_l)), g = grad_fn(
+                local_params, f, src, dst, em, l, a, m
+            )
             g = jax.tree_util.tree_map(lambda x: x * wi, g)
             return g, loss * wi, lat_l * wi, ano_l * wi
 
@@ -688,6 +679,7 @@ def make_sharded_slot_grad(mesh: Mesh, grad_fn, axis: str = "slots"):
     return batch_grads
 
 
+@programs.register("mesh.sharded_service_scores")
 @partial(jax.jit, static_argnames=("mesh", "num_services", "axis"))
 def sharded_service_scores(
     mesh: Mesh,

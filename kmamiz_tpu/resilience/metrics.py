@@ -41,6 +41,7 @@ _HANDLES: Dict[str, object] = {
     "ingestDropped": _slo.INGEST_DROPPED,
     "quarantined": _slo.QUARANTINED,
     "dpFallback": _FAM.handle("dpFallback"),
+    "scorerHostFallback": _FAM.handle("scorerHostFallback"),
     "walRecords": _FAM.handle("walRecords"),
     "walAppendErrors": _FAM.handle("walAppendErrors"),
     "walReplays": _FAM.handle("walReplays"),
@@ -223,6 +224,8 @@ def resilience_summary() -> dict:
         "counters": counters,
         "ingestDropped": counters.get("ingestDropped", 0),
         "dpFallback": counters.get("dpFallback", 0),
+        # device scorer raised and the API answered from the host path
+        "scorerHostFallback": counters.get("scorerHostFallback", 0),
     }
 
 

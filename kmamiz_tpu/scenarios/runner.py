@@ -324,7 +324,10 @@ def _run_kill9_child(spec: ScenarioSpec, wal_dir: str) -> dict:
         **os.environ,
         "KMAMIZ_WAL": "1",
         "KMAMIZ_WAL_DIR": wal_dir,
-        "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+        # deliberately a CPU process: this parent holds the accelerator
+        # (one process per chip), and what the child proves — append
+        # before merge, then SIGKILL — needs none
+        "JAX_PLATFORMS": "cpu",
     }
     child_env.pop("KMAMIZ_INGEST_MAX_BYTES", None)
     child = subprocess.run(
@@ -348,6 +351,7 @@ def _run_kill9_child(spec: ScenarioSpec, wal_dir: str) -> dict:
         timeout=SCENARIO_MAX_WALL_S,
     )
     return {
+        "platform": "cpu",
         "child_sigkilled": child.returncode == -signal.SIGKILL,
         "returncode": child.returncode,
         "stderr_tail": child.stderr.decode(errors="replace")[-400:],

@@ -327,10 +327,13 @@ def make_handler(processor: DataProcessor, router=None):
             if path == "/timings":
                 from kmamiz_tpu.analysis.concurrency import witness
                 from kmamiz_tpu.core.profiling import step_timer
+                from kmamiz_tpu.telemetry import device as tel_device
 
                 self._send_json(
                     200,
                     {
+                        # device, native, compileCache, sparse
+                        **tel_device.runtime_report(),
                         "phases": step_timer.summary(),
                         "programs": programs.summary(),
                         "resilience": res_metrics.resilience_summary(),
@@ -777,15 +780,14 @@ def main() -> None:
     (kmamiz_data_processor/src/env.rs): BIND_IP, DP_PORT, ZIPKIN_URL,
     KUBEAPI_HOST, IS_RUNNING_IN_K8S. Point a stock KMamiz install's
     EXTERNAL_DATA_PROCESSOR here."""
-    import os
+    import signal
 
+    from kmamiz_tpu.core import compile_cache
     from kmamiz_tpu.ingestion.kubernetes import KubernetesClient
     from kmamiz_tpu.ingestion.zipkin import ZipkinClient
 
     logging.basicConfig(level=os.environ.get("LOG_LEVEL", "INFO").upper())
-    from kmamiz_tpu.core import compile_cache
-
-    compile_cache.enable_from_env()
+    compile_cache.enable()  # before the first jit dispatch
     # arm the lock witness BEFORE the processor exists so every lock the
     # serving stack creates is wrapped (KMAMIZ_LOCK_WITNESS=1; the
     # scenario runner does the same for soaks — docs/STATIC_ANALYSIS.md)
@@ -824,7 +826,16 @@ def main() -> None:
         port=int(os.environ.get("DP_PORT", "8600")),
     )
     logger.info("external DP listening on %d", server.port)
+
+    def _stop(signum, frame):
+        # shutdown() blocks until serve_forever returns, and the handler
+        # runs ON the serving thread: hand it to another one
+        threading.Thread(target=server.stop, name="dp-stop").start()
+
+    signal.signal(signal.SIGTERM, _stop)
+    signal.signal(signal.SIGINT, _stop)
     server.serve_forever()
+    logger.info("external DP stopped")
 
 
 if __name__ == "__main__":

@@ -161,6 +161,13 @@ class Initializer:
     # -- startup (Initializer.ts:149-178) ------------------------------------
 
     def production_server_startup(self) -> None:
+        """Caches, base data, device warm-start, schedule registration.
+
+        The three jobs are registered, never started, here: the
+        application entry point starts them after first-time setup
+        (api/app.py) — a realtime tick that read the dependency cache
+        before the setup filled it would write its stale view back over
+        the backfill."""
         ctx = self._ctx
         self.register_data_caches()
 
@@ -183,9 +190,9 @@ class Initializer:
                     len(records),
                 )
             # pre-warm the merge programs at the restored capacity so the
-            # first tick never eats a mid-request compile wall (pair with
-            # KMAMIZ_COMPILE_CACHE_DIR to make restarts load these from
-            # disk; KMAMIZ_PREWARM=0 opts out)
+            # first tick never eats a mid-request compile wall (the
+            # persistent cache, core/compile_cache.py, makes restarts
+            # load these from disk; KMAMIZ_PREWARM=0 opts out)
             import os as _os
 
             if _os.environ.get("KMAMIZ_PREWARM", "1") != "0":
@@ -218,7 +225,6 @@ class Initializer:
             ctx.settings.dispatch_interval,
             ctx.dispatch.sync,
         )
-        ctx.scheduler.start()
 
     def simulation_server_startup(self) -> None:
         self.register_data_caches()

@@ -118,7 +118,9 @@ def _spawn_workers(man: SoakManifest, n: int, run_id: str, verbose: bool):
         **os.environ,
         "KMAMIZ_SOAK_RUN_ID": run_id,
         "KMAMIZ_PROF_FLIGHT_DIR": man.flights_dir,
-        "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+        # pool workers are N concurrent processes and a chip belongs to
+        # one: they are CPU processes by design, whatever the driver holds
+        "JAX_PLATFORMS": "cpu",
     }
     cmd = [sys.executable, "-m", "kmamiz_tpu.soak.worker", "--dir", man.root]
     if verbose:
@@ -232,6 +234,7 @@ def run_sweep(
     ]
     report["soak_dir"] = man.root
     report["run_id"] = run_id
+    report["platform"] = "cpu"  # what _spawn_workers pins the pool to
     report["cells_executed"] = len(executed)
     report["wall_s"] = round(wall_s, 1)
     report["cells_per_min"] = (

@@ -1,10 +1,17 @@
-"""Device telemetry: HBM residency gauges and on-demand profiler capture.
+"""Device telemetry: which device this process holds, HBM residency
+gauges and on-demand profiler capture.
 
-Two sources, merged at scrape time (never on the tick):
+`device_block()` is what every server reports about the accelerator it
+runs on (DP `/timings`, API `/api/v1/health`): platform, device kind,
+count, versions, whether the deployed path is sharding over a mesh, and
+`memory_stats()` of EVERY local device — so "everything landed on
+device 0" is seen rather than assumed.
 
-- `memory_stats()` from the first addressable device, where the backend
-  supports it (TPU does; CPU returns None) — bytes_in_use / peak /
-  limit as `kmamiz_device_*` gauges.
+Two gauge sources, merged at scrape time (never on the tick):
+
+- `memory_stats()` of every local device, where the backend supports it
+  (TPU does; CPU returns None) — bytes_in_use / peak / limit as
+  `kmamiz_device_*{device=...}` gauges.
 - Tracked arena sizes: device-resident subsystems (graph-store edge
   arena, endpoint metadata, staged streaming buffers, scorer caches)
   report their allocation sizes via `track_arena`, exported per-arena
@@ -21,7 +28,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, List, Optional
 
 from .registry import REGISTRY
 
@@ -30,14 +37,20 @@ _ARENA_BYTES = REGISTRY.gauge_family(
     "Tracked device-resident allocation bytes per arena",
     ("arena",),
 )
-_DEV_IN_USE = REGISTRY.gauge(
-    "kmamiz_device_bytes_in_use", "Device bytes in use (memory_stats)"
+_DEV_IN_USE = REGISTRY.gauge_family(
+    "kmamiz_device_bytes_in_use",
+    "Device bytes in use (memory_stats)",
+    ("device",),
 )
-_DEV_PEAK = REGISTRY.gauge(
-    "kmamiz_device_bytes_peak", "Peak device bytes in use (memory_stats)"
+_DEV_PEAK = REGISTRY.gauge_family(
+    "kmamiz_device_bytes_peak",
+    "Peak device bytes in use (memory_stats)",
+    ("device",),
 )
-_DEV_LIMIT = REGISTRY.gauge(
-    "kmamiz_device_bytes_limit", "Device memory limit (memory_stats)"
+_DEV_LIMIT = REGISTRY.gauge_family(
+    "kmamiz_device_bytes_limit",
+    "Device memory limit (memory_stats)",
+    ("device",),
 )
 
 _arena_sources: Dict[str, Callable[[], float]] = {}
@@ -54,16 +67,74 @@ def track_arena(name: str, size_fn: Callable[[], float]) -> None:
             _arena_handles[name] = _ARENA_BYTES.handle(name)
 
 
-def device_memory_stats() -> Optional[dict]:
-    try:
-        import jax
+def local_memory_stats() -> List[dict]:
+    """`memory_stats()` of every local device, one row per device
+    (``{"id": ...}`` alone where the backend reports none)."""
+    import jax
 
-        devs = jax.local_devices()
-        if not devs:
-            return None
-        return devs[0].memory_stats()
-    except Exception:
+    return [
+        {"id": int(d.id), **(d.memory_stats() or {})}
+        for d in jax.local_devices()
+    ]
+
+
+def device_memory_stats() -> Optional[dict]:
+    """Device 0's memory_stats (the per-tick HBM watermark sample)."""
+    import jax
+
+    return jax.local_devices()[0].memory_stats()
+
+
+def _libtpu_version() -> Optional[str]:
+    from importlib import metadata
+
+    try:
+        return metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
         return None
+
+
+def device_block() -> dict:
+    """The accelerator this process holds, as JAX reports it."""
+    import jax
+    import jaxlib
+
+    from kmamiz_tpu.parallel.mesh import active_mesh
+
+    first = jax.devices()[0]
+    mesh = active_mesh()
+    return {
+        "platform": first.platform,
+        "device_kind": first.device_kind,
+        "count": len(jax.devices()),
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "libtpu": _libtpu_version(),
+        # the deployed path shards over this mesh (None: single device)
+        "mesh": (
+            None
+            if mesh is None
+            else {name: int(n) for name, n in mesh.shape.items()}
+        ),
+        "memory": local_memory_stats(),
+    }
+
+
+def runtime_report() -> dict:
+    """What this process runs on and what it runs, for the servers'
+    timing routes and chip_smoke.py: the device as JAX reports it, the
+    native parser's provenance, where compiled programs are cached, and
+    which sparse kernels were routed where."""
+    from kmamiz_tpu import native
+    from kmamiz_tpu.core import compile_cache
+    from kmamiz_tpu.ops import sparse
+
+    return {
+        "device": device_block(),
+        "native": native.build_report(),
+        "compileCache": compile_cache.stats(),
+        "sparse": sparse.route_stats(),
+    }
 
 
 def _collect() -> None:
@@ -74,11 +145,11 @@ def _collect() -> None:
             _arena_handles[name].set(float(fn()))
         except Exception:
             pass
-    stats = device_memory_stats()
-    if stats:
-        _DEV_IN_USE.set(float(stats.get("bytes_in_use", 0) or 0))
-        _DEV_PEAK.set(float(stats.get("peak_bytes_in_use", 0) or 0))
-        _DEV_LIMIT.set(float(stats.get("bytes_limit", 0) or 0))
+    for row in local_memory_stats():
+        dev = str(row["id"])
+        _DEV_IN_USE.handle(dev).set(float(row.get("bytes_in_use", 0) or 0))
+        _DEV_PEAK.handle(dev).set(float(row.get("peak_bytes_in_use", 0) or 0))
+        _DEV_LIMIT.handle(dev).set(float(row.get("bytes_limit", 0) or 0))
 
 
 REGISTRY.register_callback(_collect)

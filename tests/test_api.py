@@ -522,6 +522,40 @@ class TestApplication:
             app.tear_down()
 
 
+    def test_schedules_start_only_after_first_time_setup(
+        self, pdas_traces, monkeypatch
+    ):
+        """A realtime tick that ran during first-time setup could write
+        its pre-setup view of the caches back over the backfill: the
+        jobs are registered before the setup and started after it."""
+        from kmamiz_tpu.server.initializer import Initializer
+
+        s = Settings()
+        s.external_data_processor = ""
+        s.storage_uri = "memory://"
+        processor = DataProcessor(
+            trace_source=lambda lb, t, lim: [], k8s_source=None
+        )
+        ctx = AppContext.build(
+            app_settings=s, store=MemoryStore(), processor=processor
+        )
+        seen = {}
+
+        def setup(self):
+            seen["jobs"] = set(ctx.scheduler.jobs)
+            seen["started_during_setup"] = ctx.scheduler._started
+
+        monkeypatch.setattr(Initializer, "first_time_setup", setup)
+        app = Application(app_settings=s, ctx=ctx)
+        app.start_up()
+        try:
+            assert seen["jobs"] == {"aggregation", "realtime", "dispatch"}
+            assert seen["started_during_setup"] is False
+            assert ctx.scheduler._started is True
+        finally:
+            ctx.scheduler.stop()
+
+
 class TestStaticServing:
     """The entry point serves the SPA build and the Envoy filter binary
     (reference index.ts:46-53)."""

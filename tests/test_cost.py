@@ -394,7 +394,6 @@ class TestGrowthProbe:
         # run_probe writes these; monkeypatch restores them afterwards
         monkeypatch.setenv("KMAMIZ_COST", "1")
         monkeypatch.setenv("KMAMIZ_COST_PREWARM", "sync")
-        monkeypatch.delenv("KMAMIZ_COMPILE_CACHE_DIR", raising=False)
         monkeypatch.delenv("KMAMIZ_SHAPE_HINTS", raising=False)
         from kmamiz_tpu.cost.growth_probe import run_probe
 

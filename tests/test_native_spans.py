@@ -690,33 +690,6 @@ class TestStreamingIngest:
             assert d["merge_ms"] >= d["transfer_ms"] >= 0
         assert out["drain_ms"] >= 0
 
-    def test_bench_critical_path_composition(self):
-        # unit-check the reconstruction formula against hand-walked
-        # schedules of the ingest_raw_stream dataflow
-        import bench
-
-        # parse-bound: merges are instant, so the pipeline is the parse
-        # chain end to end plus the drain
-        detail = [
-            {"parse_ms": 100.0, "merge_ms": 5.0, "transfer_ms": 5.0},
-            {"parse_ms": 100.0, "merge_ms": 5.0, "transfer_ms": 5.0},
-            {"parse_ms": 100.0, "merge_ms": 5.0, "transfer_ms": 5.0},
-        ]
-        # t=100 (parse0) -> merge0 free, parse1 done at 200, merge1 free,
-        # parse2 done at 300 -> +drain 10 = 310
-        assert bench.critical_path_ms(detail, 10.0) == 310.0
-
-        # merge-bound: parses hide entirely under merges
-        detail = [
-            {"parse_ms": 10.0, "merge_ms": 100.0, "transfer_ms": 20.0},
-            {"parse_ms": 10.0, "merge_ms": 100.0, "transfer_ms": 20.0},
-        ]
-        # t=10 (parse0) -> +80 merge0 = 90; parse1 done at 20 (hidden);
-        # +80 merge1 = 170; +drain 5 = 175
-        assert bench.critical_path_ms(detail, 5.0) == 175.0
-
-        assert bench.critical_path_ms([], 7.0) == 7.0
-
     def test_stream_dedup_across_chunks(self):
         from kmamiz_tpu.server.processor import DataProcessor
 

@@ -205,6 +205,9 @@ class TestInitializer:
                 "realtime",
                 "dispatch",
             }
+            # registered, not started: api/app.py starts them after
+            # first-time setup
+            assert ctx.scheduler._started is False
         finally:
             ctx.scheduler.stop()
 

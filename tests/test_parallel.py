@@ -790,6 +790,31 @@ class TestSlotDataParallel:
             float(loss_mesh), sum(ls) / 8.0, rtol=1e-5
         )
 
+        # padded slots carry weight 0 and must contribute nothing: each
+        # device weights ITS slots before anything crosses devices (the
+        # gradient of replicated params is taken w.r.t. a per-device
+        # view, parallel/mesh.py). Uniform weights cannot tell that apart
+        # from reducing the raw per-slot grads over the axis first.
+        weights = np.array([1, 1, 0, 1, 0, 0, 1, 1], dtype=np.float32)
+        g_mesh, loss_mesh, _, _ = bg(
+            params, feats[0], tl[0], ta[0], nm[0],
+            st.src, st.dst, st.edge_mask, jnp.asarray(weights),
+        )
+        kept = [i for i in range(8) if weights[i]]
+        g_ref = jax.tree_util.tree_map(
+            lambda *xs: sum(xs) / len(kept), *[gs[i] for i in kept]
+        )
+        for a, b in zip(
+            jax.tree_util.tree_leaves(g_mesh),
+            jax.tree_util.tree_leaves(g_ref),
+        ):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
+            )
+        np.testing.assert_allclose(
+            float(loss_mesh), sum(ls[i] for i in kept) / len(kept), rtol=1e-5
+        )
+
     def test_mesh_training_matches_one_device(self):
         from kmamiz_tpu.models import trainer
 

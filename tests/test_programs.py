@@ -144,8 +144,13 @@ class TestHints:
         ]
 
     def test_unconfigured_hints_are_inert(self, monkeypatch):
+        """A process that never enabled the persistent cache (library
+        use, this test run) keeps no hint file: hints live beside the
+        cache (tests/test_chip_bringup.py covers the enabled case)."""
+        from kmamiz_tpu.core import compile_cache
+
         monkeypatch.delenv("KMAMIZ_SHAPE_HINTS", raising=False)
-        monkeypatch.delenv("KMAMIZ_COMPILE_CACHE_DIR", raising=False)
+        assert not compile_cache.enabled()
         assert programs.hints_path() is None
         assert programs.save_hints() is None
         assert programs.load_hints() == {}

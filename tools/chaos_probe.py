@@ -41,7 +41,7 @@ import tempfile
 import time
 import urllib.request
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # clean chunks must fit under this while the plan's "bomb" payloads
 # (~4.1 KB, chaos.mutate_payload) overflow it
@@ -320,6 +320,9 @@ def pillar_wal_recovery(seed: int, tmpdir: str) -> dict:
         **os.environ,
         "KMAMIZ_WAL": "1",
         "KMAMIZ_WAL_DIR": wal_dir,
+        # deliberately a CPU process: this probe holds the accelerator
+        # (one process per chip) while the crash child runs
+        "JAX_PLATFORMS": "cpu",
     }
     child = subprocess.run(
         [
@@ -362,6 +365,7 @@ def pillar_wal_recovery(seed: int, tmpdir: str) -> dict:
             and replay["replayed"] == len(chunks)
             and recovered_sig == reference_sig
         ),
+        "child_platform": "cpu",
         "child_sigkilled": killed,
         "wal_records_replayed": replay["replayed"],
         "windows": len(chunks),

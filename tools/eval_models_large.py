@@ -13,10 +13,13 @@ anomalous, current slot clean) the model flags. Persistence scores 0
 there by construction — onset detection is precisely what a forecaster
 adds over "alert when it's already broken".
 
+Runs on whatever device JAX finds (set JAX_PLATFORMS=cpu for the CPU
+numbers MODELS.md quotes) and names it in its output.
+
 Usage:
-  JAX_PLATFORMS=cpu python tools/eval_models_large.py            # 1k ep
-  JAX_PLATFORMS=cpu python tools/eval_models_large.py --services 10
-  JAX_PLATFORMS=cpu python tools/eval_models_large.py --tenk     # wall-clock
+  python tools/eval_models_large.py            # 1k ep
+  python tools/eval_models_large.py --services 10
+  python tools/eval_models_large.py --tenk     # wall-clock
 """
 from __future__ import annotations
 
@@ -26,11 +29,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from eval_models import _force_cpu  # noqa: E402
-
-_force_cpu()
 
 import numpy as np  # noqa: E402
 import yaml  # noqa: E402
@@ -202,6 +200,13 @@ def make_mesh_config(
 
 
 # -- threshold-free + onset metrics -----------------------------------------
+
+
+def jax_device() -> str:
+    import jax
+
+    d = jax.devices()[0]
+    return f"{d.platform} {d.device_kind} x{len(jax.devices())}"
 
 
 def collect_scores(params, dataset, model):
@@ -638,7 +643,7 @@ def main() -> None:
         print(
             f"\n10k-endpoint wall-clock (BASELINE config 4 shape, 1 day): "
             f"simulate {gen_s:.1f}s, 1-epoch GraphSAGE train+eval {step_s:.1f}s "
-            f"(single CPU core; the TPU path trains the same jitted step)"
+            f"(on {jax_device()})"
         )
 
 

@@ -58,6 +58,9 @@ class _Worker:
         env = dict(os.environ)
         env["KMAMIZ_WAL"] = "1"
         env["KMAMIZ_WAL_DIR"] = os.path.join(wal_root, "workers", worker_id)
+        # four workers, one chip: until each worker owns a device
+        # (ROADMAP R8) they are CPU processes, and the result says so
+        env["JAX_PLATFORMS"] = "cpu"
         # workers are ingest-only here; keep their pollers/schedulers quiet
         env.setdefault("KMAMIZ_PROF", "0")
         self.proc = subprocess.Popen(
@@ -188,6 +191,7 @@ def main(argv=None) -> int:
         "fleet_migration_lost_spans": None,
         "fleet_migration_pass": None,
         "fleet_host_cores": os.cpu_count(),
+        "platform": "cpu",
     }
     workers = []
     with tempfile.TemporaryDirectory(prefix="fleet-bench-") as wal_root:

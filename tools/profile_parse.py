@@ -2,18 +2,19 @@
 
 Times kmamiz_tpu.native.parse_spans on the bench's 1.05M-span synthetic
 window across thread counts, printing per-rep walls plus the native phase
-breakdown, min and median. No jax import needed (bench.py's module level
-is jax-free; make_raw_window is imported from it so the profiled workload
-IS the headline workload).
+breakdown, min and median. No jax import needed (kmamiz_tpu.synth is
+jax-free; make_raw_window is the generator bench.py's headline uses, so
+the profiled workload IS the headline workload).
 """
 from __future__ import annotations
 
+import os
 import sys
 import time
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import make_raw_window  # noqa: E402
+from kmamiz_tpu.synth import make_raw_window  # noqa: E402
 from kmamiz_tpu import native as native_mod  # noqa: E402
 
 

@@ -1,30 +1,35 @@
 """Restart-warmth probe: boot a fresh process, pre-warm, time the first tick.
 
-Run twice against the same KMAMIZ_COMPILE_CACHE_DIR to measure the
-production restart story (VERDICT r4 #5b):
+Run twice against the same persistent cache directory (the one
+core/compile_cache.py resolves: JAX_COMPILATION_CACHE_DIR if set, else
+<checkout>/.xla-cache) to measure the production restart story
+(VERDICT r4 #5b):
 
   run 1 (cold cache): the boot prewarm plan pays the real compile walls,
   once, and autosaves the exercised bucket shapes into the shape-hint
-  file next to the cache dir (core/programs.py);
+  file beside the cache (core/programs.py);
   run 2 (warm cache): the plan replays exactly those hints — populating
   the jit dispatch caches from the persistent XLA cache — and the first
   tick runs with zero compile exposure.
 
-stdout carries ONE JSON line: {"prewarm_s": ..., "first_tick_ms": ...,
-"second_tick_ms": ..., "first_tick_new_compiles": ...,
-"second_tick_new_compiles": ..., "programs": {...}}. The per-program
-compile-count / compile-ms table goes to stderr. bench.py invokes this as
-a subprocess for the warm-boot extras; it is also a deployable smoke
-check (KMAMIZ_COMPILE_CACHE_DIR=/var/cache/kmamiz python
+stdout carries ONE JSON line: {"platform": ..., "prewarm_s": ...,
+"first_tick_ms": ..., "second_tick_ms": ..., "first_tick_new_compiles":
+..., "second_tick_new_compiles": ..., "compile_cache": {dir, hits,
+misses}, "programs": {...}}. The per-program compile-count / compile-ms
+table goes to stderr. bench.py runs this as a sibling process for the
+warm-boot extras, pointing both arms at a fixed subdirectory that it
+empties before the cold arm; it is also a deployable smoke check
+(JAX_COMPILATION_CACHE_DIR=/var/cache/kmamiz python
 tools/warm_boot_probe.py).
 """
 from __future__ import annotations
 
 import json
+import os
 import sys
 import time
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _print_program_table(summary: dict) -> None:
@@ -60,7 +65,7 @@ def _print_program_table(summary: dict) -> None:
 def main() -> None:
     from kmamiz_tpu.core import compile_cache, programs
 
-    compile_cache.enable_from_env()
+    compile_cache.enable()
 
     from kmamiz_tpu.server.processor import DataProcessor
     from kmamiz_tpu.synth import make_raw_window
@@ -97,9 +102,13 @@ def main() -> None:
 
     summary = programs.summary()
     _print_program_table(summary)
+    import jax
+
     print(
         json.dumps(
             {
+                "platform": jax.default_backend(),
+                "compile_cache": compile_cache.stats(),
                 "prewarm_s": round(prewarm_s, 1),
                 "prewarm_programs": report["warmed"]
                 + report["defaultGraphPrograms"],

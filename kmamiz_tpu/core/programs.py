@@ -43,6 +43,7 @@ import logging
 import os
 import threading
 import time
+from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from kmamiz_tpu.core import compile_cache
@@ -59,6 +60,7 @@ except Exception:  # noqa: BLE001 - profiling is optional at this layer
     _prof_device_attr = None
 
 _MAX_HINTS_PER_PROGRAM = 16
+_RECENT_RUNS = 64
 
 _registry_lock = threading.Lock()
 _REGISTRY: Dict[str, "Program"] = {}
@@ -196,7 +198,17 @@ class Program:
         self.last_compile_ms = 0.0
         self.prewarmed = 0
         self.prewarm_ms = 0.0
-        self.run_ewma_ms = 0.0  # warm-dispatch wall EWMA (graftcost label)
+        # warm-DISPATCH wall EWMA (graftcost's label): the time the call
+        # took to return, not the time the program ran on the device
+        self.run_ewma_ms = 0.0
+        # measured run time, reported by a caller that already fences
+        # (note_run): wall from the dispatch to the result on the host
+        self.runs = 0
+        self.run_ms = 0.0
+        self.last_run_ms = 0.0
+        # the last runs as (end_s on perf_counter, run_ms, units), for a
+        # reader that wants those of one window
+        self._recent_runs: deque = deque(maxlen=_RECENT_RUNS)
         self._specs: Dict[str, Any] = {}  # canonical json -> spec
         # canonical json -> (spec, compile_ms, run_ms): the cost-model
         # training labels (run_ms 0.0 until a warm call lands)
@@ -221,8 +233,9 @@ class Program:
                 self.compile_ms += elapsed_ms
                 self.last_compile_ms = elapsed_ms
             elif before is not None:
-                # warm dispatch: the per-program run-cost label the
-                # graftcost regressor trains its run-ms head on
+                # warm dispatch: the wall of the call's return, which the
+                # graftcost regressor trains its run-ms head on (a
+                # program's measured run time is note_run's)
                 self.run_ewma_ms = (
                     elapsed_ms
                     if self.run_ewma_ms == 0.0
@@ -237,6 +250,26 @@ class Program:
 
     def __getattr__(self, item):
         return getattr(self.fn, item)
+
+    # -- measured run time --------------------------------------------------
+    def note_run(self, run_ms: float, units: int = 0) -> None:
+        """Report one run of this program, by the caller that already
+        waits for its result and as soon as it has it (the registry adds
+        no fence of its own): `run_ms` is the wall from the dispatch to
+        the result on the host, `units` the work the run held (the slot
+        updates of an epoch block)."""
+        end_s = time.perf_counter()  # graftlint: disable=hot-path-clock -- once per fenced run, beside the caller's own wait
+        with self._lock:
+            self.runs += 1
+            self.run_ms += run_ms
+            self.last_run_ms = run_ms
+            self._recent_runs.append((end_s, run_ms, units))
+
+    def recent_runs(self) -> List[Tuple[float, float, int]]:
+        """The last reported runs, oldest first: (end_s on
+        `time.perf_counter`, run_ms, units)."""
+        with self._lock:
+            return list(self._recent_runs)
 
     def _cache_entries(self) -> Optional[int]:
         try:
@@ -293,7 +326,8 @@ class Program:
     def labels(self) -> List[Tuple[Any, float, float]]:
         """(spec, compile_ms, run_ms) rows observed by this process plus
         adopted history. A live row whose warm wall hasn't landed yet
-        borrows the program-level run EWMA."""
+        borrows the program-level EWMA. `run_ms` here is the wall of a
+        warm DISPATCH, not the measured run time `note_run` keeps."""
         with self._lock:
             ewma = self.run_ewma_ms
             return [
@@ -351,6 +385,10 @@ class Program:
 
     # -- reporting ----------------------------------------------------------
     def stats(self) -> dict:
+        """The /timings row. `runEwmaMs` is the wall of a warm DISPATCH
+        (the call's return: under a millisecond for an epoch block that
+        runs for seconds); `runs` / `runMs` / `lastRunMs` are the
+        measured run time that fencing callers report (`note_run`)."""
         with self._lock:
             return {
                 "calls": self.calls,
@@ -360,6 +398,9 @@ class Program:
                 "prewarmed": self.prewarmed,
                 "prewarmMs": round(self.prewarm_ms, 1),
                 "runEwmaMs": round(self.run_ewma_ms, 3),
+                "runs": self.runs,
+                "runMs": round(self.run_ms, 3),
+                "lastRunMs": round(self.last_run_ms, 3),
                 "cacheSize": self._cache_entries(),
                 "buckets": [_bucket_label(s) for s in self._specs.values()],
             }
@@ -837,10 +878,15 @@ REGISTERED_JIT_SITES: Dict[str, set] = {
     # graftcost continual trainer (registered as cost.ridge_fit)
     "kmamiz_tpu/cost/model.py": {"_ridge_fit"},
     # scanner resolves inline jits to the nearest def: "fwd" is the
-    # body _jitted_forward jits (registered as models.forecast_forward),
-    # "run" the epoch blocks of epoch_runner/dp_epoch_runner
+    # body _jitted_forward jits (registered as models.forecast_forward)
     "kmamiz_tpu/models/serving.py": {"fwd"},
-    "kmamiz_tpu/models/stacked.py": {"run", "_batched_forward"},
+    # named after their registry base, so that the device module reads
+    # jit_<base> in a profiler capture
+    "kmamiz_tpu/models/stacked.py": {
+        "sage_epoch_block",
+        "sage_dp_epoch_block",
+        "batched_forward",
+    },
     # STLGT: "run" is the continual-refresh epoch block (registered as
     # models.stlgt_epoch_block), "fwd" the quantile serving forward
     # (models.stlgt_quantile_forward)

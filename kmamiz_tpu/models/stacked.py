@@ -50,6 +50,17 @@ import optax
 from kmamiz_tpu.core import programs
 from kmamiz_tpu.core.spans import _pad_size
 from kmamiz_tpu.models import common
+from kmamiz_tpu.telemetry.registry import REGISTRY
+from kmamiz_tpu.telemetry.tracing import TRACER, operation_span, phase_span
+
+_STACK_BUILDS = REGISTRY.counter(
+    "kmamiz_model_stack_builds_total",
+    "stack_dataset calls that built and uploaded the stack",
+)
+_STACK_HITS = REGISTRY.counter(
+    "kmamiz_model_stack_hits_total",
+    "stack_dataset calls served by the stack memoised on the dataset",
+)
 
 
 def _resolve_epoch_runner(key: str):
@@ -139,11 +150,29 @@ def stack_dataset(dataset) -> StackedDataset:
     re-staging S slots each time. Node and edge counts pad to power-of-two
     buckets (graph-store capacity discipline) with False masks, so padded
     rows contribute nothing and bucket-shaped programs are shared across
-    datasets of the same bucket."""
-    cached = getattr(dataset, "_stacked_cache", None)
-    if cached is not None and cached.layout() == dataset_layout(dataset):
-        return cached
+    datasets of the same bucket.
 
+    Traced as `refresh.stack` (a trace of its own when called alone, a
+    child of `refresh.train` inside a refresh), a build split into
+    `refresh.stack.host_fill` and `refresh.stack.device_put`. The
+    spans end where the calls return: the wait for the transfer is
+    whoever blocks next."""
+    with operation_span("refresh.stack"):
+        cached = getattr(dataset, "_stacked_cache", None)
+        if cached is not None and cached.layout() == dataset_layout(dataset):
+            _STACK_HITS.inc()
+            TRACER.note(hit=1)
+            return cached
+        _STACK_BUILDS.inc()
+        stacked = _build_stack(dataset)
+        try:
+            dataset._stacked_cache = stacked
+        except (AttributeError, TypeError):  # frozen/slotted containers
+            pass
+        return stacked
+
+
+def _build_stack(dataset) -> StackedDataset:
     s = len(dataset.features)
     n = dataset.num_nodes
     f = (
@@ -154,41 +183,44 @@ def stack_dataset(dataset) -> StackedDataset:
     e = int(np.asarray(dataset.src).shape[0])
     nb, eb = _pad_size(n), _pad_size(e)
 
-    feats = np.zeros((s, nb, f), dtype=np.float32)
-    t_lat = np.zeros((s, nb), dtype=np.float32)
-    t_ano = np.zeros((s, nb), dtype=np.float32)
-    n_mask = np.zeros((s, nb), dtype=bool)
-    for i in range(s):
-        feats[i, :n] = np.asarray(dataset.features[i], dtype=np.float32)
-        t_lat[i, :n] = np.asarray(dataset.target_latency[i], dtype=np.float32)
-        t_ano[i, :n] = np.asarray(dataset.target_anomaly[i], dtype=np.float32)
-        n_mask[i, :n] = np.asarray(dataset.node_mask[i], dtype=bool)
+    with phase_span("refresh.stack.host_fill"):
+        feats = np.zeros((s, nb, f), dtype=np.float32)
+        t_lat = np.zeros((s, nb), dtype=np.float32)
+        t_ano = np.zeros((s, nb), dtype=np.float32)
+        n_mask = np.zeros((s, nb), dtype=bool)
+        for i in range(s):
+            feats[i, :n] = np.asarray(dataset.features[i], dtype=np.float32)
+            t_lat[i, :n] = np.asarray(dataset.target_latency[i], dtype=np.float32)
+            t_ano[i, :n] = np.asarray(dataset.target_anomaly[i], dtype=np.float32)
+            n_mask[i, :n] = np.asarray(dataset.node_mask[i], dtype=bool)
 
-    src = np.zeros(eb, dtype=np.int32)
-    dst = np.zeros(eb, dtype=np.int32)
-    e_mask = np.zeros(eb, dtype=bool)
-    src[:e] = np.asarray(dataset.src, dtype=np.int32)
-    dst[:e] = np.asarray(dataset.dst, dtype=np.int32)
-    e_mask[:e] = np.asarray(dataset.edge_mask, dtype=bool)
-
-    stacked = StackedDataset(
-        features=jnp.asarray(feats),
-        target_latency=jnp.asarray(t_lat),
-        target_anomaly=jnp.asarray(t_ano),
-        node_mask=jnp.asarray(n_mask),
-        src=jnp.asarray(src),
-        dst=jnp.asarray(dst),
-        edge_mask=jnp.asarray(e_mask),
-        num_slots=s,
-        num_nodes=n,
-        num_edges=e,
-        bucket_nodes=nb,
-        bucket_edges=eb,
-    )
-    try:
-        dataset._stacked_cache = stacked
-    except (AttributeError, TypeError):  # frozen/slotted containers
-        pass
+        src = np.zeros(eb, dtype=np.int32)
+        dst = np.zeros(eb, dtype=np.int32)
+        e_mask = np.zeros(eb, dtype=bool)
+        src[:e] = np.asarray(dataset.src, dtype=np.int32)
+        dst[:e] = np.asarray(dataset.dst, dtype=np.int32)
+        e_mask[:e] = np.asarray(dataset.edge_mask, dtype=bool)
+        nbytes = sum(
+            a.nbytes for a in (feats, t_lat, t_ano, n_mask, src, dst, e_mask)
+        )
+        TRACER.note(bytes=nbytes)
+    with phase_span("refresh.stack.device_put"):
+        stacked = StackedDataset(
+            features=jnp.asarray(feats),
+            target_latency=jnp.asarray(t_lat),
+            target_anomaly=jnp.asarray(t_ano),
+            node_mask=jnp.asarray(n_mask),
+            src=jnp.asarray(src),
+            dst=jnp.asarray(dst),
+            edge_mask=jnp.asarray(e_mask),
+            num_slots=s,
+            num_nodes=n,
+            num_edges=e,
+            bucket_nodes=nb,
+            bucket_edges=eb,
+        )
+        TRACER.note(bytes=nbytes)
+    TRACER.note(hit=0, bytes=nbytes)  # on refresh.stack
     return stacked
 
 
@@ -217,7 +249,7 @@ def epoch_runner(model, lr: float, pos_weight: float):
         static_argnames=("n_epochs",),
         donate_argnames=("params", "opt_state"),
     )
-    def run(
+    def sage_epoch_block(
         params,
         opt_state,
         features,
@@ -255,7 +287,7 @@ def epoch_runner(model, lr: float, pos_weight: float):
     return programs.register_instance(
         "models.sage_epoch_block",
         f"{model.__name__}|{lr}|{pos_weight}",
-        run,
+        sage_epoch_block,
     )
 
 
@@ -340,7 +372,7 @@ def dp_epoch_runner(
         static_argnames=("n_epochs",),
         donate_argnames=("params", "opt_state"),
     )
-    def run(
+    def sage_dp_epoch_block(
         params,
         opt_state,
         b_features,  # [n_batches, B, Nb, F]
@@ -393,9 +425,9 @@ def dp_epoch_runner(
         return programs.register_instance(
             "models.sage_dp_epoch_block",
             f"{model.__name__}|{lr}|{pos_weight}|{axis}",
-            run,
+            sage_dp_epoch_block,
         )
-    return run
+    return sage_dp_epoch_block
 
 
 # ---------------------------------------------------------------------------
@@ -405,12 +437,16 @@ def dp_epoch_runner(
 
 @functools.lru_cache(maxsize=16)
 def _batched_forward(model):
+    # a function of its own, named after its registry base as the epoch
+    # blocks are: the device module reads jit_batched_forward in a trace
+    @jax.jit
+    def batched_forward(params, features, src, dst, edge_mask):
+        return jax.vmap(model.forward, in_axes=(None, 0, None, None, None))(
+            params, features, src, dst, edge_mask
+        )
+
     return programs.register_instance(
-        "models.batched_forward",
-        model.__name__,
-        jax.jit(
-            jax.vmap(model.forward, in_axes=(None, 0, None, None, None))
-        ),
+        "models.batched_forward", model.__name__, batched_forward
     )
 
 

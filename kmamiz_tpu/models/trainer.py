@@ -24,12 +24,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kmamiz_tpu.core import programs
 from kmamiz_tpu.models import graphsage
 from kmamiz_tpu.models import stacked as stacked_mod
 from kmamiz_tpu.simulator.naming import extract_unique_service_name
 from kmamiz_tpu.simulator.slot_metrics import parse_slot_key
+from kmamiz_tpu.telemetry.profiling import events as prof_events
+from kmamiz_tpu.telemetry.registry import REGISTRY
+from kmamiz_tpu.telemetry.tracing import TRACER, operation_span, phase_span
 
 logger = logging.getLogger("kmamiz_tpu.models.trainer")
+
+_REFRESHES = REGISTRY.counter(
+    "kmamiz_model_refresh_total", "Model refreshes started (trainer.train calls)"
+)
+_SLOT_UPDATES = REGISTRY.counter(
+    "kmamiz_model_refresh_slot_updates_total",
+    "Slots taken through an optimizer update by model refreshes (epochs x slots)",
+)
+_EPOCH_BLOCKS = REGISTRY.counter(
+    "kmamiz_model_refresh_epoch_blocks_total",
+    "Fused epoch-block programs dispatched by model refreshes",
+)
 
 ANOMALY_ERROR_SHARE = 0.10  # next-slot 5xx share that counts as anomalous
 SLOT_SECONDS = 3600.0  # simulator slots are hourly
@@ -190,6 +206,7 @@ def _epoch_blocks(start: int, total: int, every: int) -> List[Tuple[int, int]]:
     return blocks
 
 
+@operation_span("refresh.train")
 def train(
     dataset: GraphDataset,
     epochs: int = 30,
@@ -222,7 +239,12 @@ def train(
     (kmamiz_tpu.models.checkpoint) and snapshots every checkpoint_every
     epochs (0 = only at the end) plus at the end. Resuming validates the
     saved hyperparameters against the requested ones, and the saved
-    stacked layout (node/edge buckets, slot count) against the dataset's."""
+    stacked layout (node/edge buckets, slot count) against the dataset's.
+
+    Traced as `refresh.train`: a trace of its own, or a child of the
+    tick that called it (telemetry/tracing.operation_span), with a
+    `refresh.*` span around every stretch below. Every span ends where
+    the code already returns or already waits; none adds a sync."""
     from kmamiz_tpu.models import checkpoint as ckpt
 
     if fused is None:
@@ -231,6 +253,16 @@ def train(
             "off",
             "false",
         )
+    model_name = model.__name__.rsplit(".", 1)[-1]
+    num_slots = len(dataset.features) if dataset is not None else 0
+    _REFRESHES.inc()
+    TRACER.note(
+        model=model_name,
+        epochs=epochs,
+        slots=num_slots,
+        batch_slots=batch_slots,
+        fused=int(bool(fused)),
+    )
 
     # node-identity embeddings are OPT-IN: on the small simulator meshes
     # they overfit (held-out F1 drops ~0.02 and latency MAE inflates ~17x
@@ -247,89 +279,95 @@ def train(
         if dataset is not None and dataset.features
         else model.NUM_FEATURES
     )
-    params = model.init_params(
-        jax.random.PRNGKey(seed),
-        hidden=hidden,
-        num_features=num_features,
-        num_nodes=num_nodes,
-    )
-    optimizer = model.make_optimizer(lr)
-    opt_state = optimizer.init(params)
+    with phase_span("refresh.init"):
+        params = model.init_params(
+            jax.random.PRNGKey(seed),
+            hidden=hidden,
+            num_features=num_features,
+            num_nodes=num_nodes,
+        )
+        optimizer = model.make_optimizer(lr)
+        opt_state = optimizer.init(params)
 
     start_epoch = 0
     if checkpoint_dir:
-        # resolve the resume step ONCE (guard/validate/restore must agree
-        # even if another instance writes meanwhile); incomplete saves
-        # (dir without sidecar) fall back to the previous complete step
-        resume_step = ckpt.latest_complete_step(checkpoint_dir)
-        if resume_step is None and ckpt.latest_step(checkpoint_dir) is not None:
-            logger.warning(
-                "checkpoint dir %s has only incomplete saves; starting fresh",
-                checkpoint_dir,
-            )
-        if resume_step is not None:
-            # validate hyperparameters BEFORE restoring: orbax would
-            # silently return the saved shapes against a mismatched template
-            meta = ckpt.load_metadata(checkpoint_dir, resume_step) or {}
-            if meta.get("num_features") is None:
-                raise ValueError(
-                    f"checkpoint {checkpoint_dir} step {resume_step} was "
-                    "saved before the 10-feature layout (no num_features in "
-                    "metadata) and cannot restore into the current model; "
-                    "delete the directory or retrain"
+        with phase_span("refresh.resume"):
+            # resolve the resume step ONCE (guard/validate/restore must agree
+            # even if another instance writes meanwhile); incomplete saves
+            # (dir without sidecar) fall back to the previous complete step
+            resume_step = ckpt.latest_complete_step(checkpoint_dir)
+            if resume_step is None and ckpt.latest_step(checkpoint_dir) is not None:
+                logger.warning(
+                    "checkpoint dir %s has only incomplete saves; starting fresh",
+                    checkpoint_dir,
                 )
-            model_name = model.__name__.rsplit(".", 1)[-1]
-            for name, want in (
-                ("hidden", hidden),
-                ("lr", lr),
-                ("seed", seed),
-                ("model", model_name),
-                ("num_features", num_features),
-                ("num_nodes", num_nodes),
-            ):
-                saved = meta.get(name)
-                if saved is None:
-                    raise ValueError(
-                        f"checkpoint {checkpoint_dir} step {resume_step} "
-                        f"metadata lacks '{name}'; was it saved outside "
-                        "trainer.train()?"
-                    )
-                if saved != want:
-                    raise ValueError(
-                        f"checkpoint {checkpoint_dir} was trained with "
-                        f"{name}={saved}, requested {name}={want}"
-                    )
-            # the stacked layout (node/edge capacity buckets + slot count)
-            # is part of the training schedule: resuming against a dataset
-            # that stacks differently would silently change which compiled
-            # program and which slot sequence the remaining epochs run
-            saved_layout = meta.get("stacked")
-            if saved_layout is not None and dataset is not None:
-                current_layout = stacked_mod.dataset_layout(dataset)
-                if dict(saved_layout) != current_layout:
+            if resume_step is not None:
+                # validate hyperparameters BEFORE restoring: orbax would
+                # silently return the saved shapes against a mismatched template
+                meta = ckpt.load_metadata(checkpoint_dir, resume_step) or {}
+                if meta.get("num_features") is None:
                     raise ValueError(
                         f"checkpoint {checkpoint_dir} step {resume_step} was "
-                        f"saved with stacked layout {dict(saved_layout)} but "
-                        f"the dataset stacks to {current_layout}; resume "
-                        "needs the same node/edge buckets and slot count "
-                        "(retrain, or rebuild the matching dataset)"
+                        "saved before the 10-feature layout (no num_features in "
+                        "metadata) and cannot restore into the current model; "
+                        "delete the directory or retrain"
                     )
-            restored = ckpt.restore_checkpoint(
-                checkpoint_dir, params, opt_state, step=resume_step
-            )
-            if restored is not None:
-                params, opt_state, meta = restored
-                start_epoch = int(meta.get("step", 0))
+                for name, want in (
+                    ("hidden", hidden),
+                    ("lr", lr),
+                    ("seed", seed),
+                    ("model", model_name),
+                    ("num_features", num_features),
+                    ("num_nodes", num_nodes),
+                ):
+                    saved = meta.get(name)
+                    if saved is None:
+                        raise ValueError(
+                            f"checkpoint {checkpoint_dir} step {resume_step} "
+                            f"metadata lacks '{name}'; was it saved outside "
+                            "trainer.train()?"
+                        )
+                    if saved != want:
+                        raise ValueError(
+                            f"checkpoint {checkpoint_dir} was trained with "
+                            f"{name}={saved}, requested {name}={want}"
+                        )
+                # the stacked layout (node/edge capacity buckets + slot count)
+                # is part of the training schedule: resuming against a dataset
+                # that stacks differently would silently change which compiled
+                # program and which slot sequence the remaining epochs run
+                saved_layout = meta.get("stacked")
+                if saved_layout is not None and dataset is not None:
+                    current_layout = stacked_mod.dataset_layout(dataset)
+                    if dict(saved_layout) != current_layout:
+                        raise ValueError(
+                            f"checkpoint {checkpoint_dir} step {resume_step} was "
+                            f"saved with stacked layout {dict(saved_layout)} but "
+                            f"the dataset stacks to {current_layout}; resume "
+                            "needs the same node/edge buckets and slot count "
+                            "(retrain, or rebuild the matching dataset)"
+                        )
+                restored = ckpt.restore_checkpoint(
+                    checkpoint_dir, params, opt_state, step=resume_step
+                )
+                if restored is not None:
+                    params, opt_state, meta = restored
+                    start_epoch = int(meta.get("step", 0))
+            TRACER.note(resumed_from=start_epoch)
 
     # balance the rare positive class: weight by the inverse base rate of
     # the training slots (clipped; 1.0 when no positives exist)
-    pos = sum(
-        float((np.asarray(a) * np.asarray(m)).sum())
-        for a, m in zip(dataset.target_anomaly, dataset.node_mask)
-    )
-    tot = sum(float(np.asarray(m).sum()) for m in dataset.node_mask)
-    base_rate = pos / tot if tot else 0.0
-    pos_weight = float(np.clip(1.0 / base_rate, 1.0, 20.0)) if base_rate else 1.0
+    with phase_span("refresh.pos_weight"):
+        pos = sum(
+            float((np.asarray(a) * np.asarray(m)).sum())
+            for a, m in zip(dataset.target_anomaly, dataset.node_mask)
+        )
+        tot = sum(float(np.asarray(m).sum()) for m in dataset.node_mask)
+        base_rate = pos / tot if tot else 0.0
+        pos_weight = (
+            float(np.clip(1.0 / base_rate, 1.0, 20.0)) if base_rate else 1.0
+        )
+        TRACER.note(slots=len(dataset.node_mask), value=pos_weight)
 
     def metadata(last_loss):
         return {
@@ -337,11 +375,22 @@ def train(
             "hidden": hidden,
             "lr": lr,
             "seed": seed,
-            "model": model.__name__.rsplit(".", 1)[-1],
+            "model": model_name,
             "num_features": num_features,
             "num_nodes": num_nodes,
             "stacked": stacked_mod.dataset_layout(dataset),
         }
+
+    def save(step, last_loss):
+        with phase_span("refresh.checkpoint_save"):
+            ckpt.save_checkpoint(
+                checkpoint_dir,
+                params,
+                opt_state,
+                step=step,
+                metadata=metadata(last_loss),
+            )
+            TRACER.note(step=step)
 
     losses, lat_losses, ano_losses = [], [], []
     if fused and dataset.features:
@@ -376,40 +425,49 @@ def train(
 
         save_every = checkpoint_every if checkpoint_dir else 0
         for e0, e1 in _epoch_blocks(start_epoch, epochs, save_every):
-            params, opt_state, block = run_block(params, opt_state, e1 - e0)
-            block = np.asarray(block, dtype=np.float64)  # [e1-e0, 3]
+            slot_updates = (e1 - e0) * st.num_slots
+            dispatched_ns = prof_events.now_ns()
+            with phase_span("refresh.epoch_block"):
+                params, opt_state, block = run_block(params, opt_state, e1 - e0)
+                TRACER.note(epochs=e1 - e0, slot_updates=slot_updates)
+            # the fence that was always here: the host waits for the device
+            with phase_span("refresh.loss_fetch"):
+                block = np.asarray(block, dtype=np.float64)  # [e1-e0, 3]
+            if isinstance(runner, programs.Program):  # a mesh runner is none
+                runner.note_run(
+                    (prof_events.now_ns() - dispatched_ns) / 1e6, slot_updates
+                )
+            _EPOCH_BLOCKS.inc()
+            _SLOT_UPDATES.inc(slot_updates)
             losses.extend(block[:, 0].tolist())
             lat_losses.extend(block[:, 1].tolist())
             ano_losses.extend(block[:, 2].tolist())
             if checkpoint_dir:
-                ckpt.save_checkpoint(
-                    checkpoint_dir,
-                    params,
-                    opt_state,
-                    step=e1,
-                    metadata=metadata(losses[-1]),
-                )
+                save(e1, losses[-1])
         return TrainResult(params, losses, lat_losses, ano_losses)
 
     step = model.make_train_step(optimizer, pos_weight=pos_weight)
     for epoch in range(start_epoch, epochs):
         epoch_loss = epoch_lat = epoch_ano = 0.0
-        for i in range(len(dataset.features)):
-            params, opt_state, loss, (lat_l, ano_l) = step(
-                params,
-                opt_state,
-                dataset.features[i],
-                dataset.src,
-                dataset.dst,
-                dataset.edge_mask,
-                dataset.target_latency[i],
-                dataset.target_anomaly[i],
-                dataset.node_mask[i],
-            )
-            epoch_loss += float(loss)
-            epoch_lat += float(lat_l)
-            epoch_ano += float(ano_l)
-        slots = max(len(dataset.features), 1)
+        with phase_span("refresh.legacy_epoch"):
+            for i in range(num_slots):
+                params, opt_state, loss, (lat_l, ano_l) = step(
+                    params,
+                    opt_state,
+                    dataset.features[i],
+                    dataset.src,
+                    dataset.dst,
+                    dataset.edge_mask,
+                    dataset.target_latency[i],
+                    dataset.target_anomaly[i],
+                    dataset.node_mask[i],
+                )
+                epoch_loss += float(loss)
+                epoch_lat += float(lat_l)
+                epoch_ano += float(ano_l)
+            TRACER.note(slots=num_slots)
+        _SLOT_UPDATES.inc(num_slots)
+        slots = max(num_slots, 1)
         losses.append(epoch_loss / slots)
         lat_losses.append(epoch_lat / slots)
         ano_losses.append(epoch_ano / slots)
@@ -417,13 +475,7 @@ def train(
             (checkpoint_every > 0 and (epoch + 1) % checkpoint_every == 0)
             or epoch + 1 == epochs
         ):
-            ckpt.save_checkpoint(
-                checkpoint_dir,
-                params,
-                opt_state,
-                step=epoch + 1,
-                metadata=metadata(losses[-1]),
-            )
+            save(epoch + 1, losses[-1])
     return TrainResult(params, losses, lat_losses, ano_losses)
 
 

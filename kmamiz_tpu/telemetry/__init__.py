@@ -5,16 +5,18 @@ Four parts behind one package:
 - `registry`  — unified metrics registry (counters / gauges /
   fixed-bucket histograms, preallocated handles, Prometheus text
   exposition at `GET /metrics`).
-- `tracing`   — per-tick span traces in a ring, exported as Zipkin v2
-  JSON at `GET /debug/traces`; the processor can re-ingest its own
-  export (self-trace).
+- `tracing`   — span traces of each tick and each model refresh in a
+  ring, exported as Zipkin v2 JSON at `GET /debug/traces`; the processor
+  can re-ingest its own export (self-trace). Every span is also a
+  `jax.profiler.TraceAnnotation`, so a profiler capture shows the
+  program's spans on the device's clock.
 - `device`    — HBM/arena residency gauges and the on-demand
   `POST /debug/profile` jax.profiler capture.
 - `slo`       — the rolling SLO scorecard bench.py emits as headline
   keys and `tools/slo_report.py` gates on.
 - `profiling` — graftprof: the lock-free host event ring, native
-  parse/merge contention counters, device attribution, and the
-  SLO-breach flight recorder (`GET /debug/graftprof`,
+  parse/merge contention counters, the compile-cause log and HBM
+  timeline, and the SLO-breach flight recorder (`GET /debug/graftprof`,
   tools/graftprof.py).
 
 `KMAMIZ_TELEMETRY=0` disables span capture; the metrics registry stays

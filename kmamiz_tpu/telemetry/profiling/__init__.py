@@ -8,8 +8,7 @@ off-hot-path:
 - `native_counters` — the C++ parse/merge contention counters
   (per-shard parse ns, merge lock-wait ns, claim contention, intern
   probe stats) surfaced as registry families and per-tick ring deltas.
-- `device_attr` — compile-cause log, HBM watermark timeline, and the
-  jax.profiler capture join back to named programs.
+- `device_attr` — compile-cause log and HBM watermark timeline.
 - `recorder` — the SLO-breach flight recorder (watchdog trip, breaker
   open, scenario gate failure freeze the last-N-ticks of evidence).
 - `report` — profile condensation, text rendering, and per-phase

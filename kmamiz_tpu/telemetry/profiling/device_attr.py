@@ -1,6 +1,6 @@
-"""Device-plane attribution: kernel costs joined back to named programs.
+"""Device-plane attribution: what compiled, and what the device held.
 
-Three sources, all cold-path:
+Two sources, both cold-path:
 
 - **Compile-cause log** — `core/programs.py` reports every cache-entry
   growth (a real XLA compile) via `note_compile`; the ring here keeps
@@ -10,21 +10,20 @@ Three sources, all cold-path:
   gauges (`telemetry/device.device_memory_stats`), ring-buffered as
   ``(tick_id, bytes_in_use, peak_bytes)`` — the flight recorder freezes
   it next to the host events.
-- **jax.profiler trace join** — `parse_profile_dir` walks a capture
-  directory (the `POST /debug/profile` output), aggregates device-op
-  durations from the Chrome-trace/`.trace.json(.gz)` files, and joins
-  ``jit_<name>`` kernels back to `core/programs.py` registry entries,
-  mirrored as `kmamiz_prof_program_device_ms` gauges.
+
+Device time per program is read from a profiler capture, not here:
+`POST /debug/profile` writes the `.xplane.pb` that xprof/TensorBoard
+opens, in which a device module carries its program's name
+(`jit_sage_epoch_block`) and the tracer's spans lie beside it on the
+device's clock (telemetry/tracing.py); a program's measured run time is
+in the registry (`core/programs.Program.note_run`).
 """
 from __future__ import annotations
 
-import gzip
-import json
-import os
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import List
 
 from ..registry import REGISTRY
 from . import events
@@ -39,11 +38,6 @@ _hbm: deque = deque(maxlen=_HBM_MAX)
 _COMPILE_EVENTS = REGISTRY.counter(
     "kmamiz_prof_compile_events_total",
     "Compile-cause log entries recorded (program cache growth)",
-)
-_PROG_DEVICE_MS = REGISTRY.gauge_family(
-    "kmamiz_prof_program_device_ms",
-    "Per-program device time from the last joined jax.profiler capture",
-    ("program",),
 )
 
 
@@ -93,105 +87,6 @@ def hbm_timeline() -> List[List[int]]:
     """(tick_id, bytes_in_use, peak_bytes) rows, oldest first."""
     with _lock:
         return [list(row) for row in _hbm]
-
-
-# -- jax.profiler trace join -------------------------------------------------
-
-
-def _iter_trace_files(root: str) -> List[str]:
-    """All .trace.json(.gz) files under a profiler capture directory
-    (jax writes plugins/profile/<ts>/<host>.trace.json.gz)."""
-    found: List[str] = []
-    for dirpath, _dirs, files in os.walk(root):
-        for fname in files:
-            if fname.endswith(".trace.json") or fname.endswith(
-                ".trace.json.gz"
-            ):
-                found.append(os.path.join(dirpath, fname))
-    return sorted(found)
-
-
-def _load_trace_events(path: str) -> List[dict]:
-    try:
-        if path.endswith(".gz"):
-            with gzip.open(path, "rt", encoding="utf-8", errors="replace") as f:
-                doc = json.load(f)
-        else:
-            with open(path, encoding="utf-8", errors="replace") as f:
-                doc = json.load(f)
-    except (OSError, ValueError):
-        return []
-    if isinstance(doc, dict):
-        evs = doc.get("traceEvents", [])
-        return evs if isinstance(evs, list) else []
-    return doc if isinstance(doc, list) else []
-
-
-def _program_names() -> List[str]:
-    try:
-        from kmamiz_tpu.core import programs
-
-        return sorted(programs.all_programs().keys(), key=len, reverse=True)
-    except Exception:  # noqa: BLE001 - attribution without a registry
-        return []
-
-
-def join_kernels_to_programs(
-    kernel_us: Dict[str, float], names: Optional[List[str]] = None
-) -> Dict[str, float]:
-    """Fold per-kernel device microseconds onto registry program names:
-    a kernel named `jit_<prog>...` (or containing `<prog>`) credits
-    `<prog>`; the rest lands under `__unattributed__`. Longest program
-    name wins, so `forecast_forward_v2` never miscredits
-    `forecast_forward`."""
-    if names is None:
-        names = _program_names()
-    out: Dict[str, float] = {}
-    for kernel, us in kernel_us.items():
-        base = kernel[4:] if kernel.startswith("jit_") else kernel
-        target = "__unattributed__"
-        for name in names:
-            if base == name or base.startswith(name) or name in base:
-                target = name
-                break
-        out[target] = out.get(target, 0.0) + float(us)
-    return out
-
-
-def parse_profile_dir(root: str) -> dict:
-    """Aggregate a jax.profiler capture directory into per-program
-    device ms. Tolerant of partial/foreign captures: unparseable files
-    skip, unmatched kernels report as `__unattributed__`."""
-    files = _iter_trace_files(root)
-    kernel_us: Dict[str, float] = {}
-    n_events = 0
-    for path in files:
-        for ev in _load_trace_events(path):
-            if not isinstance(ev, dict) or ev.get("ph") != "X":
-                continue
-            name = ev.get("name")
-            dur = ev.get("dur")
-            if not name or not isinstance(dur, (int, float)):
-                continue
-            kernel_us[name] = kernel_us.get(name, 0.0) + float(dur)
-            n_events += 1
-    programs_us = join_kernels_to_programs(kernel_us)
-    programs_ms = {
-        name: round(us / 1000.0, 3) for name, us in sorted(programs_us.items())
-    }
-    for name, ms in programs_ms.items():
-        if name != "__unattributed__":
-            _PROG_DEVICE_MS.handle(name).set(ms)
-    total_ms = round(sum(programs_us.values()) / 1000.0, 3)
-    return {
-        "files": len(files),
-        "events": n_events,
-        "total_device_ms": total_ms,
-        "unattributed_ms": programs_ms.get("__unattributed__", 0.0),
-        "programs": {
-            k: v for k, v in programs_ms.items() if k != "__unattributed__"
-        },
-    }
 
 
 def reset_for_tests() -> None:

@@ -36,7 +36,7 @@ _DEFAULT_RING = 4096
 
 # root-event names: the per-tick wall-clock denominators of the
 # attribution report (report.py) — everything else is an attributed phase
-ROOT_EVENTS = ("dp-tick", "dp-ingest")
+ROOT_EVENTS = ("dp-tick", "dp-ingest", "refresh.train", "refresh.stack")
 # native counter-delta events (native_counters.poll): they overlap the
 # host phase spans that contain them, so attribution must NOT sum them
 NATIVE_EVENTS = ("native-merge", "native-merge-lockwait")
@@ -91,14 +91,16 @@ def refresh_from_env() -> None:
     _enabled = os.environ.get("KMAMIZ_PROF", "1") not in ("0", "false", "")
 
 
-def emit(name: str, dur_ns: int) -> None:
-    """Append one event (hot path: one counter bump + one slot store)."""
+def emit(name: str, dur_ns: int, tick_id: Optional[int] = None) -> None:
+    """Append one event (hot path: one counter bump + one slot store).
+    `tick_id` is the id of the trace the event belongs to (the tracer
+    passes its builder's); without one the process's current tick."""
     if not _enabled:
         return
     ring = _ring
     ring[next(_idx) % len(ring)] = (
         name,
-        _cur_tick,
+        _cur_tick if tick_id is None else tick_id,
         time.perf_counter_ns(),
         int(dur_ns),
     )
@@ -121,16 +123,27 @@ def note_tick_start() -> int:
     return _cur_tick
 
 
-def note_tick_end(root_name: str, dur_ns: int) -> None:
+def new_tick_id() -> int:
+    """An id for a trace that is no tick (a model refresh on a scheduler
+    thread): drawn from the ticks' sequence, but NOT made the process's
+    current tick, so what a tick emits meanwhile keeps the tick's id."""
+    return next(_tick_seq) if _enabled else _cur_tick
+
+
+def note_tick_end(
+    root_name: str, dur_ns: int, tick_id: Optional[int] = None
+) -> None:
     """Close a tick: emit its root event, run the per-tick hooks."""
     if not _enabled:
         return
-    emit(root_name, dur_ns)
+    if tick_id is None:
+        tick_id = _cur_tick
+    emit(root_name, dur_ns, tick_id)
     with _hook_lock:
         hooks = list(_tick_end_hooks)
     for fn in hooks:
         try:
-            fn(_cur_tick)
+            fn(tick_id)
         except Exception:  # noqa: BLE001 - a broken hook must not break ticks
             pass
 

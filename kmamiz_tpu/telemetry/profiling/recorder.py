@@ -147,8 +147,11 @@ def _record(
     ns = _safe_namespace(namespace)
     now = time.monotonic()
     with _lock:
-        last = _last_dump_by_ns.get(ns, 0.0)
-        if not force and (now - last) < _debounce_s():
+        # a namespace that never dumped is not debounced: monotonic counts
+        # from boot, so a default of 0.0 would swallow the first dump of a
+        # host up for less than the debounce interval
+        last = _last_dump_by_ns.get(ns)
+        if not force and last is not None and (now - last) < _debounce_s():
             return None
         _last_dump_by_ns[ns] = now
         seq = next(_seq)

@@ -8,6 +8,17 @@ points the tick ALREADY synchronizes (`block_until_ready` fences that
 exist for correctness), so tracing adds zero host syncs and zero device
 round-trips; span timing is host `perf_counter_ns` only.
 
+A model refresh (`models/trainer.train`, `models/stacked.stack_dataset`)
+is traced the same way through `operation_span`: the root of a trace of
+its own when no trace is open on the thread (a tool, a scheduler
+thread, the benchmark), a child of the tick when one is. Its spans are
+named `refresh.*` and may carry counts (`TRACER.note`).
+
+Every span also opens a `jax.profiler.TraceAnnotation` of its name for
+its lifetime: under a profiler session (`POST /debug/profile`) the
+program's spans lie in the trace's host plane on the device's clock;
+with no session the annotation costs under a microsecond.
+
 Finished traces land in a ring (`KMAMIZ_TRACE_RING` traces, default
 256) and export as Zipkin v2 JSON trace groups at `GET /debug/traces` —
 in exactly the Istio-sidecar span shape the ingest path parses
@@ -17,8 +28,8 @@ the self-trace round-trip test).
 
 Overhead: when disabled (`KMAMIZ_TELEMETRY=0`) `tick()`/`span()` yield
 immediately with no allocation. When enabled, a span is one list append
-of a 4-tuple; Zipkin formatting happens only at export time, never on
-the tick.
+of a 4-tuple and one annotation; Zipkin formatting happens only at
+export time, never on the tick.
 """
 from __future__ import annotations
 
@@ -27,7 +38,7 @@ import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from .profiling import events as prof_events
 from .registry import REGISTRY
@@ -58,6 +69,19 @@ PHASES = (
     # scheduling decisions), a first-class phase so controller cost is
     # attributable and gated like any other
     "control-decide",
+    # the model refresh (models/trainer.train, models/stacked.stack_dataset;
+    # docs/OBSERVABILITY.md has what each covers)
+    "refresh.train",
+    "refresh.init",
+    "refresh.resume",
+    "refresh.pos_weight",
+    "refresh.stack",
+    "refresh.stack.host_fill",
+    "refresh.stack.device_put",
+    "refresh.epoch_block",
+    "refresh.loss_fetch",
+    "refresh.checkpoint_save",
+    "refresh.legacy_epoch",
 )
 
 _SELFTRACE_NAMESPACE = "graftscope"
@@ -69,6 +93,20 @@ def _ring_size() -> int:
         return max(1, int(os.environ.get("KMAMIZ_TRACE_RING", "256")))
     except ValueError:
         return 256
+
+
+# jax.profiler.TraceAnnotation, resolved by the first span: telemetry/
+# imports jax inside functions only, and a span pays no import lookup
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 def telemetry_enabled() -> bool:
@@ -83,14 +121,28 @@ class _TraceBuilder:
     Built once per tick; appends are the only hot-path operation.
     """
 
-    __slots__ = ("trace_id", "wall_us", "t0_ns", "spans", "_stack", "status")
+    __slots__ = (
+        "trace_id",
+        "tick_id",
+        "wall_us",
+        "t0_ns",
+        "spans",
+        "counts",
+        "_stack",
+        "status",
+    )
 
-    def __init__(self, trace_id: str, root_name: str) -> None:
+    def __init__(self, trace_id: str, root_name: str, tick_id: int = 0) -> None:
         self.trace_id = trace_id
+        # the graftprof tick id this trace's events carry: its own, not
+        # the process's current one, which a trace on another thread moves
+        self.tick_id = tick_id
         self.wall_us = time.time_ns() // 1000
         self.t0_ns = time.perf_counter_ns()
         # span 0 is the root; dur filled at close
         self.spans: List[Tuple[str, int, int, int]] = [(root_name, 0, -1, -1)]
+        # span index -> counts noted while it was open (TickTracer.note)
+        self.counts: Dict[int, dict] = {}
         self._stack = [0]
         self.status = "200"
 
@@ -144,34 +196,54 @@ class TickTracer:
         if not telemetry_enabled() or self.current() is not None:
             yield None
             return
+        with self._trace(root_name, prof_events.note_tick_start()) as builder:
+            try:
+                yield builder
+            finally:
+                builder.close()
+                prof_events.note_tick_end(
+                    root_name, builder.spans[0][2], builder.tick_id
+                )
+
+    @contextmanager
+    def _trace(self, root_name: str, tick_id: int):
+        """Open a trace on this thread; the caller closes the builder
+        (it reports the root's duration) and this files it in the ring."""
         with self._lock:
             self._seq += 1
             trace_id = f"graftscope-{self._seq}"
-        builder = _TraceBuilder(trace_id, root_name)
+        builder = _TraceBuilder(trace_id, root_name, tick_id)
         self._tls.builder = builder
-        prof_events.note_tick_start()
         try:
-            yield builder
+            with _annotation(root_name):
+                yield builder
         finally:
             self._tls.builder = None
-            builder.close()
             with self._lock:
                 self._ring.append(builder)
-            prof_events.note_tick_end(root_name, builder.spans[0][2])
 
     @contextmanager
     def span(self, name: str):
         """Record one phase span on the current trace (no-op outside a
-        tick or with telemetry off)."""
+        trace or with telemetry off)."""
         builder = self.current()
         if builder is None:
             yield
             return
         idx = builder.open_span(name)
         try:
-            yield
+            with _annotation(name):
+                yield
         finally:
             builder.close_span(idx)
+
+    def note(self, **counts) -> None:
+        """Attach counts to the innermost open span of this thread's
+        trace (slots read, bytes stacked, hit or build); exported as
+        Zipkin tags. No-op outside a trace."""
+        builder = self.current()
+        if builder is not None and builder._stack:
+            builder.counts.setdefault(builder._stack[-1], {}).update(counts)
 
     def annotate_last(self, name: str, dur_ms: float) -> None:
         """Append a post-tick span (e.g. encode-serve, which happens
@@ -209,6 +281,10 @@ class TickTracer:
                 svc = name.replace("_", "-").replace(".", "-")
                 ns = _SELFTRACE_NAMESPACE
                 url = f"http://{svc}.{ns}.svc.cluster.local/tick/{svc}"
+                counts = {
+                    f"kmamiz.{k}": str(v)
+                    for k, v in tb.counts.get(i, {}).items()
+                }
                 group.append(
                     {
                         "traceId": tb.trace_id,
@@ -231,6 +307,7 @@ class TickTracer:
                             "istio.namespace": ns,
                             "response_flags": "-",
                             "upstream_cluster": "inbound|9080||",
+                            **counts,
                         },
                     }
                 )
@@ -266,13 +343,56 @@ def phase_span(name: str):
     if builder is None:
         yield
         return
-    h = SPAN_HANDLES.get(name)
     idx = builder.open_span(name)
     try:
-        yield
+        with _annotation(name):
+            yield
     finally:
         builder.close_span(idx)
-        _n, _s, dur_ns, _p = builder.spans[idx]
-        prof_events.emit(name, dur_ns)
-        if h is not None:
-            h.observe(dur_ns / 1e6)
+        dur_ns = builder.spans[idx][2]
+        prof_events.emit(name, dur_ns, builder.tick_id)
+        _observe(name, dur_ns)
+
+
+def _observe(name: str, dur_ns: int) -> None:
+    h = SPAN_HANDLES.get(name)
+    if h is not None:
+        h.observe(dur_ns / 1e6)
+
+
+@contextmanager
+def operation_span(name: str):
+    """A span that is the ROOT of a new trace when none is open on this
+    thread, and a CHILD of the open trace when one is: a model refresh
+    called from a tick nests under it, called from a tool or a scheduler
+    thread it is a trace of its own. Observed in the histogram either
+    way; a no-op with telemetry off.
+
+    A root is no tick: it takes a tick id of its own without making it
+    the process's current one and runs no per-tick hook, so a tick that
+    runs meanwhile on another thread keeps its events and its
+    native-counter deltas. The graftprof ring gets the span as a root
+    event only (`ROOT_EVENTS` names it as a denominator): as a child it
+    is explained by the phases inside it, and counting it as well would
+    count them twice."""
+    builder = TRACER.current()
+    if builder is not None:
+        idx = builder.open_span(name)
+        try:
+            with _annotation(name):
+                yield
+        finally:
+            builder.close_span(idx)
+            _observe(name, builder.spans[idx][2])
+        return
+    if not telemetry_enabled():
+        yield
+        return
+    with TRACER._trace(name, prof_events.new_tick_id()) as builder:
+        try:
+            yield
+        finally:
+            builder.close()
+            dur_ns = builder.spans[0][2]
+            prof_events.emit(name, dur_ns, builder.tick_id)
+            _observe(name, dur_ns)

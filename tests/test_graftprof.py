@@ -147,6 +147,23 @@ class TestFlightRecorder:
         assert recorder.record("breaker-open", "zipkin") is None
         assert recorder.record("breaker-open", "zipkin", force=True) is not None
 
+    def test_first_dump_is_not_debounced_on_a_fresh_boot(self, monkeypatch):
+        """time.monotonic() counts from boot on Linux: with an uptime under
+        the debounce interval the FIRST dump of a namespace must still be
+        written (the cold-start breach is what the recorder exists for)."""
+        monkeypatch.setenv("KMAMIZ_PROF_FLIGHT_DEBOUNCE_S", "600")
+        readings = [3.0, 4.0, 5.0, 700.0]  # one per record() below
+        monkeypatch.setattr(
+            recorder.time,
+            "monotonic",
+            lambda: readings.pop(0) if len(readings) > 1 else readings[0],
+        )
+        assert recorder.record("watchdog", "cold-start") is not None
+        assert recorder.record("watchdog", "cold-start") is None
+        # another namespace has a debounce clock of its own
+        assert recorder.record("watchdog", "cell", namespace="cell-1") is not None
+        assert recorder.record("watchdog", "cold-start") is not None  # 700 s
+
     def test_retention_prunes_to_newest(self, monkeypatch):
         monkeypatch.setenv("KMAMIZ_PROF_FLIGHT_MAX", "2")
         paths = [

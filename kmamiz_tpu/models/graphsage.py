@@ -157,17 +157,29 @@ def neighbor_mean(
     dst_ep: jnp.ndarray,  # [E]
     edge_mask: jnp.ndarray,  # [E]
     deg: jnp.ndarray = None,  # [N] precomputed neighbor_degree
+    plan: sparse.EdgePlan = None,  # the topology prepared per dataset
 ) -> jnp.ndarray:
     """Mean of neighbor states over both edge directions (segment mean).
 
     deg omitted keeps the self-contained single-layer form; callers with
     several layers over one topology (forward) pass the hoisted degree.
 
+    A caller that holds an edge plan of this topology (the training
+    refresh: models/stacked.py builds one per dataset) passes it, and the
+    sum is one row gather and one owner-sorted reduction with its own VJP
+    (sparse.planned_neighbor_sum), the degree the plan's. Without a plan
+    (the tick's one-off graphs) the edge list is reduced as it comes:
+
     Under the pallas backends the whole gather -> mask -> two segment_sums
     chain runs as one fused SpMM kernel (ops/sparse.py) when the node
     table fits the VMEM budget; the division stays out here so the
     normalization matches the XLA path exactly."""
     n = h.shape[0]
+    if plan is not None:
+        agg = sparse.planned_neighbor_sum(plan, h)
+        if deg is None:
+            deg = plan.degree.astype(h.dtype)
+        return agg / jnp.maximum(deg, 1.0)[:, None]
     if sparse.fused_route(n):
         agg, fused_deg = sparse.fused_neighbor_sums(
             h.astype(jnp.float32),
@@ -197,15 +209,23 @@ def forward(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
+    plan: sparse.EdgePlan = None,
 ):
-    """Two SAGE layers -> (latency prediction [N], anomaly logits [N])."""
+    """Two SAGE layers -> (latency prediction [N], anomaly logits [N]).
+
+    `plan` is the edge plan of (src_ep, dst_ep, edge_mask) where the caller
+    has prepared one (neighbor_mean); the result is the same sums in
+    another order."""
     x = _common.concat_embedding(features, params.embedding)
-    deg = neighbor_degree(features.shape[0], src_ep, dst_ep, edge_mask)
-    agg1 = neighbor_mean(x, src_ep, dst_ep, edge_mask, deg)
+    if plan is None:
+        deg = neighbor_degree(features.shape[0], src_ep, dst_ep, edge_mask)
+    else:
+        deg = plan.degree
+    agg1 = neighbor_mean(x, src_ep, dst_ep, edge_mask, deg, plan)
     h1 = jax.nn.relu(
         x @ params.w_self_1 + agg1 @ params.w_neigh_1 + params.b_1
     )
-    agg2 = neighbor_mean(h1, src_ep, dst_ep, edge_mask, deg)
+    agg2 = neighbor_mean(h1, src_ep, dst_ep, edge_mask, deg, plan)
     h2 = jax.nn.relu(h1 @ params.w_self_2 + agg2 @ params.w_neigh_2 + params.b_2)
     latency = (
         h2 @ params.w_latency + features @ params.w_latency_skip + params.b_latency

@@ -29,6 +29,14 @@ device-native:
 - `predict_all` vmaps a head's forward over every stacked slot in one
   jitted call — the batched evaluation path shared by trainer.evaluate
   and trainer.calibrate_threshold.
+- every stack carries the EDGE PLAN of its topology (ops/sparse.py:
+  the neighbour list sorted by owner, the degree, a tiled reducer's work
+  list), built once on the host and memoised by the identity of the edge
+  arrays, so datasets over one graph (a history and its head, a train and
+  a test split) share one. `epoch_runner`'s block takes it as a loop
+  constant beside src/dst/edge_mask and hands it to a head whose forward
+  takes one (`plan_for`); the vmapped paths (`dp_epoch_runner`,
+  `predict_all`) pass none and reduce the edge list as it comes.
 
 Bit discipline: with the default batch size of 1 the scan body performs
 the identical per-slot update sequence as the legacy Python loop; only
@@ -39,6 +47,7 @@ differ, so losses and params agree within fp32 tolerance
 from __future__ import annotations
 
 import functools
+import inspect
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -50,6 +59,7 @@ import optax
 from kmamiz_tpu.core import programs
 from kmamiz_tpu.core.spans import _pad_size
 from kmamiz_tpu.models import common
+from kmamiz_tpu.ops import sparse
 from kmamiz_tpu.telemetry.registry import REGISTRY
 from kmamiz_tpu.telemetry.tracing import TRACER, operation_span, phase_span
 
@@ -60,6 +70,15 @@ _STACK_BUILDS = REGISTRY.counter(
 _STACK_HITS = REGISTRY.counter(
     "kmamiz_model_stack_hits_total",
     "stack_dataset calls served by the stack memoised on the dataset",
+)
+_PLAN_BUILDS = REGISTRY.counter(
+    "kmamiz_model_edge_plan_builds_total",
+    "stack_dataset calls that sorted a topology into an edge plan and uploaded it",
+)
+_PLAN_HITS = REGISTRY.counter(
+    "kmamiz_model_edge_plan_hits_total",
+    "stack_dataset calls whose edge plan came from a memo (the stack's, "
+    "or that of another dataset over the same edge arrays)",
 )
 
 
@@ -116,6 +135,9 @@ class StackedDataset:
     num_edges: int  # real E (<= bucket_edges)
     bucket_nodes: int
     bucket_edges: int
+    plan: Optional[sparse.EdgePlan] = None  # of (src, dst, edge_mask)
+    plan_entries: int = 0  # real (owner, neighbour) entries: 2 x real edges
+    plan_items: int = 0  # real (node tile, edge block) products of one sum
 
     def layout(self) -> dict:
         """The shape contract a checkpoint records (and resume validates):
@@ -154,14 +176,19 @@ def stack_dataset(dataset) -> StackedDataset:
 
     Traced as `refresh.stack` (a trace of its own when called alone, a
     child of `refresh.train` inside a refresh), a build split into
-    `refresh.stack.host_fill` and `refresh.stack.device_put`. The
-    spans end where the calls return: the wait for the transfer is
-    whoever blocks next."""
+    `refresh.stack.host_fill`, `refresh.stack.plan` (the edge plan, where
+    no memo holds it) and `refresh.stack.device_put`. The spans end where
+    the calls return: the wait for the transfer is whoever blocks next."""
     with operation_span("refresh.stack"):
         cached = getattr(dataset, "_stacked_cache", None)
         if cached is not None and cached.layout() == dataset_layout(dataset):
             _STACK_HITS.inc()
-            TRACER.note(hit=1)
+            _PLAN_HITS.inc()
+            TRACER.note(
+                hit=1,
+                plan_entries=cached.plan_entries,
+                plan_items=cached.plan_items,
+            )
             return cached
         _STACK_BUILDS.inc()
         stacked = _build_stack(dataset)
@@ -170,6 +197,42 @@ def stack_dataset(dataset) -> StackedDataset:
         except (AttributeError, TypeError):  # frozen/slotted containers
             pass
         return stacked
+
+
+#: edge plans by the identity of the edge arrays they were made from (and
+#: the node bucket): [(src, dst, edge_mask, bucket_nodes, plan, entries,
+#: items)], newest last. The arrays are held so that their ids stay theirs;
+#: like the stack's memo it trusts that nobody writes into them.
+_PLAN_MEMO: list = []
+_PLAN_MEMO_SIZE = 4
+
+
+def _edge_plan(dataset, src, dst, e_mask, nb: int):
+    """(device EdgePlan, entries, items) of a dataset's padded edge list."""
+    key = (dataset.src, dataset.dst, dataset.edge_mask)
+    for *held, held_nb, plan, entries, items in _PLAN_MEMO:
+        if held_nb == nb and all(a is b for a, b in zip(held, key)):
+            _PLAN_HITS.inc()
+            return plan, entries, items
+    _PLAN_BUILDS.inc()
+    with phase_span("refresh.stack.plan"):
+        host_plan, entries, items = sparse.build_edge_plan(src, dst, e_mask, nb)
+        plan = jax.tree_util.tree_map(jnp.asarray, host_plan)
+        TRACER.note(entries=entries, items=items)
+    _PLAN_MEMO.append((*key, nb, plan, entries, items))
+    del _PLAN_MEMO[:-_PLAN_MEMO_SIZE]
+    return plan, entries, items
+
+
+def plan_for(model, stacked: StackedDataset) -> Optional[sparse.EdgePlan]:
+    """The stack's edge plan, for a head whose `forward` takes one; None for
+    the others and under KMAMIZ_SPARSE=xla (the legacy formulation
+    everywhere). What `train()` hands the epoch block."""
+    if not sparse.use_sparse():
+        return None
+    if "plan" not in inspect.signature(model.forward).parameters:
+        return None
+    return stacked.plan
 
 
 def _build_stack(dataset) -> StackedDataset:
@@ -204,6 +267,7 @@ def _build_stack(dataset) -> StackedDataset:
             a.nbytes for a in (feats, t_lat, t_ano, n_mask, src, dst, e_mask)
         )
         TRACER.note(bytes=nbytes)
+    plan, plan_entries, plan_items = _edge_plan(dataset, src, dst, e_mask, nb)
     with phase_span("refresh.stack.device_put"):
         stacked = StackedDataset(
             features=jnp.asarray(feats),
@@ -218,9 +282,14 @@ def _build_stack(dataset) -> StackedDataset:
             num_edges=e,
             bucket_nodes=nb,
             bucket_edges=eb,
+            plan=plan,
+            plan_entries=plan_entries,
+            plan_items=plan_items,
         )
         TRACER.note(bytes=nbytes)
-    TRACER.note(hit=0, bytes=nbytes)  # on refresh.stack
+    TRACER.note(  # on refresh.stack
+        hit=0, bytes=nbytes, plan_entries=plan_entries, plan_items=plan_items
+    )
     return stacked
 
 
@@ -236,6 +305,11 @@ def epoch_runner(model, lr: float, pos_weight: float):
     scan over slots, one optimizer update per slot — the legacy loop's
     schedule without its per-slot dispatch and transfers. params/opt_state
     are donated (they live and die on device across the whole run).
+
+    `plan` (the stack's EdgePlan, `plan_for`) is a constant of both scans
+    like src/dst/edge_mask, handed to `model.forward` as its `plan`; None
+    keeps the forward's own reduction of the edge list, and is another
+    program of the same family.
 
     Memoized per (model, lr, pos_weight) so repeated train() calls in one
     process reuse the compiled program family (jit then keys on the
@@ -260,11 +334,21 @@ def epoch_runner(model, lr: float, pos_weight: float):
         dst,
         edge_mask,
         n_epochs: int,
+        plan=None,
     ):
+        slot_grad = grad_fn
+        if plan is not None:
+            slot_grad = jax.value_and_grad(
+                common.make_loss_fn(
+                    functools.partial(model.forward, plan=plan), pos_weight
+                ),
+                has_aux=True,
+            )
+
         def slot_step(carry, xs):
             p, s = carry
             f, tl, ta, nm = xs
-            (loss, (lat_l, ano_l)), grads = grad_fn(
+            (loss, (lat_l, ano_l)), grads = slot_grad(
                 p, f, src, dst, edge_mask, tl, ta, nm
             )
             updates, s = optimizer.update(grads, s, p)

@@ -402,12 +402,14 @@ def train(
                 model, lr, pos_weight, mesh=mesh, axis=axis
             )
             batched = stacked_mod.batch_slots_arrays(st, batch)
+            plan = None  # the vmapped per-slot grads reduce the edge list
 
             def run_block(p, s, n_ep):
                 return runner(p, s, *batched, st.src, st.dst, st.edge_mask, n_ep)
 
         else:
             runner = stacked_mod.epoch_runner(model, lr, pos_weight)
+            plan = stacked_mod.plan_for(model, st)
 
             def run_block(p, s, n_ep):
                 return runner(
@@ -421,6 +423,7 @@ def train(
                     st.dst,
                     st.edge_mask,
                     n_ep,
+                    plan,
                 )
 
         save_every = checkpoint_every if checkpoint_dir else 0
@@ -429,7 +432,11 @@ def train(
             dispatched_ns = prof_events.now_ns()
             with phase_span("refresh.epoch_block"):
                 params, opt_state, block = run_block(params, opt_state, e1 - e0)
-                TRACER.note(epochs=e1 - e0, slot_updates=slot_updates)
+                TRACER.note(
+                    epochs=e1 - e0,
+                    slot_updates=slot_updates,
+                    planned=int(plan is not None),
+                )
             # the fence that was always here: the host waits for the device
             with phase_span("refresh.loss_fetch"):
                 block = np.asarray(block, dtype=np.float64)  # [e1-e0, 3]

@@ -19,6 +19,15 @@ backend behind all four:
   HBM and the padded-dense adjacency is never materialized. Used by the
   STLGT neighbor bias (gated mode) and GraphSAGE neighbor sums (plain
   mode) when the backend is ``pallas``/``pallas_interpret``.
+- **The planned neighbour sum** (``EdgePlan``, ``planned_neighbor_sum``):
+  where a caller runs one topology many times (the training refresh: 432
+  slots a call over one edge list), the topology is sorted ONCE by owner
+  into an edge plan and ``A @ h`` is one row gather and one tiled
+  reduction of consecutive rows, with its own VJP (A is symmetric: the
+  backward is the forward) — O(E) one-hot work, no node-table cap, no
+  scatter. A Pallas kernel on the TPU, the same items in plain XLA
+  elsewhere. It engages by what the caller passes (a plan), under every
+  backend but ``xla``.
 - **Sparse counting primitives** for the scorer rewrite
   (``dense_rank_pairs``, ``run_start_index``): the scorers replace the
   8M-row 5-key lexsort with packed-int32 single-key UNSTABLE sorts per
@@ -31,8 +40,9 @@ Backend knob (mirrored in config.Settings):
 - ``KMAMIZ_SPARSE=sparse`` (default): scorers use the packed-key sparse
   counting path, the dependency walk picks the flat-gather variant on
   CPU hosts (the MXU packed walk stays default on TPU, where it measures
-  >=50x faster); GraphSAGE/STLGT keep their gather/segment-sum XLA code,
-  which already IS the sparse formulation for those shapes.
+  >=50x faster); GraphSAGE/STLGT keep their gather/segment-sum XLA code
+  for a graph used once (the tick), and GraphSAGE's training refresh
+  takes the planned sum over the stack's edge plan.
 - ``KMAMIZ_SPARSE=pallas``: additionally routes the STLGT bias and
   GraphSAGE neighbor sums through the fused Pallas kernel, compiled by
   Mosaic — on a backend Mosaic cannot target the kernel RAISES; it never
@@ -42,7 +52,8 @@ Backend knob (mirrored in config.Settings):
 - ``KMAMIZ_SPARSE=pallas_interpret``: the same kernels in interpret mode
   (CI/CPU parity testing) — the ONLY setting that interprets.
 - ``KMAMIZ_SPARSE=xla``: every consumer keeps the legacy dense/XLA path
-  bit-for-bit (the fallback the parity tests pin against).
+  bit-for-bit (the fallback the parity tests pin against); no edge plan
+  is handed to a model (``models/stacked.plan_for``).
 
 ``KMAMIZ_SPARSE_TILE`` sets the edge-tile block (default 256, a
 multiple of the 128-lane width); ``KMAMIZ_SPARSE_NODE_MAX`` bounds the
@@ -60,10 +71,11 @@ from __future__ import annotations
 import os
 import threading
 from functools import partial
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -78,7 +90,7 @@ _node_max_cache: Optional[int] = None
 _route_lock = threading.Lock()
 #: fused-kernel routing decisions since process start (trace-time
 #: counts: the consumers decide inside their jit traces)
-_route_counts = {"fused": 0, "gaveWay": 0, "lastGaveWayNodes": 0}
+_route_counts = {"fused": 0, "gaveWay": 0, "lastGaveWayNodes": 0, "planned": 0}
 
 
 def backend() -> str:
@@ -125,7 +137,7 @@ def reset_for_tests() -> None:
     _tile_cache = None
     _node_max_cache = None
     with _route_lock:
-        _route_counts.update(fused=0, gaveWay=0, lastGaveWayNodes=0)
+        _route_counts.update(fused=0, gaveWay=0, lastGaveWayNodes=0, planned=0)
 
 
 def use_sparse() -> bool:
@@ -412,6 +424,254 @@ def fused_neighbor_sums(
         gated=False, tile=tile, interpret=interpret,
     )
     return agg, deg
+
+
+# ---------------------------------------------------------------------------
+# planned neighbour sum: a per-dataset edge plan and a sorted, tiled SpMM
+# ---------------------------------------------------------------------------
+#
+# A training refresh runs the same edge list through every slot of every
+# call, so what depends on the topology alone is prepared ONCE, on the host,
+# where the stack is built (models/stacked.py): the undirected neighbour list
+# sorted by owner, the degree, and the work list of a tiled reduction. With
+# the list sorted, `agg = A @ h` (A the symmetric 0/1 adjacency) is one row
+# gather and one reduction of CONSECUTIVE rows into node tiles: a node tile
+# meets only the few edge blocks that overlap it, so the one-hot products
+# are O(E), not the O(E x N) of the fused kernel above. A is symmetric, so
+# the cotangent of h is the same product of the cotangent of agg: the VJP is
+# the forward, nothing is saved, and no scatter is left in either pass.
+
+#: rows of one output tile and entries of one edge block. A product is
+#: [PLAN_NODE_TILE, PLAN_EDGE_BLOCK] @ [PLAN_EDGE_BLOCK, width]; the one-hot
+#: work grows with entries x PLAN_NODE_TILE + nodes x PLAN_EDGE_BLOCK and the
+#: number of grid steps falls with both.
+PLAN_NODE_TILE = 128
+PLAN_EDGE_BLOCK = 512
+
+
+class EdgePlan(NamedTuple):
+    """The topology of one stacked dataset, prepared for `planned_neighbor_sum`.
+    Shapes are a function of the node and edge buckets alone.
+
+    An entry is one real edge seen from one end: (owner, neighbour). Entries
+    are sorted by owner; masked and padding edges are parked past the end
+    with an owner no tile holds. An item is one (node tile, edge block) pair
+    whose product contributes to the tile; every tile has at least one item
+    (an empty tile's product is all zeros, and writes them), so there are at
+    most node_tiles + edge_blocks, and the list is padded to that with no-ops.
+    """
+
+    owner: jnp.ndarray  # [1, L] int32, ascending; L = 2 * edge bucket, blocked
+    neighbour: jnp.ndarray  # [L] int32: the row each entry adds to its owner
+    degree: jnp.ndarray  # [Nb] float32: entries per owner (row-pointer steps)
+    item_tile: jnp.ndarray  # [I] int32, ascending
+    item_block: jnp.ndarray  # [I] int32
+    item_flag: jnp.ndarray  # [I] int32: 1 first of its tile, 0 adds, -1 no-op
+
+
+def plan_shapes(bucket_nodes: int, bucket_edges: int) -> Tuple[int, int, int]:
+    """(entries L, node tiles, items I) of the plan of a bucket pair."""
+    entries = _pad_to(max(2 * bucket_edges, 1), PLAN_EDGE_BLOCK)
+    node_tiles = -(-max(bucket_nodes, 1) // PLAN_NODE_TILE)
+    return entries, node_tiles, node_tiles + entries // PLAN_EDGE_BLOCK
+
+
+def build_edge_plan(src, dst, edge_mask, bucket_nodes: int):
+    """Host arrays of one dataset's (bucket-padded) edge list -> (EdgePlan of
+    numpy arrays, real entries, real items). One stable sort of 2 x edges
+    keys; an edge whose mask is False or whose end lies outside the bucket
+    contributes nothing, as in `graphsage.neighbor_mean`."""
+    src = np.asarray(src, dtype=np.int64)
+    dst = np.asarray(dst, dtype=np.int64)
+    nb = int(bucket_nodes)
+    real = (
+        np.asarray(edge_mask, dtype=bool)
+        & (src >= 0) & (src < nb) & (dst >= 0) & (dst < nb)
+    )
+    entries, node_tiles, items = plan_shapes(nb, src.shape[0])
+    tn, be = PLAN_NODE_TILE, PLAN_EDGE_BLOCK
+    edge_blocks = entries // be
+
+    own = np.concatenate([src[real], dst[real]])
+    nei = np.concatenate([dst[real], src[real]])
+    order = np.argsort(own, kind="stable")
+    n_real = int(own.shape[0])
+    owner = np.full(entries, node_tiles * tn, dtype=np.int32)  # parked
+    neighbour = np.zeros(entries, dtype=np.int32)
+    owner[:n_real] = own[order]
+    neighbour[:n_real] = nei[order]
+
+    counts = np.bincount(own, minlength=nb)  # every owner is < nb
+    row_ptr = np.zeros(node_tiles * tn + 1, dtype=np.int64)
+    np.cumsum(counts, out=row_ptr[1 : nb + 1])
+    row_ptr[nb + 1 :] = n_real
+
+    # the blocks that hold entries of each tile; an empty tile takes the one
+    # block its (empty) range starts in, where no owner matches its rows
+    lo, hi = row_ptr[:-1:tn], row_ptr[tn::tn]
+    first = np.minimum(lo // be, edge_blocks - 1)
+    last = np.where(hi > lo, (hi - 1) // be, first)
+    per_tile = last - first + 1
+    n_items = int(per_tile.sum())
+    starts = np.cumsum(per_tile) - per_tile
+    item_tile = np.full(items, node_tiles - 1, dtype=np.int32)
+    item_block = np.empty(items, dtype=np.int32)
+    item_flag = np.full(items, -1, dtype=np.int32)
+    tiles = np.repeat(np.arange(node_tiles), per_tile)
+    item_tile[:n_items] = tiles
+    item_block[:n_items] = first[tiles] + np.arange(n_items) - starts[tiles]
+    item_block[n_items:] = item_block[n_items - 1]  # no new block to fetch
+    item_flag[:n_items] = 0
+    item_flag[starts] = 1
+    plan = EdgePlan(
+        owner=owner[None, :],
+        neighbour=neighbour,
+        degree=counts.astype(np.float32),
+        item_tile=item_tile,
+        item_block=item_block,
+        item_flag=item_flag,
+    )
+    return plan, n_real, n_items
+
+
+def _planned_kernel(tile_ref, block_ref, flag_ref, owner_ref, msg_ref, out_ref):
+    """One item: out[tile] (+)= onehot(owner block against the tile's rows)
+    @ message block. The output tile stays in VMEM across the consecutive
+    items of its tile; the grid is sequential, so the order of the sums is
+    fixed and two runs give the same bits.
+
+    The product is float32-exact in three bfloat16 passes: the one-hot is
+    exact in bfloat16, and a float32 value is the sum of three bfloat16
+    pieces (8 + 8 + 8 bits of mantissa), each product accumulated in
+    float32. On the v5e that is 1.93 ms a sum at the 100k-endpoint bucket
+    against 2.26 ms at Precision.HIGHEST, which splits the one-hot too
+    (PERF.md, PR 27)."""
+    del block_ref  # read by the index maps
+    i = pl.program_id(0)
+    flag = flag_ref[i]
+
+    @pl.when(flag == 1)
+    def _first():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(flag >= 0)
+    def _add():
+        tn, be = out_ref.shape[0], owner_ref.shape[1]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (tn, be), 0) + tile_ref[i] * tn
+        one_hot = (owner_ref[...] == rows).astype(jnp.bfloat16)
+        m = msg_ref[...]
+        hi = m.astype(jnp.bfloat16)
+        rest = m - hi.astype(jnp.float32)
+        mid = rest.astype(jnp.bfloat16)
+        lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+        dot = partial(jnp.dot, preferred_element_type=jnp.float32)
+        out_ref[...] += dot(one_hot, hi) + dot(one_hot, mid) + dot(one_hot, lo)
+
+
+def _node_tiles(plan: EdgePlan) -> int:
+    return plan.item_tile.shape[0] - plan.owner.shape[1] // PLAN_EDGE_BLOCK
+
+
+def _planned_reduce_pallas(plan: EdgePlan, messages, interpret: bool):
+    width = messages.shape[1]
+    tn, be = PLAN_NODE_TILE, PLAN_EDGE_BLOCK
+    return pl.pallas_call(
+        _planned_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(plan.item_tile.shape[0],),
+            in_specs=[
+                pl.BlockSpec((1, be), lambda i, tile, block, flag: (0, block[i])),
+                pl.BlockSpec((be, width), lambda i, tile, block, flag: (block[i], 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (tn, width), lambda i, tile, block, flag: (tile[i], 0)
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((_node_tiles(plan) * tn, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        name="planned_neighbor_sum",
+        interpret=interpret,
+    )(plan.item_tile, plan.item_block, plan.item_flag, plan.owner, messages)
+
+
+def _planned_reduce_xla(plan: EdgePlan, messages):
+    """The same items in plain XLA: a batched one-hot product per item, then
+    a sorted sum of the items' tiles (node_tiles + edge_blocks rows, not one
+    per edge). The path off the TPU, and the oracle of the kernel."""
+    width = messages.shape[1]
+    tn, be = PLAN_NODE_TILE, PLAN_EDGE_BLOCK
+    node_tiles = _node_tiles(plan)
+    owners = plan.owner.reshape(-1, be)[plan.item_block]  # [I, be]
+    blocks = messages.reshape(-1, be, width)[plan.item_block]  # [I, be, W]
+    rows = plan.item_tile[:, None] * tn + jnp.arange(tn, dtype=jnp.int32)
+    one_hot = (owners[:, None, :] == rows[:, :, None]) & (
+        plan.item_flag >= 0
+    )[:, None, None]
+    partial_tiles = jnp.einsum(
+        "itb,ibw->itw",
+        one_hot.astype(jnp.float32),
+        blocks,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    tiles = jax.ops.segment_sum(
+        partial_tiles,
+        plan.item_tile,
+        num_segments=node_tiles,
+        indices_are_sorted=True,
+    )
+    return tiles.reshape(node_tiles * tn, width)
+
+
+def planned_impl() -> str:
+    """Which reducer a planned sum traces to: the Mosaic kernel on a TPU (or
+    wherever KMAMIZ_SPARSE=pallas asks for it, and then it raises where
+    Mosaic cannot compile), the kernel interpreted under pallas_interpret,
+    plain XLA elsewhere."""
+    if fused_interpret():
+        return "pallas_interpret"
+    if backend() == "pallas" or jax.default_backend() == "tpu":
+        return "pallas"
+    return "xla"
+
+
+def _planned_sum(plan: EdgePlan, h, impl: str):
+    messages = h.astype(jnp.float32)[plan.neighbour]  # [L, W]; parked read row 0
+    if impl == "xla":
+        out = _planned_reduce_xla(plan, messages)
+    else:
+        out = _planned_reduce_pallas(plan, messages, impl == "pallas_interpret")
+    return out[: h.shape[0]].astype(h.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _planned_sum_vjp(plan: EdgePlan, h, impl: str):
+    return _planned_sum(plan, h, impl)
+
+
+def _planned_sum_fwd(plan, h, impl):
+    return _planned_sum(plan, h, impl), plan
+
+
+def _planned_sum_bwd(impl, plan, g):
+    # A is symmetric: d(A @ h) pulls back through A itself
+    return None, _planned_sum(plan, g, impl)
+
+
+_planned_sum_vjp.defvjp(_planned_sum_fwd, _planned_sum_bwd)
+
+
+def planned_neighbor_sum(plan: EdgePlan, h: jnp.ndarray, impl: Optional[str] = None):
+    """Sum of neighbour rows over both edge directions, [N, W] -> [N, W]: what
+    `neighbor_mean`'s two gathers, mask multiplies and segment sums make,
+    from a prepared plan. `impl` is for tests and timing; callers leave it
+    to `planned_impl`. Counted in `route_stats()["planned"]` (trace time)."""
+    with _route_lock:
+        _route_counts["planned"] += 1
+    return _planned_sum_vjp(plan, h, impl or planned_impl())
 
 
 # ---------------------------------------------------------------------------

@@ -77,6 +77,7 @@ PHASES = (
     "refresh.pos_weight",
     "refresh.stack",
     "refresh.stack.host_fill",
+    "refresh.stack.plan",
     "refresh.stack.device_put",
     "refresh.epoch_block",
     "refresh.loss_fetch",

@@ -1,0 +1,461 @@
+"""The per-dataset edge plan and the planned neighbour sum (ops/sparse.py):
+the topology sorted once by owner, a tiled reduction over it in plain XLA
+and as a Pallas kernel (interpreted here), its own VJP, and the wiring into
+graphsage.forward, the stack and the epoch block."""
+from __future__ import annotations
+
+import zlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kmamiz_tpu.models import gat, graphsage, stacked, trainer
+from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.telemetry import REGISTRY
+
+TN, BE = sparse.PLAN_NODE_TILE, sparse.PLAN_EDGE_BLOCK
+IMPLS = ("xla", "pallas_interpret")
+
+
+def _zipf(rng, n, e):
+    weights = 1.0 / (np.arange(n) + 2.0)
+    return rng.choice(n, size=e, p=weights / weights.sum())
+
+
+def _case(name):
+    """(src, dst, edge_mask, bucket_nodes), bucket-padded as the stack pads."""
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    if name == "random":
+        n, nb, e, eb = 300, 512, 1500, 2048
+        src, dst, mask = rng.integers(0, n, e), rng.integers(0, n, e), np.ones(e, bool)
+    elif name == "zipf_heavy":  # endpoint 0 holds about a third of the entries
+        n, nb, e, eb = 900, 1024, 6000, 8192
+        src, dst, mask = rng.integers(0, n, e), _zipf(rng, n, e), np.ones(e, bool)
+    elif name == "masked":
+        n, nb, e, eb = 200, 256, 1000, 1024
+        src, dst, mask = rng.integers(0, n, e), rng.integers(0, n, e), rng.random(e) < 0.6
+    elif name == "padded":  # a handful of edges in a wide bucket
+        n, nb, e, eb = 40, 64, 9, 4096
+        src, dst, mask = rng.integers(0, n, e), rng.integers(0, n, e), np.ones(e, bool)
+    elif name == "empty":
+        n, nb, e, eb = 20, 32, 0, 8
+        src, dst, mask = np.zeros(0, int), np.zeros(0, int), np.ones(0, bool)
+    elif name == "heavier_than_a_block":  # one owner spans three edge blocks
+        n, nb, e, eb = 50, 64, 1400, 2048
+        src, dst, mask = rng.integers(0, n, e), rng.integers(0, n, e), np.ones(e, bool)
+        src[: 2 * BE + 100] = 7
+    elif name == "tile_with_no_edges":  # rows 128..383 hold no edge at all
+        n, nb, e, eb = 600, 1024, 700, 1024
+        src = np.where(rng.random(e) < 0.5, rng.integers(0, TN, e), rng.integers(3 * TN, n, e))
+        dst = np.where(rng.random(e) < 0.5, rng.integers(0, TN, e), rng.integers(3 * TN, n, e))
+        mask = np.ones(e, bool)
+    elif name == "self_loops_and_repeats":
+        n, nb, e, eb = 30, 32, 400, 512
+        src, dst, mask = rng.integers(0, 6, e), rng.integers(0, 6, e), np.ones(e, bool)
+    elif name == "every_entry_real":  # 2 x edges fills the last block to its end
+        n, nb, e, eb = 100, 128, 512, 512
+        src, dst, mask = rng.integers(0, n, e), rng.integers(0, n, e), np.ones(e, bool)
+    else:
+        raise KeyError(name)
+    pad = eb - e
+    return (
+        np.concatenate([src, np.zeros(pad, int)]).astype(np.int32),
+        np.concatenate([dst, np.zeros(pad, int)]).astype(np.int32),
+        np.concatenate([mask, np.zeros(pad, bool)]),
+        nb,
+    )
+
+
+CASES = (
+    "random", "zipf_heavy", "masked", "padded", "empty", "heavier_than_a_block",
+    "tile_with_no_edges", "self_loops_and_repeats", "every_entry_real",
+)
+
+
+def _device(plan):
+    return jax.tree_util.tree_map(jnp.asarray, plan)
+
+
+def _legacy_sum(h, src, dst, mask):
+    n = h.shape[0]
+    ones = jnp.ones(n, h.dtype)  # a degree of one: the mean is the sum
+    return graphsage.neighbor_mean(h, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask), ones)
+
+
+class TestBuildEdgePlan:
+    @pytest.mark.parametrize("name", CASES)
+    def test_shapes_are_a_function_of_the_buckets(self, name):
+        src, dst, mask, nb = _case(name)
+        plan, entries, items = sparse.build_edge_plan(src, dst, mask, nb)
+        total, node_tiles, bound = sparse.plan_shapes(nb, src.shape[0])
+        assert plan.owner.shape == (1, total) and plan.neighbour.shape == (total,)
+        assert plan.degree.shape == (nb,) and plan.degree.dtype == np.float32
+        assert plan.item_tile.shape == plan.item_block.shape == plan.item_flag.shape == (bound,)
+        assert total % BE == 0 and node_tiles == -(-nb // TN)
+        assert entries == 2 * int(mask.sum()) and node_tiles <= items <= bound
+
+    @pytest.mark.parametrize("name", CASES)
+    def test_degree_equals_neighbor_degree_exactly(self, name):
+        src, dst, mask, nb = _case(name)
+        plan, _, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        want = graphsage.neighbor_degree(nb, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask))
+        np.testing.assert_array_equal(plan.degree, np.asarray(want))
+
+    @pytest.mark.parametrize("name", CASES)
+    def test_every_real_edge_appears_once_per_direction_sorted_by_owner(self, name):
+        src, dst, mask, nb = _case(name)
+        plan, entries, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        owner, neighbour = plan.owner[0], plan.neighbour
+        assert (np.diff(owner) >= 0).all()
+        assert (owner[entries:] >= -(-nb // TN) * TN).all()  # parked past every tile
+        got = sorted(zip(owner[:entries].tolist(), neighbour[:entries].tolist()))
+        want = sorted(
+            list(zip(src[mask].tolist(), dst[mask].tolist()))
+            + list(zip(dst[mask].tolist(), src[mask].tolist()))
+        )
+        assert got == want
+
+    @pytest.mark.parametrize("name", CASES)
+    def test_work_list_covers_every_entry_and_every_tile(self, name):
+        src, dst, mask, nb = _case(name)
+        plan, entries, items = sparse.build_edge_plan(src, dst, mask, nb)
+        tile, block, flag = plan.item_tile, plan.item_block, plan.item_flag
+        assert (np.diff(tile) >= 0).all()
+        assert (flag[:items] >= 0).all() and (flag[items:] == -1).all()
+        # exactly one first item a tile, at the start of its run: it zeroes it
+        firsts = tile[:items][flag[:items] == 1]
+        np.testing.assert_array_equal(firsts, np.arange(-(-nb // TN)))
+        assert (flag[:items][np.r_[True, np.diff(tile[:items]) > 0]] == 1).all()
+        # no-ops stay on the last real item's blocks: nothing new is fetched
+        assert (tile[items:] == tile[items - 1]).all() and (block[items:] == block[items - 1]).all()
+        assert (block >= 0).all() and (block < plan.owner.shape[1] // BE).all()
+        have = set(zip(tile[:items].tolist(), block[:items].tolist()))
+        assert len(have) == items  # no product twice
+        owner = plan.owner[0, :entries]
+        need = set(zip((owner // TN).tolist(), (np.arange(entries) // BE).tolist()))
+        assert need <= have
+
+    def test_a_heavy_owner_spans_blocks_and_an_empty_tile_still_has_an_item(self):
+        src, dst, mask, nb = _case("heavier_than_a_block")
+        plan, _, items = sparse.build_edge_plan(src, dst, mask, nb)
+        assert plan.degree[7] > 2 * BE and items >= 3  # one tile, at least three blocks
+        src, dst, mask, nb = _case("tile_with_no_edges")
+        plan, _, items = sparse.build_edge_plan(src, dst, mask, nb)
+        assert plan.degree[TN : 3 * TN].sum() == 0
+        assert {1, 2} <= set(plan.item_tile[:items].tolist())
+
+    def test_an_edge_out_of_the_bucket_contributes_nothing(self):
+        src = np.array([0, 1, 99, 2], np.int32)
+        dst = np.array([1, 2, 3, -1], np.int32)
+        plan, entries, _ = sparse.build_edge_plan(src, dst, np.ones(4, bool), 8)
+        assert entries == 4
+        np.testing.assert_array_equal(plan.degree, [1, 2, 1, 0, 0, 0, 0, 0])
+
+
+class TestPlannedNeighborSum:
+    @pytest.mark.parametrize("impl", IMPLS)
+    @pytest.mark.parametrize("width", (18, 64))
+    @pytest.mark.parametrize("name", CASES)
+    def test_against_segment_sum(self, name, width, impl):
+        src, dst, mask, nb = _case(name)
+        plan, _, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        h = jnp.asarray(np.random.default_rng(1).normal(size=(nb, width)).astype(np.float32))
+        got = np.asarray(sparse.planned_neighbor_sum(_device(plan), h, impl))
+        want = np.asarray(_legacy_sum(h, src, dst, mask))
+        assert got.shape == want.shape and got.dtype == want.dtype
+        # the same float32 addends in another order: rounding of partial
+        # sums as large as the largest row (a heavy owner adds 1,200 rows)
+        scale = max(float(np.abs(want).max()), 1.0)
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * scale)
+        # rows with no neighbour are exact zeros, not rounding
+        np.testing.assert_array_equal(got[plan.degree == 0], 0.0)
+
+    @pytest.mark.parametrize("name", ("random", "heavier_than_a_block", "tile_with_no_edges"))
+    def test_kernel_and_xla_reducer_sum_the_same_items(self, name):
+        """Item by item the same products; the kernel adds its three bfloat16
+        passes one after the other, so the last bits may differ. Two runs of
+        either give the same bits."""
+        src, dst, mask, nb = _case(name)
+        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        h = jnp.asarray(np.random.default_rng(2).normal(size=(nb, 18)).astype(np.float32))
+        a, b = (np.asarray(sparse.planned_neighbor_sum(plan, h, impl)) for impl in IMPLS)
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * max(float(np.abs(a).max()), 1.0))
+        for impl, first in zip(IMPLS, (a, b)):
+            again = np.asarray(sparse.planned_neighbor_sum(plan, h, impl))
+            np.testing.assert_array_equal(again, first)
+
+    def test_products_are_float32_exact(self):
+        """One neighbour each: the sum IS the neighbour's row, every bit of
+        its float32 mantissa (a single bfloat16 pass would keep eight)."""
+        n = 256
+        src = np.arange(0, n, 2, dtype=np.int32)
+        dst = src + 1
+        plan = _device(sparse.build_edge_plan(src, dst, np.ones(n // 2, bool), n)[0])
+        h = jnp.asarray((np.random.default_rng(3).random((n, 64)) + 1.0).astype(np.float32))
+        swapped = np.asarray(h).reshape(n // 2, 2, 64)[:, ::-1].reshape(n, 64)
+        for impl in IMPLS:
+            np.testing.assert_array_equal(
+                np.asarray(sparse.planned_neighbor_sum(plan, h, impl)), swapped
+            )
+
+    @pytest.mark.parametrize("impl", IMPLS)
+    def test_vjp_is_the_forward_and_matches_autodiff_of_the_legacy_sum(self, impl):
+        src, dst, mask, nb = _case("masked")
+        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        rng = np.random.default_rng(4)
+        h = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
+        ct = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
+        out, pull = jax.vjp(lambda x: sparse.planned_neighbor_sum(plan, x, impl), h)
+        (got,) = pull(ct)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(sparse.planned_neighbor_sum(plan, ct, impl))
+        )
+        _, pull_legacy = jax.vjp(lambda x: _legacy_sum(x, src, dst, mask), h)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(pull_legacy(ct)[0]), rtol=0, atol=1e-4
+        )
+
+    def test_no_scatter_over_edge_rows_is_left_forward_or_backward(self):
+        src, dst, mask, nb = _case("random")
+        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        h = jnp.ones((nb, 18), jnp.float32)
+        loss = lambda x: (sparse.planned_neighbor_sum(plan, x, "xla") ** 2).sum()  # noqa: E731
+        text = jax.jit(jax.grad(loss)).lower(h).as_text()
+        entries = plan.neighbour.shape[0]
+        for line in text.splitlines():
+            if "scatter" in line:
+                assert f"{entries}x" not in line and f"{src.shape[0]}x" not in line, line
+
+    def test_counts_in_route_stats(self):
+        src, dst, mask, nb = _case("padded")
+        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        assert sparse.route_stats()["planned"] == 0
+        sparse.planned_neighbor_sum(plan, jnp.ones((nb, 4)), "xla")
+        assert sparse.route_stats()["planned"] == 1
+        sparse.reset_for_tests()
+        assert sparse.route_stats()["planned"] == 0
+
+    def test_which_reducer_the_backend_knob_selects(self, monkeypatch):
+        assert sparse.planned_impl() == "xla"  # a CPU, no knob
+        for value, want in (("pallas_interpret", "pallas_interpret"), ("pallas", "pallas")):
+            monkeypatch.setenv("KMAMIZ_SPARSE", value)
+            sparse.reset_for_tests()
+            assert sparse.planned_impl() == want
+
+
+def _params(num_features, hidden=8, num_nodes=0, seed=0):
+    return graphsage.init_params(
+        jax.random.PRNGKey(seed), hidden=hidden, num_features=num_features, num_nodes=num_nodes
+    )
+
+
+class TestForwardWithAPlan:
+    @pytest.mark.parametrize("backend", ("sparse", "pallas_interpret"))
+    @pytest.mark.parametrize("embeddings", (False, True), ids=("features", "embeddings"))
+    def test_loss_and_gradient_match_todays_forward(self, monkeypatch, backend, embeddings):
+        src, dst, mask, nb = _case("masked")
+        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        rng = np.random.default_rng(5)
+        x = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
+        tl = jnp.asarray(rng.normal(size=nb).astype(np.float32))
+        ta = jnp.asarray((rng.random(nb) < 0.2).astype(np.float32))
+        nm = jnp.asarray(rng.random(nb) < 0.9)
+        params = _params(18, num_nodes=nb if embeddings else 0)
+        args = (x, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask), tl, ta, nm)
+        want, want_grad = jax.value_and_grad(graphsage.loss_fn, has_aux=True)(params, *args)
+
+        monkeypatch.setenv("KMAMIZ_SPARSE", backend)
+        sparse.reset_for_tests()
+        from functools import partial
+
+        from kmamiz_tpu.models import common
+
+        planned = common.make_loss_fn(partial(graphsage.forward, plan=plan))
+        got, got_grad = jax.value_and_grad(planned, has_aux=True)(params, *args)
+        assert sparse.route_stats()["planned"] == 2  # both layers, at trace time
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=2e-6)
+        for name, a, b in zip(params._fields, got_grad, want_grad):
+            if a is None:
+                assert b is None
+                continue
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6, err_msg=name
+            )
+
+    def test_no_plan_keeps_todays_formulation(self):
+        src, dst, mask, nb = _case("random")
+        x = jnp.ones((nb, 10), jnp.float32)
+        graphsage.forward(_params(10), x, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask))
+        assert sparse.route_stats()["planned"] == 0
+
+
+def _dataset(n_nodes=40, n_edges=120, n_slots=4, seed=0):
+    rng = np.random.default_rng(seed)
+    return trainer.GraphDataset(
+        endpoint_names=[f"ep{i}" for i in range(n_nodes)],
+        src=rng.integers(0, n_nodes, n_edges, dtype=np.int32),
+        dst=rng.integers(0, n_nodes, n_edges, dtype=np.int32),
+        edge_mask=rng.random(n_edges) < 0.9,
+        features=[rng.normal(size=(n_nodes, 10)).astype(np.float32) for _ in range(n_slots)],
+        target_latency=[rng.normal(size=n_nodes).astype(np.float32) for _ in range(n_slots)],
+        target_anomaly=[(rng.random(n_nodes) < 0.2).astype(np.float32) for _ in range(n_slots)],
+        node_mask=[rng.random(n_nodes) < 0.9 for _ in range(n_slots)],
+        slot_keys=[f"s{i}" for i in range(n_slots)],
+    )
+
+
+def _head(ds, n):
+    return trainer.GraphDataset(
+        endpoint_names=ds.endpoint_names, src=ds.src, dst=ds.dst, edge_mask=ds.edge_mask,
+        features=ds.features[:n], target_latency=ds.target_latency[:n],
+        target_anomaly=ds.target_anomaly[:n], node_mask=ds.node_mask[:n],
+        slot_keys=ds.slot_keys[:n],
+    )
+
+
+def _counter(name):
+    for line in REGISTRY.render().splitlines():
+        if line.startswith(name + " "):
+            return float(line.split()[-1])
+    raise AssertionError(f"{name} is not in /metrics")
+
+
+class TestStackCarriesThePlan:
+    def test_the_stack_holds_the_plan_of_its_padded_edge_list(self):
+        ds = _dataset()
+        st = stacked.stack_dataset(ds)
+        want, entries, items = sparse.build_edge_plan(
+            np.asarray(st.src), np.asarray(st.dst), np.asarray(st.edge_mask), st.bucket_nodes
+        )
+        assert (st.plan_entries, st.plan_items) == (entries, items)
+        assert entries == 2 * int(np.asarray(ds.edge_mask).sum())
+        for got, w in zip(st.plan, want):
+            np.testing.assert_array_equal(np.asarray(got), w)
+        shapes = sparse.plan_shapes(st.bucket_nodes, st.bucket_edges)
+        assert st.plan.owner.shape == (1, shapes[0]) and st.plan.item_tile.shape == (shapes[2],)
+
+    def test_builds_and_hits_are_counted_and_datasets_over_one_graph_share_a_plan(self):
+        ds = _dataset()
+        st = stacked.stack_dataset(ds)
+        assert _counter("kmamiz_model_edge_plan_builds_total") == 1
+        assert _counter("kmamiz_model_edge_plan_hits_total") == 0
+        assert stacked.stack_dataset(ds) is st  # the stack's own memo
+        assert _counter("kmamiz_model_edge_plan_hits_total") == 1
+        head = stacked.stack_dataset(_head(ds, 2))  # another stack, the same arrays
+        assert head is not st and head.plan is st.plan
+        assert _counter("kmamiz_model_edge_plan_builds_total") == 1
+        assert _counter("kmamiz_model_edge_plan_hits_total") == 2
+        other = stacked.stack_dataset(_dataset(seed=1))  # another graph
+        assert other.plan is not st.plan
+        assert _counter("kmamiz_model_edge_plan_builds_total") == 2
+
+    def test_plan_for_goes_by_what_the_forward_takes_and_by_the_legacy_knob(self, monkeypatch):
+        st = stacked.stack_dataset(_dataset())
+        assert stacked.plan_for(graphsage, st) is st.plan
+        assert stacked.plan_for(gat, st) is None  # its forward takes none
+        monkeypatch.setenv("KMAMIZ_SPARSE", "xla")
+        sparse.reset_for_tests()
+        assert stacked.plan_for(graphsage, st) is None
+
+
+class TestTrainingThroughThePlan:
+    def test_a_refresh_engages_the_plan_with_no_knob_set(self, monkeypatch):
+        monkeypatch.delenv("KMAMIZ_SPARSE", raising=False)
+        sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()  # a fresh trace, so the route is counted
+        r = trainer.train(_dataset(), epochs=2, hidden=8)
+        assert np.isfinite(r.losses).all()
+        assert sparse.route_stats()["planned"] > 0
+        assert _counter("kmamiz_model_edge_plan_builds_total") == 1
+
+    @pytest.mark.parametrize("backend", ("sparse", "pallas_interpret"))
+    def test_epoch_block_with_a_plan_matches_the_legacy_per_slot_loop(self, monkeypatch, backend):
+        ds = _dataset()
+        # the oracle runs under no knob: the fused kernel of the pallas
+        # backends (<= 2,048 nodes, no plan) has no VJP to train through
+        legacy = trainer.train(ds, epochs=3, hidden=8, seed=0, fused=False)
+        assert sparse.route_stats()["planned"] == 0
+        monkeypatch.setenv("KMAMIZ_SPARSE", backend)
+        sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()
+        fused = trainer.train(ds, epochs=3, hidden=8, seed=0, fused=True)
+        assert sparse.route_stats()["planned"] > 0
+        np.testing.assert_allclose(fused.losses, legacy.losses, rtol=1e-4, atol=1e-5)
+        for a, b in zip(
+            jax.tree_util.tree_leaves(fused.params), jax.tree_util.tree_leaves(legacy.params)
+        ):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-5)
+
+    def test_the_xla_knob_keeps_the_legacy_formulation_everywhere(self, monkeypatch):
+        monkeypatch.setenv("KMAMIZ_SPARSE", "xla")
+        sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()
+        r = trainer.train(_dataset(), epochs=1, hidden=8)
+        assert np.isfinite(r.losses).all()
+        assert sparse.route_stats()["planned"] == 0
+
+    @pytest.mark.parametrize("path", ("dp_epoch_runner", "predict_all", "gat"))
+    def test_paths_that_pass_no_plan_keep_todays_code(self, path):
+        stacked.epoch_runner.cache_clear()
+        stacked.dp_epoch_runner.cache_clear()
+        stacked._batched_forward.cache_clear()
+        ds = _dataset()
+        if path == "dp_epoch_runner":
+            r = trainer.train(ds, epochs=1, hidden=8, batch_slots=2)
+            assert np.isfinite(r.losses).all()
+        elif path == "predict_all":
+            lat, logit = stacked.predict_all(_params(10), ds, graphsage)
+            assert lat.shape == logit.shape == (4, 40)
+        else:
+            r = trainer.train(ds, epochs=1, hidden=8, model=gat)
+            assert np.isfinite(r.losses).all()
+        assert sparse.route_stats()["planned"] == 0
+
+
+# -- the kernel at the cell's shapes, through the chip's own compiler ---------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("width", (18, 64))
+def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_chip, width):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    nb, eb = 131072, 524288
+    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    assert (entries, items) == (1048576, 1024 + 2048)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    plan = sparse.EdgePlan(
+        arg((1, entries), jnp.int32), arg((entries,), jnp.int32), arg((nb,), jnp.float32),
+        arg((items,), jnp.int32), arg((items,), jnp.int32), arg((items,), jnp.int32),
+    )
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
+    compilation_cache.reset_cache()
+    try:
+        compiled = (
+            jax.jit(lambda p, h: sparse.planned_neighbor_sum(p, h, "pallas"))
+            .lower(plan, arg((nb, width), jnp.float32))
+            .compile()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "planned_neighbor_sum" in text
+    assert "scatter" not in text

@@ -111,6 +111,7 @@ class TestRefreshTrace:
         stack_idx = [i for i, s in _children(tb) if s[0] == "refresh.stack"][0]
         assert _names(tb, stack_idx) == [
             "refresh.stack.host_fill",
+            "refresh.stack.plan",
             "refresh.stack.device_put",
         ]
         _assert_nested(tb)
@@ -129,7 +130,13 @@ class TestRefreshTrace:
         )
         assert by_name["refresh.stack"]["bytes"] == want
         assert by_name["refresh.stack.host_fill"]["bytes"] == want
-        assert by_name["refresh.epoch_block"] == {"epochs": 2, "slot_updates": 10}
+        assert by_name["refresh.epoch_block"] == {
+            "epochs": 2, "slot_updates": 10, "planned": 1,
+        }
+        real_edges = int(np.asarray(st.edge_mask).sum())
+        assert by_name["refresh.stack"]["plan_entries"] == 2 * real_edges
+        assert by_name["refresh.stack.plan"]["entries"] == 2 * real_edges
+        assert by_name["refresh.stack"]["plan_items"] == st.plan_items >= 1
 
     def test_second_refresh_hits_the_memoised_stack(self):
         ds = _dataset()
@@ -138,7 +145,10 @@ class TestRefreshTrace:
         tb = TRACER.traces()[-1]
         assert _names(tb) == FUSED_CHILDREN
         stack_idx = [i for i, s in _children(tb) if s[0] == "refresh.stack"][0]
-        assert _names(tb, stack_idx) == [] and tb.counts[stack_idx] == {"hit": 1}
+        st = stacked.stack_dataset(ds)
+        assert _names(tb, stack_idx) == [] and tb.counts[stack_idx] == {
+            "hit": 1, "plan_entries": st.plan_entries, "plan_items": st.plan_items,
+        }
 
     def test_train_inside_a_tick_nests_under_it_and_opens_no_second_trace(self):
         ds = _dataset()
@@ -200,12 +210,15 @@ class TestRefreshTrace:
         stacked.stack_dataset(ds)
         build, hit = TRACER.traces()
         assert [s[0] for s in build.spans] == [
-            "refresh.stack", "refresh.stack.host_fill", "refresh.stack.device_put",
+            "refresh.stack", "refresh.stack.host_fill", "refresh.stack.plan",
+            "refresh.stack.device_put",
         ]
         assert [s[0] for s in hit.spans] == ["refresh.stack"]
-        assert build.counts[0]["hit"] == 0 and hit.counts[0] == {"hit": 1}
+        assert build.counts[0]["hit"] == 0 and hit.counts[0]["hit"] == 1
         assert _counter("kmamiz_model_stack_builds_total") == 1
         assert _counter("kmamiz_model_stack_hits_total") == 1
+        assert _counter("kmamiz_model_edge_plan_builds_total") == 1
+        assert _counter("kmamiz_model_edge_plan_hits_total") == 1
 
     def test_telemetry_off_records_nothing_and_trains_the_same(self, monkeypatch):
         ds = _dataset()
@@ -220,8 +233,8 @@ class TestRefreshTrace:
     def test_refresh_names_have_histograms_and_the_program_avoids_the_benchmarks_name(self):
         wanted = {
             "refresh.train", "refresh.init", "refresh.resume", "refresh.pos_weight",
-            "refresh.stack", "refresh.stack.host_fill", "refresh.stack.device_put",
-            "refresh.epoch_block", "refresh.loss_fetch", "refresh.checkpoint_save",
+            "refresh.stack", "refresh.stack.host_fill", "refresh.stack.plan",
+            "refresh.stack.device_put", "refresh.epoch_block", "refresh.loss_fetch", "refresh.checkpoint_save",
             "refresh.legacy_epoch",
         }
         assert wanted <= set(PHASES)

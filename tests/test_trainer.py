@@ -515,6 +515,43 @@ class TestFusedTraining:
                 np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-5
             )
 
+    @pytest.mark.parametrize(
+        "n_nodes,n_edges", [(16, 24), (300, 1500)], ids=["one_tile", "three_tiles"]
+    )
+    def test_fused_runs_through_the_edge_plan_and_matches_legacy(
+        self, n_nodes, n_edges
+    ):
+        """The epoch block takes the stack's edge plan (models/stacked.py
+        plan_for): the neighbour sums are the planned, owner-sorted
+        reduction with its own VJP, the legacy loop's are the per-edge
+        segment sums, and the schedule is the same."""
+        import jax
+
+        from kmamiz_tpu.models import stacked
+        from kmamiz_tpu.ops import sparse
+
+        stacked.epoch_runner.cache_clear()  # count this trace's routing
+        ds = _synthetic_dataset(n_nodes=n_nodes, n_edges=n_edges, n_slots=4)
+        r_legacy = trainer.train(ds, epochs=4, hidden=8, seed=0, fused=False)
+        assert sparse.route_stats()["planned"] == 0
+        r_fused = trainer.train(ds, epochs=4, hidden=8, seed=0, fused=True)
+        assert sparse.route_stats()["planned"] > 0
+        st = stacked.stack_dataset(ds)
+        assert st.plan is not None and st.plan_entries == 2 * n_edges
+        np.testing.assert_allclose(
+            r_fused.losses, r_legacy.losses, rtol=1e-4, atol=1e-5
+        )
+        np.testing.assert_allclose(
+            r_fused.anomaly_losses, r_legacy.anomaly_losses, rtol=1e-4, atol=1e-5
+        )
+        for a, b in zip(
+            jax.tree_util.tree_leaves(r_fused.params),
+            jax.tree_util.tree_leaves(r_legacy.params),
+        ):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-5
+            )
+
     def test_fused_matches_legacy_with_embeddings(self):
         ds = _synthetic_dataset()
         r_l = trainer.train(

@@ -1181,9 +1181,11 @@ def child_models(sizes: dict) -> None:
 
 
 def child_kernels(sizes: dict) -> None:
-    """Phase D in the child that holds the chip: the three Pallas kernels
-    through their consumers, against the XLA path, at the tolerances
-    tests/test_ops_window.py and tests/test_ops_sparse.py pin."""
+    """Phase D in the child that holds the chip: the three older Pallas
+    kernels through their consumers, and the planned neighbour sum and the
+    planned attention of the refresh, against the XLA path, at the
+    tolerances tests/test_ops_window.py, tests/test_ops_sparse.py and
+    tests/test_planned_attention.py pin."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1292,6 +1294,44 @@ def child_kernels(sizes: dict) -> None:
     for name in ("sparse.fused_neighbor_sums", "sparse.fused_gated_bias"):
         check(programs.get(name).calls > 0, f"{name} was never dispatched")
     out["routes"] = routed
+
+    # -- the refresh's default since PR 27 and PR 28: the planned neighbour
+    # sum and the planned attention over an edge plan, Mosaic against the
+    # XLA items, values and one VJP each ----------------------------------
+    planned_impl = "pallas" if on_tpu else "pallas_interpret"
+    plan = jax.tree_util.tree_map(
+        jnp.asarray,
+        sparse.build_edge_plan(np.asarray(src), np.asarray(dst), np.asarray(mask), nodes)[0],
+    )
+    s_t = jnp.asarray(rng.normal(size=(2, nodes, 2)).astype(np.float32))
+    ct = jnp.asarray(rng.normal(size=(nodes, feat)).astype(np.float32))
+
+    def planned(impl):
+        total, pull_sum = jax.vjp(lambda x: sparse.planned_neighbor_sum(plan, x, impl), h)
+        att, pull_att = jax.vjp(
+            lambda x, s, t: sparse.planned_attention(plan, x, s, t, 0.2, impl),
+            h, s_t[0], s_t[1],
+        )
+        return [np.asarray(a) for a in (total, *pull_sum(ct), att, *pull_att(ct))]
+
+    check(
+        mosaic(
+            jax.jit(
+                jax.grad(lambda x: sparse.planned_attention(
+                    plan, x, s_t[0], s_t[1], 0.2, planned_impl).sum())
+            ).lower(h)
+        ) == on_tpu,
+        "the planned attention holds no Mosaic kernel on this TPU",
+    )
+    for name, got, want in zip(
+        ("sum", "sum.d_h", "attention", "attention.d_hw", "attention.d_s", "attention.d_t"),
+        planned(planned_impl), planned("xla"),
+    ):
+        scale = max(float(np.abs(want).max()), 1.0)
+        np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
+    out["planned_neighbor_sum"] = out["planned_attention"] = (
+        "mosaic" if on_tpu else "interpret"
+    )
 
     os.environ["KMAMIZ_SPARSE"] = "xla"
     sparse.reset_for_tests()  # the backend knob is cached after first read

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from kmamiz_tpu.models import common
 from kmamiz_tpu.models.graphsage import EMB_DIM, NUM_FEATURES
+from kmamiz_tpu.ops import sparse
 
 LEAK = 0.2
 
@@ -130,8 +131,20 @@ def _attend(h, src, dst, edge_mask, a_src, a_dst):
     return jax.ops.segment_sum(msgs, dst_c, num_segments=n)
 
 
-def _layer(h, src, dst, edge_mask, w, a_s, a_d, a_sr, a_dr, b):
+def _layer(h, src, dst, edge_mask, w, a_s, a_d, a_sr, a_dr, b, plan=None):
     hw = h @ w
+    if plan is not None:
+        # both directions in one pass over the plan's entries (owner,
+        # neighbour, direction): the neighbour's half of the score is made
+        # per node, not per edge. Direction 0 is an edge out of the owner
+        # (the reverse attention: the owner is the edge's source), 1 an edge
+        # into it (the forward attention). Four matrix-vector products, as
+        # `_attend` makes per edge: XLA's TPU backend reduces those in
+        # float32, where a [H, 2] product would go through the MXU in one
+        # bfloat16 pass and move the loss by 1e-5 (PERF.md, PR 28)
+        s = jnp.stack([hw @ a_sr, hw @ a_s], axis=1)  # [N, 2], of the neighbour
+        t = jnp.stack([hw @ a_dr, hw @ a_d], axis=1)  # [N, 2], of the owner
+        return jax.nn.elu(hw + sparse.planned_attention(plan, hw, s, t, LEAK) + b)
     fwd = _attend(hw, src, dst, edge_mask, a_s, a_d)
     rev = _attend(hw, dst, src, edge_mask, a_sr, a_dr)
     return jax.nn.elu(hw + fwd + rev + b)
@@ -143,18 +156,25 @@ def forward(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
+    plan: sparse.EdgePlan = None,
 ):
-    """Two attention layers -> (latency prediction [N], anomaly logits [N])."""
+    """Two attention layers -> (latency prediction [N], anomaly logits [N]).
+
+    `plan` is the edge plan of (src_ep, dst_ep, edge_mask) where the caller
+    has prepared one (the training refresh: models/stacked.py): each layer's
+    two segment softmaxes and weighted sums are then one pass over the
+    plan's sorted entries (sparse.planned_attention), the same mathematics
+    in another order. Without one the edge list is reduced as it comes."""
     x = common.concat_embedding(features, params.embedding)
     h1 = _layer(
         x, src_ep, dst_ep, edge_mask,
         params.w_1, params.a_src_1, params.a_dst_1,
-        params.a_src_1r, params.a_dst_1r, params.b_1,
+        params.a_src_1r, params.a_dst_1r, params.b_1, plan,
     )
     h2 = _layer(
         h1, src_ep, dst_ep, edge_mask,
         params.w_2, params.a_src_2, params.a_dst_2,
-        params.a_src_2r, params.a_dst_2r, params.b_2,
+        params.a_src_2r, params.a_dst_2r, params.b_2, plan,
     )
     latency = (
         h2 @ params.w_latency + features @ params.w_latency_skip + params.b_latency

@@ -30,13 +30,17 @@ device-native:
   jitted call — the batched evaluation path shared by trainer.evaluate
   and trainer.calibrate_threshold.
 - every stack carries the EDGE PLAN of its topology (ops/sparse.py:
-  the neighbour list sorted by owner, the degree, a tiled reducer's work
-  list), built once on the host and memoised by the identity of the edge
-  arrays, so datasets over one graph (a history and its head, a train and
-  a test split) share one. `epoch_runner`'s block takes it as a loop
-  constant beside src/dst/edge_mask and hands it to a head whose forward
-  takes one (`plan_for`); the vmapped paths (`dp_epoch_runner`,
-  `predict_all`) pass none and reduce the edge list as it comes.
+  the neighbour list sorted by owner and, within an owner, by the edge's
+  direction, the degree, a tiled reducer's work list), built once on the
+  host and memoised by the identity of the edge arrays, so datasets over
+  one graph (a history and its head, a train and a test split) share one.
+  `epoch_runner`'s block takes it as a loop constant beside
+  src/dst/edge_mask and hands it to a head whose forward takes one
+  (`plan_for`): GraphSAGE sums neighbour rows over it
+  (`sparse.planned_neighbor_sum`), GAT runs its directed segment softmax
+  and weighted sums over it (`sparse.planned_attention`); STLGT takes
+  none yet. The vmapped paths (`dp_epoch_runner`, `predict_all`) pass none
+  and reduce the edge list as it comes.
 
 Bit discipline: with the default batch size of 1 the scan body performs
 the identical per-slot update sequence as the legacy Python loop; only
@@ -138,6 +142,7 @@ class StackedDataset:
     plan: Optional[sparse.EdgePlan] = None  # of (src, dst, edge_mask)
     plan_entries: int = 0  # real (owner, neighbour) entries: 2 x real edges
     plan_items: int = 0  # real (node tile, edge block) products of one sum
+    plan_runs: int = 0  # (owner, direction) runs that hold an entry: the softmaxes
 
     def layout(self) -> dict:
         """The shape contract a checkpoint records (and resume validates):
@@ -188,6 +193,7 @@ def stack_dataset(dataset) -> StackedDataset:
                 hit=1,
                 plan_entries=cached.plan_entries,
                 plan_items=cached.plan_items,
+                plan_runs=cached.plan_runs,
             )
             return cached
         _STACK_BUILDS.inc()
@@ -200,34 +206,47 @@ def stack_dataset(dataset) -> StackedDataset:
 
 
 #: edge plans by the identity of the edge arrays they were made from (and
-#: the node bucket): [(src, dst, edge_mask, bucket_nodes, plan, entries,
-#: items)], newest last. The arrays are held so that their ids stay theirs;
-#: like the stack's memo it trusts that nobody writes into them.
+#: the node bucket): [(src, dst, edge_mask, bucket_nodes, plan, (entries,
+#: items, runs))], newest last. The arrays are held so that their ids stay
+#: theirs; like the stack's memo it trusts that nobody writes into them.
 _PLAN_MEMO: list = []
 _PLAN_MEMO_SIZE = 4
 
 
 def _edge_plan(dataset, src, dst, e_mask, nb: int):
-    """(device EdgePlan, entries, items) of a dataset's padded edge list."""
+    """(device EdgePlan, (entries, items, runs)) of a dataset's padded edge
+    list. The span counts what the directed head walks: the entries of each
+    direction (an edge out of its owner, an edge into it) and the (owner,
+    direction) runs, one softmax each."""
     key = (dataset.src, dataset.dst, dataset.edge_mask)
-    for *held, held_nb, plan, entries, items in _PLAN_MEMO:
+    for *held, held_nb, plan, counts in _PLAN_MEMO:
         if held_nb == nb and all(a is b for a, b in zip(held, key)):
             _PLAN_HITS.inc()
-            return plan, entries, items
+            return plan, counts
     _PLAN_BUILDS.inc()
     with phase_span("refresh.stack.plan"):
         host_plan, entries, items = sparse.build_edge_plan(src, dst, e_mask, nb)
+        run_key = host_plan.owner[0, :entries] * 2 + host_plan.direction[0, :entries]
+        runs = int(np.count_nonzero(np.diff(run_key))) + 1 if entries else 0
+        entries_in = int(host_plan.direction[0, :entries].sum())
         plan = jax.tree_util.tree_map(jnp.asarray, host_plan)
-        TRACER.note(entries=entries, items=items)
-    _PLAN_MEMO.append((*key, nb, plan, entries, items))
+        TRACER.note(
+            entries=entries,
+            items=items,
+            entries_out=entries - entries_in,
+            entries_in=entries_in,
+            runs=runs,
+        )
+    counts = (entries, items, runs)
+    _PLAN_MEMO.append((*key, nb, plan, counts))
     del _PLAN_MEMO[:-_PLAN_MEMO_SIZE]
-    return plan, entries, items
+    return plan, counts
 
 
 def plan_for(model, stacked: StackedDataset) -> Optional[sparse.EdgePlan]:
-    """The stack's edge plan, for a head whose `forward` takes one; None for
-    the others and under KMAMIZ_SPARSE=xla (the legacy formulation
-    everywhere). What `train()` hands the epoch block."""
+    """The stack's edge plan, for a head whose `forward` takes one
+    (GraphSAGE, GAT); None for the others and under KMAMIZ_SPARSE=xla (the
+    legacy formulation everywhere). What `train()` hands the epoch block."""
     if not sparse.use_sparse():
         return None
     if "plan" not in inspect.signature(model.forward).parameters:
@@ -267,7 +286,9 @@ def _build_stack(dataset) -> StackedDataset:
             a.nbytes for a in (feats, t_lat, t_ano, n_mask, src, dst, e_mask)
         )
         TRACER.note(bytes=nbytes)
-    plan, plan_entries, plan_items = _edge_plan(dataset, src, dst, e_mask, nb)
+    plan, (plan_entries, plan_items, plan_runs) = _edge_plan(
+        dataset, src, dst, e_mask, nb
+    )
     with phase_span("refresh.stack.device_put"):
         stacked = StackedDataset(
             features=jnp.asarray(feats),
@@ -285,10 +306,15 @@ def _build_stack(dataset) -> StackedDataset:
             plan=plan,
             plan_entries=plan_entries,
             plan_items=plan_items,
+            plan_runs=plan_runs,
         )
         TRACER.note(bytes=nbytes)
     TRACER.note(  # on refresh.stack
-        hit=0, bytes=nbytes, plan_entries=plan_entries, plan_items=plan_items
+        hit=0,
+        bytes=nbytes,
+        plan_entries=plan_entries,
+        plan_items=plan_items,
+        plan_runs=plan_runs,
     )
     return stacked
 

@@ -27,7 +27,15 @@ backend behind all four:
   backward is the forward) — O(E) one-hot work, no node-table cap, no
   scatter. A Pallas kernel on the TPU, the same items in plain XLA
   elsewhere. It engages by what the caller passes (a plan), under every
-  backend but ``xla``.
+  backend but ``xla``. GraphSAGE's refresh takes it.
+- **The planned attention** (``planned_attention``): GAT's refresh on the
+  same plan. An entry also says which direction of its edge it is, so the
+  two directed segment softmaxes of a layer and their weighted sums are
+  one pass over the sorted entries: a softmax over consecutive runs, a
+  weighted planned sum, the per-entry product ``<g[owner], hw[neighbour]>``
+  of the backward pass, with their VJP. Five Mosaic kernels on the TPU,
+  sorted segment reductions in plain XLA elsewhere; no scatter and no 1-D
+  gather in either pass.
 - **Sparse counting primitives** for the scorer rewrite
   (``dense_rank_pairs``, ``run_start_index``): the scorers replace the
   8M-row 5-key lexsort with packed-int32 single-key UNSTABLE sorts per
@@ -41,8 +49,9 @@ Backend knob (mirrored in config.Settings):
   counting path, the dependency walk picks the flat-gather variant on
   CPU hosts (the MXU packed walk stays default on TPU, where it measures
   >=50x faster); GraphSAGE/STLGT keep their gather/segment-sum XLA code
-  for a graph used once (the tick), and GraphSAGE's training refresh
-  takes the planned sum over the stack's edge plan.
+  for a graph used once (the tick), and the training refresh of GraphSAGE
+  and of GAT takes the planned sum and the planned attention over the
+  stack's edge plan.
 - ``KMAMIZ_SPARSE=pallas``: additionally routes the STLGT bias and
   GraphSAGE neighbor sums through the fused Pallas kernel, compiled by
   Mosaic — on a backend Mosaic cannot target the kernel RAISES; it never
@@ -90,7 +99,9 @@ _node_max_cache: Optional[int] = None
 _route_lock = threading.Lock()
 #: fused-kernel routing decisions since process start (trace-time
 #: counts: the consumers decide inside their jit traces)
-_route_counts = {"fused": 0, "gaveWay": 0, "lastGaveWayNodes": 0, "planned": 0}
+_route_counts = {
+    "fused": 0, "gaveWay": 0, "lastGaveWayNodes": 0, "planned": 0, "attention": 0,
+}
 
 
 def backend() -> str:
@@ -137,7 +148,9 @@ def reset_for_tests() -> None:
     _tile_cache = None
     _node_max_cache = None
     with _route_lock:
-        _route_counts.update(fused=0, gaveWay=0, lastGaveWayNodes=0, planned=0)
+        _route_counts.update(
+            fused=0, gaveWay=0, lastGaveWayNodes=0, planned=0, attention=0
+        )
 
 
 def use_sparse() -> bool:
@@ -453,12 +466,18 @@ class EdgePlan(NamedTuple):
     """The topology of one stacked dataset, prepared for `planned_neighbor_sum`.
     Shapes are a function of the node and edge buckets alone.
 
-    An entry is one real edge seen from one end: (owner, neighbour). Entries
-    are sorted by owner; masked and padding edges are parked past the end
-    with an owner no tile holds. An item is one (node tile, edge block) pair
-    whose product contributes to the tile; every tile has at least one item
-    (an empty tile's product is all zeros, and writes them), so there are at
-    most node_tiles + edge_blocks, and the list is padded to that with no-ops.
+    An entry is one real edge seen from one end: (owner, neighbour,
+    direction), direction 0 where the owner is the edge's source (an edge OUT
+    of the owner) and 1 where it is the destination (an edge INTO it). Entries
+    are sorted by owner and, within an owner, by direction; masked and padding
+    edges are parked past the end with an owner no tile holds. Every real
+    edge makes two entries that mirror each other: (u, v, 0) and (v, u, 1).
+    An item is one (node tile, edge block) pair whose product contributes to
+    the tile; every tile has at least one item (an empty tile's product is
+    all zeros, and writes them), so there are at most node_tiles +
+    edge_blocks, and the list is padded to that with no-ops. The blocks of
+    consecutive items never fall, so a per-entry result can be written block
+    by block as a per-node result is tile by tile.
     """
 
     owner: jnp.ndarray  # [1, L] int32, ascending; L = 2 * edge bucket, blocked
@@ -467,6 +486,7 @@ class EdgePlan(NamedTuple):
     item_tile: jnp.ndarray  # [I] int32, ascending
     item_block: jnp.ndarray  # [I] int32
     item_flag: jnp.ndarray  # [I] int32: 1 first of its tile, 0 adds, -1 no-op
+    direction: jnp.ndarray  # [1, L] int32: 0 owner is the source, 1 the destination
 
 
 def plan_shapes(bucket_nodes: int, bucket_edges: int) -> Tuple[int, int, int]:
@@ -498,8 +518,11 @@ def build_edge_plan(src, dst, edge_mask, bucket_nodes: int):
     n_real = int(own.shape[0])
     owner = np.full(entries, node_tiles * tn, dtype=np.int32)  # parked
     neighbour = np.zeros(entries, dtype=np.int32)
+    direction = np.zeros(entries, dtype=np.int32)
     owner[:n_real] = own[order]
     neighbour[:n_real] = nei[order]
+    # the sort is stable and the sources come first: out-edges, then in-edges
+    direction[:n_real] = order >= n_real // 2
 
     counts = np.bincount(own, minlength=nb)  # every owner is < nb
     row_ptr = np.zeros(node_tiles * tn + 1, dtype=np.int64)
@@ -530,8 +553,18 @@ def build_edge_plan(src, dst, edge_mask, bucket_nodes: int):
         item_tile=item_tile,
         item_block=item_block,
         item_flag=item_flag,
+        direction=direction[None, :],
     )
     return plan, n_real, n_items
+
+
+def _split3(x):
+    """A float32 array as three bfloat16 pieces that sum to it exactly."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
 
 def _planned_kernel(tile_ref, block_ref, flag_ref, owner_ref, msg_ref, out_ref):
@@ -559,11 +592,7 @@ def _planned_kernel(tile_ref, block_ref, flag_ref, owner_ref, msg_ref, out_ref):
         tn, be = out_ref.shape[0], owner_ref.shape[1]
         rows = jax.lax.broadcasted_iota(jnp.int32, (tn, be), 0) + tile_ref[i] * tn
         one_hot = (owner_ref[...] == rows).astype(jnp.bfloat16)
-        m = msg_ref[...]
-        hi = m.astype(jnp.bfloat16)
-        rest = m - hi.astype(jnp.float32)
-        mid = rest.astype(jnp.bfloat16)
-        lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+        hi, mid, lo = _split3(msg_ref[...])
         dot = partial(jnp.dot, preferred_element_type=jnp.float32)
         out_ref[...] += dot(one_hot, hi) + dot(one_hot, mid) + dot(one_hot, lo)
 
@@ -672,6 +701,464 @@ def planned_neighbor_sum(plan: EdgePlan, h: jnp.ndarray, impl: Optional[str] = N
     with _route_lock:
         _route_counts["planned"] += 1
     return _planned_sum_vjp(plan, h, impl or planned_impl())
+
+
+# ---------------------------------------------------------------------------
+# planned attention: a directed segment softmax and its weighted sum on the plan
+# ---------------------------------------------------------------------------
+#
+# GAT's layer over both edge directions is ONE pass over the plan's entries:
+# entry e = (owner i, neighbour j, direction d) scores
+# `leaky_relu(s[j, d] + t[i, d])`, the softmax runs over the consecutive
+# entries of one (owner, direction), and `out[i] = sum_e alpha_e * hw[j]`.
+# On the TPU that is five walks of the plan's items, forward and backward,
+# each a Mosaic kernel the device trace names:
+#
+#   planned_attention_max       z_e and the largest score of each run
+#   planned_attention_softmax   p_e = exp(score_e - max) and each run's sum
+#   planned_attention_sum       alpha_e = p_e / sum, out = sum alpha_e hw[j]
+#   planned_attention_edge_dot  d alpha_e = <g[i], hw[j]>, and sum alpha d alpha
+#   planned_attention_backward  the softmax's and leaky-relu's gradients, the
+#                               sorted sums d s, d t, and the transposed
+#                               weighted sum d hw[j] = sum alpha_e g[i]
+#
+# Inside a kernel a per-entry scalar lives in a ROW ([8, block], entries on
+# lanes) and a per-node scalar of the tile in a row too ([8, tile]); the
+# item's one-hot [tile, block] carries one into the other on the MXU (exact:
+# the one-hot is exact in bfloat16, the value goes as three bfloat16 pieces).
+# What belongs to the NEIGHBOUR travels with its gathered row: the message
+# array is [entries, 128 lanes] whatever the width (PERF.md), so the lanes
+# past the width hold the neighbour's scalars, and the kernel turns those
+# columns into rows on the MXU. So there is no 1-D gather, and no scatter.
+#
+# The transposed sums (d hw[j] and d s[j, d] run over the entries whose
+# NEIGHBOUR is j) need no permutation either: every entry has a mirror, the
+# same edge seen from its other end, with owner and neighbour swapped and
+# the other direction. The sum over "entries whose neighbour is j" is the
+# sum over the mirrors of "entries whose owner is j", and a mirror's alpha
+# is recomputed where it is needed, bit for bit, from what the walk has at
+# hand: s of the owner, and t, max and sum of the neighbour (in the lanes of
+# its gathered row).
+
+ATT_ROWS = 8  # sublanes of a row table: at most eight scalars per entry or node
+ATT_SPARE = 8  # lanes kept past the width for the neighbour's scalars
+_NEG = -1e30  # below every score; an empty run's maximum
+_NN = (((1,), (0,)), ((), ()))  # [m, k] @ [k, n]
+_NT = (((1,), (1,)), ((), ()))  # [m, k] @ [n, k]^T
+
+
+def _mxu(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _dot6(a3, b3, dims):
+    """The float32 product of two split arrays in six bfloat16 passes (the
+    three left out are below 2^-24 of the result), small terms first."""
+    a1, a2, a3_ = a3
+    b1, b2, b3_ = b3
+    low = _mxu(a1, b3_, dims) + _mxu(a3_, b1, dims) + _mxu(a2, b2, dims)
+    return low + (_mxu(a1, b2, dims) + _mxu(a2, b1, dims)) + _mxu(a1, b1, dims)
+
+
+def _expand(node_rows, hot):
+    """[R, tile] node rows -> [R, block] entry rows by owner (0 for an entry
+    no row of the tile owns)."""
+    return sum(_mxu(piece, hot, _NN) for piece in reversed(_split3(node_rows)))
+
+
+def _reduce(entry_rows, hot):
+    """[R, block] entry rows -> [R, tile]: each node's sum over its entries."""
+    return sum(_mxu(piece, hot, _NT) for piece in reversed(_split3(entry_rows)))
+
+
+def _columns_as_rows(msg3, first: int):
+    """Columns first .. first + 7 of a split [block, lanes] message block as
+    [8, block] rows."""
+    lanes = msg3[0].shape[1]
+    want = jax.lax.broadcasted_iota(jnp.int32, (ATT_ROWS, lanes), 0) + first
+    pick = (jax.lax.broadcasted_iota(jnp.int32, (ATT_ROWS, lanes), 1) == want)
+    pick = pick.astype(jnp.bfloat16)
+    return sum(_mxu(pick, piece, _NT) for piece in reversed(msg3))
+
+
+def _rows(*vectors):
+    """[1, n] rows stacked into an [ATT_ROWS, n] table, zeros below."""
+    n = vectors[0].shape[1]
+    rid = jax.lax.broadcasted_iota(jnp.int32, (ATT_ROWS, n), 0)
+    out = jnp.zeros((ATT_ROWS, n), jnp.float32)
+    for k, v in enumerate(vectors):
+        out = jnp.where(rid == k, v, out)
+    return out
+
+
+def _by_direction(d, x0, x1):
+    return jnp.where(d == 0, x0, x1)
+
+
+def _leaky(z, leak):
+    return jnp.where(z >= 0, z, leak * z)
+
+
+def _rows_by_direction(d, x):
+    """A per-entry row as two, one per direction: what `_reduce` turns into
+    each node's sum over its out-entries and over its in-entries."""
+    return _rows(jnp.where(d == 0, x, 0.0), jnp.where(d == 1, x, 0.0))
+
+
+def _item(tile_ref, block_ref, flag_ref, owner_ref, by_block=(), by_tile=(), fill=0.0):
+    """What every walk starts from. Zeroes the per-entry outputs `by_block` on
+    the first visit of their edge block and fills the per-node outputs
+    `by_tile` on the first item of their tile (both stay in VMEM across the
+    consecutive items that share them), and returns (whether the item is
+    real and not padding, its one-hot [tile, block] as a mask)."""
+    i = pl.program_id(0)
+    flag = flag_ref[i]
+    tn, be = PLAN_NODE_TILE, owner_ref.shape[1]
+    row_in_tile = owner_ref[...] - tile_ref[i] * tn  # [1, block]
+    one_hot = row_in_tile == jax.lax.broadcasted_iota(jnp.int32, (tn, be), 0)
+
+    @pl.when((i == 0) | (block_ref[i] != block_ref[jnp.maximum(i - 1, 0)]))
+    def _new_block():
+        for ref in by_block:
+            ref[...] = jnp.zeros_like(ref)
+
+    @pl.when(flag == 1)
+    def _new_tile():
+        for ref in by_tile:
+            ref[...] = jnp.full_like(ref, fill)
+
+    return flag >= 0, one_hot
+
+
+def _attention_max_kernel(
+    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, msg_ref, trow_ref,
+    z_ref, top_ref, *, width: int, leak: float,
+):
+    real, one_hot = _item(
+        tile_ref, block_ref, flag_ref, owner_ref, (z_ref,), (top_ref,), _NEG
+    )
+
+    @pl.when(real)
+    def _walk():
+        hot = one_hot.astype(jnp.bfloat16)
+        nbr = _columns_as_rows(_split3(msg_ref[...]), width)  # s of the neighbour
+        own = _expand(trow_ref[...], hot)  # t of the owner, and a row of ones
+        d = dir_ref[...]
+        inside = own[2:3] > 0.5
+        z = _by_direction(d, nbr[0:1] + own[0:1], nbr[1:2] + own[1:2])
+        z_ref[...] += _rows(jnp.where(inside, z, 0.0))
+        score = _leaky(z, leak)
+        tops = []
+        for k in (0, 1):
+            mine = jnp.where(inside & (d == k), score, _NEG)
+            tops.append(jnp.max(jnp.where(one_hot, mine, _NEG), axis=1, keepdims=True))
+        lane = jax.lax.broadcasted_iota(jnp.int32, top_ref.shape, 1)
+        top_ref[...] = jnp.maximum(top_ref[...], jnp.where(lane == 0, tops[0], tops[1]))
+
+
+def _attention_softmax_kernel(
+    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, z_ref, mrow_ref,
+    p_ref, total_ref, *, leak: float,
+):
+    real, one_hot = _item(tile_ref, block_ref, flag_ref, owner_ref, (p_ref,), (total_ref,))
+
+    @pl.when(real)
+    def _walk():
+        hot = one_hot.astype(jnp.bfloat16)
+        own = _expand(mrow_ref[...], hot)  # the run's maximum, and a row of ones
+        d = dir_ref[...]
+        inside = own[2:3] > 0.5
+        shift = _by_direction(d, own[0:1], own[1:2])
+        delta = jnp.clip(_leaky(z_ref[0:1, :], leak) - shift, -60.0, 0.0)
+        p = jnp.where(inside, jnp.exp(delta), 0.0)
+        p_ref[...] += _rows(p)
+        total_ref[...] += _reduce(_rows_by_direction(d, p), hot)
+
+
+def _attention_sum_kernel(
+    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, p_ref, lrow_ref, msg_ref,
+    alpha_ref, out_ref,
+):
+    real, one_hot = _item(tile_ref, block_ref, flag_ref, owner_ref, (alpha_ref,), (out_ref,))
+
+    @pl.when(real)
+    def _walk():
+        hot = one_hot.astype(jnp.bfloat16)
+        own = _expand(lrow_ref[...], hot)  # the run's sum, and a row of ones
+        d = dir_ref[...]
+        inside = own[2:3] > 0.5
+        total = jnp.maximum(_by_direction(d, own[0:1], own[1:2]), 1e-30)
+        alpha = jnp.where(inside, p_ref[0:1, :] / total, 0.0)
+        alpha_ref[...] += _rows(alpha)
+        weights = jnp.where(one_hot, alpha, 0.0)  # [tile, block]
+        out_ref[...] += _dot6(_split3(weights), _split3(msg_ref[...]), _NN)
+
+
+def _attention_edge_dot_kernel(
+    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, alpha_ref, msg_ref, g_ref,
+    dalpha_ref, c_ref,
+):
+    real, one_hot = _item(tile_ref, block_ref, flag_ref, owner_ref, (dalpha_ref,), (c_ref,))
+
+    @pl.when(real)
+    def _walk():
+        hot = one_hot.astype(jnp.bfloat16)
+        dots = _dot6(_split3(g_ref[...]), _split3(msg_ref[...]), _NT)  # [tile, block]
+        dalpha = jnp.sum(jnp.where(one_hot, dots, 0.0), axis=0, keepdims=True)
+        dalpha_ref[...] += _rows(dalpha)
+        d = dir_ref[...]
+        y = alpha_ref[0:1, :] * dalpha  # 0 for an entry of another tile
+        c_ref[...] += _reduce(_rows_by_direction(d, y), hot)
+
+
+def _attention_backward_kernel(
+    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, z_ref, alpha_ref, dalpha_ref,
+    msg_ref, hw_ref, nrow_ref, dhw_ref, dst_ref, *, width: int, leak: float,
+):
+    real, one_hot = _item(tile_ref, block_ref, flag_ref, owner_ref, (), (dhw_ref, dst_ref))
+
+    @pl.when(real)
+    def _walk():
+        hot = one_hot.astype(jnp.bfloat16)
+        msg3 = _split3(msg_ref[...])  # g of the neighbour, then its t, max, sum, c
+        nbr = _columns_as_rows(msg3, width)
+        own = _expand(nrow_ref[...], hot)  # s and c of the owner, and a row of ones
+        d = dir_ref[...]
+        inside = own[4:5] > 0.5
+        # this entry's own softmax: d z = alpha (d alpha - c) leaky'(z)
+        z = z_ref[0:1, :]
+        c = _by_direction(d, own[2:3], own[3:4])
+        dz = alpha_ref[0:1, :] * (dalpha_ref[0:1, :] - c) * jnp.where(z >= 0, 1.0, leak)
+        dz = jnp.where(inside, dz, 0.0)
+        # its mirror's, in the other direction: s of the owner, the rest the
+        # neighbour's (rows t0 t1 max0 max1 sum0 sum1 c0 c1)
+        zm = _by_direction(d, own[1:2] + nbr[1:2], own[0:1] + nbr[0:1])
+        shift = _by_direction(d, nbr[3:4], nbr[2:3])
+        total = jnp.maximum(_by_direction(d, nbr[5:6], nbr[4:5]), 1e-30)
+        cm = _by_direction(d, nbr[7:8], nbr[6:7])
+        pm = jnp.exp(jnp.clip(_leaky(zm, leak) - shift, -60.0, 0.0))
+        alpha_m = jnp.where(inside, pm / total, 0.0)
+        dots = _dot6(_split3(hw_ref[...]), msg3, _NT)  # <hw[owner], g[neighbour]>
+        dalpha_m = jnp.sum(jnp.where(one_hot, dots, 0.0), axis=0, keepdims=True)
+        dzm = alpha_m * (dalpha_m - cm) * jnp.where(zm >= 0, 1.0, leak)
+        # the mirror's direction is 1 - d: its d s lands in the other row
+        dst_ref[...] += _reduce(
+            _rows(
+                jnp.where(d == 1, dzm, 0.0), jnp.where(d == 0, dzm, 0.0),
+                jnp.where(d == 0, dz, 0.0), jnp.where(d == 1, dz, 0.0),
+            ),
+            hot,
+        )
+        weights = jnp.where(one_hot, alpha_m, 0.0)
+        dhw_ref[...] += _dot6(_split3(weights), msg3, _NN)
+
+
+def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bool):
+    """One walk of the plan's items. `inputs` and `outputs` are (kind, array
+    or lane width) pairs; the kind says how a block follows the item: "entry"
+    [R, L] by edge block, "message" [L, lanes] by edge block, "node_rows"
+    [R, nodes] by tile, "node" [nodes, lanes] by tile."""
+    tn, be = PLAN_NODE_TILE, PLAN_EDGE_BLOCK
+    entries, nodes = plan.owner.shape[1], _node_tiles(plan) * tn
+
+    def spec(kind, lanes):
+        if kind == "entry":
+            return pl.BlockSpec((lanes, be), lambda i, tile, block, flag: (0, block[i]))
+        if kind == "message":
+            return pl.BlockSpec((be, lanes), lambda i, tile, block, flag: (block[i], 0))
+        if kind == "node_rows":
+            return pl.BlockSpec((lanes, tn), lambda i, tile, block, flag: (0, tile[i]))
+        return pl.BlockSpec((tn, lanes), lambda i, tile, block, flag: (tile[i], 0))
+
+    def shape(kind, lanes):
+        if kind == "entry":
+            return (lanes, entries)
+        if kind == "node_rows":
+            return (lanes, nodes)
+        return (nodes, lanes)
+
+    def lanes_of(kind, a):
+        return a.shape[0] if kind in ("entry", "node_rows") else a.shape[1]
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(plan.item_tile.shape[0],),
+            in_specs=[spec(kind, lanes_of(kind, a)) for kind, a in inputs],
+            out_specs=[spec(kind, lanes) for kind, lanes in outputs],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(shape(kind, lanes), jnp.float32)
+            for kind, lanes in outputs
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=64 << 20
+        ),
+        name=name,
+        interpret=interpret,
+    )(plan.item_tile, plan.item_block, plan.item_flag, *(a for _kind, a in inputs))
+
+
+def _node_table(nodes: int, lanes: int, *parts):
+    """[n, w] parts side by side, padded to [nodes, lanes] with zeros."""
+    table = jnp.concatenate([p.astype(jnp.float32) for p in parts], axis=1)
+    return jnp.pad(table, ((0, nodes - table.shape[0]), (0, lanes - table.shape[1])))
+
+
+def _gather_rows(table, neighbour):
+    """`table[neighbour]` at the table's full lane width. Behind a barrier:
+    XLA otherwise gathers the columns that are not padding and pads the
+    [entries, lanes] result in a pass of its own, which costs more than the
+    gather saves (a gathered row fills its 128 lanes in memory either way)."""
+    return jax.lax.optimization_barrier(table)[neighbour]
+
+
+def _node_rows(nodes: int, *columns):
+    """[n] columns as the rows of an [ATT_ROWS, nodes] table; after them a
+    row of ones, which a walk expands into "some row of this tile owns the
+    entry"; zeros below and past n. From columns, also where a walk has just
+    made the values as rows: stacked from slices of a kernel's row output
+    the table cost the next walk 0.4 ms more on the v5e (PERF.md, PR 28)."""
+    rows = [jnp.pad(c.astype(jnp.float32), (0, nodes - c.shape[0])) for c in columns]
+    rows.append(jnp.ones(nodes, jnp.float32))
+    rows += [jnp.zeros(nodes, jnp.float32)] * (ATT_ROWS - len(rows))
+    return jnp.stack(rows)
+
+
+def _attention_shapes(plan: EdgePlan, hw):
+    nodes = _node_tiles(plan) * PLAN_NODE_TILE
+    return nodes, _pad_to(hw.shape[1] + ATT_SPARE, 128)
+
+
+def _attention_pallas_fwd(plan: EdgePlan, hw, s, t, leak: float, interpret: bool):
+    n, width = hw.shape
+    nodes, lanes = _attention_shapes(plan, hw)
+    entry = [("entry", plan.owner), ("entry", plan.direction)]
+    msg = _gather_rows(_node_table(nodes, lanes, hw, s), plan.neighbour)  # [L, lanes]
+    z, top = _walk_call(
+        plan, partial(_attention_max_kernel, width=width, leak=leak),
+        "planned_attention_max",
+        entry + [("message", msg), ("node_rows", _node_rows(nodes, t[:, 0], t[:, 1]))],
+        [("entry", ATT_ROWS), ("node", 2)], interpret,
+    )
+    top = jnp.where(top > _NEG / 2, top, 0.0)[:n]  # an empty run shifts by 0
+    p, total = _walk_call(
+        plan, partial(_attention_softmax_kernel, leak=leak),
+        "planned_attention_softmax",
+        entry + [("entry", z), ("node_rows", _node_rows(nodes, top[:, 0], top[:, 1]))],
+        [("entry", ATT_ROWS), ("node_rows", ATT_ROWS)], interpret,
+    )
+    total = total[:2, :n].T  # [n, 2]
+    alpha, out = _walk_call(
+        plan, _attention_sum_kernel, "planned_attention_sum",
+        entry + [
+            ("entry", p),
+            ("node_rows", _node_rows(nodes, total[:, 0], total[:, 1])),
+            ("message", msg),
+        ],
+        [("entry", ATT_ROWS), ("node", lanes)], interpret,
+    )
+    saved = (hw, s, t, msg, z, alpha, top, total)
+    return out[:n, :width].astype(hw.dtype), saved
+
+
+def _attention_pallas_bwd(plan: EdgePlan, leak: float, interpret: bool, saved, g):
+    hw, s, t, msg, z, alpha, top, total = saved
+    n, width = hw.shape
+    nodes, lanes = _attention_shapes(plan, hw)
+    entry = [("entry", plan.owner), ("entry", plan.direction)]
+    g = g.astype(jnp.float32)
+    dalpha, c = _walk_call(
+        plan, _attention_edge_dot_kernel, "planned_attention_edge_dot",
+        entry + [
+            ("entry", alpha), ("message", msg), ("node", _node_table(nodes, lanes, g)),
+        ],
+        [("entry", ATT_ROWS), ("node_rows", ATT_ROWS)], interpret,
+    )
+    c = c[:2, :n].T  # [n, 2]: the sum of alpha * d alpha over each run
+    g_msg = _gather_rows(_node_table(nodes, lanes, g, t, top, total, c), plan.neighbour)
+    dhw, dst = _walk_call(
+        plan, partial(_attention_backward_kernel, width=width, leak=leak),
+        "planned_attention_backward",
+        entry + [
+            ("entry", z), ("entry", alpha), ("entry", dalpha), ("message", g_msg),
+            ("node", _node_table(nodes, lanes, hw)),
+            ("node_rows", _node_rows(nodes, s[:, 0], s[:, 1], c[:, 0], c[:, 1])),
+        ],
+        [("node", lanes), ("node_rows", ATT_ROWS)], interpret,
+    )
+    dst = dst[:4, :n].T
+    return (
+        dhw[:n, :width].astype(hw.dtype),
+        dst[:, 0:2].astype(s.dtype),
+        dst[:, 2:4].astype(t.dtype),
+    )
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _attention_pallas(plan: EdgePlan, hw, s, t, leak: float, interpret: bool):
+    return _attention_pallas_fwd(plan, hw, s, t, leak, interpret)[0]
+
+
+def _attention_pallas_fwd_rule(plan, hw, s, t, leak, interpret):
+    out, saved = _attention_pallas_fwd(plan, hw, s, t, leak, interpret)
+    return out, (plan, saved)
+
+
+def _attention_pallas_bwd_rule(leak, interpret, saved, g):
+    plan, rest = saved
+    return (None, *_attention_pallas_bwd(plan, leak, interpret, rest, g))
+
+
+_attention_pallas.defvjp(_attention_pallas_fwd_rule, _attention_pallas_bwd_rule)
+
+
+def _attention_xla(plan: EdgePlan, hw, s, t, leak: float):
+    """The same mathematics in plain XLA, as sorted segment reductions over
+    the plan's (owner, direction) runs, differentiated by JAX: the path off
+    the TPU, and the oracle of the kernels."""
+    n = hw.shape[0]
+    nodes = _node_tiles(plan) * PLAN_NODE_TILE
+    owner, nbr, d = plan.owner[0], plan.neighbour, plan.direction[0]
+    real = owner < nodes
+    own = jnp.minimum(owner, n - 1)
+    score = _leaky(s[nbr, d] + t[own, d], leak)
+    run = jnp.where(real, owner * 2 + d, 2 * nodes)  # ascending
+    seg = partial(jax.ops.segment_sum, num_segments=2 * nodes + 1, indices_are_sorted=True)
+    neg = jnp.finfo(score.dtype).min
+    top = jax.ops.segment_max(
+        jnp.where(real, score, neg), run, num_segments=2 * nodes + 1,
+        indices_are_sorted=True,
+    )
+    top = jnp.where(top > neg / 2, top, 0.0)
+    p = jnp.where(real, jnp.exp(jnp.clip(score - top[run], -60.0, 0.0)), 0.0)
+    alpha = p / jnp.maximum(seg(p, run)[run], 1e-30)
+    out = jax.ops.segment_sum(
+        alpha[:, None] * hw[nbr], jnp.where(real, owner, nodes),
+        num_segments=nodes + 1, indices_are_sorted=True,
+    )
+    return out[:n]
+
+
+def planned_attention(
+    plan: EdgePlan, hw, s, t, leak: float = 0.2, impl: Optional[str] = None
+):
+    """GAT's aggregation over both edge directions from a prepared plan,
+    `[N, W] -> [N, W]`: `out[i] = sum alpha_e * hw[j]` over the entries
+    (i, j, d) of i, `alpha` the softmax of `leaky_relu(s[j, d] + t[i, d])`
+    over the entries of one (i, d). `s` and `t` are `[N, 2]`, a column per
+    direction (0: edges out of the owner, 1: edges into it). Empty runs and
+    the exponent are treated as `gat._segment_softmax` treats them. `impl`
+    as for `planned_neighbor_sum`. Counted in `route_stats()["planned"]`, as
+    every reduction over a plan is, and in `["attention"]` (trace time)."""
+    with _route_lock:
+        _route_counts["planned"] += 1
+        _route_counts["attention"] += 1
+    impl = impl or planned_impl()
+    if impl == "xla":
+        return _attention_xla(plan, hw, s, t, leak)
+    return _attention_pallas(plan, hw, s, t, leak, impl == "pallas_interpret")
 
 
 # ---------------------------------------------------------------------------

@@ -93,6 +93,7 @@ class TestBuildEdgePlan:
         assert plan.owner.shape == (1, total) and plan.neighbour.shape == (total,)
         assert plan.degree.shape == (nb,) and plan.degree.dtype == np.float32
         assert plan.item_tile.shape == plan.item_block.shape == plan.item_flag.shape == (bound,)
+        assert plan.direction.shape == plan.owner.shape and plan.direction.dtype == np.int32
         assert total % BE == 0 and node_tiles == -(-nb // TN)
         assert entries == 2 * int(mask.sum()) and node_tiles <= items <= bound
 
@@ -352,12 +353,16 @@ class TestStackCarriesThePlan:
         assert _counter("kmamiz_model_edge_plan_builds_total") == 2
 
     def test_plan_for_goes_by_what_the_forward_takes_and_by_the_legacy_knob(self, monkeypatch):
+        from kmamiz_tpu.models.stlgt import model as stlgt_model
+
         st = stacked.stack_dataset(_dataset())
         assert stacked.plan_for(graphsage, st) is st.plan
-        assert stacked.plan_for(gat, st) is None  # its forward takes none
+        assert stacked.plan_for(gat, st) is st.plan
+        assert stacked.plan_for(stlgt_model, st) is None  # its forward takes none
         monkeypatch.setenv("KMAMIZ_SPARSE", "xla")
         sparse.reset_for_tests()
         assert stacked.plan_for(graphsage, st) is None
+        assert stacked.plan_for(gat, st) is None
 
 
 class TestTrainingThroughThePlan:
@@ -396,22 +401,52 @@ class TestTrainingThroughThePlan:
         assert np.isfinite(r.losses).all()
         assert sparse.route_stats()["planned"] == 0
 
-    @pytest.mark.parametrize("path", ("dp_epoch_runner", "predict_all", "gat"))
+    @pytest.mark.parametrize(
+        "path", ("dp_epoch_runner", "predict_all", "gat_dp_epoch_runner", "gat_predict_all", "gat_unfused")
+    )
     def test_paths_that_pass_no_plan_keep_todays_code(self, path):
         stacked.epoch_runner.cache_clear()
         stacked.dp_epoch_runner.cache_clear()
         stacked._batched_forward.cache_clear()
         ds = _dataset()
-        if path == "dp_epoch_runner":
-            r = trainer.train(ds, epochs=1, hidden=8, batch_slots=2)
+        model = gat if path.startswith("gat_") else graphsage
+        if path.endswith("dp_epoch_runner"):
+            r = trainer.train(ds, epochs=1, hidden=8, batch_slots=2, model=model)
             assert np.isfinite(r.losses).all()
-        elif path == "predict_all":
-            lat, logit = stacked.predict_all(_params(10), ds, graphsage)
+        elif path.endswith("predict_all"):
+            params = model.init_params(jax.random.PRNGKey(0), hidden=8, num_features=10)
+            lat, logit = stacked.predict_all(params, ds, model)
             assert lat.shape == logit.shape == (4, 40)
         else:
-            r = trainer.train(ds, epochs=1, hidden=8, model=gat)
+            r = trainer.train(ds, epochs=1, hidden=8, model=gat, fused=False)
             assert np.isfinite(r.losses).all()
-        assert sparse.route_stats()["planned"] == 0
+        assert sparse.route_stats()["planned"] == sparse.route_stats()["attention"] == 0
+
+    @pytest.mark.parametrize("knob,engaged", ((None, True), ("xla", False)))
+    def test_a_gat_refresh_engages_the_plan_with_no_knob_and_not_under_xla(
+        self, monkeypatch, knob, engaged
+    ):
+        """The case that used to pin GAT to the code without a plan."""
+        from kmamiz_tpu.telemetry.tracing import TRACER
+
+        if knob is None:
+            monkeypatch.delenv("KMAMIZ_SPARSE", raising=False)
+        else:
+            monkeypatch.setenv("KMAMIZ_SPARSE", knob)
+        sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()
+        r = trainer.train(_dataset(), epochs=1, hidden=8, model=gat)
+        assert np.isfinite(r.losses).all()
+        stats = sparse.route_stats()
+        assert (stats["planned"] > 0) is engaged and (stats["attention"] > 0) is engaged
+        assert stats["planned"] == stats["attention"]  # a layer each, and nothing else
+        counts = [
+            dict(tb.counts.get(i, {}))
+            for tb in TRACER.traces()
+            for i, span in enumerate(tb.spans)
+            if span[0] == "refresh.epoch_block"
+        ]
+        assert counts and counts[-1]["planned"] == int(engaged)
 
 
 # -- the kernel at the cell's shapes, through the chip's own compiler ---------
@@ -429,6 +464,15 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _described_plan(arg, nb, entries, items):
+    return sparse.EdgePlan(
+        owner=arg((1, entries), jnp.int32), neighbour=arg((entries,), jnp.int32),
+        degree=arg((nb,), jnp.float32), item_tile=arg((items,), jnp.int32),
+        item_block=arg((items,), jnp.int32), item_flag=arg((items,), jnp.int32),
+        direction=arg((1, entries), jnp.int32),
+    )
+
+
 @pytest.mark.parametrize("width", (18, 64))
 def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_chip, width):
     from jax.experimental.compilation_cache import compilation_cache
@@ -440,10 +484,7 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_chip, width):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    plan = sparse.EdgePlan(
-        arg((1, entries), jnp.int32), arg((entries,), jnp.int32), arg((nb,), jnp.float32),
-        arg((items,), jnp.int32), arg((items,), jnp.int32), arg((items,), jnp.int32),
-    )
+    plan = _described_plan(arg, nb, entries, items)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
     compilation_cache.reset_cache()
@@ -459,3 +500,38 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_chip, width):
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "planned_neighbor_sum" in text
     assert "scatter" not in text
+
+
+def test_attention_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip):
+    """The five walks of `planned_attention`, forward and backward, at the
+    width of `mv100k-gat`: what Mosaic refuses here costs no chip time."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    nb, eb, width = 131072, 524288, 64
+    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(hw, s, t, plan):
+        return (sparse.planned_attention(plan, hw, s, t, 0.2, "pallas") ** 2).sum()
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = (
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            .lower(arg((nb, width)), arg((nb, 2)), arg((nb, 2)), _described_plan(arg, nb, entries, items))
+            .compile()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    for name in ("max", "softmax", "sum", "edge_dot", "backward"):
+        assert f"planned_attention_{name}" in text
+    assert "scatter" not in text
+    # the only gathers left are the two row gathers, at the full lane width
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert len(gathers) == 2 and all(f"f32[{entries},128]" in line for line in gathers)

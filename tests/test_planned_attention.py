@@ -1,0 +1,371 @@
+"""The planned attention (ops/sparse.planned_attention): GAT's directed
+segment softmax, its weighted sum and their VJP over the stack's edge plan, in
+plain XLA and as the Pallas kernels (interpreted here), against the unsorted
+formulation of `gat._attend`, against the plain reference of the benchmark,
+and through `gat.forward`, the stack and the epoch block."""
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import gat as reference_gat
+from kmamiz_tpu.models import common, gat, stacked, trainer
+from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.telemetry.tracing import TRACER
+from test_edge_plan import BE, CASES, IMPLS, TN, _case, _dataset, _device
+
+WIDTH = 16
+
+
+def _plan(name):
+    src, dst, mask, nb = _case(name)
+    return src, dst, mask, nb, _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+
+
+def _inputs(nb, seed=1, width=WIDTH):
+    rng = np.random.default_rng(seed)
+    hw = jnp.asarray(rng.normal(size=(nb, width)).astype(np.float32))
+    vectors = tuple(jnp.asarray(rng.normal(size=width).astype(np.float32)) for _ in range(4))
+    ct = jnp.asarray(rng.normal(size=(nb, width)).astype(np.float32))
+    return hw, vectors, ct
+
+
+def _unsorted(hw, vectors, src, dst, mask):
+    """Both directions of a layer as `gat._layer` makes them without a plan."""
+    a_s, a_d, a_sr, a_dr = vectors
+    src, dst, mask = jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask)
+    return gat._attend(hw, src, dst, mask, a_s, a_d) + gat._attend(hw, dst, src, mask, a_sr, a_dr)
+
+
+def _planned(hw, vectors, plan, impl):
+    a_s, a_d, a_sr, a_dr = vectors
+    s = jnp.stack([hw @ a_sr, hw @ a_s], axis=1)
+    t = jnp.stack([hw @ a_dr, hw @ a_d], axis=1)
+    return sparse.planned_attention(plan, hw, s, t, gat.LEAK, impl)
+
+
+def _close(got, want, tol, what=""):
+    scale = max(float(np.abs(np.asarray(want)).max()), 1.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=tol * scale, err_msg=what)
+
+
+class TestTheDirectedPlan:
+    @pytest.mark.parametrize("name", CASES)
+    def test_entries_are_sorted_by_owner_then_direction_and_say_which_end_they_are(self, name):
+        src, dst, mask, nb = _case(name)
+        plan, entries, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        owner, nbr, d = plan.owner[0, :entries], plan.neighbour[:entries], plan.direction[0, :entries]
+        assert set(np.unique(d).tolist()) <= {0, 1} and (plan.direction[0, entries:] == 0).all()
+        assert (np.diff(owner.astype(np.int64) * 2 + d) >= 0).all()
+        # direction 0: the owner is the edge's source; 1: its destination
+        out_edges = sorted(zip(owner[d == 0].tolist(), nbr[d == 0].tolist()))
+        in_edges = sorted(zip(nbr[d == 1].tolist(), owner[d == 1].tolist()))
+        real = sorted(zip(src[mask].tolist(), dst[mask].tolist()))
+        assert out_edges == in_edges == real
+
+    @pytest.mark.parametrize("name", CASES)
+    def test_the_mirror_is_an_involution_that_swaps_direction(self, name):
+        """Every entry (i, j, d) has one mirror (j, i, 1 - d): the same edge
+        seen from its other end. The backward pass sums over mirrors in place
+        of a permutation, so the pairing has to be one to one."""
+        src, dst, mask, nb = _case(name)
+        plan, entries, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        owner, nbr, d = plan.owner[0, :entries], plan.neighbour[:entries], plan.direction[0, :entries]
+        here = np.lexsort((d, nbr, owner))  # by (owner, neighbour, direction)
+        there = np.lexsort((1 - d, owner, nbr))  # by (neighbour, owner, other direction)
+        mirror = np.empty(entries, np.int64)
+        mirror[here] = there  # repeated edges pair up in order
+        np.testing.assert_array_equal(owner[mirror], nbr)
+        np.testing.assert_array_equal(nbr[mirror], owner)
+        np.testing.assert_array_equal(d[mirror], 1 - d)
+        np.testing.assert_array_equal(mirror[mirror], np.arange(entries))
+
+    def test_the_block_of_consecutive_items_never_falls(self):
+        for name in CASES:
+            src, dst, mask, nb = _case(name)
+            plan, _, _ = sparse.build_edge_plan(src, dst, mask, nb)
+            assert (np.diff(plan.item_block) >= 0).all(), name
+
+
+class TestPlannedAttention:
+    @pytest.mark.parametrize("impl", IMPLS)
+    @pytest.mark.parametrize("name", CASES)
+    def test_values_against_the_unsorted_formulation(self, name, impl):
+        src, dst, mask, nb, plan = _plan(name)
+        hw, vectors, _ = _inputs(nb)
+        got = _planned(hw, vectors, plan, impl)
+        want = _unsorted(hw, vectors, src, dst, mask)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        _close(got, want, 1e-5)
+        # a node with no edge at all receives exact zeros
+        np.testing.assert_array_equal(np.asarray(got)[np.asarray(plan.degree) == 0], 0.0)
+
+    @pytest.mark.parametrize("impl", IMPLS)
+    @pytest.mark.parametrize(
+        "name", ("random", "masked", "padded", "heavier_than_a_block", "tile_with_no_edges", "self_loops_and_repeats")
+    )
+    def test_gradients_against_autodiff_of_the_unsorted_formulation(self, name, impl):
+        src, dst, mask, nb, plan = _plan(name)
+        hw, vectors, ct = _inputs(nb, seed=2)
+        _, pull = jax.vjp(lambda h, a: _planned(h, a, plan, impl), hw, vectors)
+        _, pull_unsorted = jax.vjp(lambda h, a: _unsorted(h, a, src, dst, mask), hw, vectors)
+        got, want = pull(ct), pull_unsorted(ct)
+        # a heavy owner sums 1,200 terms in another order than the scatters do
+        _close(got[0], want[0], 5e-5, "d hw")
+        for g, w, which in zip(got[1], want[1], ("a_s", "a_d", "a_sr", "a_dr")):
+            _close(g, w, 5e-5, f"d {which}")
+
+    def test_each_run_is_a_softmax_whatever_the_scores(self):
+        """With every row of hw equal to ones, a node's output is the number
+        of its directions that hold an edge: each run's weights sum to one."""
+        src, dst, mask, nb, plan = _plan("masked")
+        rng = np.random.default_rng(3)
+        s = jnp.asarray(rng.normal(size=(nb, 2)).astype(np.float32) * 30.0)  # peaked and flat runs
+        t = jnp.asarray(rng.normal(size=(nb, 2)).astype(np.float32))
+        has_out = np.bincount(src[mask], minlength=nb) > 0
+        has_in = np.bincount(dst[mask], minlength=nb) > 0
+        want = (has_out.astype(np.float32) + has_in.astype(np.float32))[:, None] * np.ones((1, 4), np.float32)
+        for impl in IMPLS:
+            got = sparse.planned_attention(plan, jnp.ones((nb, 4), jnp.float32), s, t, 0.2, impl)
+            np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=2e-6)
+
+    @pytest.mark.parametrize("impl", IMPLS)
+    def test_a_node_with_no_edge_in_one_direction(self, impl):
+        # 0 -> 1, 0 -> 2, 3 -> 1: node 0 has no in-edge, nodes 1 and 2 no out-edge
+        src = np.array([0, 0, 3, 0], np.int32)
+        dst = np.array([1, 2, 1, 0], np.int32)
+        mask = np.array([True, True, True, False])
+        plan = _device(sparse.build_edge_plan(src, dst, mask, 8)[0])
+        hw, vectors, ct = _inputs(8, seed=4)
+        got, pull = jax.vjp(lambda h, a: _planned(h, a, plan, impl), hw, vectors)
+        want, pull_unsorted = jax.vjp(lambda h, a: _unsorted(h, a, src, dst, mask), hw, vectors)
+        _close(got, want, 1e-6)
+        np.testing.assert_allclose(np.asarray(got[2]), np.asarray(hw[0]), rtol=1e-6)  # one in-edge: alpha = 1
+        for g, w in zip(jax.tree_util.tree_leaves(pull(ct)), jax.tree_util.tree_leaves(pull_unsorted(ct))):
+            assert np.isfinite(np.asarray(g)).all()
+            _close(g, w, 1e-5)
+
+    @pytest.mark.parametrize("impl", IMPLS)
+    def test_a_run_of_masked_edges_only_gives_no_nan_in_the_gradient(self, impl):
+        """Every edge into node 5 is masked, and the bucket's padding is
+        clamped onto the last node: the unsorted softmax needs its clip for
+        this; on the plan such a run holds no entry at all."""
+        src = np.array([1, 2, 3, 0, 0, 0, 0, 0], np.int32)
+        dst = np.array([5, 5, 5, 1, 0, 0, 0, 0], np.int32)
+        mask = np.array([False, False, False, True, False, False, False, False])
+        plan = _device(sparse.build_edge_plan(src, dst, mask, 8)[0])
+        hw, vectors, ct = _inputs(8, seed=5)
+        got, pull = jax.vjp(lambda h, a: _planned(h, a, plan, impl), hw, vectors)
+        want, pull_unsorted = jax.vjp(lambda h, a: _unsorted(h, a, src, dst, mask), hw, vectors)
+        np.testing.assert_array_equal(np.asarray(got[5]), 0.0)
+        for g, w in zip(jax.tree_util.tree_leaves(pull(ct)), jax.tree_util.tree_leaves(pull_unsorted(ct))):
+            assert np.isfinite(np.asarray(g)).all()
+            _close(g, w, 1e-5)
+        _close(got, want, 1e-6)
+
+    def test_a_hub_whose_entries_span_many_edge_blocks(self):
+        """Node 3 is called over 4 x BE + 37 edges and calls one node itself:
+        its run of in-edges crosses five edge blocks, so its maximum, its sum
+        and its weighted sum are carried from item to item."""
+        n, e = 256, 4 * BE + 37
+        rng = np.random.default_rng(6)
+        src = np.concatenate([rng.integers(4, n, e), [3]]).astype(np.int32)
+        dst = np.concatenate([np.full(e, 3), [200]]).astype(np.int32)
+        mask = np.ones(e + 1, bool)
+        host, entries, items = sparse.build_edge_plan(src, dst, mask, n)
+        assert host.degree[3] == e + 1 and items >= 6
+        plan = _device(host)
+        hw, vectors, ct = _inputs(n, seed=7)
+        want, pull_unsorted = jax.vjp(lambda h, a: _unsorted(h, a, src, dst, mask), hw, vectors)
+        for impl in IMPLS:
+            got, pull = jax.vjp(lambda h, a: _planned(h, a, plan, impl), hw, vectors)
+            _close(got, want, 1e-5, impl)
+            for g, w in zip(jax.tree_util.tree_leaves(pull(ct)), jax.tree_util.tree_leaves(pull_unsorted(ct))):
+                _close(g, w, 5e-5, impl)
+
+    @pytest.mark.parametrize("impl", IMPLS)
+    def test_two_runs_give_the_same_bits(self, impl):
+        src, dst, mask, nb, plan = _plan("heavier_than_a_block")
+        hw, vectors, ct = _inputs(nb, seed=8)
+        runs = []
+        for _ in range(2):
+            out, pull = jax.vjp(lambda h, a: _planned(h, a, plan, impl), hw, vectors)
+            runs.append([np.asarray(x) for x in jax.tree_util.tree_leaves((out, pull(ct)))])
+        for a, b in zip(*runs):
+            np.testing.assert_array_equal(a, b)
+
+    def test_kernels_and_xla_agree_closer_than_either_does_with_the_scatters(self):
+        src, dst, mask, nb, plan = _plan("zipf_heavy")
+        hw, vectors, _ = _inputs(nb, seed=9)
+        a, b = (np.asarray(_planned(hw, vectors, plan, impl)) for impl in IMPLS)
+        _close(a, b, 2e-6)
+
+    def test_a_width_past_the_first_128_lanes(self):
+        """The neighbour's scalars travel in the lanes past the width: 124
+        floats leave no room in the first 128, so the rows take 256."""
+        src, dst, mask, nb, plan = _plan("masked")
+        hw, vectors, ct = _inputs(nb, seed=10, width=124)
+        want, pull_unsorted = jax.vjp(lambda h, a: _unsorted(h, a, src, dst, mask), hw, vectors)
+        got, pull = jax.vjp(lambda h, a: _planned(h, a, plan, "pallas_interpret"), hw, vectors)
+        _close(got, want, 1e-5)
+        for g, w in zip(jax.tree_util.tree_leaves(pull(ct)), jax.tree_util.tree_leaves(pull_unsorted(ct))):
+            _close(g, w, 5e-5)
+
+    def test_counts_in_route_stats_beside_planned(self):
+        _src, _dst, _mask, nb, plan = _plan("padded")
+        assert sparse.route_stats()["attention"] == 0
+        sparse.planned_attention(plan, jnp.ones((nb, 4)), jnp.zeros((nb, 2)), jnp.zeros((nb, 2)), 0.2, "xla")
+        stats = sparse.route_stats()
+        assert stats["attention"] == 1 and stats["planned"] == 1
+        sparse.planned_neighbor_sum(plan, jnp.ones((nb, 4)), "xla")
+        stats = sparse.route_stats()
+        assert stats["attention"] == 1 and stats["planned"] == 2
+        sparse.reset_for_tests()
+        assert sparse.route_stats()["attention"] == 0
+
+    def test_no_scatter_and_no_1d_gather_over_the_entries_in_the_kernel_path(self):
+        """What the TPU runs, lowered here with the kernels interpreted: the
+        interpreter's own loops aside, the glue XLA is left with holds two
+        row gathers a layer pass and nothing indexed entry by entry."""
+        _src, _dst, _mask, nb, plan = _plan("random")
+        hw, vectors, _ = _inputs(nb)
+        entries = plan.neighbour.shape[0]
+
+        def glue_only(plan_, _kernel, _name, inputs, outputs, _interpret):
+            """A walk's outputs without its body, so that only XLA's glue lowers."""
+            nodes = sparse._node_tiles(plan_) * TN
+            shapes = {"entry": (None, entries), "node_rows": (None, nodes), "node": (nodes, None)}
+            alive = sum(jnp.sum(a.astype(jnp.float32)) for _kind, a in inputs)
+            return [
+                jnp.zeros([lanes if dim is None else dim for dim in shapes[kind]], jnp.float32) + alive
+                for kind, lanes in outputs
+            ]
+
+        real = sparse._walk_call
+        sparse._walk_call = glue_only
+        try:
+            loss = lambda h, a: (_planned(h, a, plan, "pallas_interpret") ** 2).sum()  # noqa: E731
+            text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(hw, vectors).as_text()
+        finally:
+            sparse._walk_call = real
+        assert "scatter" not in text
+        gathers = [line for line in text.splitlines() if "gather" in line and "stablehlo.gather" in line]
+        assert len(gathers) == 2  # [hw | s] forward, [g | t, max, sum, c] backward
+        for line in gathers:
+            assert f"tensor<{entries}x128xf32>" in line, line
+
+
+def _gat_params(num_features, hidden=8, num_nodes=0, seed=0):
+    return gat.init_params(
+        jax.random.PRNGKey(seed), hidden=hidden, num_features=num_features, num_nodes=num_nodes
+    )
+
+
+def _as_reference_params(params):
+    return {k: v for k, v in params._asdict().items() if v is not None}
+
+
+class TestGatForwardWithAPlan:
+    @pytest.mark.parametrize("backend", ("sparse", "pallas_interpret"))
+    @pytest.mark.parametrize("embeddings", (False, True), ids=("features", "embeddings"))
+    def test_loss_and_gradient_match_the_forward_without_a_plan(self, monkeypatch, backend, embeddings):
+        src, dst, mask, nb, plan = _plan("masked")
+        rng = np.random.default_rng(11)
+        x = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
+        tl = jnp.asarray(rng.normal(size=nb).astype(np.float32))
+        ta = jnp.asarray((rng.random(nb) < 0.2).astype(np.float32))
+        nm = jnp.asarray(rng.random(nb) < 0.9)
+        params = _gat_params(18, num_nodes=nb if embeddings else 0)
+        args = (x, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask), tl, ta, nm)
+        want, want_grad = jax.value_and_grad(gat.loss_fn, has_aux=True)(params, *args)
+
+        monkeypatch.setenv("KMAMIZ_SPARSE", backend)
+        sparse.reset_for_tests()
+        planned = common.make_loss_fn(partial(gat.forward, plan=plan))
+        got, got_grad = jax.value_and_grad(planned, has_aux=True)(params, *args)
+        assert sparse.route_stats()["attention"] == 2  # both layers, at trace time
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=2e-6)
+        for name, a, b in zip(params._fields, got_grad, want_grad):
+            if a is None:
+                assert b is None
+                continue
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-6, err_msg=name)
+
+    @pytest.mark.parametrize("impl", IMPLS)
+    def test_values_and_gradients_of_every_parameter_against_the_plain_reference(self, monkeypatch, impl):
+        """`benchmarks/reference/gat.py` knows no mask and no padding: it is
+        given the real edges, the system the bucket-padded list and its plan."""
+        monkeypatch.setenv("KMAMIZ_SPARSE", "sparse" if impl == "xla" else impl)
+        sparse.reset_for_tests()
+        src, dst, mask, nb, plan = _plan("masked")
+        rng = np.random.default_rng(12)
+        x = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
+        w_lat = jnp.asarray(rng.normal(size=nb).astype(np.float32))
+        w_logit = jnp.asarray(rng.normal(size=nb).astype(np.float32))
+        params = _gat_params(18, hidden=16, seed=3)
+        # move the zero-initialised leaves off zero, so their gradients are exercised
+        params = params._replace(
+            b_1=params.b_1 + 0.1, b_2=params.b_2 - 0.1,
+            w_latency_skip=params.w_latency_skip + 0.05, w_anomaly_skip=params.w_anomaly_skip - 0.05,
+        )
+        real_src, real_dst = jnp.asarray(src[mask]), jnp.asarray(dst[mask])
+
+        def system(p):
+            lat, logit = gat.forward(p, x, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask), plan=plan)
+            return jnp.sum(lat * w_lat) + jnp.sum(logit * w_logit), (lat, logit)
+
+        def reference(p):
+            lat, logit = reference_gat.forward(p, x, real_src, real_dst)
+            return jnp.sum(lat * w_lat) + jnp.sum(logit * w_logit), (lat, logit)
+
+        (_, got_out), got_grad = jax.value_and_grad(system, has_aux=True)(params)
+        (_, want_out), want_grad = jax.value_and_grad(reference, has_aux=True)(_as_reference_params(params))
+        for g, w in zip(got_out, want_out):
+            _close(g, w, 2e-6)
+        for name in want_grad:
+            _close(getattr(got_grad, name), want_grad[name], 2e-5, name)
+
+    def test_no_plan_keeps_todays_formulation(self):
+        src, dst, mask, nb = _case("random")
+        x = jnp.ones((nb, 10), jnp.float32)
+        gat.forward(_gat_params(10), x, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask))
+        stats = sparse.route_stats()
+        assert stats["planned"] == stats["attention"] == 0
+
+
+class TestGatTrainingThroughThePlan:
+    @pytest.mark.parametrize("backend", ("sparse", "pallas_interpret"))
+    def test_epoch_block_with_a_plan_matches_the_per_slot_loop_over_three_epochs(self, monkeypatch, backend):
+        ds = _dataset()
+        legacy = trainer.train(ds, epochs=3, hidden=8, seed=0, fused=False, model=gat)
+        assert sparse.route_stats()["attention"] == 0
+        monkeypatch.setenv("KMAMIZ_SPARSE", backend)
+        sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()
+        fused = trainer.train(ds, epochs=3, hidden=8, seed=0, fused=True, model=gat)
+        assert sparse.route_stats()["attention"] > 0
+        np.testing.assert_allclose(fused.losses, legacy.losses, rtol=1e-4, atol=1e-5)
+        for a, b in zip(
+            jax.tree_util.tree_leaves(fused.params), jax.tree_util.tree_leaves(legacy.params)
+        ):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-5)
+
+    def test_the_plan_span_counts_the_entries_of_each_direction_and_the_runs(self):
+        ds = _dataset()
+        st = stacked.stack_dataset(ds)
+        real = np.asarray(ds.edge_mask)
+        src, dst = np.asarray(ds.src)[real], np.asarray(ds.dst)[real]
+        runs = len(set(src.tolist())) + len(set(dst.tolist()))
+        assert st.plan_runs == runs and st.plan_entries == 2 * int(real.sum())
+        noted = {}
+        for tb in TRACER.traces():
+            for i, span in enumerate(tb.spans):
+                if span[0] in ("refresh.stack", "refresh.stack.plan"):
+                    noted[span[0]] = dict(tb.counts.get(i, {}))
+        plan_counts = noted["refresh.stack.plan"]
+        assert plan_counts["entries_out"] == plan_counts["entries_in"] == int(real.sum())
+        assert plan_counts["runs"] == runs and noted["refresh.stack"]["plan_runs"] == runs

@@ -148,6 +148,7 @@ class TestRefreshTrace:
         st = stacked.stack_dataset(ds)
         assert _names(tb, stack_idx) == [] and tb.counts[stack_idx] == {
             "hit": 1, "plan_entries": st.plan_entries, "plan_items": st.plan_items,
+            "plan_runs": st.plan_runs,
         }
 
     def test_train_inside_a_tick_nests_under_it_and_opens_no_second_trace(self):

@@ -1297,7 +1297,7 @@ def child_kernels(sizes: dict) -> None:
 
     # -- the refresh's default since PR 27 and PR 28: the planned neighbour
     # sum and the planned attention over an edge plan, Mosaic against the
-    # XLA items, values and one VJP each ----------------------------------
+    # XLA items, values and one VJP each, at the width `feat` ---------------
     planned_impl = "pallas" if on_tpu else "pallas_interpret"
     plan = jax.tree_util.tree_map(
         jnp.asarray,
@@ -1332,6 +1332,22 @@ def child_kernels(sizes: dict) -> None:
     out["planned_neighbor_sum"] = out["planned_attention"] = (
         "mosaic" if on_tpu else "interpret"
     )
+
+    # -- the width the epoch block's slot group sums at (PR 29): seven slots'
+    # 18 features side by side. Mosaic against the XLA items, and every
+    # slot's columns against the kernel's sum of that slot alone, bit for bit
+    slots = rng.normal(size=(7, nodes, 18)).astype(np.float32)
+    table = jnp.asarray(np.moveaxis(slots, 0, 1).reshape(nodes, 126))
+    packed = np.asarray(sparse.planned_neighbor_sum(plan, table, planned_impl))
+    want = np.asarray(sparse.planned_neighbor_sum(plan, table, "xla"))
+    scale = max(float(np.abs(want).max()), 1.0)
+    np.testing.assert_allclose(packed, want, rtol=0, atol=5e-5 * scale, err_msg="sum.w126")
+    for j in range(7):
+        alone = sparse.planned_neighbor_sum(plan, jnp.asarray(slots[j]), planned_impl)
+        np.testing.assert_array_equal(
+            packed[:, j * 18 : (j + 1) * 18], np.asarray(alone), f"slot {j} of the packed sum"
+        )
+    out["planned_neighbor_sum_w126"] = out["planned_neighbor_sum"]
 
     os.environ["KMAMIZ_SPARSE"] = "xla"
     sparse.reset_for_tests()  # the backend knob is cached after first read

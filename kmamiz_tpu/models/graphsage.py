@@ -158,11 +158,17 @@ def neighbor_mean(
     edge_mask: jnp.ndarray,  # [E]
     deg: jnp.ndarray = None,  # [N] precomputed neighbor_degree
     plan: sparse.EdgePlan = None,  # the topology prepared per dataset
+    neighbor_sum: jnp.ndarray = None,  # [N, F] the sum of h's neighbour rows
 ) -> jnp.ndarray:
     """Mean of neighbor states over both edge directions (segment mean).
 
     deg omitted keeps the self-contained single-layer form; callers with
     several layers over one topology (forward) pass the hoisted degree.
+
+    `neighbor_sum` is the numerator where the caller has made it already:
+    the epoch block sums the neighbour rows of several slots' features in
+    one planned sum (models/stacked.py, the slot group) and hands each slot
+    its columns. Only the division is left to do here.
 
     A caller that holds an edge plan of this topology (the training
     refresh: models/stacked.py builds one per dataset) passes it, and the
@@ -175,6 +181,10 @@ def neighbor_mean(
     table fits the VMEM budget; the division stays out here so the
     normalization matches the XLA path exactly."""
     n = h.shape[0]
+    if neighbor_sum is not None:
+        if deg is None:
+            deg = neighbor_degree(n, src_ep, dst_ep, edge_mask, dtype=h.dtype)
+        return neighbor_sum / jnp.maximum(deg, 1.0)[:, None]
     if plan is not None:
         agg = sparse.planned_neighbor_sum(plan, h)
         if deg is None:
@@ -210,18 +220,27 @@ def forward(
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
     plan: sparse.EdgePlan = None,
+    neighbor_sum_1: jnp.ndarray = None,
 ):
     """Two SAGE layers -> (latency prediction [N], anomaly logits [N]).
 
     `plan` is the edge plan of (src_ep, dst_ep, edge_mask) where the caller
     has prepared one (neighbor_mean); the result is the same sums in
-    another order."""
+    another order.
+
+    `neighbor_sum_1` is layer 1's neighbour sum of the input, [N, F], where
+    the caller has made it: without node embeddings the input is data, so
+    that sum depends on no parameter and takes no gradient, and the epoch
+    block makes it for a group of slots at once (models/stacked.py). It
+    stands for the sum of `features` alone, so a head with embeddings (whose
+    layer-1 input holds parameters) must not be given one. Layer 2 always
+    makes its own: `h1` depends on the parameters."""
     x = _common.concat_embedding(features, params.embedding)
     if plan is None:
         deg = neighbor_degree(features.shape[0], src_ep, dst_ep, edge_mask)
     else:
         deg = plan.degree
-    agg1 = neighbor_mean(x, src_ep, dst_ep, edge_mask, deg, plan)
+    agg1 = neighbor_mean(x, src_ep, dst_ep, edge_mask, deg, plan, neighbor_sum_1)
     h1 = jax.nn.relu(
         x @ params.w_self_1 + agg1 @ params.w_neigh_1 + params.b_1
     )

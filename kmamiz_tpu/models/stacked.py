@@ -41,6 +41,17 @@ device-native:
   and weighted sums over it (`sparse.planned_attention`); STLGT takes
   none yet. The vmapped paths (`dp_epoch_runner`, `predict_all`) pass none
   and reduce the edge list as it comes.
+- the SLOT GROUP: GraphSAGE's first layer sums the neighbours' features,
+  which are data (no parameter in them, no gradient through them) and 18
+  floats wide where the chip pads a gathered row to 128 lanes. So
+  `epoch_runner`'s block makes that sum for `slot_group` = 128 // F
+  consecutive slots at once, one `[Nb, G * F]` table, one gather and one
+  planned sum, and each slot's update reads its own F columns
+  (`graphsage.forward(..., neighbor_sum_1=)`): still one optimizer update
+  per slot, in slot order, from bit for bit the same sums (the reducer's
+  columns are independent). It engages where the block can see that it
+  may: the head's `forward` takes the sum, the parameters hold no node
+  embedding, and there is a plan; every other call runs the flat scan.
 
 Bit discipline: with the default batch size of 1 the scan body performs
 the identical per-slot update sequence as the legacy Python loop; only
@@ -254,6 +265,31 @@ def plan_for(model, stacked: StackedDataset) -> Optional[sparse.EdgePlan]:
     return stacked.plan
 
 
+#: lanes of a row on the chip: a gathered message row and a reducer's block
+#: are padded to this many floats whatever their width (PERF.md, PR 27)
+ROW_LANES = 128
+
+
+def slot_group(model, params, features, plan) -> int:
+    """How many consecutive slots share one planned sum of layer 1 in the
+    epoch block, 0 where every slot makes its own (`epoch_runner`).
+
+    The block groups where it can see that the sum is of data alone: the
+    head's `forward` takes the sum from its caller (`neighbor_sum_1`), the
+    parameters hold no node embedding (with one the layer's input holds
+    parameters and a gradient goes through the graph), and there is a plan
+    to sum over. The size comes from the feature width F and the chip's
+    128 lanes alone, 128 // F (7 at F = 18), at most the slots there are;
+    a group of one is no group."""
+    if plan is None or getattr(params, "embedding", None) is not None:
+        return 0
+    if "neighbor_sum_1" not in inspect.signature(model.forward).parameters:
+        return 0
+    n_slots, _, width = features.shape
+    group = min(ROW_LANES // max(width, 1), n_slots)
+    return group if group > 1 else 0
+
+
 def _build_stack(dataset) -> StackedDataset:
     s = len(dataset.features)
     n = dataset.num_nodes
@@ -337,6 +373,15 @@ def epoch_runner(model, lr: float, pos_weight: float):
     keeps the forward's own reduction of the edge list, and is another
     program of the same family.
 
+    With a slot group (`slot_group`; `group` overrides it for tests and
+    timing, 0 for none, as `impl` does for `planned_neighbor_sum`) an epoch
+    is a scan over the groups around a loop over each group's slots: the
+    group's layer-1 neighbour sums are made in one planned sum of width
+    group x F and handed to each slot's forward as `neighbor_sum_1`. The
+    last group of an epoch reaches back to stay inside the stack and starts
+    its loop at its own first slot, so any slot count runs the one program;
+    the groups are made again every epoch.
+
     Memoized per (model, lr, pos_weight) so repeated train() calls in one
     process reuse the compiled program family (jit then keys on the
     bucket shapes)."""
@@ -346,7 +391,7 @@ def epoch_runner(model, lr: float, pos_weight: float):
 
     @functools.partial(
         jax.jit,
-        static_argnames=("n_epochs",),
+        static_argnames=("n_epochs", "group"),
         donate_argnames=("params", "opt_state"),
     )
     def sage_epoch_block(
@@ -361,19 +406,23 @@ def epoch_runner(model, lr: float, pos_weight: float):
         edge_mask,
         n_epochs: int,
         plan=None,
+        group: Optional[int] = None,
     ):
-        slot_grad = grad_fn
-        if plan is not None:
-            slot_grad = jax.value_and_grad(
-                common.make_loss_fn(
-                    functools.partial(model.forward, plan=plan), pos_weight
-                ),
-                has_aux=True,
-            )
+        if group is None:
+            group = slot_group(model, params, features, plan)
 
-        def slot_step(carry, xs):
+        def slot_step(carry, xs, **summed):
             p, s = carry
             f, tl, ta, nm = xs
+            slot_grad = grad_fn
+            if plan is not None:
+                slot_grad = jax.value_and_grad(
+                    common.make_loss_fn(
+                        functools.partial(model.forward, plan=plan, **summed),
+                        pos_weight,
+                    ),
+                    has_aux=True,
+                )
             (loss, (lat_l, ano_l)), grads = slot_grad(
                 p, f, src, dst, edge_mask, tl, ta, nm
             )
@@ -389,8 +438,48 @@ def epoch_runner(model, lr: float, pos_weight: float):
             )
             return carry, per_slot.mean(axis=0)
 
+        n_slots, n_nodes, width = features.shape
+        at = functools.partial(jax.lax.dynamic_index_in_dim, keepdims=False)
+
+        def group_step(carry, first):
+            """The slots [first, first + group) of one epoch: their layer-1
+            neighbour sums in one planned sum, then their updates in order."""
+            # the last group reaches back to stay inside the stack, and its
+            # loop starts at the first slot that is the group's own
+            start = jnp.minimum(first, n_slots - group)
+            packed = jax.lax.dynamic_slice_in_dim(features, start, group)
+            table = jnp.moveaxis(packed, 0, 1).reshape(n_nodes, group * width)
+            sums = sparse.planned_neighbor_sum(plan, table)  # [Nb, group * F]
+
+            def member_step(j, carry):
+                carry, per_slot = carry
+                t = start + j
+                # the features from the group's slice, not from the stack:
+                # with per-slot reads of the stack beside the group's, XLA
+                # re-laid the whole stack slot-major first (5.4 GB, PERF.md)
+                xs = (
+                    at(packed, j),
+                    at(target_latency, t),
+                    at(target_anomaly, t),
+                    at(node_mask, t),
+                )
+                mine = jax.lax.dynamic_slice_in_dim(sums, j * width, width, axis=1)
+                carry, losses = slot_step(carry, xs, neighbor_sum_1=mine)
+                return carry, per_slot.at[t].set(losses)
+
+            return jax.lax.fori_loop(first - start, group, member_step, carry), None
+
+        def grouped_epoch_step(carry, _):
+            firsts = jnp.arange(0, n_slots, group, dtype=jnp.int32)
+            per_slot = jnp.zeros((n_slots, 3), jnp.float32)
+            (carry, per_slot), _ = jax.lax.scan(group_step, (carry, per_slot), firsts)
+            return carry, per_slot.mean(axis=0)
+
         (params, opt_state), losses = jax.lax.scan(
-            epoch_step, (params, opt_state), None, length=n_epochs
+            grouped_epoch_step if group else epoch_step,
+            (params, opt_state),
+            None,
+            length=n_epochs,
         )
         return params, opt_state, losses
 

@@ -403,6 +403,7 @@ def train(
             )
             batched = stacked_mod.batch_slots_arrays(st, batch)
             plan = None  # the vmapped per-slot grads reduce the edge list
+            group = 0
 
             def run_block(p, s, n_ep):
                 return runner(p, s, *batched, st.src, st.dst, st.edge_mask, n_ep)
@@ -410,6 +411,7 @@ def train(
         else:
             runner = stacked_mod.epoch_runner(model, lr, pos_weight)
             plan = stacked_mod.plan_for(model, st)
+            group = stacked_mod.slot_group(model, params, st.features, plan)
 
             def run_block(p, s, n_ep):
                 return runner(
@@ -436,6 +438,7 @@ def train(
                     epochs=e1 - e0,
                     slot_updates=slot_updates,
                     planned=int(plan is not None),
+                    slot_group=group,
                 )
             # the fence that was always here: the host waits for the device
             with phase_span("refresh.loss_fetch"):

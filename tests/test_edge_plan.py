@@ -173,19 +173,37 @@ class TestPlannedNeighborSum:
         # rows with no neighbour are exact zeros, not rounding
         np.testing.assert_array_equal(got[plan.degree == 0], 0.0)
 
-    @pytest.mark.parametrize("name", ("random", "heavier_than_a_block", "tile_with_no_edges"))
-    def test_kernel_and_xla_reducer_sum_the_same_items(self, name):
+    @pytest.mark.parametrize(
+        "name,width",
+        [("random", 18), ("heavier_than_a_block", 18), ("tile_with_no_edges", 18),
+         ("random", 126)],  # seven slots' features side by side: the slot group's table
+    )
+    def test_kernel_and_xla_reducer_sum_the_same_items(self, name, width):
         """Item by item the same products; the kernel adds its three bfloat16
         passes one after the other, so the last bits may differ. Two runs of
         either give the same bits."""
         src, dst, mask, nb = _case(name)
         plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
-        h = jnp.asarray(np.random.default_rng(2).normal(size=(nb, 18)).astype(np.float32))
+        h = jnp.asarray(np.random.default_rng(2).normal(size=(nb, width)).astype(np.float32))
         a, b = (np.asarray(sparse.planned_neighbor_sum(plan, h, impl)) for impl in IMPLS)
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * max(float(np.abs(a).max()), 1.0))
         for impl, first in zip(IMPLS, (a, b)):
             again = np.asarray(sparse.planned_neighbor_sum(plan, h, impl))
             np.testing.assert_array_equal(again, first)
+
+    @pytest.mark.parametrize("name", ("masked", "heavier_than_a_block"))
+    def test_the_kernels_columns_are_independent_bit_for_bit(self, name):
+        """What the epoch block's slot group rests on: a column of a packed
+        sum is the sum the kernel makes of that column alone. (XLA's matrix
+        product on a CPU blocks by the width, so its last bit is not.)"""
+        src, dst, mask, nb = _case(name)
+        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        slots = np.random.default_rng(6).normal(size=(7, nb, 18)).astype(np.float32)
+        table = jnp.asarray(np.moveaxis(slots, 0, 1).reshape(nb, 126))
+        packed = np.asarray(sparse.planned_neighbor_sum(plan, table, "pallas_interpret"))
+        for j in (0, 3, 6):
+            alone = sparse.planned_neighbor_sum(plan, jnp.asarray(slots[j]), "pallas_interpret")
+            np.testing.assert_array_equal(packed[:, j * 18 : (j + 1) * 18], np.asarray(alone))
 
     def test_products_are_float32_exact(self):
         """One neighbour each: the sum IS the neighbour's row, every bit of
@@ -316,6 +334,18 @@ def _head(ds, n):
     )
 
 
+def _epoch_block_counts():
+    """The counts of every `refresh.epoch_block` span recorded, oldest first."""
+    from kmamiz_tpu.telemetry.tracing import TRACER
+
+    return [
+        dict(tb.counts.get(i, {}))
+        for tb in TRACER.traces()
+        for i, span in enumerate(tb.spans)
+        if span[0] == "refresh.epoch_block"
+    ]
+
+
 def _counter(name):
     for line in REGISTRY.render().splitlines():
         if line.startswith(name + " "):
@@ -427,8 +457,6 @@ class TestTrainingThroughThePlan:
         self, monkeypatch, knob, engaged
     ):
         """The case that used to pin GAT to the code without a plan."""
-        from kmamiz_tpu.telemetry.tracing import TRACER
-
         if knob is None:
             monkeypatch.delenv("KMAMIZ_SPARSE", raising=False)
         else:
@@ -440,13 +468,169 @@ class TestTrainingThroughThePlan:
         stats = sparse.route_stats()
         assert (stats["planned"] > 0) is engaged and (stats["attention"] > 0) is engaged
         assert stats["planned"] == stats["attention"]  # a layer each, and nothing else
-        counts = [
-            dict(tb.counts.get(i, {}))
-            for tb in TRACER.traces()
-            for i, span in enumerate(tb.spans)
-            if span[0] == "refresh.epoch_block"
-        ]
+        counts = _epoch_block_counts()
         assert counts and counts[-1]["planned"] == int(engaged)
+
+
+# -- the slot group: layer 1's data-only sum for several slots in one ------------
+
+
+def _wide_dataset(n_slots, width, n_nodes=150, n_edges=600, seed=0):
+    rng = np.random.default_rng(seed)
+    ds = _dataset(n_nodes=n_nodes, n_edges=n_edges, n_slots=n_slots, seed=seed)
+    ds.features = [rng.normal(size=(n_nodes, width)).astype(np.float32) for _ in range(n_slots)]
+    return ds
+
+
+def _run_block(ds, n_epochs, **block_args):
+    """One call of GraphSAGE's epoch block on a dataset's stack -> (leaves, losses)."""
+    st = stacked.stack_dataset(ds)
+    params = _params(int(st.features.shape[2]))
+    opt_state = graphsage.make_optimizer(1e-2).init(params)
+    params, _, losses = stacked.epoch_runner(graphsage, 1e-2, 3.0)(
+        params, opt_state, st.features, st.target_latency, st.target_anomaly, st.node_mask,
+        st.src, st.dst, st.edge_mask, n_epochs, stacked.plan_for(graphsage, st), **block_args,
+    )
+    return [np.asarray(a) for a in jax.tree_util.tree_leaves(params)], np.asarray(losses)
+
+
+def _parent_epoch_block(model, lr, pos_weight):
+    """The epoch block as PR 28 left it, for the programs that must not
+    change: one flat scan over the slots, the plan closed over."""
+    import functools
+
+    import optax
+
+    from kmamiz_tpu.models import common
+
+    optimizer = model.make_optimizer(lr)
+    grad_fn = jax.value_and_grad(common.make_loss_fn(model.forward, pos_weight), has_aux=True)
+
+    def sage_epoch_block(
+        params, opt_state, features, target_latency, target_anomaly, node_mask,
+        src, dst, edge_mask, n_epochs, plan=None,
+    ):
+        slot_grad = grad_fn
+        if plan is not None:
+            slot_grad = jax.value_and_grad(
+                common.make_loss_fn(functools.partial(model.forward, plan=plan), pos_weight),
+                has_aux=True,
+            )
+
+        def slot_step(carry, xs):
+            p, s = carry
+            f, tl, ta, nm = xs
+            (loss, (lat_l, ano_l)), grads = slot_grad(p, f, src, dst, edge_mask, tl, ta, nm)
+            updates, s = optimizer.update(grads, s, p)
+            p = optax.apply_updates(p, updates)
+            return (p, s), jnp.stack([loss, lat_l, ano_l])
+
+        def epoch_step(carry, _):
+            carry, per_slot = jax.lax.scan(
+                slot_step, carry, (features, target_latency, target_anomaly, node_mask)
+            )
+            return carry, per_slot.mean(axis=0)
+
+        (params, opt_state), losses = jax.lax.scan(
+            epoch_step, (params, opt_state), None, length=n_epochs
+        )
+        return params, opt_state, losses
+
+    return jax.jit(
+        sage_epoch_block, static_argnames=("n_epochs",), donate_argnames=("params", "opt_state")
+    )
+
+
+class TestSlotGroup:
+    @pytest.mark.parametrize("n_epochs", (1, 2))
+    @pytest.mark.parametrize(
+        "n_slots,width,forced,group",
+        [(1, 18, None, 0), (5, 18, None, 5), (7, 18, None, 7), (8, 18, None, 7),
+         (15, 18, None, 7), (9, 70, None, 0), (8, 18, 3, 3), (7, 18, 2, 2)],
+    )
+    @pytest.mark.parametrize("backend", ("sparse", "pallas_interpret"))
+    def test_grouped_block_equals_the_per_slot_block(
+        self, monkeypatch, backend, n_slots, width, forced, group, n_epochs
+    ):
+        """One update a slot, in slot order, from the same layer-1 sums: bit
+        for bit on the kernel (its columns are independent), to the last
+        bits on a CPU's XLA reducer (its matrix product blocks by width)."""
+        monkeypatch.setenv("KMAMIZ_SPARSE", backend)
+        sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()
+        ds = _wide_dataset(n_slots, width)
+        st = stacked.stack_dataset(ds)
+        params = _params(width)
+        if forced is None:
+            assert stacked.slot_group(graphsage, params, st.features, st.plan) == group
+        grouped = _run_block(ds, n_epochs, group=forced)
+        # at trace time: the packed sum and layer 2's, or both layers' (the
+        # VJP's transposed sum is the rule's own call and never was counted)
+        assert sparse.route_stats()["planned"] == 2
+        per_slot = _run_block(ds, n_epochs, group=0)
+        assert sparse.route_stats()["planned"] == 4
+        for a, b in zip(grouped[0] + [grouped[1]], per_slot[0] + [per_slot[1]]):
+            if backend == "pallas_interpret":
+                np.testing.assert_array_equal(a, b)
+            else:
+                np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+        assert grouped[1].shape == (n_epochs, 3) and np.isfinite(grouped[1]).all()
+
+    @pytest.mark.parametrize("case", ("embeddings", "no_plan", "gat", "stlgt"))
+    def test_who_offers_no_data_only_sum_runs_the_program_of_the_parent(self, monkeypatch, case):
+        """Node embeddings put parameters into layer 1's input, a call
+        without a plan has nothing to sum over, GAT multiplies by W1 before it
+        touches an edge: the block is the flat scan it was, jaxpr for jaxpr."""
+        from kmamiz_tpu.models.stlgt import model as stlgt_model
+
+        if case == "no_plan":
+            monkeypatch.setenv("KMAMIZ_SPARSE", "xla")
+            sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()
+        model = {"gat": gat, "stlgt": stlgt_model}.get(case, graphsage)
+        ds = _wide_dataset(9, 18)
+        st = stacked.stack_dataset(ds)
+        plan = stacked.plan_for(model, st)
+        assert (plan is None) is (case in ("no_plan", "stlgt"))
+        kw = {"num_nodes": st.num_nodes} if case == "embeddings" else {}
+        params = model.init_params(jax.random.PRNGKey(0), hidden=8, num_features=18, **kw)
+        assert stacked.slot_group(model, params, st.features, plan) == 0
+        opt_state = model.make_optimizer(1e-2).init(params)
+        args = (
+            params, opt_state, st.features, st.target_latency, st.target_anomaly,
+            st.node_mask, st.src, st.dst, st.edge_mask,
+        )
+        got = jax.make_jaxpr(
+            lambda *a: stacked.epoch_runner(model, 1e-2, 3.0).fn(*a, 2, plan)
+        )(*args)
+        want = jax.make_jaxpr(
+            lambda *a: _parent_epoch_block(model, 1e-2, 3.0)(*a, 2, plan)
+        )(*args)
+        assert str(got) == str(want)
+
+    def test_the_refresh_counts_its_group_on_the_epoch_block_span(self, monkeypatch):
+        """The cell's shapes at a small node count: 18 features, more than
+        seven slots, no embeddings, no knob."""
+        last_counts = lambda: _epoch_block_counts()[-1]  # noqa: E731
+        monkeypatch.delenv("KMAMIZ_SPARSE", raising=False)
+        sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()
+        ds = _wide_dataset(9, 18)
+        r = trainer.train(ds, epochs=2, hidden=8)
+        assert np.isfinite(r.losses).all()
+        assert last_counts() == {"epochs": 2, "slot_updates": 18, "planned": 1, "slot_group": 7}
+        assert sparse.route_stats()["planned"] == 2  # the packed sum, and layer 2's
+        trainer.train(ds, epochs=1, hidden=8, use_node_embeddings=True)
+        assert last_counts()["planned"] == 1 and last_counts()["slot_group"] == 0
+        trainer.train(ds, epochs=1, hidden=8, model=gat)
+        assert last_counts()["planned"] == 1 and last_counts()["slot_group"] == 0
+        trainer.train(ds, epochs=1, hidden=8, batch_slots=2)
+        assert last_counts()["planned"] == 0 and last_counts()["slot_group"] == 0
+        monkeypatch.setenv("KMAMIZ_SPARSE", "xla")
+        sparse.reset_for_tests()
+        stacked.epoch_runner.cache_clear()
+        trainer.train(ds, epochs=1, hidden=8)
+        assert last_counts()["planned"] == 0 and last_counts()["slot_group"] == 0
 
 
 # -- the kernel at the cell's shapes, through the chip's own compiler ---------
@@ -473,7 +657,7 @@ def _described_plan(arg, nb, entries, items):
     )
 
 
-@pytest.mark.parametrize("width", (18, 64))
+@pytest.mark.parametrize("width", (18, 64, 126))  # 126: seven slots' layer-1 sums in one
 def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_chip, width):
     from jax.experimental.compilation_cache import compilation_cache
 

@@ -132,6 +132,7 @@ class TestRefreshTrace:
         assert by_name["refresh.stack.host_fill"]["bytes"] == want
         assert by_name["refresh.epoch_block"] == {
             "epochs": 2, "slot_updates": 10, "planned": 1,
+            "slot_group": 5,  # 128 // 10 features is 12, and there are five slots
         }
         real_edges = int(np.asarray(st.edge_mask).sum())
         assert by_name["refresh.stack"]["plan_entries"] == 2 * real_edges

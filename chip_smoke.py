@@ -22,9 +22,10 @@ accelerator (every child gets JAX_PLATFORMS=tpu, so JAX itself raises).
                       trainer takes epoch blocks on the 10k-endpoint /
                       50k-edge / 24-slot graph, saves and restores an
                       orbax checkpoint, forecast_forward answers from it
-  D  kernels          segment_stats_matmul, fused_neighbor_sums and
-                      fused_gated_bias compiled by Mosaic through their
-                      consumers and compared with the XLA path
+  D  kernels          segment_stats_matmul through window_stats, and the
+                      refresh's planned neighbour sum and planned
+                      attention over an edge plan, compiled by Mosaic
+                      and compared with their XLA twins
 
 One process per chip: this parent never imports JAX (it imports
 kmamiz_tpu.synth, which is JAX-free, and the stdlib); it serves a stub
@@ -99,9 +100,7 @@ FULL = {
     "realtime_interval": None,  # the deployed 5 s cron
     "sage": {"nodes": 10_000, "edges": 50_000, "slots": 24, "hidden": 32},
     "stats": {"records": 16_384, "endpoints": 8192, "statuses": 2},
-    "fused": {"nodes": 1000, "edges": 8192, "feat": 32},
-    "gave_way_nodes": 10_000,
-    "node_budget": None,  # the deployed 2,048-row budget
+    "plan": {"nodes": 1000, "edges": 8192, "feat": 64},
 }
 
 #: What the FULL-size run must produce, whatever it runs on. The inputs
@@ -150,9 +149,7 @@ TINY = {
     "realtime_interval": "* * * * * *",  # every second: the test waits on it
     "sage": {"nodes": 200, "edges": 600, "slots": 8, "hidden": 8},
     "stats": {"records": 512, "endpoints": 64, "statuses": 2},
-    "fused": {"nodes": 96, "edges": 400, "feat": 8},
-    "gave_way_nodes": 300,
-    "node_budget": 128,
+    "plan": {"nodes": 96, "edges": 400, "feat": 8},
 }
 
 
@@ -996,10 +993,7 @@ def phase_models(ctx) -> dict:
 
 
 def phase_kernels(ctx) -> dict:
-    extra = {"KMAMIZ_SPARSE": "pallas" if ctx["platform"] == "tpu" else "pallas_interpret"}
-    if ctx["sizes"]["node_budget"]:
-        extra["KMAMIZ_SPARSE_NODE_MAX"] = ctx["sizes"]["node_budget"]
-    return run_child_phase(ctx, "phaseD_kernels", "D", extra, 500.0)
+    return run_child_phase(ctx, "phaseD_kernels", "D", {}, 500.0)
 
 
 def _child_common():
@@ -1181,30 +1175,19 @@ def child_models(sizes: dict) -> None:
 
 
 def child_kernels(sizes: dict) -> None:
-    """Phase D in the child that holds the chip: the three older Pallas
-    kernels through their consumers, and the planned neighbour sum and the
-    planned attention of the refresh, against the XLA path, at the
-    tolerances tests/test_ops_window.py, tests/test_ops_sparse.py and
+    """Phase D in the child that holds the chip: segment_stats_matmul
+    through window_stats, and the planned neighbour sum and the planned
+    attention of the refresh, against the XLA path, at the tolerances
+    tests/test_ops_window.py, tests/test_edge_plan.py and
     tests/test_planned_attention.py pin."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     out = {"device": _child_common()}
-    from kmamiz_tpu.models import graphsage
-    from kmamiz_tpu.models.stlgt import model as stlgt
     from kmamiz_tpu.ops import sparse, window
 
     on_tpu = jax.default_backend() == "tpu"
-    want_backend = "pallas" if on_tpu else "pallas_interpret"
-    check(
-        sparse.backend() == want_backend,
-        f"KMAMIZ_SPARSE={sparse.backend()!r}, expected {want_backend!r}",
-    )
-    check(
-        sparse.fused_interpret() == (not on_tpu),
-        f"interpret={sparse.fused_interpret()} on {jax.default_backend()}",
-    )
 
     def mosaic(lowered) -> bool:
         return "tpu_custom_call" in lowered.as_text()
@@ -1245,60 +1228,19 @@ def child_kernels(sizes: dict) -> None:
     )
     out["segment_stats_matmul"] = "mosaic" if on_tpu else "interpret"
 
-    # -- the fused kernels through GraphSAGE and STLGT at config 3 ----------
-    fz = sizes["fused"]
-    nodes, edges, feat = fz["nodes"], fz["edges"], fz["feat"]
+    # -- an edge list at config 3 and its plan --------------------------------
+    pz = sizes["plan"]
+    nodes, edges, feat = pz["nodes"], pz["edges"], pz["feat"]
     rng = np.random.default_rng(2)
     h = jnp.asarray(rng.normal(size=(nodes, feat)).astype(np.float32))
     src = jnp.asarray(rng.integers(0, nodes, edges).astype(np.int32))
     dst = jnp.asarray(rng.integers(0, nodes, edges).astype(np.int32))
     mask = jnp.asarray(rng.random(edges) < 0.8)
-    stlgt_params = stlgt.init_params(jax.random.PRNGKey(5), hidden=feat)
-    stlgt_feats = jnp.asarray(
-        rng.normal(size=(nodes, stlgt.NUM_FEATURES)).astype(np.float32)
-    )
-    big = sizes["gave_way_nodes"]
-    h_big = jnp.asarray(rng.normal(size=(big, feat)).astype(np.float32))
-    src_big = jnp.asarray(rng.integers(0, big, edges).astype(np.int32))
-    dst_big = jnp.asarray(rng.integers(0, big, edges).astype(np.int32))
-
-    def consumers():
-        return (
-            np.asarray(graphsage.neighbor_mean(h, src, dst, mask)),
-            tuple(
-                np.asarray(x)
-                for x in stlgt.forward(stlgt_params, stlgt_feats, src, dst, mask)
-            ),
-            np.asarray(graphsage.neighbor_mean(h_big, src_big, dst_big, mask)),
-        )
-
-    check(
-        mosaic(jax.jit(graphsage.neighbor_mean).lower(h, src, dst, mask)) == on_tpu,
-        "GraphSAGE neighbor_mean under KMAMIZ_SPARSE=pallas holds no Mosaic kernel",
-    )
-    before = sparse.route_stats()
-    fused_mean, fused_stlgt, big_mean = consumers()
-    routed = sparse.route_stats()
-    # the lowering check above traced neighbor_mean once more
-    check(
-        routed["fused"] - before["fused"] == 2,
-        f"{nodes}-node consumers did not both take the fused kernel: {routed}",
-    )
-    check(
-        routed["gaveWay"] - before["gaveWay"] == 1
-        and routed["lastGaveWayNodes"] == big,
-        f"{big}-node table did not give way to XLA visibly: {routed}",
-    )
-    from kmamiz_tpu.core import programs
-
-    for name in ("sparse.fused_neighbor_sums", "sparse.fused_gated_bias"):
-        check(programs.get(name).calls > 0, f"{name} was never dispatched")
-    out["routes"] = routed
 
     # -- the refresh's default since PR 27 and PR 28: the planned neighbour
     # sum and the planned attention over an edge plan, Mosaic against the
     # XLA items, values and one VJP each, at the width `feat` ---------------
-    planned_impl = "pallas" if on_tpu else "pallas_interpret"
+    kernel_impl = "pallas" if on_tpu else "pallas_interpret"
     plan = jax.tree_util.tree_map(
         jnp.asarray,
         sparse.build_edge_plan(np.asarray(src), np.asarray(dst), np.asarray(mask), nodes)[0],
@@ -1315,17 +1257,27 @@ def child_kernels(sizes: dict) -> None:
         return [np.asarray(a) for a in (total, *pull_sum(ct), att, *pull_att(ct))]
 
     check(
+        sparse.planned_impl() == ("pallas" if on_tpu else "xla"),
+        f"planned_impl() is {sparse.planned_impl()!r} on {jax.default_backend()}",
+    )
+    check(
+        mosaic(
+            jax.jit(jax.grad(lambda x: sparse.planned_neighbor_sum(plan, x).sum())).lower(h)
+        ) == on_tpu,
+        "the planned sum a caller gets holds no Mosaic kernel on this TPU",
+    )
+    check(
         mosaic(
             jax.jit(
                 jax.grad(lambda x: sparse.planned_attention(
-                    plan, x, s_t[0], s_t[1], 0.2, planned_impl).sum())
+                    plan, x, s_t[0], s_t[1], 0.2, kernel_impl).sum())
             ).lower(h)
         ) == on_tpu,
         "the planned attention holds no Mosaic kernel on this TPU",
     )
     for name, got, want in zip(
         ("sum", "sum.d_h", "attention", "attention.d_hw", "attention.d_s", "attention.d_t"),
-        planned(planned_impl), planned("xla"),
+        planned(kernel_impl), planned("xla"),
     ):
         scale = max(float(np.abs(want).max()), 1.0)
         np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
@@ -1338,28 +1290,17 @@ def child_kernels(sizes: dict) -> None:
     # slot's columns against the kernel's sum of that slot alone, bit for bit
     slots = rng.normal(size=(7, nodes, 18)).astype(np.float32)
     table = jnp.asarray(np.moveaxis(slots, 0, 1).reshape(nodes, 126))
-    packed = np.asarray(sparse.planned_neighbor_sum(plan, table, planned_impl))
+    packed = np.asarray(sparse.planned_neighbor_sum(plan, table, kernel_impl))
     want = np.asarray(sparse.planned_neighbor_sum(plan, table, "xla"))
     scale = max(float(np.abs(want).max()), 1.0)
     np.testing.assert_allclose(packed, want, rtol=0, atol=5e-5 * scale, err_msg="sum.w126")
     for j in range(7):
-        alone = sparse.planned_neighbor_sum(plan, jnp.asarray(slots[j]), planned_impl)
+        alone = sparse.planned_neighbor_sum(plan, jnp.asarray(slots[j]), kernel_impl)
         np.testing.assert_array_equal(
             packed[:, j * 18 : (j + 1) * 18], np.asarray(alone), f"slot {j} of the packed sum"
         )
     out["planned_neighbor_sum_w126"] = out["planned_neighbor_sum"]
-
-    os.environ["KMAMIZ_SPARSE"] = "xla"
-    sparse.reset_for_tests()  # the backend knob is cached after first read
-    ref_mean, ref_stlgt, ref_big = consumers()
-    check(sparse.route_stats()["fused"] == 0, "xla reference ran a fused kernel")
-    np.testing.assert_allclose(fused_mean, ref_mean, rtol=1e-5, atol=1e-5)
-    for got, want in zip(fused_stlgt, ref_stlgt):
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(big_mean, ref_big, rtol=1e-5, atol=1e-5)
-    out["fused_neighbor_sums"] = out["fused_gated_bias"] = (
-        "mosaic" if on_tpu else "interpret"
-    )
+    out["routes"] = sparse.route_stats()
     _child_tail(out)
 
 

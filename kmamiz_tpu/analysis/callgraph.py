@@ -38,8 +38,8 @@ DEFAULT_SEED_MODULES = (
     "kmamiz_tpu/control/admission.py",
     "kmamiz_tpu/control/policy.py",
     "kmamiz_tpu/control/warmup.py",
-    # the fused SDDMM/SpMM kernels sit under every sparse-backend
-    # consumer (scorers, packed walk, graphsage, stlgt bias) — seed the
+    # the edge plan's reductions and the counting primitives sit under
+    # every sparse-backend consumer (scorers, packed walk, graphsage, gat) — seed the
     # module itself so the hot-path rules see its helpers even when the
     # consumer dispatch is behind the KMAMIZ_SPARSE knob
     "kmamiz_tpu/ops/sparse.py",

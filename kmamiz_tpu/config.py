@@ -100,10 +100,7 @@ class Settings:
     # instance); mirrored here so one `Settings()` dump shows them.
     sparse_backend: str = field(
         default_factory=lambda: os.environ.get("KMAMIZ_SPARSE", "sparse")
-    )  # xla | sparse | pallas | pallas_interpret
-    sparse_tile: int = field(
-        default_factory=lambda: int(os.environ.get("KMAMIZ_SPARSE_TILE", "256"))
-    )  # edge-tile rows per fused-kernel grid step (multiple of 8)
+    )  # xla | sparse
     store_grow: str = field(
         default_factory=lambda: os.environ.get("KMAMIZ_STORE_GROW", "segment")
     )  # segment = compile-free overflow tail; repack = pow2 re-pad

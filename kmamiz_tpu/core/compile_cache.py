@@ -2,7 +2,7 @@
 
 Compiling the graph-union and scorer programs is the largest part of a
 cold boot, so every entry point (dp_server.main, api.app.main,
-fleet.worker.main, the tools, bench.py's and chip_smoke.py's children)
+fleet.worker.main, the tools, benchmarks/run.py, chip_smoke.py's children)
 calls :func:`enable` before its first jit dispatch. The rule:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX itself reads it; this module

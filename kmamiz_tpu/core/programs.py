@@ -183,7 +183,7 @@ class Program:
     """Instrumented wrapper around one jitted callable.
 
     Transparent for callers: ``__call__`` delegates, and jit attributes
-    (``lower``, ``_cache_size`` — bench.py reads it) pass through via
+    (``lower``, ``_cache_size`` — the tests read it) pass through via
     ``__getattr__``. Telemetry costs two ``_cache_size()`` reads and one
     timer per dispatch.
     """
@@ -843,10 +843,6 @@ REGISTERED_JIT_SITES: Dict[str, set] = {
         "_bulk_dist_bounds",
         "_cat_segments",
     },
-    "kmamiz_tpu/ops/sparse.py": {
-        "fused_gated_bias",
-        "fused_neighbor_sums",
-    },
     # multi-chip programs: registered for their call/compile counters
     # (chip_smoke.py reads them to see the mesh path was taken). Their
     # specs carry a Mesh, which no hint can encode, so they stay out of
@@ -901,6 +897,6 @@ ALLOWLISTED_JIT_SITES: Dict[str, Dict[str, str]] = {
     },
     "kmamiz_tpu/models/common.py": {
         "train_step": "legacy per-slot trainer loop "
-        "(KMAMIZ_SAGE_FUSED=0 parity reference), off the serving path",
+        "(train(fused=False), the parity reference), off the serving path",
     },
 }

@@ -7,10 +7,10 @@ costs the same as any steady-state merge; cold, the same merge eats the
 multi-program compile wall. This module drives ONE deterministic edge
 ramp across the threshold on a bare ``EndpointGraph`` and reports the
 crossing batch's wall time, its program-registry compile delta, and the
-final graph signature — bench.py runs it twice as subprocesses (compile
+final graph signature — run it twice as subprocesses (compile
 caches are process-global; an in-process A/B would leak warmth from the
-first arm into the second) and asserts signature equality, so the A/B
-compares identical work.
+first arm into the second) and compare the signatures, so the A/B
+compares identical work (the round-5 bench did).
 
     python -m kmamiz_tpu.cost.growth_probe --prewarm on
     python -m kmamiz_tpu.cost.growth_probe --prewarm off --capacity 256
@@ -35,8 +35,8 @@ DEFAULT_ROWS = 300
 
 def _batches(n_batches: int, rows: int):
     """Globally-distinct (src, dst, dist) int32 triples per batch, so
-    the union's dedup never collapses the ramp (bench.py's generator
-    idiom). Pure arithmetic — both arms see identical bytes."""
+    the union's dedup never collapses the ramp (the round-5 bench's
+    generator idiom). Pure arithmetic — both arms see identical bytes."""
     import numpy as np
 
     for i in range(n_batches):

@@ -22,7 +22,7 @@ exercised, not assumed. Scored like every runner scorecard:
 Workers are in-process (``LocalTransport``) by default so the soak fits
 the tier-1 budget; the coordination logic — ring, drain queue, handoff
 protocol, fold — is byte-identical to the multi-process deployment,
-which ``bench.py``'s fleet section exercises with real subprocess
+which ``tools/fleet_bench.py`` exercises with real subprocess
 workers over ``HTTPTransport``.
 """
 from __future__ import annotations

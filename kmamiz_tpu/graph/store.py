@@ -318,7 +318,7 @@ class EndpointGraph:
 
     Edge semantics: src depends-ON dst (src is the CLIENT-side ancestor).
 
-    Capacity policy (bench.py's graph_scale_* extras characterize it to
+    Capacity policy (the round-5 bench's graph_scale_* extras took it to
     100k endpoints / ~5.2M edges): edge arrays are padded to
     power-of-2 capacities. Two growth modes (KMAMIZ_STORE_GROW / the
     `grow` ctor arg):

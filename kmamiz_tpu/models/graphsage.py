@@ -174,12 +174,8 @@ def neighbor_mean(
     refresh: models/stacked.py builds one per dataset) passes it, and the
     sum is one row gather and one owner-sorted reduction with its own VJP
     (sparse.planned_neighbor_sum), the degree the plan's. Without a plan
-    (the tick's one-off graphs) the edge list is reduced as it comes:
-
-    Under the pallas backends the whole gather -> mask -> two segment_sums
-    chain runs as one fused SpMM kernel (ops/sparse.py) when the node
-    table fits the VMEM budget; the division stays out here so the
-    normalization matches the XLA path exactly."""
+    (the tick's one-off graphs) the edge list is reduced as it comes, by
+    XLA's masked gathers and segment sums."""
     n = h.shape[0]
     if neighbor_sum is not None:
         if deg is None:
@@ -190,18 +186,6 @@ def neighbor_mean(
         if deg is None:
             deg = plan.degree.astype(h.dtype)
         return agg / jnp.maximum(deg, 1.0)[:, None]
-    if sparse.fused_route(n):
-        agg, fused_deg = sparse.fused_neighbor_sums(
-            h.astype(jnp.float32),
-            src_ep,
-            dst_ep,
-            edge_mask,
-            tile=sparse.tile_size(),
-            interpret=sparse.fused_interpret(),
-        )
-        if deg is None:
-            deg = fused_deg
-        return (agg / jnp.maximum(deg, 1.0)[:, None]).astype(h.dtype)
     src = jnp.where(edge_mask, src_ep, n)
     dst = jnp.where(edge_mask, dst_ep, n)
     dst_h = h[jnp.minimum(dst, n - 1)] * edge_mask[:, None]

@@ -40,7 +40,6 @@ import jax.numpy as jnp
 
 from kmamiz_tpu.models import common as _common
 from kmamiz_tpu.models.graphsage import NUM_FEATURES, assemble_features  # noqa: F401 - re-export: one feature layout for every head
-from kmamiz_tpu.ops import sparse
 
 #: forecast quantile levels, in emitted column order (p50, p95, p99)
 QUANTILES: Tuple[float, ...] = (0.50, 0.95, 0.99)
@@ -141,39 +140,22 @@ def encode(
     # neighbor bias from the CSR edge list: gated messages over both
     # directions (callers and callees are both signal), sentinel-indexed
     # like graphsage.neighbor_mean so padded edges contribute nothing.
-    # Under the pallas backends the SDDMM gate + bidirectional gated SpMM
-    # run as one fused kernel (ops/sparse.py) when the node table fits
-    # the VMEM budget; the deg normalization stays out here either way.
-    if sparse.fused_route(n):
-        bias, deg, gate = sparse.fused_gated_bias(
-            q,
-            k,
-            v,
-            params.b_edge[0],
-            src_ep,
-            dst_ep,
-            edge_mask,
-            tile=sparse.tile_size(),
-            interpret=sparse.fused_interpret(),
-        )
-        bias = bias / jnp.maximum(deg, 1.0)[:, None]
-    else:
-        em = edge_mask.astype(jnp.float32)
-        src_c = jnp.minimum(src_ep, n - 1)
-        dst_c = jnp.minimum(dst_ep, n - 1)
-        affinity = (q[src_c] * k[dst_c]).sum(axis=1) / jnp.sqrt(
-            jnp.float32(q.shape[1])
-        )
-        gate = jax.nn.sigmoid(affinity + params.b_edge[0]) * em
-        src_s = jnp.where(edge_mask, src_ep, n)
-        dst_s = jnp.where(edge_mask, dst_ep, n)
-        msg_fwd = v[src_c] * gate[:, None]
-        msg_bwd = v[dst_c] * gate[:, None]
-        bias = jax.ops.segment_sum(msg_fwd, dst_s, num_segments=n + 1)[:-1]
-        bias = bias + jax.ops.segment_sum(msg_bwd, src_s, num_segments=n + 1)[:-1]
-        deg = jax.ops.segment_sum(gate, dst_s, num_segments=n + 1)[:-1]
-        deg = deg + jax.ops.segment_sum(gate, src_s, num_segments=n + 1)[:-1]
-        bias = bias / jnp.maximum(deg, 1.0)[:, None]
+    em = edge_mask.astype(jnp.float32)
+    src_c = jnp.minimum(src_ep, n - 1)
+    dst_c = jnp.minimum(dst_ep, n - 1)
+    affinity = (q[src_c] * k[dst_c]).sum(axis=1) / jnp.sqrt(
+        jnp.float32(q.shape[1])
+    )
+    gate = jax.nn.sigmoid(affinity + params.b_edge[0]) * em
+    src_s = jnp.where(edge_mask, src_ep, n)
+    dst_s = jnp.where(edge_mask, dst_ep, n)
+    msg_fwd = v[src_c] * gate[:, None]
+    msg_bwd = v[dst_c] * gate[:, None]
+    bias = jax.ops.segment_sum(msg_fwd, dst_s, num_segments=n + 1)[:-1]
+    bias = bias + jax.ops.segment_sum(msg_bwd, src_s, num_segments=n + 1)[:-1]
+    deg = jax.ops.segment_sum(gate, dst_s, num_segments=n + 1)[:-1]
+    deg = deg + jax.ops.segment_sum(gate, src_s, num_segments=n + 1)[:-1]
+    bias = bias / jnp.maximum(deg, 1.0)[:, None]
 
     h1 = x + jax.nn.relu((attn + bias) @ params.w_o)
     h2 = h1 + jax.nn.relu(

@@ -16,7 +16,6 @@ works on any simulation config.
 from __future__ import annotations
 
 import logging
-import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
 
@@ -217,17 +216,17 @@ def train(
     checkpoint_every: int = 10,
     model=graphsage,
     use_node_embeddings: bool = False,
-    fused: bool = None,
+    fused: bool = True,
     batch_slots: int = 1,
     mesh=None,
 ) -> TrainResult:
     """Full-graph training, one step per slot per epoch.
 
-    fused (default on; KMAMIZ_SAGE_FUSED=0 or fused=False for the legacy
-    host loop) stacks the dataset device-resident (models/stacked.py) and
-    runs whole epoch blocks as ONE jitted lax.scan with donated
-    params/optimizer state — the per-slot update schedule is identical to
-    the legacy loop, so losses/params agree within fp32 tolerance.
+    fused (default on; fused=False for the legacy host loop) stacks the
+    dataset device-resident (models/stacked.py) and runs whole epoch blocks
+    as ONE jitted lax.scan with donated params/optimizer state — the
+    per-slot update schedule is identical to the legacy loop, so
+    losses/params agree within fp32 tolerance.
 
     batch_slots > 1 switches to slot-minibatch SGD (per-batch averaged
     grads, one update per batch); with `mesh` the batch axis additionally
@@ -247,12 +246,6 @@ def train(
     the code already returns or already waits; none adds a sync."""
     from kmamiz_tpu.models import checkpoint as ckpt
 
-    if fused is None:
-        fused = os.environ.get("KMAMIZ_SAGE_FUSED", "1") not in (
-            "0",
-            "off",
-            "false",
-        )
     model_name = model.__name__.rsplit(".", 1)[-1]
     num_slots = len(dataset.features) if dataset is not None else 0
     _REFRESHES.inc()

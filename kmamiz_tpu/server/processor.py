@@ -1232,8 +1232,8 @@ class DataProcessor:
         phase breakdown (`chunk_detail`: spans / parse_ms / merge_ms /
         transfer_ms per chunk, plus `drain_ms` for the final device
         sync) — enough to reconstruct the pipeline's critical path with
-        the host->device copy priced at any bandwidth (bench.py does
-        exactly that)."""
+        the host->device copy priced at any bandwidth (the round-5 bench
+        did exactly that)."""
         from kmamiz_tpu.core.spans import raw_spans_to_batch
 
         depth = self._stream_depth(depth)

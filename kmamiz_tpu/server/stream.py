@@ -126,7 +126,7 @@ class StreamEngine:
     fence and the epoch accounting — under HTTP each request is one
     micro-tick and the OS/network overlaps arrivals. `run_stream`
     drives a known request sequence with true producer/consumer
-    overlap (bench.py and the scenario runner use it)."""
+    overlap (the scenario runner uses it)."""
 
     def __init__(self, processor, watchdog=None) -> None:
         self.processor = processor

@@ -1,6 +1,6 @@
 """Synthetic raw-Zipkin workload generation.
 
-One generator shared by the bench headline (bench.py), the driver's
+One generator shared by chip_smoke.py, the driver's
 multi-chip dryrun (__graft_entry__.dryrun_multichip), and the parallel
 tests: Istio-sidecar-shaped span groups serialized exactly like a Zipkin
 `GET /api/v2/traces` response body, so the native SoA loader

@@ -12,8 +12,8 @@ Four parts behind one package:
   program's spans on the device's clock.
 - `device`    — HBM/arena residency gauges and the on-demand
   `POST /debug/profile` jax.profiler capture.
-- `slo`       — the rolling SLO scorecard bench.py emits as headline
-  keys and `tools/slo_report.py` gates on.
+- `slo`       — the rolling SLO scorecard (`/timings`), whose keys
+  `tools/slo_report.py` gates on.
 - `profiling` — graftprof: the lock-free host event ring, native
   parse/merge contention counters, the compile-cause log and HBM
   timeline, and the SLO-breach flight recorder (`GET /debug/graftprof`,

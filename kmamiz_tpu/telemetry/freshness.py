@@ -12,8 +12,8 @@ up here as the p99 dropping toward single-stage cost.
 Surfaces:
 
 - rolling percentile snapshot (`snapshot()`) — `/timings` "freshness"
-  key, the scenario runner's freshness gate, and bench.py's
-  `stream_freshness_ms_p99` headline;
+  key, the scenario runner's freshness gate, and the
+  `stream_freshness_ms_p99` key `tools/slo_report.py` gates;
 - Prometheus: `kmamiz_freshness_ms` histogram + observation counter,
   plus scrape-time p50/p95/p99 gauges refreshed via the registry's
   callback hook (same pull-gauge idiom as telemetry/device.py).

@@ -3,9 +3,9 @@
 Rolling tick-latency percentiles (p50/p95/p99 over the last
 `KMAMIZ_SLO_WINDOW` ticks) plus rates derived from registry counters:
 stale-serve rate, ingest-drop rate, quarantine rate, and the process
-recompile count from the program registry. `bench.py` emits the
-scorecard as headline keys; `tools/slo_report.py --check` gates
-regressions against the last recorded BENCH_r*.json.
+recompile count from the program registry. `tools/slo_report.py --check`
+gates a result's scorecard keys against the last recorded BENCH_r*.json
+(none is in the tree since the round-5 bench went: ROADMAP D12).
 """
 from __future__ import annotations
 
@@ -90,7 +90,7 @@ class Scorecard:
 
 SCORECARD = Scorecard()
 
-# the keys bench.py promotes to headline level, and the direction in
+# the scorecard's headline keys (the round-5 bench's), and the direction in
 # which each regresses (for tools/slo_report.py --check)
 SLO_KEYS_HIGHER_IS_WORSE = (
     "tick_p50_ms",

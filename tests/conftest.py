@@ -69,6 +69,24 @@ def _reset_resilience_state():
     yield
 
 
+@pytest.fixture
+def plan_reducer(request, monkeypatch):
+    """Which reducer the planned sums and attentions traced in this test
+    run, for `parametrize("plan_reducer", ..., indirect=True)`: "xla", what
+    `sparse.planned_impl` answers on a CPU, or "pallas_interpret", the Pallas
+    kernels interpreted. No environment value selects the second, so it is
+    patched in; the epoch block's cached trace goes, so the choice is seen."""
+    from kmamiz_tpu.models import stacked
+    from kmamiz_tpu.ops import sparse
+
+    if request.param != "xla":
+        monkeypatch.setattr(sparse, "planned_impl", lambda: request.param)
+    assert sparse.planned_impl() == request.param
+    stacked.epoch_runner.cache_clear()
+    yield request.param
+    stacked.epoch_runner.cache_clear()  # no patched trace outlives the test
+
+
 FIXTURES = Path(__file__).parent / "fixtures"
 
 

@@ -386,7 +386,7 @@ class TestCostPlane:
         assert snap["lastCrossing"]["hit"] is False
 
 
-# -- the stall probe (bench.py's A/B arms) ------------------------------------
+# -- the stall probe (two arms, a process each) ------------------------------------
 
 
 class TestGrowthProbe:

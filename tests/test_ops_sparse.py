@@ -3,8 +3,9 @@
 Per-consumer parity against the legacy XLA paths: service scorers
 (bit-exact integer lanes, fp32-tolerance relying factor across all three
 sparse rf branches), the packed dependency walk (edge-multiset equality),
-and the fused SDDMM/SpMM kernels behind GraphSAGE ``neighbor_mean`` and
-the STLGT gated neighbor bias (interpret mode on CPU). Plus the
+and the model plane's two neighbour reductions (GraphSAGE
+``neighbor_mean`` with a plan against without, the STLGT gated neighbour
+bias against a per-edge loop). Plus the
 segment-append capacity growth contract: one capacity crossing completes
 with ZERO new compiles of any registered program, while the legacy
 repack mode recompiles — and both modes hold identical edge sets.
@@ -209,9 +210,10 @@ class TestWalkParity:
             assert self._multiset(got) == self._multiset(dense)
 
 
-class TestFusedKernelParity:
-    """The fused SDDMM/SpMM Pallas kernels (interpret mode on CPU) vs
-    the XLA gather/segment-sum formulations they replace."""
+class TestModelPlaneReductions:
+    """What is left of the model plane's neighbour reductions: the plan's
+    where the caller holds a plan, XLA's gathers and segment sums where it
+    does not, and no environment value that chooses a third."""
 
     @staticmethod
     def _graph(seed, n, e, f):
@@ -223,78 +225,145 @@ class TestFusedKernelParity:
             jnp.asarray(rng.random(e) < 0.8),
         )
 
-    def test_fused_neighbor_sums(self):
-        h, src, dst, mask = self._graph(0, 40, 300, 16)
-        n = h.shape[0]
-        agg, deg = sparse.fused_neighbor_sums(
-            h, src, dst, mask, tile=64, interpret=True
-        )
-        src_s = jnp.where(mask, src, n)
-        dst_s = jnp.where(mask, dst, n)
-        ref = jax.ops.segment_sum(
-            h[jnp.minimum(dst, n - 1)] * mask[:, None], src_s,
-            num_segments=n + 1,
-        )[:-1]
-        ref = ref + jax.ops.segment_sum(
-            h[jnp.minimum(src, n - 1)] * mask[:, None], dst_s,
-            num_segments=n + 1,
-        )[:-1]
-        em = mask.astype(jnp.float32)
-        ref_deg = jax.ops.segment_sum(em, src_s, num_segments=n + 1)[:-1]
-        ref_deg = ref_deg + jax.ops.segment_sum(
-            em, dst_s, num_segments=n + 1
-        )[:-1]
-        np.testing.assert_allclose(agg, ref, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(deg, ref_deg, rtol=1e-5, atol=1e-5)
+    @pytest.mark.parametrize("value", ("pallas", "pallas_interpret"))
+    def test_backend_refuses_the_removed_values_by_name(self, monkeypatch, value):
+        monkeypatch.setenv("KMAMIZ_SPARSE", value)
+        sparse.reset_for_tests()
+        with pytest.raises(ValueError) as err:
+            sparse.backend()
+        message = str(err.value)
+        assert repr(value) in message
+        assert "'xla'" in message and "'sparse'" in message
+        assert message.count("pallas") == 1  # the refused value, not an option
 
-    def test_fused_gated_bias(self):
-        rng = np.random.default_rng(1)
-        n, e, hdim = 32, 200, 8
-        q = jnp.asarray(rng.normal(size=(n, hdim)).astype(np.float32))
-        k = jnp.asarray(rng.normal(size=(n, hdim)).astype(np.float32))
-        v = jnp.asarray(rng.normal(size=(n, hdim)).astype(np.float32))
-        b_edge = jnp.float32(0.3)
-        src = jnp.asarray(rng.integers(0, n, e).astype(np.int32))
-        dst = jnp.asarray(rng.integers(0, n, e).astype(np.int32))
-        mask = jnp.asarray(rng.random(e) < 0.8)
-        bias, deg, gate = sparse.fused_gated_bias(
-            q, k, v, b_edge, src, dst, mask, tile=64, interpret=True
-        )
-        # the STLGT model's XLA else-branch, verbatim
-        em = mask.astype(jnp.float32)
-        src_c = jnp.minimum(src, n - 1)
-        dst_c = jnp.minimum(dst, n - 1)
-        affinity = (q[src_c] * k[dst_c]).sum(axis=1) / jnp.sqrt(
-            jnp.float32(hdim)
-        )
-        ref_gate = jax.nn.sigmoid(affinity + b_edge) * em
-        src_s = jnp.where(mask, src, n)
-        dst_s = jnp.where(mask, dst, n)
-        ref_bias = jax.ops.segment_sum(
-            v[src_c] * ref_gate[:, None], dst_s, num_segments=n + 1
-        )[:-1]
-        ref_bias = ref_bias + jax.ops.segment_sum(
-            v[dst_c] * ref_gate[:, None], src_s, num_segments=n + 1
-        )[:-1]
-        ref_deg = jax.ops.segment_sum(ref_gate, dst_s, num_segments=n + 1)[:-1]
-        ref_deg = ref_deg + jax.ops.segment_sum(
-            ref_gate, src_s, num_segments=n + 1
-        )[:-1]
-        np.testing.assert_allclose(gate, ref_gate, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(bias, ref_bias, rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(deg, ref_deg, rtol=1e-5, atol=1e-5)
+    @pytest.mark.parametrize(
+        "platform, env, want",
+        (("cpu", None, "xla"), ("tpu", None, "pallas"), ("tpu", "xla", "pallas")),
+        ids=("cpu", "tpu", "tpu-env-xla"),
+    )
+    def test_planned_impl_follows_the_platform_alone(
+        self, monkeypatch, platform, env, want
+    ):
+        if env is None:
+            monkeypatch.delenv("KMAMIZ_SPARSE", raising=False)
+        else:
+            monkeypatch.setenv("KMAMIZ_SPARSE", env)
+        sparse.reset_for_tests()
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert sparse.planned_impl() == want
+        if env is not None:  # and the knob off the TPU changes nothing either
+            monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+            assert sparse.planned_impl() == "xla"
 
-    def test_neighbor_mean_backend_parity(self, monkeypatch):
+    @pytest.mark.parametrize("nodes", (48, 2048, 4096))
+    def test_neighbor_mean_with_a_plan_against_without(self, nodes):
+        """Values and the gradient of a scalar loss, on both sides of the
+        2,048 rows the removed kernel's node table was held to."""
         from kmamiz_tpu.models import graphsage
 
-        h, src, dst, mask = self._graph(2, 48, 256, 12)
-        monkeypatch.setenv("KMAMIZ_SPARSE", "xla")
-        sparse.reset_for_tests()
-        ref = np.asarray(graphsage.neighbor_mean(h, src, dst, mask))
-        monkeypatch.setenv("KMAMIZ_SPARSE", "pallas_interpret")
-        sparse.reset_for_tests()
-        got = np.asarray(graphsage.neighbor_mean(h, src, dst, mask))
-        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+        h, src, dst, mask = self._graph(nodes, nodes, 4 * nodes, 12)
+        plan = jax.tree_util.tree_map(
+            jnp.asarray,
+            sparse.build_edge_plan(
+                np.asarray(src), np.asarray(dst), np.asarray(mask), nodes
+            )[0],
+        )
+        weights = jnp.asarray(
+            np.random.default_rng(1).normal(size=h.shape).astype(np.float32)
+        )
+
+        def loss(x, plan_):
+            mean = graphsage.neighbor_mean(x, src, dst, mask, plan=plan_)
+            return (mean * weights).sum(), mean
+
+        (_, want), want_grad = jax.value_and_grad(loss, has_aux=True)(h, None)
+        (_, got), got_grad = jax.value_and_grad(loss, has_aux=True)(h, plan)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got_grad, want_grad, rtol=1e-5, atol=1e-5)
+        assert np.abs(np.asarray(want_grad)).max() > 0
+
+
+def _stlgt_case():
+    """A 12-node bucket (9 real rows), 24 edge slots: masked edges, padding
+    edges parked past the bucket, a self loop, a repeated edge, and node 8
+    on no edge at all."""
+    from kmamiz_tpu.models.stlgt import model as stlgt
+
+    rng = np.random.default_rng(7)
+    n, real, e, hidden = 12, 9, 24, 8
+    feats = np.zeros((n, stlgt.NUM_FEATURES), np.float32)
+    feats[:real] = rng.normal(size=(real, stlgt.NUM_FEATURES))
+    src = rng.integers(0, 8, e).astype(np.int32)
+    dst = rng.integers(0, 8, e).astype(np.int32)
+    src[0], dst[0] = 3, 3  # a self loop
+    src[1], dst[1] = src[2], dst[2]  # the same edge twice
+    mask = rng.random(e) < 0.75
+    mask[:3] = True
+    src[-4:], dst[-4:], mask[-4:] = n, n, False  # bucket padding
+    params = stlgt.init_params(jax.random.PRNGKey(3), hidden=hidden)
+    params = params._replace(b_edge=jnp.asarray([0.3], jnp.float32))
+    return stlgt, params, feats, src, dst, mask
+
+
+class TestStlgtNeighborBias:
+    """The gated neighbour bias of `stlgt.model`, the model's own XLA path
+    (what the removed gated kernel was compared with), against a per-edge
+    numpy loop."""
+
+    def test_encode_against_a_per_edge_loop(self):
+        stlgt, params, feats, src, dst, mask = _stlgt_case()
+        p = jax.tree_util.tree_map(lambda a: np.asarray(a, np.float64), params)
+        relu = lambda a: np.maximum(a, 0.0)  # noqa: E731
+        phi = lambda a: np.where(a > 0, a, np.expm1(a)) + 1.0  # noqa: E731
+        n, hidden = feats.shape[0], p.w_q.shape[1]
+        f64 = feats.astype(np.float64)
+        lane = (np.abs(f64).sum(axis=1) > 0).astype(np.float64)
+        x = relu(f64 @ p.w_in + p.b_in)
+        q = phi(x @ p.w_q) * lane[:, None]
+        k = phi(x @ p.w_k) * lane[:, None]
+        v = (x @ p.w_v) * lane[:, None]
+        attn = (q @ (k.T @ v)) / (q @ k.sum(axis=0) + 1e-6)[:, None]
+        gate = np.zeros(len(src))
+        bias, deg = np.zeros((n, hidden)), np.zeros(n)
+        for e, (u, w, on) in enumerate(zip(src, dst, mask)):
+            if not on:
+                continue
+            g = 1.0 / (1.0 + np.exp(-(q[u] @ k[w] / np.sqrt(hidden) + p.b_edge[0])))
+            gate[e] = g
+            bias[w] += g * v[u]  # the caller's message, to the callee
+            bias[u] += g * v[w]  # and the callee's, back
+            deg[w] += g
+            deg[u] += g
+        bias /= np.maximum(deg, 1.0)[:, None]
+        h1 = x + relu((attn + bias) @ p.w_o)
+        h2 = h1 + relu(relu(h1 @ p.w_f1 + p.b_f1) @ p.w_f2 + p.b_f2)
+        want = h2 * lane[:, None]
+
+        got, got_gate = stlgt.encode(
+            params, jnp.asarray(feats), jnp.asarray(src), jnp.asarray(dst),
+            jnp.asarray(mask),
+        )
+        np.testing.assert_allclose(got_gate, gate, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+        assert (np.asarray(got_gate)[~mask] == 0).all()
+        assert deg[8] == 0 and np.abs(bias[8]).max() == 0  # the isolated node
+        assert np.abs(bias[:8]).max() > 0  # the bias is not a bystander here
+        assert (np.asarray(got)[9:] == 0).all()  # padded lanes stay dark
+
+    def test_gradient_through_forward_is_finite(self):
+        stlgt, params, feats, src, dst, mask = _stlgt_case()
+        args = tuple(jnp.asarray(a) for a in (feats, src, dst, mask))
+
+        def loss(p):
+            latency, logit = stlgt.forward(p, *args)
+            return (latency**2).sum() + (logit**2).sum()
+
+        grads = jax.grad(loss)(params)
+        for name, g in grads._asdict().items():
+            assert np.isfinite(np.asarray(g)).all(), name
+        # the gate and the messages are on the path of the loss
+        assert float(np.abs(np.asarray(grads.b_edge)).max()) > 0
+        assert float(np.abs(np.asarray(grads.w_v)).max()) > 0
 
 
 def _distinct_batches(n_batches, rows=300):

@@ -270,9 +270,9 @@ def _as_reference_params(params):
 
 
 class TestGatForwardWithAPlan:
-    @pytest.mark.parametrize("backend", ("sparse", "pallas_interpret"))
+    @pytest.mark.parametrize("plan_reducer", IMPLS, indirect=True)
     @pytest.mark.parametrize("embeddings", (False, True), ids=("features", "embeddings"))
-    def test_loss_and_gradient_match_the_forward_without_a_plan(self, monkeypatch, backend, embeddings):
+    def test_loss_and_gradient_match_the_forward_without_a_plan(self, plan_reducer, embeddings):
         src, dst, mask, nb, plan = _plan("masked")
         rng = np.random.default_rng(11)
         x = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
@@ -283,8 +283,6 @@ class TestGatForwardWithAPlan:
         args = (x, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask), tl, ta, nm)
         want, want_grad = jax.value_and_grad(gat.loss_fn, has_aux=True)(params, *args)
 
-        monkeypatch.setenv("KMAMIZ_SPARSE", backend)
-        sparse.reset_for_tests()
         planned = common.make_loss_fn(partial(gat.forward, plan=plan))
         got, got_grad = jax.value_and_grad(planned, has_aux=True)(params, *args)
         assert sparse.route_stats()["attention"] == 2  # both layers, at trace time
@@ -295,12 +293,10 @@ class TestGatForwardWithAPlan:
                 continue
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-6, err_msg=name)
 
-    @pytest.mark.parametrize("impl", IMPLS)
-    def test_values_and_gradients_of_every_parameter_against_the_plain_reference(self, monkeypatch, impl):
+    @pytest.mark.parametrize("plan_reducer", IMPLS, indirect=True)
+    def test_values_and_gradients_of_every_parameter_against_the_plain_reference(self, plan_reducer):
         """`benchmarks/reference/gat.py` knows no mask and no padding: it is
         given the real edges, the system the bucket-padded list and its plan."""
-        monkeypatch.setenv("KMAMIZ_SPARSE", "sparse" if impl == "xla" else impl)
-        sparse.reset_for_tests()
         src, dst, mask, nb, plan = _plan("masked")
         rng = np.random.default_rng(12)
         x = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
@@ -338,14 +334,11 @@ class TestGatForwardWithAPlan:
 
 
 class TestGatTrainingThroughThePlan:
-    @pytest.mark.parametrize("backend", ("sparse", "pallas_interpret"))
-    def test_epoch_block_with_a_plan_matches_the_per_slot_loop_over_three_epochs(self, monkeypatch, backend):
+    @pytest.mark.parametrize("plan_reducer", IMPLS, indirect=True)
+    def test_epoch_block_with_a_plan_matches_the_per_slot_loop_over_three_epochs(self, plan_reducer):
         ds = _dataset()
         legacy = trainer.train(ds, epochs=3, hidden=8, seed=0, fused=False, model=gat)
         assert sparse.route_stats()["attention"] == 0
-        monkeypatch.setenv("KMAMIZ_SPARSE", backend)
-        sparse.reset_for_tests()
-        stacked.epoch_runner.cache_clear()
         fused = trainer.train(ds, epochs=3, hidden=8, seed=0, fused=True, model=gat)
         assert sparse.route_stats()["attention"] > 0
         np.testing.assert_allclose(fused.losses, legacy.losses, rtol=1e-4, atol=1e-5)

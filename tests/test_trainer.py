@@ -398,6 +398,14 @@ class TestCheckpointResume:
         assert checkpoint.latest_complete_step(str(tmp_path)) is None
 
 
+def _last_refresh_spans():
+    """Names of the spans of the newest trace rooted at `refresh.train`."""
+    from kmamiz_tpu.telemetry.tracing import TRACER
+
+    newest = [tb for tb in TRACER.traces() if tb.spans and tb.spans[0][0] == "refresh.train"]
+    return {span[0] for span in newest[-1].spans}
+
+
 def _synthetic_dataset(n_nodes=16, n_edges=24, n_slots=5, seed=0, anomaly=0.2):
     import jax.numpy as jnp
 
@@ -564,15 +572,28 @@ class TestFusedTraining:
         # padded rows never receive embedding gradient: table stays [N, D]
         assert np.asarray(r_f.params.embedding).shape == (ds.num_nodes, 8)
 
-    def test_env_var_disables_fusion(self, monkeypatch):
+    @pytest.mark.parametrize("value", ("0", "1"))
+    def test_the_parameter_decides_and_no_environment_value(self, monkeypatch, value):
+        """The variable that used to choose is gone: `fused=False` is the legacy
+        loop and no `fused` argument the epoch block, whatever the environment
+        holds. (Its name in parts: test_chip_bringup.py's tree check greps.)"""
         from kmamiz_tpu.models import stacked
 
+        monkeypatch.setenv("KMAMIZ_SAGE" + "_FUSED", value)
         ds = _synthetic_dataset(n_slots=2)
-        monkeypatch.setenv("KMAMIZ_SAGE_FUSED", "0")
-        r = trainer.train(ds, epochs=1, hidden=8)
-        # legacy path does not build the device stack
+        r = trainer.train(ds, epochs=1, hidden=8, fused=False)
+        # the legacy path builds no device stack and runs no epoch block
         assert not hasattr(ds, "_stacked_cache")
         assert np.isfinite(r.losses[-1])
+        assert _last_refresh_spans() & {"refresh.legacy_epoch", "refresh.epoch_block"} == {
+            "refresh.legacy_epoch"
+        }
+        r = trainer.train(ds, epochs=1, hidden=8)
+        assert ds._stacked_cache is stacked.stack_dataset(ds)
+        assert np.isfinite(r.losses[-1])
+        assert _last_refresh_spans() & {"refresh.legacy_epoch", "refresh.epoch_block"} == {
+            "refresh.epoch_block"
+        }
 
     def test_dp_batched_runner_trains(self):
         ds = _synthetic_dataset(n_slots=6)

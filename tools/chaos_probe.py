@@ -26,7 +26,7 @@ seeded FaultPlan so a failure reproduces exactly:
 stdout carries ONE JSON line: {"seed": ..., "ok": ..., per-pillar
 results, "chaos_recovery_ms": ..., "degraded_serve_ms": ...}. The
 human-readable pillar table goes to stderr. Exit 0 iff every pillar
-holds. bench.py invokes this as a subprocess for the chaos extras;
+holds. Run it as a subprocess (the round-5 bench did, for its chaos extras);
 `--child-kill` is the internal crash-child mode (never returns).
 """
 from __future__ import annotations
@@ -454,7 +454,7 @@ def main() -> int:
 
     pillars = ("quarantine", "breaker", "degraded_serve", "wal_recovery")
     results["ok"] = all(results[p]["ok"] for p in pillars)
-    # the two bench.py extras, hoisted to the top level
+    # the two headline extras, hoisted to the top level
     results["chaos_recovery_ms"] = results["wal_recovery"]["chaos_recovery_ms"]
     results["degraded_serve_ms"] = results["degraded_serve"][
         "degraded_serve_ms"

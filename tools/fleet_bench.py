@@ -17,8 +17,7 @@ multi-core host the ROADMAP scale-out target is efficiency >= 0.75
 only timeslice, so tools/slo_report.py's absolute floor stays disarmed
 (the artifact carries ``fleet_host_cores`` for exactly that guard).
 
-Run by bench.py's fleet section (KMAMIZ_BENCH_FLEET=0 skips there);
-standalone: ``python tools/fleet_bench.py [--frames N] [--spawn-s S]``.
+Run standalone: ``python tools/fleet_bench.py [--frames N] [--spawn-s S]``.
 """
 from __future__ import annotations
 

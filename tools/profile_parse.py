@@ -3,8 +3,8 @@
 Times kmamiz_tpu.native.parse_spans on the bench's 1.05M-span synthetic
 window across thread counts, printing per-rep walls plus the native phase
 breakdown, min and median. No jax import needed (kmamiz_tpu.synth is
-jax-free; make_raw_window is the generator bench.py's headline uses, so
-the profiled workload IS the headline workload).
+jax-free; make_raw_window is the generator chip_smoke.py ingests, so
+the profiled workload IS the smoke's workload).
 """
 from __future__ import annotations
 

@@ -9,7 +9,7 @@ exactness, recovery-time-to-fresh, zero steady-state recompiles, and a
 bit-exact reference-graph replay (docs/SCENARIOS.md).
 
 stdout carries ONE JSON line with the per-scenario scorecards plus the
-bench.py headline keys hoisted to the top level:
+headline keys hoisted to the top level:
 
     scenario_matrix_pass        every scenario passed all its gates
     scenario_worst_p99_tick_ms  max p99 fresh-tick latency across cards
@@ -17,8 +17,7 @@ bench.py headline keys hoisted to the top level:
     scenario_lost_spans         total lost spans across cards (must be 0)
 
 The human-readable scorecard table goes to stderr. Exit 0 iff the
-matrix passes (always 0 with --list). bench.py invokes this as a
-subprocess for the scenario extras; tools/slo_report.py gates the
+matrix passes (always 0 with --list). tools/slo_report.py gates the
 headline keys across rounds.
 
     python tools/scenario_soak.py --seed 0              # full matrix

@@ -420,7 +420,7 @@ def main(argv=None) -> int:
             print(
                 f"{os.path.basename(cand_path)}: no parseable bench result "
                 '("parsed": null and no JSON line in tail) — re-record the '
-                "round with tools/bench_driver.py instead of gating past it",
+                "round instead of gating past it",
                 file=sys.stderr,
             )
             return 2

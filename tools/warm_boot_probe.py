@@ -16,9 +16,9 @@ stdout carries ONE JSON line: {"platform": ..., "prewarm_s": ...,
 "first_tick_ms": ..., "second_tick_ms": ..., "first_tick_new_compiles":
 ..., "second_tick_new_compiles": ..., "compile_cache": {dir, hits,
 misses}, "programs": {...}}. The per-program compile-count / compile-ms
-table goes to stderr. bench.py runs this as a sibling process for the
-warm-boot extras, pointing both arms at a fixed subdirectory that it
-empties before the cold arm; it is also a deployable smoke check
+table goes to stderr. Run it twice as sibling processes, both arms
+pointed at a fixed subdirectory emptied before the cold arm (the round-5
+bench did); it is also a deployable smoke check
 (JAX_COMPILATION_CACHE_DIR=/var/cache/kmamiz python
 tools/warm_boot_probe.py).
 """

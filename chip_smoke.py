@@ -1285,6 +1285,28 @@ def child_kernels(sizes: dict) -> None:
         "mosaic" if on_tpu else "interpret"
     )
 
+    # -- the attention at a width whose spare lanes straddle the first 128:
+    # 124 floats and the neighbour's eight scalars make 256-lane rows, so
+    # `_max`'s ring holds 256-lane blocks and the scalars are rows 124..131
+    # of the transposed block (PR 31). All five walks against the XLA items
+    wide = jnp.asarray(rng.normal(size=(nodes, 124)).astype(np.float32))
+    ct_wide = jnp.asarray(rng.normal(size=(nodes, 124)).astype(np.float32))
+
+    def attention_wide(impl):
+        att, pull = jax.vjp(
+            lambda x, s, t: sparse.planned_attention(plan, x, s, t, 0.2, impl),
+            wide, s_t[0], s_t[1],
+        )
+        return [np.asarray(a) for a in (att, *pull(ct_wide))]
+
+    for name, got, want in zip(
+        ("attention.w124", "attention.w124.d_hw", "attention.w124.d_s", "attention.w124.d_t"),
+        attention_wide(kernel_impl), attention_wide("xla"),
+    ):
+        scale = max(float(np.abs(want).max()), 1.0)
+        np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
+    out["planned_attention_w124"] = out["planned_attention"]
+
     # -- the width the epoch block's slot group sums at (PR 29): seven slots'
     # 18 features side by side. Mosaic against the XLA items, and every
     # slot's columns against the kernel's sum of that slot alone, bit for bit

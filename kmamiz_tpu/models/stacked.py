@@ -153,6 +153,7 @@ class StackedDataset:
     plan: Optional[sparse.EdgePlan] = None  # of (src, dst, edge_mask)
     plan_entries: int = 0  # real (owner, neighbour) entries: 2 x real edges
     plan_items: int = 0  # real (node tile, edge block) products of one sum
+    plan_blocks: int = 0  # edge blocks those items visit: message blocks a walk fetches
     plan_runs: int = 0  # (owner, direction) runs that hold an entry: the softmaxes
 
     def layout(self) -> dict:
@@ -204,6 +205,7 @@ def stack_dataset(dataset) -> StackedDataset:
                 hit=1,
                 plan_entries=cached.plan_entries,
                 plan_items=cached.plan_items,
+                plan_blocks=cached.plan_blocks,
                 plan_runs=cached.plan_runs,
             )
             return cached
@@ -218,17 +220,17 @@ def stack_dataset(dataset) -> StackedDataset:
 
 #: edge plans by the identity of the edge arrays they were made from (and
 #: the node bucket): [(src, dst, edge_mask, bucket_nodes, plan, (entries,
-#: items, runs))], newest last. The arrays are held so that their ids stay
+#: items, blocks, runs))], newest last. The arrays are held so that their ids stay
 #: theirs; like the stack's memo it trusts that nobody writes into them.
 _PLAN_MEMO: list = []
 _PLAN_MEMO_SIZE = 4
 
 
 def _edge_plan(dataset, src, dst, e_mask, nb: int):
-    """(device EdgePlan, (entries, items, runs)) of a dataset's padded edge
-    list. The span counts what the directed head walks: the entries of each
-    direction (an edge out of its owner, an edge into it) and the (owner,
-    direction) runs, one softmax each."""
+    """(device EdgePlan, (entries, items, blocks, runs)) of a dataset's padded
+    edge list. The span counts what the directed head walks: the entries of
+    each direction (an edge out of its owner, an edge into it), the (owner,
+    direction) runs, one softmax each, and the edge blocks the items visit."""
     key = (dataset.src, dataset.dst, dataset.edge_mask)
     for *held, held_nb, plan, counts in _PLAN_MEMO:
         if held_nb == nb and all(a is b for a, b in zip(held, key)):
@@ -240,15 +242,17 @@ def _edge_plan(dataset, src, dst, e_mask, nb: int):
         run_key = host_plan.owner[0, :entries] * 2 + host_plan.direction[0, :entries]
         runs = int(np.count_nonzero(np.diff(run_key))) + 1 if entries else 0
         entries_in = int(host_plan.direction[0, :entries].sum())
+        blocks = sparse.plan_blocks(host_plan, items)
         plan = jax.tree_util.tree_map(jnp.asarray, host_plan)
         TRACER.note(
             entries=entries,
             items=items,
+            blocks=blocks,
             entries_out=entries - entries_in,
             entries_in=entries_in,
             runs=runs,
         )
-    counts = (entries, items, runs)
+    counts = (entries, items, blocks, runs)
     _PLAN_MEMO.append((*key, nb, plan, counts))
     del _PLAN_MEMO[:-_PLAN_MEMO_SIZE]
     return plan, counts
@@ -322,7 +326,7 @@ def _build_stack(dataset) -> StackedDataset:
             a.nbytes for a in (feats, t_lat, t_ano, n_mask, src, dst, e_mask)
         )
         TRACER.note(bytes=nbytes)
-    plan, (plan_entries, plan_items, plan_runs) = _edge_plan(
+    plan, (plan_entries, plan_items, plan_blocks, plan_runs) = _edge_plan(
         dataset, src, dst, e_mask, nb
     )
     with phase_span("refresh.stack.device_put"):
@@ -342,6 +346,7 @@ def _build_stack(dataset) -> StackedDataset:
             plan=plan,
             plan_entries=plan_entries,
             plan_items=plan_items,
+            plan_blocks=plan_blocks,
             plan_runs=plan_runs,
         )
         TRACER.note(bytes=nbytes)
@@ -350,6 +355,7 @@ def _build_stack(dataset) -> StackedDataset:
         bytes=nbytes,
         plan_entries=plan_entries,
         plan_items=plan_items,
+        plan_blocks=plan_blocks,
         plan_runs=plan_runs,
     )
     return stacked

@@ -401,7 +401,33 @@ def planned_neighbor_sum(plan: EdgePlan, h: jnp.ndarray, impl: Optional[str] = N
 # What belongs to the NEIGHBOUR travels with its gathered row: the message
 # array is [entries, 128 lanes] whatever the width (PERF.md), so the lanes
 # past the width hold the neighbour's scalars, and the kernel turns those
-# columns into rows on the MXU. So there is no 1-D gather, and no scatter.
+# columns into rows by transposing the block. So there is no 1-D gather, and
+# no scatter.
+#
+# What a walk costs (PERF.md, PR 31) is its MXU passes and its grid steps,
+# not its vector work, which hides behind them: a pass pays for the
+# [128, 128] tiles it loads as the stationary operand however few rows stream
+# against them, and a step pays about 0.1 ms a walk for every block it
+# fetches or writes, whatever the block's size. So:
+#
+# - the per-entry scalars are ONE operand, the entries' state [8, entries]:
+#   the plan's owner and direction (as bits) and a row for each of z, p,
+#   alpha, d alpha. A walk reads a block of it and hands it on with its own
+#   row added; `_backward` reads five rows in one fetch. The residual of the
+#   forward pass is the state after `_sum` (z and alpha) and the float32
+#   messages, once;
+# - `_expand` sends the three pieces of a row table through the one-hot in
+#   one pass, stacked, and a weight tile is selected from the split of its
+#   row (`_weights3`); the neighbour's scalars come out of the float32 block
+#   by a transposition, which the MXU took twelve tile loads for;
+# - every product whose terms are summed in the MXU (`_reduce`, `_dot6`)
+#   keeps its passes and their order: stacking those changes the bits;
+# - a float32 block is split where it is used, once an item. Splitting it
+#   once a block or a tile into VMEM, or once a layer into HBM, measured
+#   SLOWER on the v5e (the split hides behind the MXU; a conditional region
+#   and 805 MB of pieces do not);
+# - `_max`, whose step is shorter than the latency of its message block's
+#   fetch, keeps a ring of three blocks in VMEM and fetches two ahead.
 #
 # The transposed sums (d hw[j] and d s[j, d] run over the entries whose
 # NEIGHBOUR is j) need no permutation either: every entry has a mirror, the
@@ -414,6 +440,10 @@ def planned_neighbor_sum(plan: EdgePlan, h: jnp.ndarray, impl: Optional[str] = N
 
 ATT_ROWS = 8  # sublanes of a row table: at most eight scalars per entry or node
 ATT_SPARE = 8  # lanes kept past the width for the neighbour's scalars
+#: rows of the entries' state; a walk adds one to zeros, and the plan's owner
+#: and direction ride below them as bits
+ROW_Z, ROW_P, ROW_ALPHA, ROW_DALPHA, ROW_OWNER, ROW_DIR = 0, 1, 2, 3, 6, 7
+MSG_RING = 3  # message blocks `_max` holds in VMEM: the item's, and two on their way
 _NEG = -1e30  # below every score; an empty run's maximum
 _NN = (((1,), (0,)), ((), ()))  # [m, k] @ [k, n]
 _NT = (((1,), (1,)), ((), ()))  # [m, k] @ [n, k]^T
@@ -434,8 +464,15 @@ def _dot6(a3, b3, dims):
 
 def _expand(node_rows, hot):
     """[R, tile] node rows -> [R, block] entry rows by owner (0 for an entry
-    no row of the tile owns)."""
-    return sum(_mxu(piece, hot, _NN) for piece in reversed(_split3(node_rows)))
+    no row of the tile owns). ONE pass of the one-hot through the MXU, the
+    three pieces of the rows below one another, where a pass a piece loads the
+    same [tile, block] one-hot three times: an entry has one owner, so each
+    result is one piece itself and the sum is the three separate passes' to
+    the bit."""
+    r = node_rows.shape[0]
+    stacked = jnp.concatenate([p.astype(jnp.float32) for p in _split3(node_rows)], axis=0)
+    x = _mxu(stacked.astype(jnp.bfloat16), hot, _NN)  # [3R, block]
+    return sum((x[2 * r : 3 * r], x[r : 2 * r], x[0:r]))
 
 
 def _reduce(entry_rows, hot):
@@ -443,14 +480,10 @@ def _reduce(entry_rows, hot):
     return sum(_mxu(piece, hot, _NT) for piece in reversed(_split3(entry_rows)))
 
 
-def _columns_as_rows(msg3, first: int):
-    """Columns first .. first + 7 of a split [block, lanes] message block as
-    [8, block] rows."""
-    lanes = msg3[0].shape[1]
-    want = jax.lax.broadcasted_iota(jnp.int32, (ATT_ROWS, lanes), 0) + first
-    pick = (jax.lax.broadcasted_iota(jnp.int32, (ATT_ROWS, lanes), 1) == want)
-    pick = pick.astype(jnp.bfloat16)
-    return sum(_mxu(pick, piece, _NT) for piece in reversed(msg3))
+def _neighbour_rows(msg_ref, first: int):
+    """Columns first .. first + 7 of a float32 [block, lanes] message block as
+    [8, block] rows: the neighbour's scalars, to the bit, by a transposition."""
+    return msg_ref[...].T[first : first + ATT_ROWS, :]
 
 
 def _rows(*vectors):
@@ -477,48 +510,120 @@ def _rows_by_direction(d, x):
     return _rows(jnp.where(d == 0, x, 0.0), jnp.where(d == 1, x, 0.0))
 
 
-def _item(tile_ref, block_ref, flag_ref, owner_ref, by_block=(), by_tile=(), fill=0.0):
-    """What every walk starts from. Zeroes the per-entry outputs `by_block` on
-    the first visit of their edge block and fills the per-node outputs
-    `by_tile` on the first item of their tile (both stay in VMEM across the
-    consecutive items that share them), and returns (whether the item is
-    real and not padding, its one-hot [tile, block] as a mask)."""
+def _weights3(one_hot, row):
+    """`_split3(where(one_hot, row, 0))`, the three pieces of a [tile, block]
+    weight tile, from the split of the [1, block] row: the split is
+    elementwise and `_split3(0)` is 0, so a piece of the row placed under the
+    mask is that piece of the tile, in six passes over the tile for eight."""
+    return tuple(
+        jnp.where(one_hot, piece.astype(jnp.float32), 0.0).astype(jnp.bfloat16)
+        for piece in _split3(row)
+    )
+
+
+def plan_blocks(plan: EdgePlan, items: int) -> int:
+    """Edge blocks the `items` real items of a host plan visit: the message
+    blocks a walk fetches, where its grid steps are the items. Their blocks
+    start at 0 and rise by at most one (a tile starts in the block the tile
+    before it ended in, or in the next), so the last one says how many."""
+    return int(np.asarray(plan.item_block)[items - 1]) + 1 if items else 0
+
+
+def _new_block(block_ref):
+    """Whether this item is the first of its edge block."""
+    i = pl.program_id(0)
+    return (i == 0) | (block_ref[i] != block_ref[jnp.maximum(i - 1, 0)])
+
+
+def _message_block(block_ref, msg_hbm, ring, sems):
+    """The item's [block, lanes] message block, from a ring in VMEM that the
+    walk fills itself, `MSG_RING - 1` blocks ahead of the one in use: the
+    pipeline's one block ahead leaves a short step waiting on the fetch. It
+    leans on the plan: the real items' blocks start at 0 and rise by at most
+    one, and the no-ops after them repeat the last."""
+    i = pl.program_id(0)
+    b = block_ref[i]
+    n_blocks = block_ref[pl.num_programs(0) - 1] + 1  # the no-ops repeat the last real block
+    be = ring.shape[1]
+
+    def copy(k):
+        slot = k % MSG_RING
+        return pltpu.make_async_copy(
+            msg_hbm.at[pl.ds(pl.multiple_of(k * be, be), be), :], ring.at[slot], sems.at[slot]
+        )
+
+    @pl.when(i == 0)
+    def _first():
+        for k in range(MSG_RING - 1):
+            @pl.when(k < n_blocks)
+            def _start():
+                copy(k).start()
+
+    @pl.when(_new_block(block_ref))
+    def _fetch():
+        @pl.when(b + MSG_RING - 1 < n_blocks)
+        def _ahead():
+            copy(b + MSG_RING - 1).start()
+
+        copy(b).wait()
+
+    return ring.at[b % MSG_RING]
+
+
+def _row(state_ref, row: int):
+    return state_ref[row : row + 1, :]
+
+
+def _add_row(next_ref, row: int, x):
+    """A walk's own row of the state: the sum over the block's items, each of
+    which holds the entries of its tile and zeros elsewhere."""
+    next_ref[row : row + 1, :] += x
+
+
+def _item(tile_ref, block_ref, flag_ref, state_ref, next_ref=None, by_tile=(), fill=0.0):
+    """What every walk starts from. The entries' state is ONE [ATT_ROWS, L]
+    operand that a walk reads a block of and hands on with its own row added
+    (`next_ref`: copied on the first visit of the edge block, so that the row
+    the walk accumulates starts at zero and the others travel on); the plan's
+    owner and direction ride in it as bits. Fills the per-node outputs
+    `by_tile` on the first item of their tile. Returns (whether the item is
+    real and not padding, its one-hot [tile, block] as a mask, the entries'
+    direction [1, block])."""
     i = pl.program_id(0)
     flag = flag_ref[i]
-    tn, be = PLAN_NODE_TILE, owner_ref.shape[1]
-    row_in_tile = owner_ref[...] - tile_ref[i] * tn  # [1, block]
+    tn, be = PLAN_NODE_TILE, state_ref.shape[1]
+    as_int = partial(jax.lax.bitcast_convert_type, new_dtype=jnp.int32)
+    row_in_tile = as_int(_row(state_ref, ROW_OWNER)) - tile_ref[i] * tn  # [1, block]
     one_hot = row_in_tile == jax.lax.broadcasted_iota(jnp.int32, (tn, be), 0)
 
-    @pl.when((i == 0) | (block_ref[i] != block_ref[jnp.maximum(i - 1, 0)]))
-    def _new_block():
-        for ref in by_block:
-            ref[...] = jnp.zeros_like(ref)
+    if next_ref is not None:
+        @pl.when(_new_block(block_ref))
+        def _hand_on():
+            next_ref[...] = state_ref[...]
 
     @pl.when(flag == 1)
     def _new_tile():
         for ref in by_tile:
             ref[...] = jnp.full_like(ref, fill)
 
-    return flag >= 0, one_hot
+    return flag >= 0, one_hot, as_int(_row(state_ref, ROW_DIR))
 
 
 def _attention_max_kernel(
-    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, msg_ref, trow_ref,
-    z_ref, top_ref, *, width: int, leak: float,
+    tile_ref, block_ref, flag_ref, state_ref, msg_ref, trow_ref,
+    next_ref, top_ref, ring, sems, *, width: int, leak: float,
 ):
-    real, one_hot = _item(
-        tile_ref, block_ref, flag_ref, owner_ref, (z_ref,), (top_ref,), _NEG
-    )
+    msg_ref = _message_block(block_ref, msg_ref, ring, sems)
+    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (top_ref,), _NEG)
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
-        nbr = _columns_as_rows(_split3(msg_ref[...]), width)  # s of the neighbour
+        nbr = _neighbour_rows(msg_ref, width)  # s of the neighbour
         own = _expand(trow_ref[...], hot)  # t of the owner, and a row of ones
-        d = dir_ref[...]
         inside = own[2:3] > 0.5
         z = _by_direction(d, nbr[0:1] + own[0:1], nbr[1:2] + own[1:2])
-        z_ref[...] += _rows(jnp.where(inside, z, 0.0))
+        _add_row(next_ref, ROW_Z, jnp.where(inside, z, 0.0))
         score = _leaky(z, leak)
         tops = []
         for k in (0, 1):
@@ -529,78 +634,74 @@ def _attention_max_kernel(
 
 
 def _attention_softmax_kernel(
-    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, z_ref, mrow_ref,
-    p_ref, total_ref, *, leak: float,
+    tile_ref, block_ref, flag_ref, state_ref, mrow_ref,
+    next_ref, total_ref, *, leak: float,
 ):
-    real, one_hot = _item(tile_ref, block_ref, flag_ref, owner_ref, (p_ref,), (total_ref,))
+    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (total_ref,))
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
         own = _expand(mrow_ref[...], hot)  # the run's maximum, and a row of ones
-        d = dir_ref[...]
         inside = own[2:3] > 0.5
         shift = _by_direction(d, own[0:1], own[1:2])
-        delta = jnp.clip(_leaky(z_ref[0:1, :], leak) - shift, -60.0, 0.0)
+        delta = jnp.clip(_leaky(_row(state_ref, ROW_Z), leak) - shift, -60.0, 0.0)
         p = jnp.where(inside, jnp.exp(delta), 0.0)
-        p_ref[...] += _rows(p)
+        _add_row(next_ref, ROW_P, p)
         total_ref[...] += _reduce(_rows_by_direction(d, p), hot)
 
 
 def _attention_sum_kernel(
-    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, p_ref, lrow_ref, msg_ref,
-    alpha_ref, out_ref,
+    tile_ref, block_ref, flag_ref, state_ref, lrow_ref, msg_ref,
+    next_ref, out_ref,
 ):
-    real, one_hot = _item(tile_ref, block_ref, flag_ref, owner_ref, (alpha_ref,), (out_ref,))
+    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (out_ref,))
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
         own = _expand(lrow_ref[...], hot)  # the run's sum, and a row of ones
-        d = dir_ref[...]
         inside = own[2:3] > 0.5
         total = jnp.maximum(_by_direction(d, own[0:1], own[1:2]), 1e-30)
-        alpha = jnp.where(inside, p_ref[0:1, :] / total, 0.0)
-        alpha_ref[...] += _rows(alpha)
-        weights = jnp.where(one_hot, alpha, 0.0)  # [tile, block]
-        out_ref[...] += _dot6(_split3(weights), _split3(msg_ref[...]), _NN)
+        alpha = jnp.where(inside, _row(state_ref, ROW_P) / total, 0.0)
+        _add_row(next_ref, ROW_ALPHA, alpha)
+        out_ref[...] += _dot6(_weights3(one_hot, alpha), _split3(msg_ref[...]), _NN)
 
 
 def _attention_edge_dot_kernel(
-    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, alpha_ref, msg_ref, g_ref,
-    dalpha_ref, c_ref,
+    tile_ref, block_ref, flag_ref, state_ref, msg_ref, g_ref,
+    next_ref, c_ref,
 ):
-    real, one_hot = _item(tile_ref, block_ref, flag_ref, owner_ref, (dalpha_ref,), (c_ref,))
+    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (c_ref,))
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
         dots = _dot6(_split3(g_ref[...]), _split3(msg_ref[...]), _NT)  # [tile, block]
         dalpha = jnp.sum(jnp.where(one_hot, dots, 0.0), axis=0, keepdims=True)
-        dalpha_ref[...] += _rows(dalpha)
-        d = dir_ref[...]
-        y = alpha_ref[0:1, :] * dalpha  # 0 for an entry of another tile
+        _add_row(next_ref, ROW_DALPHA, dalpha)
+        y = _row(state_ref, ROW_ALPHA) * dalpha  # 0 for an entry of another tile
         c_ref[...] += _reduce(_rows_by_direction(d, y), hot)
 
 
 def _attention_backward_kernel(
-    tile_ref, block_ref, flag_ref, owner_ref, dir_ref, z_ref, alpha_ref, dalpha_ref,
+    tile_ref, block_ref, flag_ref, state_ref,
     msg_ref, hw_ref, nrow_ref, dhw_ref, dst_ref, *, width: int, leak: float,
 ):
-    real, one_hot = _item(tile_ref, block_ref, flag_ref, owner_ref, (), (dhw_ref, dst_ref))
+    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, None, (dhw_ref, dst_ref))
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
         msg3 = _split3(msg_ref[...])  # g of the neighbour, then its t, max, sum, c
-        nbr = _columns_as_rows(msg3, width)
+        nbr = _neighbour_rows(msg_ref, width)
         own = _expand(nrow_ref[...], hot)  # s and c of the owner, and a row of ones
-        d = dir_ref[...]
         inside = own[4:5] > 0.5
         # this entry's own softmax: d z = alpha (d alpha - c) leaky'(z)
-        z = z_ref[0:1, :]
+        z = _row(state_ref, ROW_Z)
         c = _by_direction(d, own[2:3], own[3:4])
-        dz = alpha_ref[0:1, :] * (dalpha_ref[0:1, :] - c) * jnp.where(z >= 0, 1.0, leak)
+        alpha, dalpha = _row(state_ref, ROW_ALPHA), _row(state_ref, ROW_DALPHA)
+        dz = alpha * (dalpha - c) * jnp.where(z >= 0, 1.0, leak)
         dz = jnp.where(inside, dz, 0.0)
         # its mirror's, in the other direction: s of the owner, the rest the
         # neighbour's (rows t0 t1 max0 max1 sum0 sum1 c0 c1)
@@ -621,15 +722,17 @@ def _attention_backward_kernel(
             ),
             hot,
         )
-        weights = jnp.where(one_hot, alpha_m, 0.0)
-        dhw_ref[...] += _dot6(_split3(weights), msg3, _NN)
+        dhw_ref[...] += _dot6(_weights3(one_hot, alpha_m), msg3, _NN)
 
 
 def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bool):
     """One walk of the plan's items. `inputs` and `outputs` are (kind, array
     or lane width) pairs; the kind says how a block follows the item: "entry"
     [R, L] by edge block, "message" [L, lanes] by edge block, "node_rows"
-    [R, nodes] by tile, "node" [nodes, lanes] by tile."""
+    [R, nodes] by tile, "node" [nodes, lanes] by tile. A "message_ring" input
+    stays in HBM whole: the kernel fetches its blocks itself
+    (`_message_block`), into a ring and with the semaphores it is handed
+    after its outputs."""
     tn, be = PLAN_NODE_TILE, PLAN_EDGE_BLOCK
     entries, nodes = plan.owner.shape[1], _node_tiles(plan) * tn
 
@@ -638,6 +741,8 @@ def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bo
             return pl.BlockSpec((lanes, be), lambda i, tile, block, flag: (0, block[i]))
         if kind == "message":
             return pl.BlockSpec((be, lanes), lambda i, tile, block, flag: (block[i], 0))
+        if kind == "message_ring":
+            return pl.BlockSpec(memory_space=pl.ANY)
         if kind == "node_rows":
             return pl.BlockSpec((lanes, tn), lambda i, tile, block, flag: (0, tile[i]))
         return pl.BlockSpec((tn, lanes), lambda i, tile, block, flag: (tile[i], 0))
@@ -659,6 +764,15 @@ def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bo
             grid=(plan.item_tile.shape[0],),
             in_specs=[spec(kind, lanes_of(kind, a)) for kind, a in inputs],
             out_specs=[spec(kind, lanes) for kind, lanes in outputs],
+            scratch_shapes=[
+                scratch
+                for kind, a in inputs
+                if kind == "message_ring"
+                for scratch in (
+                    pltpu.VMEM((MSG_RING, be, a.shape[1]), jnp.float32),
+                    pltpu.SemaphoreType.DMA((MSG_RING,)),
+                )
+            ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(shape(kind, lanes), jnp.float32)
@@ -698,6 +812,15 @@ def _node_rows(nodes: int, *columns):
     return jnp.stack(rows)
 
 
+def _entry_state(plan: EdgePlan):
+    """The state of the entries before the first walk, [ATT_ROWS, L]: the
+    plan's owner and direction as bits in its last two rows, zeros above."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.concatenate([plan.owner, plan.direction], axis=0), jnp.float32
+    )
+    return jnp.pad(bits, ((ATT_ROWS - 2, 0), (0, 0)))
+
+
 def _attention_shapes(plan: EdgePlan, hw):
     nodes = _node_tiles(plan) * PLAN_NODE_TILE
     return nodes, _pad_to(hw.shape[1] + ATT_SPARE, 128)
@@ -706,46 +829,45 @@ def _attention_shapes(plan: EdgePlan, hw):
 def _attention_pallas_fwd(plan: EdgePlan, hw, s, t, leak: float, interpret: bool):
     n, width = hw.shape
     nodes, lanes = _attention_shapes(plan, hw)
-    entry = [("entry", plan.owner), ("entry", plan.direction)]
     msg = _gather_rows(_node_table(nodes, lanes, hw, s), plan.neighbour)  # [L, lanes]
-    z, top = _walk_call(
+    state, top = _walk_call(
         plan, partial(_attention_max_kernel, width=width, leak=leak),
         "planned_attention_max",
-        entry + [("message", msg), ("node_rows", _node_rows(nodes, t[:, 0], t[:, 1]))],
+        [
+            ("entry", _entry_state(plan)), ("message_ring", msg),
+            ("node_rows", _node_rows(nodes, t[:, 0], t[:, 1])),
+        ],
         [("entry", ATT_ROWS), ("node", 2)], interpret,
     )
     top = jnp.where(top > _NEG / 2, top, 0.0)[:n]  # an empty run shifts by 0
-    p, total = _walk_call(
+    state, total = _walk_call(
         plan, partial(_attention_softmax_kernel, leak=leak),
         "planned_attention_softmax",
-        entry + [("entry", z), ("node_rows", _node_rows(nodes, top[:, 0], top[:, 1]))],
+        [("entry", state), ("node_rows", _node_rows(nodes, top[:, 0], top[:, 1]))],
         [("entry", ATT_ROWS), ("node_rows", ATT_ROWS)], interpret,
     )
     total = total[:2, :n].T  # [n, 2]
-    alpha, out = _walk_call(
+    state, out = _walk_call(
         plan, _attention_sum_kernel, "planned_attention_sum",
-        entry + [
-            ("entry", p),
+        [
+            ("entry", state),
             ("node_rows", _node_rows(nodes, total[:, 0], total[:, 1])),
             ("message", msg),
         ],
         [("entry", ATT_ROWS), ("node", lanes)], interpret,
     )
-    saved = (hw, s, t, msg, z, alpha, top, total)
+    saved = (hw, s, t, msg, state, top, total)
     return out[:n, :width].astype(hw.dtype), saved
 
 
 def _attention_pallas_bwd(plan: EdgePlan, leak: float, interpret: bool, saved, g):
-    hw, s, t, msg, z, alpha, top, total = saved
+    hw, s, t, msg, state, top, total = saved
     n, width = hw.shape
     nodes, lanes = _attention_shapes(plan, hw)
-    entry = [("entry", plan.owner), ("entry", plan.direction)]
     g = g.astype(jnp.float32)
-    dalpha, c = _walk_call(
+    state, c = _walk_call(
         plan, _attention_edge_dot_kernel, "planned_attention_edge_dot",
-        entry + [
-            ("entry", alpha), ("message", msg), ("node", _node_table(nodes, lanes, g)),
-        ],
+        [("entry", state), ("message", msg), ("node", _node_table(nodes, lanes, g))],
         [("entry", ATT_ROWS), ("node_rows", ATT_ROWS)], interpret,
     )
     c = c[:2, :n].T  # [n, 2]: the sum of alpha * d alpha over each run
@@ -753,8 +875,8 @@ def _attention_pallas_bwd(plan: EdgePlan, leak: float, interpret: bool, saved, g
     dhw, dst = _walk_call(
         plan, partial(_attention_backward_kernel, width=width, leak=leak),
         "planned_attention_backward",
-        entry + [
-            ("entry", z), ("entry", alpha), ("entry", dalpha), ("message", g_msg),
+        [
+            ("entry", state), ("message", g_msg),
             ("node", _node_table(nodes, lanes, hw)),
             ("node_rows", _node_rows(nodes, s[:, 0], s[:, 1], c[:, 0], c[:, 1])),
         ],

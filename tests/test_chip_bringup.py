@@ -400,6 +400,7 @@ class TestChipSmoke:
         # nothing is left of the fused pair
         assert d["segment_stats_matmul"] == d["planned_neighbor_sum"] == "interpret"
         assert d["planned_attention"] == d["planned_neighbor_sum_w126"] == "interpret"
+        assert d["planned_attention_w124"] == "interpret"  # the spare lanes past the first 128
         assert set(d["routes"]) == {"backend", "planned", "attention"}
         assert d["routes"]["planned"] > d["routes"]["attention"] > 0
         assert not [k for k in d if "fused" in k]

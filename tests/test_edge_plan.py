@@ -677,12 +677,16 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_chip, width):
     assert "scatter" not in text
 
 
-def test_attention_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip):
+@pytest.mark.parametrize("width", (64, 124))
+def test_attention_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, width):
     """The five walks of `planned_attention`, forward and backward, at the
-    width of `mv100k-gat`: what Mosaic refuses here costs no chip time."""
+    width of `mv100k-gat`, and at one whose spare lanes straddle the first
+    128 (the neighbour's scalars are then rows 124..131 of a transposed
+    [256, block] tile, and `_max`'s ring holds 256-lane blocks): what Mosaic
+    refuses here costs no chip time."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    nb, eb, width = 131072, 524288, 64
+    nb, eb = 131072, 524288
     entries, _tiles, items = sparse.plan_shapes(nb, eb)
 
     def arg(shape, dtype=jnp.float32):
@@ -709,4 +713,5 @@ def test_attention_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip):
     assert "scatter" not in text
     # the only gathers left are the two row gathers, at the full lane width
     gathers = [line for line in text.splitlines() if " gather(" in line]
-    assert len(gathers) == 2 and all(f"f32[{entries},128]" in line for line in gathers)
+    lanes = 128 if width == 64 else 256
+    assert len(gathers) == 2 and all(f"f32[{entries},{lanes}]" in line for line in gathers)

@@ -5,6 +5,7 @@ formulation of `gat._attend`, against the plain reference of the benchmark,
 and through `gat.forward`, the stack and the epoch block."""
 from __future__ import annotations
 
+import hashlib
 from functools import partial
 
 import jax
@@ -90,6 +91,119 @@ class TestTheDirectedPlan:
             plan, _, _ = sparse.build_edge_plan(src, dst, mask, nb)
             assert (np.diff(plan.item_block) >= 0).all(), name
 
+    @pytest.mark.parametrize("name", CASES + ("hub", "shared_block", "three_by_three"))
+    def test_the_real_items_visit_blocks_0_to_plan_blocks_one_step_at_a_time(self, name):
+        """What `_max`'s ring of message blocks leans on when it fetches two
+        blocks ahead, and what `plan_blocks` counts: the real items' blocks
+        start at 0 and rise by at most one, the no-ops repeat the last."""
+        src, dst, mask, nb = _GRAPHS[name]() if name in _GRAPHS else _case(name)
+        plan, _, items = sparse.build_edge_plan(src, dst, mask, nb)
+        real = plan.item_block[:items]
+        assert real[0] == 0 and set(np.diff(real).tolist()) <= {0, 1}
+        assert (plan.item_block[items:] == real[-1]).all()
+        assert sparse.plan_blocks(plan, items) == len(np.unique(real)) == real[-1] + 1
+
+
+def _hub_graph():
+    """Node 3 is called over 4 x BE + 37 edges and calls one node itself: its
+    run of in-edges crosses five edge blocks."""
+    n, e = 256, 4 * BE + 37
+    rng = np.random.default_rng(6)
+    src = np.concatenate([rng.integers(4, n, e), [3]]).astype(np.int32)
+    dst = np.concatenate([np.full(e, 3), [200]]).astype(np.int32)
+    return src, dst, np.ones(e + 1, bool), n
+
+
+def _shared_block_graph():
+    """Eight tiles, 400 entries: every tile's item meets the one edge block,
+    and the fourth tile (rows 384..511) holds no edge at all."""
+    nb, e, eb = 8 * TN, 200, 256
+    rng = np.random.default_rng(13)
+    live = np.concatenate([np.arange(0, 3 * TN), np.arange(4 * TN, nb)])
+    src, dst = (rng.choice(live, e).astype(np.int32) for _ in range(2))
+    pad = np.zeros(eb - e, np.int32)
+    mask = np.concatenate([np.ones(e, bool), np.zeros(eb - e, bool)])
+    return np.concatenate([src, pad]), np.concatenate([dst, pad]), mask, nb
+
+
+def _three_by_three_graph():
+    """Node 5 calls 2 x BE + 100 nodes, so the first tile's out-entries span
+    three edge blocks; the handful of entries of the other three tiles all
+    lie in the third block, which four tiles' items meet."""
+    nb, heavy = 4 * TN, 2 * BE + 100
+    rng = np.random.default_rng(14)
+    src = np.concatenate([np.full(heavy, 5), rng.integers(TN, nb, 20)]).astype(np.int32)
+    dst = np.concatenate([rng.integers(0, TN, heavy), rng.integers(TN, nb, 20)]).astype(np.int32)
+    pad = np.zeros(2048 - src.shape[0], np.int32)
+    mask = np.concatenate([np.ones(src.shape[0], bool), np.zeros(pad.shape[0], bool)])
+    return np.concatenate([src, pad]), np.concatenate([dst, pad]), mask, nb
+
+
+_GRAPHS = {"hub": _hub_graph, "shared_block": _shared_block_graph, "three_by_three": _three_by_three_graph}
+
+#: sha256 (first 16 hex digits) of the bytes of `planned_attention`'s value and
+#: of its gradients to hw, s and t, interpreted kernels on the CPU, recorded on
+#: the code of PR 30 (commit 89811fd) BEFORE PR 31 touched a kernel: what the
+#: walks fetch, stack or transpose may change, the bits may not
+_PARENT_DIGESTS = {
+    "hub": ("2ba86974ebb01028", "3f72998f822aee43", "776b5f7784021467", "85c2effe97a90e06"),
+    "shared_block": ("5358e1ebef0a3839", "727cf208a09012be", "b52ebe1373d0c361", "1fac167762b54cfd"),
+}
+_DIGEST_SEEDS = {"hub": 21, "shared_block": 22}
+
+
+def _direct(graph, seed, impl, width=WIDTH):
+    """(value, d hw, d s, d t) of `planned_attention` itself on seeded inputs."""
+    src, dst, mask, nb = graph
+    plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+    rng = np.random.default_rng(seed)
+    hw, ct = (jnp.asarray(rng.normal(size=(nb, width)).astype(np.float32)) for _ in range(2))
+    s, t = (jnp.asarray(rng.normal(size=(nb, 2)).astype(np.float32)) for _ in range(2))
+    out, pull = jax.vjp(lambda h, s_, t_: sparse.planned_attention(plan, h, s_, t_, 0.2, impl), hw, s, t)
+    return [np.asarray(x) for x in (out, *pull(ct))]
+
+
+class TestTheKernelsKeepTheirBits:
+    @pytest.mark.parametrize("name", sorted(_PARENT_DIGESTS))
+    def test_value_and_gradients_reproduce_the_digests_recorded_on_the_parent(self, name):
+        got = _direct(_GRAPHS[name](), _DIGEST_SEEDS[name], "pallas_interpret")
+        digests = tuple(hashlib.sha256(x.tobytes()).hexdigest()[:16] for x in got)
+        assert digests == _PARENT_DIGESTS[name]
+
+    def test_a_block_met_by_three_tiles_and_a_tile_that_meets_three_blocks(self):
+        graph = _three_by_three_graph()
+        plan, _, items = sparse.build_edge_plan(*graph)
+        tiles, blocks = plan.item_tile[:items], plan.item_block[:items]
+        assert np.bincount(tiles).max() >= 3  # a tile by items of three blocks
+        assert np.bincount(blocks).max() >= 3  # a block by items of three tiles
+        got, want = (_direct(graph, 23, impl) for impl in ("pallas_interpret", "xla"))
+        for g, w, what in zip(got, want, ("value", "d hw", "d s", "d t")):
+            _close(g, w, 5e-5, what)
+
+    def test_the_saved_residual_holds_the_messages_once_and_one_state_of_the_entries(self):
+        """Backward gets the float32 messages once (no split copy beside
+        them: the pieces are made where they are used) and ONE [8, entries]
+        state with z and alpha in it, not an array a scalar."""
+        src, dst, mask, nb, plan = _plan("masked")
+        hw, _, _ = _inputs(nb)
+        s = t = jnp.zeros((nb, 2), jnp.float32)
+        _, saved = sparse._attention_pallas_fwd(plan, hw, s, t, 0.2, True)
+        entries = plan.neighbour.shape[0]
+        per_entry = {a.shape: a for a in saved if entries in a.shape}
+        assert sorted(per_entry) == [(sparse.ATT_ROWS, entries), (entries, 128)]
+        assert all(a.dtype == jnp.float32 for a in per_entry.values())
+        assert len([a for a in saved if entries in a.shape]) == 2
+        state = np.asarray(per_entry[(sparse.ATT_ROWS, entries)])
+        real = 2 * int(mask.sum())
+        # the blocks an item visits (no walk writes the others, none reads them)
+        seen = (int(np.asarray(plan.item_block).max()) + 1) * BE
+        state = state[:, :seen]
+        # the plan's owner and direction ride in it as bits, untouched by three walks
+        np.testing.assert_array_equal(state[sparse.ROW_OWNER].view(np.int32), np.asarray(plan.owner)[0, :seen])
+        np.testing.assert_array_equal(state[sparse.ROW_DIR].view(np.int32), np.asarray(plan.direction)[0, :seen])
+        assert (state[sparse.ROW_ALPHA, :real] > 0).all() and (state[sparse.ROW_ALPHA, real:] == 0).all()
+        assert (state[sparse.ROW_DALPHA] == 0).all()  # the backward pass's row
+
 
 class TestPlannedAttention:
     @pytest.mark.parametrize("impl", IMPLS)
@@ -171,13 +285,9 @@ class TestPlannedAttention:
         """Node 3 is called over 4 x BE + 37 edges and calls one node itself:
         its run of in-edges crosses five edge blocks, so its maximum, its sum
         and its weighted sum are carried from item to item."""
-        n, e = 256, 4 * BE + 37
-        rng = np.random.default_rng(6)
-        src = np.concatenate([rng.integers(4, n, e), [3]]).astype(np.int32)
-        dst = np.concatenate([np.full(e, 3), [200]]).astype(np.int32)
-        mask = np.ones(e + 1, bool)
+        src, dst, mask, n = _hub_graph()
         host, entries, items = sparse.build_edge_plan(src, dst, mask, n)
-        assert host.degree[3] == e + 1 and items >= 6
+        assert host.degree[3] == mask.shape[0] and items >= 6
         plan = _device(host)
         hw, vectors, ct = _inputs(n, seed=7)
         want, pull_unsorted = jax.vjp(lambda h, a: _unsorted(h, a, src, dst, mask), hw, vectors)
@@ -354,6 +464,7 @@ class TestGatTrainingThroughThePlan:
         src, dst = np.asarray(ds.src)[real], np.asarray(ds.dst)[real]
         runs = len(set(src.tolist())) + len(set(dst.tolist()))
         assert st.plan_runs == runs and st.plan_entries == 2 * int(real.sum())
+        assert st.plan_blocks == sparse.plan_blocks(st.plan, st.plan_items) == 1
         noted = {}
         for tb in TRACER.traces():
             for i, span in enumerate(tb.spans):
@@ -362,3 +473,4 @@ class TestGatTrainingThroughThePlan:
         plan_counts = noted["refresh.stack.plan"]
         assert plan_counts["entries_out"] == plan_counts["entries_in"] == int(real.sum())
         assert plan_counts["runs"] == runs and noted["refresh.stack"]["plan_runs"] == runs
+        assert plan_counts["blocks"] == noted["refresh.stack"]["plan_blocks"] == st.plan_blocks

@@ -138,6 +138,8 @@ class TestRefreshTrace:
         assert by_name["refresh.stack"]["plan_entries"] == 2 * real_edges
         assert by_name["refresh.stack.plan"]["entries"] == 2 * real_edges
         assert by_name["refresh.stack"]["plan_items"] == st.plan_items >= 1
+        assert by_name["refresh.stack"]["plan_blocks"] == st.plan_blocks >= 1
+        assert by_name["refresh.stack.plan"]["blocks"] == st.plan_blocks <= st.plan_items
 
     def test_second_refresh_hits_the_memoised_stack(self):
         ds = _dataset()
@@ -149,7 +151,7 @@ class TestRefreshTrace:
         st = stacked.stack_dataset(ds)
         assert _names(tb, stack_idx) == [] and tb.counts[stack_idx] == {
             "hit": 1, "plan_entries": st.plan_entries, "plan_items": st.plan_items,
-            "plan_runs": st.plan_runs,
+            "plan_blocks": st.plan_blocks, "plan_runs": st.plan_runs,
         }
 
     def test_train_inside_a_tick_nests_under_it_and_opens_no_second_trace(self):

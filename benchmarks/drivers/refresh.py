@@ -68,6 +68,7 @@ def run(ctx: Context) -> Record:
         )
     notes["reference"] = verdict.detail
     correct = verdict.ok
+    compared = verdict.compared()
 
     with rec.span("setup.warm_call"):
         first = ref_check.triple(call(dataset))
@@ -144,6 +145,8 @@ def run(ctx: Context) -> Record:
         traffic=mix,
         devices=ctx.devices,
         notes=notes,
+        # exact: a call whose losses are not the warm call's (SAME_RTOL), or that raised
+        compared={**compared, "window.calls_failed": {"value": failed, "limit": 0}},
     )
     if trace_dir is not None:
         from benchmarks.trace import reduce as trace_reduce

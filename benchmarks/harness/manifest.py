@@ -10,6 +10,9 @@ directories the manifest lists under `paths`:
     gen/<generator>.py             generate(config, seed), named by the config
     layer_metrics/<metric>.py      read(record) -> float or None
     trace/work/<family>.py         operations and bytes of a family's kernel
+    reference/<family>.py          the family's plain forward and, where they
+                                   are its own, its loss and its FORWARD
+                                   bounds (reference/train.py says which)
 
 A later PR adds a file and an entry and edits nothing that is here.
 """

@@ -39,3 +39,5 @@ class Record:
     trace: Optional[Any] = None
     #: why `correct` is False, for the lines above the result
     notes: Dict[str, Any] = field(default_factory=dict)
+    #: every number `correct` was decided from: name -> {"value", "limit"}
+    compared: Dict[str, Dict[str, float]] = field(default_factory=dict)

@@ -4,10 +4,13 @@ With `--trace 0` its metrics are the cell's end-to-end metrics, taken by the
 driver; with `--trace 1` its per-layer metrics, each from its own reader
 (`layer_metrics/<name>.py`, `read(record) -> float or None`). A reader that
 finds nothing to read returns None and its metric is left out of the line.
+The line's last key, `compared`, holds each number that `correct` was decided
+from beside its limit.
 """
 from __future__ import annotations
 
 import json
+import math
 from typing import Any, Dict
 
 from benchmarks.harness import device
@@ -41,4 +44,9 @@ def result_line(manifest, cell: Dict[str, Any], record: Record, traced: bool) ->
         line["device"]["busy_s"] = record.trace.busy_s
         line["device"]["window_s"] = record.trace.window_s
         line["breakdown"] = record.trace.breakdown()
+    # last, so that a record cut at its end keeps it; JSON has no nan or inf
+    line["compared"] = {
+        name: {k: v if math.isfinite(v) else None for k, v in number.items()}
+        for name, number in record.compared.items()
+    }
     return json.dumps(line)

@@ -1,5 +1,6 @@
 """Spans and counters the benchmark records around its calls into the
-program (the program's own spans are a later `tracing` PR).
+program. The program's own spans (`refresh.*`, since PR 26) reach the readers
+through `harness/program_spans.py`.
 
 A span is kept in memory on the host's monotonic clock and, while the
 profiler runs, also written into its trace as a `TraceAnnotation` of the

@@ -2,7 +2,8 @@
 device did nothing: the span around the call minus the device-busy time
 inside it, per call. It is what `models/trainer.py` spends on init, the
 per-call `pos_weight` loop over every slot, the dispatch and the loss fetch.
-A difference, until the `tracing` PR puts spans inside `train()`."""
+A difference, taken from outside; the spans inside `train()` (PR 26) give the
+same time by its parts: `trainer.span_host_ms_per_call` and its siblings."""
 
 
 def read(record):

@@ -66,6 +66,8 @@ def main(argv=None, t0: float = _T0) -> int:
     for key, value in record.notes.items():
         print(f"# {key}: {json.dumps(value, default=str)}")
     print(result_line(manifest, cell, record, traced=bool(args.trace)))
+    for name, number in record.compared.items():
+        print(f"compared {name}: {number['value']!r} limit {number['limit']!r}", file=sys.stderr)
     return 0
 
 

@@ -154,9 +154,17 @@ def test_last_line_has_exactly_the_contract_keys(tmp_path, capsys, cpu_devices, 
     code = run.main(["--manifest", str(tmp_path / "BENCHMARK.json"), "--workload", "tiny.refresh",
                      "--seed", str(2**31 + 12345), "--seconds", "0.2", "--trace", str(traced)])
     assert code == 0
-    line = _last_line(capsys)
-    want = {"correct", "attempted", "failed", "metrics", "device"} | ({"breakdown"} if traced else set())
-    assert set(line) == want
+    captured = capsys.readouterr()
+    line = json.loads(captured.out.strip().splitlines()[-1])
+    want = {"correct", "attempted", "failed", "metrics", "device", "compared"} | ({"breakdown"} if traced else set())
+    assert set(line) == want and list(line)[-1] == "compared"
+    # each number `correct` was decided from, beside its limit, and under it
+    assert {"schedule.default.loss", "forward.default.loss", "forward.highest.param", "window.calls_failed"} <= set(line["compared"])
+    assert all(set(n) == {"value", "limit"} and n["value"] <= n["limit"] for n in line["compared"].values())
+    err = captured.err.strip().splitlines()
+    assert err[-len(line["compared"]):] == [
+        f"compared {k}: {n['value']!r} limit {n['limit']!r}" for k, n in line["compared"].items()
+    ]
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
     device_keys = {"platform", "kind", "count", "memory_peak_bytes"} | ({"busy_s", "window_s"} if traced else set())
     assert set(line["device"]) == device_keys
@@ -170,3 +178,58 @@ def test_last_line_has_exactly_the_contract_keys(tmp_path, capsys, cpu_devices, 
     if traced:
         assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
         assert line["metrics"]["epoch_block.compiles"]["value"] == 0
+
+
+def _broken(fault):
+    """`trainer.train` with the timed path broken underneath, one fault of
+    those a training cell can have."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from kmamiz_tpu.models import trainer
+
+    real = trainer.train
+
+    def train(dataset, **kw):
+        if fault == "state returned unchanged":
+            result = real(dataset, **kw)
+            model = kw["model"]
+            init = model.init_params(jax.random.PRNGKey(kw["seed"]), hidden=kw["hidden"],
+                                     num_features=dataset.features[0].shape[1], num_nodes=0)
+            return dataclasses.replace(result, params=init)
+        if fault == "half of the endpoints left out":
+            half = [np.where(np.arange(m.shape[0]) % 2 == 0, np.asarray(m), False) for m in dataset.node_mask]
+            return real(dataclasses.replace(dataset, node_mask=half), **kw)
+        if fault == "a loss altered where it is reported":
+            result = real(dataset, **kw)
+            return dataclasses.replace(result, losses=[v * 1.001 for v in result.losses])
+        raise AssertionError(fault)
+
+    return train
+
+
+@pytest.mark.parametrize("fault", ["state returned unchanged", "half of the endpoints left out",
+                                   "a loss altered where it is reported"])
+@pytest.mark.parametrize("family", ["graphsage", "gat"])
+def test_a_run_on_a_broken_program_is_not_correct(tmp_path, capsys, cpu_devices, monkeypatch, tiny_config,
+                                                  family, fault):
+    from kmamiz_tpu.models import trainer
+
+    cfg = copy.deepcopy(tiny_config)
+    cfg.update(family=family, model_module=f"kmamiz_tpu.models.{family}", name="tiny")
+    (tmp_path / "tiny.json").write_text(json.dumps(cfg))
+    doc = json.loads((ROOT / "BENCHMARK.json").read_text())
+    doc["paths"] = [str(BENCH)]
+    doc["configs"] = [{"name": "tiny", "source": "test", "file": str(tmp_path / "tiny.json"), "reduced": [], "why": "t"}]
+    doc["workloads"] = [{"name": "tiny.refresh", "config": "tiny", "traffic": "refresh", "chips": 1, "why": "t"}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
+    monkeypatch.setattr(trainer, "train", _broken(fault))
+    code = run.main(["--manifest", str(tmp_path / "BENCHMARK.json"), "--workload", "tiny.refresh",
+                     "--seed", "77", "--seconds", "0.2", "--trace", "0"])
+    assert code == 0
+    line = _last_line(capsys)
+    assert line["correct"] is False
+    over = [k for k, n in line["compared"].items() if n["value"] is None or n["value"] > n["limit"]]
+    assert over, "correct is false, so some number compared is over its limit"

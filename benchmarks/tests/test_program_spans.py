@@ -126,14 +126,17 @@ def test_reader_finds_nothing_and_returns_none(program, name):
 def test_the_six_entries_are_appended_and_nothing_else_changed():
     doc = json.loads((ROOT / "BENCHMARK.json").read_text())
     names = [m["name"] for m in doc["per_layer"]]
-    assert names[-6:] == list(NEW) and len(names) == len(set(names)) == 14
-    for m in doc["per_layer"][-6:]:
+    assert len(names) == len(set(names))
+    # appended after PR 24's eight, in this order; later PRs append after them
+    assert names[8:14] == list(NEW)
+    six = [m for m in doc["per_layer"] if m["name"] in NEW]
+    for m in six:
         assert set(m) == {"name", "unit", "better", "source", "layer", "moves"}  # no `workloads`
         assert m["unit"] == NEW[m["name"]] and m["better"] == "lower"
         assert m["source"] == ("program_counter" if m["name"].startswith("epoch_block.") else "program_span")
         assert m["moves"] == ("setup_s" if m["name"].startswith("setup.") else "refresh_slot_updates_per_s")
     layers = {m["layer"] for m in doc["per_layer"][:8]}
-    assert {m["layer"] for m in doc["per_layer"][-6:]} <= layers  # the accepted names, letter for letter
+    assert {m["layer"] for m in six} <= layers  # the accepted names, letter for letter
 
 
 def test_tiny_cell_traced_on_the_cpu_reports_the_six_metrics(tmp_path, capsys, monkeypatch, tiny_config):

@@ -1,10 +1,11 @@
 """From a profiler trace to the numbers the per-layer metrics read.
 
-A copy of the idea of `telemetry/profiling/device_attr.parse_profile_dir`
-(sum device-op durations, credit them to programs by name), extended to what
-that function never had: busy time as the UNION of the intervals in which an
-operation ran, self time of nested operations, idle gaps named by the host
-span that covers them, and shares by kind of operation. It reads the
+Device-op durations summed and credited to programs by name, busy time as
+the UNION of the intervals in which an operation ran, self time of nested
+operations, idle gaps named by the host span that covers them, and shares by
+kind of operation: the benchmark's own reduction, nothing of the program's
+(its `telemetry/profiling/device_attr.py` attributes a live capture to the
+operator's `/profile`; no function of it is used or copied here). It reads the
 profiler's own `.xplane.pb` (jax 0.9 writes no `.trace.json`) through
 `jax.profiler.ProfileData`, or the same events from a JSON file, which is
 how the recorded trace beside this file is kept.

@@ -97,6 +97,7 @@ class ModelHandler(IRequestHandler):
 
                 from kmamiz_tpu.models import checkpoint as ckpt
                 from kmamiz_tpu.models import gat, graphsage
+                from kmamiz_tpu.models.stlgt import model as stlgt_model
 
                 step = ckpt.latest_complete_step(directory)
                 if step is None:
@@ -119,7 +120,11 @@ class ModelHandler(IRequestHandler):
                         "set (retrain without --embeddings)"
                     )
                     return None
-                model = gat if meta.get("model") == "gat" else graphsage
+                # the head the checkpoint names (trainer.train's metadata);
+                # a quantile head serves its p50 through this legacy shape
+                model = {"gat": gat, "stlgt": stlgt_model}.get(
+                    meta.get("model"), graphsage
+                )
                 template = model.init_params(
                     jax.random.PRNGKey(0),
                     hidden=int(meta["hidden"]),

@@ -27,6 +27,8 @@ from kmamiz_tpu.models.graphsage import EMB_DIM, NUM_FEATURES
 from kmamiz_tpu.ops import sparse
 
 LEAK = 0.2
+#: `forward` takes the stack's edge plan as `plan=` (models/stacked.plan_for)
+TAKES_PLAN = True
 
 
 class GatParams(NamedTuple):

@@ -23,6 +23,11 @@ import jax.numpy as jnp
 from kmamiz_tpu.ops import sparse
 
 NUM_FEATURES = 10  # incl. sin/cos hour-of-day
+#: what the fused trainer may hand `forward` (models/stacked.py reads these,
+#: not the signature): the stack's edge plan as `plan=`, and layer 1's
+#: neighbour sum of the features as `neighbor_sum_1=` (the slot group)
+TAKES_PLAN = True
+TAKES_NEIGHBOR_SUM_1 = True
 
 
 def assemble_features(

@@ -35,12 +35,19 @@ device-native:
   host and memoised by the identity of the edge arrays, so datasets over
   one graph (a history and its head, a train and a test split) share one.
   `epoch_runner`'s block takes it as a loop constant beside
-  src/dst/edge_mask and hands it to a head whose forward takes one
-  (`plan_for`): GraphSAGE sums neighbour rows over it
-  (`sparse.planned_neighbor_sum`), GAT runs its directed segment softmax
-  and weighted sums over it (`sparse.planned_attention`); STLGT takes
-  none yet. The vmapped paths (`dp_epoch_runner`, `predict_all`) pass none
-  and reduce the edge list as it comes.
+  src/dst/edge_mask and hands it to a head that says it takes one
+  (`TAKES_PLAN`, read by `plan_for`): GraphSAGE sums neighbour rows over
+  it (`sparse.planned_neighbor_sum`), GAT runs its directed segment
+  softmax and weighted sums over it (`sparse.planned_attention`), STLGT
+  its sigmoid-gated neighbour bias (`sparse_gated.planned_gated_sum`). The
+  vmapped paths (`dp_epoch_runner`, `predict_all`) pass none and reduce the
+  edge list as it comes.
+- the HEAD'S OWN LOSS: a head module may state `make_loss_fn(pos_weight)`,
+  whose product takes what `common.make_loss_fn`'s takes and the forward's
+  extra arguments by keyword (STLGT: the pinball loss over its three
+  quantiles); both epoch blocks train under it (`head_loss_fn`). A head
+  that states none trains under `common.make_loss_fn(model.forward, ...)`.
+  Either way a block returns (total, first, second) per epoch.
 - the SLOT GROUP: GraphSAGE's first layer sums the neighbours' features,
   which are data (no parameter in them, no gradient through them) and 18
   floats wide where the chip pads a gathered row to 128 lanes. So
@@ -50,8 +57,9 @@ device-native:
   (`graphsage.forward(..., neighbor_sum_1=)`): still one optimizer update
   per slot, in slot order, from bit for bit the same sums (the reducer's
   columns are independent). It engages where the block can see that it
-  may: the head's `forward` takes the sum, the parameters hold no node
-  embedding, and there is a plan; every other call runs the flat scan.
+  may: the head says its `forward` takes the sum (`TAKES_NEIGHBOR_SUM_1`),
+  the parameters hold no node embedding, and there is a plan; every other
+  call runs the flat scan.
 
 Bit discipline: with the default batch size of 1 the scan body performs
 the identical per-slot update sequence as the legacy Python loop; only
@@ -62,7 +70,6 @@ differ, so losses and params agree within fp32 tolerance
 from __future__ import annotations
 
 import functools
-import inspect
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -259,14 +266,31 @@ def _edge_plan(dataset, src, dst, e_mask, nb: int):
 
 
 def plan_for(model, stacked: StackedDataset) -> Optional[sparse.EdgePlan]:
-    """The stack's edge plan, for a head whose `forward` takes one
-    (GraphSAGE, GAT); None for the others and under KMAMIZ_SPARSE=xla (the
-    legacy formulation everywhere). What `train()` hands the epoch block."""
-    if not sparse.use_sparse():
-        return None
-    if "plan" not in inspect.signature(model.forward).parameters:
+    """The stack's edge plan, for a head that says its `forward` (and its
+    own loss, where it states one) takes one as `plan=`: `TAKES_PLAN` on the
+    head's module (GraphSAGE, GAT, STLGT). None for a head that says nothing
+    and under KMAMIZ_SPARSE=xla (the legacy formulation everywhere). What
+    `train()` hands the epoch block."""
+    if not sparse.use_sparse() or not getattr(model, "TAKES_PLAN", False):
         return None
     return stacked.plan
+
+
+def head_loss_fn(model, pos_weight: float, **forward_args):
+    """The loss an epoch block differentiates: the head's own where its
+    module states one (`make_loss_fn(pos_weight)`), else the family's mean
+    squared error and weighted cross-entropy over `model.forward`
+    (`common.make_loss_fn`). `forward_args` (the block's `plan`, a slot
+    group's `neighbor_sum_1`) are bound by keyword: to the forward, or to a
+    head's own loss, which hands them to its forward."""
+    make = getattr(model, "make_loss_fn", None)
+    if make is None:
+        forward = model.forward
+        if forward_args:
+            forward = functools.partial(forward, **forward_args)
+        return common.make_loss_fn(forward, pos_weight)
+    loss_fn = make(pos_weight)
+    return functools.partial(loss_fn, **forward_args) if forward_args else loss_fn
 
 
 #: lanes of a row on the chip: a gathered message row and a reducer's block
@@ -279,7 +303,8 @@ def slot_group(model, params, features, plan) -> int:
     epoch block, 0 where every slot makes its own (`epoch_runner`).
 
     The block groups where it can see that the sum is of data alone: the
-    head's `forward` takes the sum from its caller (`neighbor_sum_1`), the
+    head says its `forward` takes the sum from its caller as `neighbor_sum_1`
+    (`TAKES_NEIGHBOR_SUM_1` on its module), the
     parameters hold no node embedding (with one the layer's input holds
     parameters and a gradient goes through the graph), and there is a plan
     to sum over. The size comes from the feature width F and the chip's
@@ -287,7 +312,7 @@ def slot_group(model, params, features, plan) -> int:
     a group of one is no group."""
     if plan is None or getattr(params, "embedding", None) is not None:
         return 0
-    if "neighbor_sum_1" not in inspect.signature(model.forward).parameters:
+    if not getattr(model, "TAKES_NEIGHBOR_SUM_1", False):
         return 0
     n_slots, _, width = features.shape
     group = min(ROW_LANES // max(width, 1), n_slots)
@@ -374,6 +399,7 @@ def epoch_runner(model, lr: float, pos_weight: float):
     schedule without its per-slot dispatch and transfers. params/opt_state
     are donated (they live and die on device across the whole run).
 
+    The loss is the head's own where it states one (`head_loss_fn`).
     `plan` (the stack's EdgePlan, `plan_for`) is a constant of both scans
     like src/dst/edge_mask, handed to `model.forward` as its `plan`; None
     keeps the forward's own reduction of the edge list, and is another
@@ -392,8 +418,7 @@ def epoch_runner(model, lr: float, pos_weight: float):
     process reuse the compiled program family (jit then keys on the
     bucket shapes)."""
     optimizer = model.make_optimizer(lr)
-    loss_fn = common.make_loss_fn(model.forward, pos_weight)
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    grad_fn = jax.value_and_grad(head_loss_fn(model, pos_weight), has_aux=True)
 
     @functools.partial(
         jax.jit,
@@ -423,10 +448,7 @@ def epoch_runner(model, lr: float, pos_weight: float):
             slot_grad = grad_fn
             if plan is not None:
                 slot_grad = jax.value_and_grad(
-                    common.make_loss_fn(
-                        functools.partial(model.forward, plan=plan, **summed),
-                        pos_weight,
-                    ),
+                    head_loss_fn(model, pos_weight, plan=plan, **summed),
                     has_aux=True,
                 )
             (loss, (lat_l, ano_l)), grads = slot_grad(
@@ -550,8 +572,7 @@ def dp_epoch_runner(
     unsharded microbatch on one device (tests/test_parallel.py asserts
     this grad parity)."""
     optimizer = model.make_optimizer(lr)
-    loss_fn = common.make_loss_fn(model.forward, pos_weight)
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    grad_fn = jax.value_and_grad(head_loss_fn(model, pos_weight), has_aux=True)
 
     if mesh is not None:
         from kmamiz_tpu.parallel.mesh import make_sharded_slot_grad

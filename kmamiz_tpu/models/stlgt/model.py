@@ -18,7 +18,13 @@ Two structural channels feed each endpoint's representation:
   mask), segment-summed over both directions — the graph structure
   enters as an additive attention bias, and the per-edge gate doubles
   as the ATTRIBUTION score the eval protocol grades (which upstream
-  edge the model blames for a forecast tail).
+  edge the model blames for a forecast tail). Where the caller holds
+  the EDGE PLAN of the edge list (the training refresh over a stacked
+  history: models/stacked.py), the bias is one reduction over the
+  plan's sorted entries (ops/sparse_gated.planned_gated_sum: row
+  gathers and one Mosaic walk a pass, no scatter), the same mathematics
+  in another order of the sums; without one (serving, the vmapped
+  paths, the continual trainer's ring) it is the segment sums below.
 
 Heads: a monotone quantile stack (p50 raw, p95 = p50 + softplus, p99 =
 p95 + softplus — quantile crossing is impossible by construction) over
@@ -28,8 +34,14 @@ drops into every existing model-module surface (serving.forecast_forward,
 stacked.predict_all); ``forward_quantiles`` is the full STLGT surface.
 
 Interface contract (mirrors graphsage.py): NUM_FEATURES, init_params,
-forward, make_optimizer — the module IS the model, keyed by its import
-path in the program registry families.
+forward, make_optimizer, make_train_step — the module IS the model,
+keyed by its import path in the program registry families. What the
+fused trainer asks of a head it reads here, stated once: NAME (the
+checkpoint's and the span's word for the head), TAKES_PLAN (`forward`
+and the loss take the stack's edge plan as `plan=`), and `make_loss_fn`
+with LOSS, the head's OWN loss (pinball over the three levels), which
+`stacked.epoch_runner`'s block and `stlgt_epoch_runner`'s both call; a
+head that states none trains under `common.make_loss_fn`.
 """
 from __future__ import annotations
 
@@ -37,9 +49,17 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import optax
 
 from kmamiz_tpu.models import common as _common
 from kmamiz_tpu.models.graphsage import NUM_FEATURES, assemble_features  # noqa: F401 - re-export: one feature layout for every head
+from kmamiz_tpu.ops import sparse, sparse_gated
+
+NAME = "stlgt"
+#: `forward`, `forward_quantiles`, `encode` and the loss take `plan=`
+TAKES_PLAN = True
+#: what `make_loss_fn` makes, for the `refresh.train` span
+LOSS = "pinball+bce"
 
 #: forecast quantile levels, in emitted column order (p50, p95, p99)
 QUANTILES: Tuple[float, ...] = (0.50, 0.95, 0.99)
@@ -117,11 +137,21 @@ def encode(
     src_ep: jnp.ndarray,  # [E]
     dst_ep: jnp.ndarray,  # [E]
     edge_mask: jnp.ndarray,  # [E]
+    plan: sparse.EdgePlan = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One linear-transformer block -> (node states [N, H], edge gates
     [E]). Padded lanes (all-zero feature rows — the pow2 bucket padding
     is zero-filled everywhere in this repo) are masked out of the
-    attention sums; padded edges out of the bias by edge_mask."""
+    attention sums; padded edges out of the bias by edge_mask.
+
+    `plan` is the edge plan of (src_ep, dst_ep, edge_mask) where the
+    caller has prepared one: the neighbour bias is then
+    `sparse_gated.planned_gated_sum` over its entries. The gates are per
+    EDGE either way, in the edge list's order: an edge's gate is a
+    function of q[src] and k[dst] alone (the plan's two entries of an
+    edge hold it too), so with a plan they are still made from the edge
+    list, for whoever asks; under the loss nobody does, and XLA drops
+    them."""
     n = features.shape[0]
     # lane mask: a padded slot has an all-zero feature row; real slots
     # always carry at least the hour-of-day cos column
@@ -132,10 +162,19 @@ def encode(
     k = _phi(x @ params.w_k) * lane[:, None]
     v = (x @ params.w_v) * lane[:, None]
 
-    # global linear attention: O(N·H²) — softmax-free
+    # global linear attention: O(N·H²) — softmax-free. kv and z are sums
+    # over EVERY endpoint, so they sit in every endpoint's state: where a
+    # float32 product is one bfloat16 pass (a TPU's default), an element of
+    # kv within an ulp of a bfloat16 rounding boundary moves all N rows of
+    # q @ kv at once by 2^-9 of that term, and two correct implementations
+    # that sum kv in another order disagree by 1e-6 .. 4e-6 of the loss on one
+    # slot in seven (PERF.md, PR 33). The two products that READ the sums
+    # are therefore made at float32 ([N, H] x [H, H]: six passes of nothing);
+    # k.T @ v itself rounds per endpoint, which averages out, and stays.
     kv = k.T @ v  # [H, H]
     z = k.sum(axis=0)  # [H]
-    attn = (q @ kv) / (q @ z + 1e-6)[:, None]
+    exact = jax.lax.Precision.HIGHEST
+    attn = jnp.matmul(q, kv, precision=exact) / (jnp.matmul(q, z, precision=exact) + 1e-6)[:, None]
 
     # neighbor bias from the CSR edge list: gated messages over both
     # directions (callers and callees are both signal), sentinel-indexed
@@ -147,15 +186,18 @@ def encode(
         jnp.float32(q.shape[1])
     )
     gate = jax.nn.sigmoid(affinity + params.b_edge[0]) * em
-    src_s = jnp.where(edge_mask, src_ep, n)
-    dst_s = jnp.where(edge_mask, dst_ep, n)
-    msg_fwd = v[src_c] * gate[:, None]
-    msg_bwd = v[dst_c] * gate[:, None]
-    bias = jax.ops.segment_sum(msg_fwd, dst_s, num_segments=n + 1)[:-1]
-    bias = bias + jax.ops.segment_sum(msg_bwd, src_s, num_segments=n + 1)[:-1]
-    deg = jax.ops.segment_sum(gate, dst_s, num_segments=n + 1)[:-1]
-    deg = deg + jax.ops.segment_sum(gate, src_s, num_segments=n + 1)[:-1]
-    bias = bias / jnp.maximum(deg, 1.0)[:, None]
+    if plan is not None:
+        bias = sparse_gated.planned_gated_sum(plan, q, k, v, params.b_edge)
+    else:
+        src_s = jnp.where(edge_mask, src_ep, n)
+        dst_s = jnp.where(edge_mask, dst_ep, n)
+        msg_fwd = v[src_c] * gate[:, None]
+        msg_bwd = v[dst_c] * gate[:, None]
+        bias = jax.ops.segment_sum(msg_fwd, dst_s, num_segments=n + 1)[:-1]
+        bias = bias + jax.ops.segment_sum(msg_bwd, src_s, num_segments=n + 1)[:-1]
+        deg = jax.ops.segment_sum(gate, dst_s, num_segments=n + 1)[:-1]
+        deg = deg + jax.ops.segment_sum(gate, src_s, num_segments=n + 1)[:-1]
+        bias = bias / jnp.maximum(deg, 1.0)[:, None]
 
     h1 = x + jax.nn.relu((attn + bias) @ params.w_o)
     h2 = h1 + jax.nn.relu(
@@ -170,6 +212,7 @@ def forward_quantiles(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
+    plan: sparse.EdgePlan = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Full STLGT surface -> (latency quantiles [N, NUM_QUANTILES] in
     log1p-ms, anomaly logits [N], per-edge attribution gates [E]).
@@ -177,7 +220,7 @@ def forward_quantiles(
     Quantile columns are monotone by construction: p50 is the raw head,
     each later level adds a softplus increment — a crossed quantile pair
     cannot be emitted, so coverage scoring never needs to re-sort."""
-    h, gate = encode(params, features, src_ep, dst_ep, edge_mask)
+    h, gate = encode(params, features, src_ep, dst_ep, edge_mask, plan)
     raw = h @ params.w_quant + features @ params.w_quant_skip + params.b_quant
     q50 = raw[:, 0]
     q95 = q50 + jax.nn.softplus(raw[:, 1])
@@ -195,12 +238,13 @@ def forward(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
+    plan: sparse.EdgePlan = None,
 ):
     """Model-module compatibility surface: (p50 latency, anomaly logit) —
     the (latency, logit) pair every existing consumer expects
     (serving.forecast_forward, stacked.predict_all, common loss)."""
     quantiles, anomaly_logit, _gate = forward_quantiles(
-        params, features, src_ep, dst_ep, edge_mask
+        params, features, src_ep, dst_ep, edge_mask, plan
     )
     return quantiles[:, 0], anomaly_logit
 
@@ -210,8 +254,12 @@ def make_pinball_loss_fn(
 ):
     """Masked pinball (quantile) loss over the three levels + the
     family-standard weighted BCE anomaly term. Signature matches
-    common.make_loss_fn's product so the scan-fused epoch block pattern
-    (stacked.epoch_runner) transfers verbatim."""
+    common.make_loss_fn's product, with the forward's `plan=` after it:
+    the head's own loss, which the fused epoch block
+    (stacked.epoch_runner, as `make_loss_fn`) and the continual
+    trainer's (stlgt_epoch_runner) both train under. The triple is
+    (total, (quantile loss, anomaly loss)): `trainer.train` reports the
+    quantile loss as `latency_losses`."""
     taus = jnp.asarray(quantiles, dtype=jnp.float32)
 
     def loss_fn(
@@ -223,17 +271,16 @@ def make_pinball_loss_fn(
         target_latency,
         target_anomaly,
         node_mask,
+        plan=None,
     ):
         pred_q, anomaly_logit, _gate = forward_quantiles(
-            params, features, src_ep, dst_ep, edge_mask
+            params, features, src_ep, dst_ep, edge_mask, plan
         )
         w = node_mask.astype(jnp.float32)
         denom = jnp.maximum(w.sum(), 1.0)
         diff = target_latency[:, None] - pred_q  # [N, Q]
         pinball = jnp.maximum(taus * diff, (taus - 1.0) * diff)
         quant_loss = jnp.sum(w[:, None] * pinball) / denom
-        import optax
-
         class_w = 1.0 + (pos_weight - 1.0) * target_anomaly
         anomaly_loss = (
             jnp.sum(
@@ -250,4 +297,9 @@ def make_pinball_loss_fn(
     return loss_fn
 
 
+make_loss_fn = make_pinball_loss_fn
 make_optimizer = _common.make_optimizer
+
+
+def make_train_step(optimizer, pos_weight: float = 1.0):
+    return _common.make_train_step(optimizer, make_loss_fn(pos_weight))

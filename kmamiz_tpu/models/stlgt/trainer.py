@@ -159,7 +159,8 @@ def stlgt_epoch_runner(model, lr: float, pos_weight: float, quantiles):
     import optax
 
     optimizer = model.make_optimizer(lr)
-    loss_fn = model.make_pinball_loss_fn(pos_weight, tuple(quantiles))
+    # the head's own loss: the function stacked.epoch_runner's block calls
+    loss_fn = model.make_loss_fn(pos_weight, tuple(quantiles))
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     @functools.partial(

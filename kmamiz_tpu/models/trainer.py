@@ -246,11 +246,13 @@ def train(
     the code already returns or already waits; none adds a sync."""
     from kmamiz_tpu.models import checkpoint as ckpt
 
-    model_name = model.__name__.rsplit(".", 1)[-1]
+    # a head in a package of its own names itself (stlgt/model.py: "stlgt")
+    model_name = getattr(model, "NAME", model.__name__.rsplit(".", 1)[-1])
     num_slots = len(dataset.features) if dataset is not None else 0
     _REFRESHES.inc()
     TRACER.note(
         model=model_name,
+        loss=getattr(model, "LOSS", "mse+bce"),  # the head's own, where it states one
         epochs=epochs,
         slots=num_slots,
         batch_slots=batch_slots,

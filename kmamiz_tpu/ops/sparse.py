@@ -30,9 +30,9 @@ windows). This module holds what they share:
   see scorers.py for the counting core built on these.
 
 A caller of the model plane that holds no plan (the tick's one-off graphs,
-`dp_epoch_runner`, `predict_all`, the legacy per-slot loop, STLGT's gated
-bias) reduces its edge list with XLA's gathers and segment sums, in the
-model's own file. Nothing else exists, and no environment name chooses
+`dp_epoch_runner`, `predict_all`, the legacy per-slot loop, STLGT's ring)
+reduces its edge list with XLA's gathers and segment sums in the model's own
+file (STLGT's refresh on a plan: ops/sparse_gated.py). No environment name picks
 between the plan's Pallas kernels and their XLA twins: the platform does
 (``planned_impl``).
 

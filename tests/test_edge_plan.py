@@ -380,17 +380,30 @@ class TestStackCarriesThePlan:
         assert other.plan is not st.plan
         assert _counter("kmamiz_model_edge_plan_builds_total") == 2
 
-    def test_plan_for_goes_by_what_the_forward_takes_and_by_the_legacy_knob(self, monkeypatch):
+    def test_plan_for_goes_by_what_the_head_says_it_takes_and_by_the_legacy_knob(self, monkeypatch):
+        import inspect
+        import types
+
         from kmamiz_tpu.models.stlgt import model as stlgt_model
 
         st = stacked.stack_dataset(_dataset())
         assert stacked.plan_for(graphsage, st) is st.plan
         assert stacked.plan_for(gat, st) is st.plan
-        assert stacked.plan_for(stlgt_model, st) is None  # its forward takes none
+        assert stacked.plan_for(stlgt_model, st) is st.plan  # the gated sum runs over it (PR 33)
+        # said once, on the head, and true of its forward; the signature is not read
+        for model in (graphsage, gat, stlgt_model):
+            assert model.TAKES_PLAN and "plan" in inspect.signature(model.forward).parameters
+        assert "plan" in inspect.signature(stlgt_model.make_loss_fn(1.0)).parameters
+        assert graphsage.TAKES_NEIGHBOR_SUM_1 and "neighbor_sum_1" in inspect.signature(graphsage.forward).parameters
+        assert not hasattr(gat, "TAKES_NEIGHBOR_SUM_1") and not hasattr(stlgt_model, "TAKES_NEIGHBOR_SUM_1")
+        silent = types.SimpleNamespace(forward=graphsage.forward)  # a head that says nothing is handed none
+        assert stacked.plan_for(silent, st) is None
+        assert stacked.slot_group(silent, _params(st.features.shape[2]), st.features, st.plan) == 0
         monkeypatch.setenv("KMAMIZ_SPARSE", "xla")
         sparse.reset_for_tests()
         assert stacked.plan_for(graphsage, st) is None
         assert stacked.plan_for(gat, st) is None
+        assert stacked.plan_for(stlgt_model, st) is None
 
 
 class TestTrainingThroughThePlan:
@@ -490,7 +503,9 @@ def _run_block(ds, n_epochs, **block_args):
 
 def _parent_epoch_block(model, lr, pos_weight):
     """The epoch block as PR 28 left it, for the programs that must not
-    change: one flat scan over the slots, the plan closed over."""
+    change: one flat scan over the slots, the plan closed over. A head that
+    states a loss of its own (PR 33) has that in the family's place, the
+    plan bound to it by keyword."""
     import functools
 
     import optax
@@ -498,20 +513,29 @@ def _parent_epoch_block(model, lr, pos_weight):
     from kmamiz_tpu.models import common
 
     optimizer = model.make_optimizer(lr)
-    grad_fn = jax.value_and_grad(common.make_loss_fn(model.forward, pos_weight), has_aux=True)
+    if hasattr(model, "make_loss_fn"):
+        grad_fn = jax.value_and_grad(model.make_loss_fn(pos_weight), has_aux=True)
+    else:
+        grad_fn = jax.value_and_grad(common.make_loss_fn(model.forward, pos_weight), has_aux=True)
 
     def sage_epoch_block(
         params, opt_state, features, target_latency, target_anomaly, node_mask,
         src, dst, edge_mask, n_epochs, plan=None,
     ):
         slot_grad = grad_fn
-        if plan is not None:
+        if plan is not None and not hasattr(model, "make_loss_fn"):
             slot_grad = jax.value_and_grad(
                 common.make_loss_fn(functools.partial(model.forward, plan=plan), pos_weight),
                 has_aux=True,
             )
 
         def slot_step(carry, xs):
+            nonlocal slot_grad
+            if plan is not None and hasattr(model, "make_loss_fn"):
+                # made where the block makes it: its constants (the levels) are the scan's
+                slot_grad = jax.value_and_grad(
+                    functools.partial(model.make_loss_fn(pos_weight), plan=plan), has_aux=True
+                )
             p, s = carry
             f, tl, ta, nm = xs
             (loss, (lat_l, ano_l)), grads = slot_grad(p, f, src, dst, edge_mask, tl, ta, nm)
@@ -571,7 +595,9 @@ class TestSlotGroup:
     def test_who_offers_no_data_only_sum_runs_the_program_of_the_parent(self, monkeypatch, case):
         """Node embeddings put parameters into layer 1's input, a call
         without a plan has nothing to sum over, GAT multiplies by W1 before it
-        touches an edge: the block is the flat scan it was, jaxpr for jaxpr."""
+        touches an edge, and so does STLGT (by W_in; it takes the plan since
+        PR 33, for its gated sum, under its own loss): the block is the flat
+        scan it was, jaxpr for jaxpr."""
         from kmamiz_tpu.models.stlgt import model as stlgt_model
 
         if case == "no_plan":
@@ -582,7 +608,8 @@ class TestSlotGroup:
         ds = _wide_dataset(9, 18)
         st = stacked.stack_dataset(ds)
         plan = stacked.plan_for(model, st)
-        assert (plan is None) is (case in ("no_plan", "stlgt"))
+        assert (plan is None) is (case == "no_plan")
+        assert hasattr(model, "make_loss_fn") is (case == "stlgt")
         kw = {"num_nodes": st.num_nodes} if case == "embeddings" else {}
         params = model.init_params(jax.random.PRNGKey(0), hidden=8, num_features=18, **kw)
         assert stacked.slot_group(model, params, st.features, plan) == 0
@@ -715,3 +742,53 @@ def test_attention_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, wid
     gathers = [line for line in text.splitlines() if " gather(" in line]
     lanes = 128 if width == 64 else 256
     assert len(gathers) == 2 and all(f"f32[{entries},{lanes}]" in line for line in gathers)
+
+
+@pytest.mark.parametrize("width", (64, 100))
+def test_gated_sum_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, width):
+    """The two walks of `sparse_gated.planned_gated_sum`, forward and backward,
+    at the width of `mv100k-stlgt` (a row `[q | k]` fills the 128 lanes) and
+    at one whose halves are 128 lanes each."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from kmamiz_tpu.ops import sparse_gated
+
+    nb, eb = 131072, 524288
+    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, b, plan):
+        return (sparse_gated.planned_gated_sum(plan, q, k, v, b, "pallas") ** 2).sum()
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = (
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))
+            .lower(arg((nb, width)), arg((nb, width)), arg((nb, width)), arg((1,)),
+                   _described_plan(arg, nb, entries, items))
+            .compile()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "planned_gated_sum" in text and "planned_gated_backward" in text
+    assert "scatter" not in text
+    # three row gathers, each from a table of the node bucket's rows at the
+    # full lane width (a table of twice the rows gathers five times slower)
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    lanes = 128 if width == 64 else 256
+    assert len(gathers) == 3 and all(f"f32[{entries},{lanes}]" in line for line in gathers)
+    assert f"f32[{2 * nb}," not in text
+    if width == 64:
+        # each gather's TABLE (67 MB) is held in the chip's fast memory, `S(1)`, while it gathers: from
+        # there a row costs 1.8 ns, from HBM 10 ns (PERF.md, PR 33). The forward's two are chained for it
+        tables = [
+            next(line for line in body.splitlines() if " parameter(0)" in line)
+            for body in text.split("\n}\n") if " gather(" in body
+        ]
+        assert len(tables) == 3 and all(f"f32[{nb},{lanes}]" in t and "S(1)}" in t for t in tables), tables

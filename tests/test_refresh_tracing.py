@@ -116,7 +116,7 @@ class TestRefreshTrace:
         ]
         _assert_nested(tb)
         assert tb.counts[0] == {
-            "model": "graphsage", "epochs": 2, "slots": 5, "batch_slots": 1, "fused": 1,
+            "model": "graphsage", "loss": "mse+bce", "epochs": 2, "slots": 5, "batch_slots": 1, "fused": 1,
         }
         by_name = {tb.spans[i][0]: c for i, c in tb.counts.items()}
         assert by_name["refresh.pos_weight"]["slots"] == 5

@@ -23,9 +23,9 @@ accelerator (every child gets JAX_PLATFORMS=tpu, so JAX itself raises).
                       50k-edge / 24-slot graph, saves and restores an
                       orbax checkpoint, forecast_forward answers from it
   D  kernels          segment_stats_matmul through window_stats, and the
-                      refresh's planned neighbour sum and planned
-                      attention over an edge plan, compiled by Mosaic
-                      and compared with their XLA twins
+                      refresh's planned neighbour sum, planned attention
+                      and planned gated sum over an edge plan, compiled
+                      by Mosaic and compared with their XLA twins
 
 One process per chip: this parent never imports JAX (it imports
 kmamiz_tpu.synth, which is JAX-free, and the stdlib); it serves a stub
@@ -1306,6 +1306,28 @@ def child_kernels(sizes: dict) -> None:
         scale = max(float(np.abs(want).max()), 1.0)
         np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
     out["planned_attention_w124"] = out["planned_attention"]
+
+    # -- STLGT's gated neighbour sum over the same plan (PR 33): both walks,
+    # value and the gradients to q, k, v and b_edge, against the XLA items
+    from kmamiz_tpu.ops import sparse_gated
+
+    qkv = jnp.asarray(rng.normal(size=(3, nodes, feat)).astype(np.float32))
+    b_edge = jnp.asarray([0.3], jnp.float32)
+
+    def gated(impl):
+        bias, pull = jax.vjp(
+            lambda q, k, v, b: sparse_gated.planned_gated_sum(plan, q, k, v, b, impl),
+            qkv[0], qkv[1], qkv[2], b_edge,
+        )
+        return [np.asarray(a) for a in (bias, *pull(ct))]
+
+    for name, got, want in zip(
+        ("gated", "gated.d_q", "gated.d_k", "gated.d_v", "gated.d_b"),
+        gated(kernel_impl), gated("xla"),
+    ):
+        scale = max(float(np.abs(want).max()), 1.0)
+        np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
+    out["planned_gated_sum"] = out["planned_attention"]
 
     # -- the width the epoch block's slot group sums at (PR 29): seven slots'
     # 18 features side by side. Mosaic against the XLA items, and every

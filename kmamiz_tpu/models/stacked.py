@@ -257,7 +257,7 @@ def _edge_plan(dataset, src, dst, e_mask, nb: int):
             blocks=blocks,
             entries_out=entries - entries_in,
             entries_in=entries_in,
-            runs=runs,
+            runs=runs, mxu_products=sparse.route_stats()["mxu_products"],
         )
     counts = (entries, items, blocks, runs)
     _PLAN_MEMO.append((*key, nb, plan, counts))

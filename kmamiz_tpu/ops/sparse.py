@@ -71,8 +71,8 @@ _backend_cache: Optional[str] = None
 
 _route_lock = threading.Lock()
 #: reductions over an edge plan since process start (trace-time counts:
-#: the consumers decide inside their jit traces)
-_route_counts = {"planned": 0, "attention": 0}
+#: the consumers decide inside their jit traces); `_walk_call`'s products
+_route_counts = {"planned": 0, "attention": 0, "mxu_products": {}}
 
 
 def backend() -> str:
@@ -96,7 +96,7 @@ def reset_for_tests() -> None:
     global _backend_cache
     _backend_cache = None
     with _route_lock:
-        _route_counts.update(planned=0, attention=0)
+        _route_counts.update(planned=0, attention=0, mxu_products={})
 
 
 def use_sparse() -> bool:
@@ -105,7 +105,7 @@ def use_sparse() -> bool:
 
 
 def route_stats() -> dict:
-    """Backend selection and the planned reductions' counters (/timings)."""
+    """Backend, the planned reductions' counters, the walks' MXU products (/timings)."""
     with _route_lock:
         counts = dict(_route_counts)
     return {"backend": backend(), **counts}
@@ -417,11 +417,26 @@ def planned_neighbor_sum(plan: EdgePlan, h: jnp.ndarray, impl: Optional[str] = N
 #   forward pass is the state after `_sum` (z and alpha) and the float32
 #   messages, once;
 # - `_expand` sends the three pieces of a row table through the one-hot in
-#   one pass, stacked, and a weight tile is selected from the split of its
-#   row (`_weights3`); the neighbour's scalars come out of the float32 block
+#   one pass, stacked; the neighbour's scalars come out of the float32 block
 #   by a transposition, which the MXU took twelve tile loads for;
-# - every product whose terms are summed in the MXU (`_reduce`, `_dot6`)
-#   keeps its passes and their order: stacking those changes the bits;
+# - a WEIGHTED sum of gathered rows (`_weighted_sum`: `_sum`'s out and
+#   `_backward`'s d hw here, three more in ops/sparse_gated.py) multiplies on
+#   the VPU, one float32 multiplication an element as the oracle and the
+#   plain references make it, and sends only the one-hot through the MXU,
+#   against the exact split of the product: three passes. Until PR 34 the
+#   weight went through the MXU too, as a split [tile, block] tile, in six
+#   (a term was then kept to 2^-24 of the SUM; now it is rounded once to
+#   float32, as the reference's is). The rows meet their weights transposed
+#   (a lane an entry, so the [1, block] weight row broadcasts along sublanes):
+#   `_backward` transposes its block anyway, `_sum` now does. GAT's two sums
+#   leave their walks as they come out of `_reduce`, [lanes, nodes], and XLA
+#   transposes them once: the tile transposed back in every item cost 0.2 ms
+#   a walk, and XLA's glue around a [nodes, lanes] output 2 ms a layer more
+#   (PERF.md, PR 34; the gated walks transpose back in the item: there XLA's
+#   part grew). The weight as a column against untransposed rows cost more;
+# - the per-entry DOT products (`_dot6`: `_edge_dot`'s, `_backward`'s and the
+#   gated walks' `dots`) keep their six passes, and `_reduce` its three and
+#   their order: stacking those changes the bits;
 # - a float32 block is split where it is used, once an item. Splitting it
 #   once a block or a tile into VMEM, or once a layer into HBM, measured
 #   SLOWER on the v5e (the split hides behind the MXU; a conditional region
@@ -449,7 +464,15 @@ _NN = (((1,), (0,)), ((), ()))  # [m, k] @ [k, n]
 _NT = (((1,), (1,)), ((), ()))  # [m, k] @ [n, k]^T
 
 
+class _MxuCalls(threading.local):
+    n = 0  # `_mxu` calls this thread has traced: `_walk_call` reads it around a kernel
+
+
+_mxu_calls = _MxuCalls()
+
+
 def _mxu(a, b, dims):
+    _mxu_calls.n += 1
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
@@ -510,15 +533,15 @@ def _rows_by_direction(d, x):
     return _rows(jnp.where(d == 0, x, 0.0), jnp.where(d == 1, x, 0.0))
 
 
-def _weights3(one_hot, row):
-    """`_split3(where(one_hot, row, 0))`, the three pieces of a [tile, block]
-    weight tile, from the split of the [1, block] row: the split is
-    elementwise and `_split3(0)` is 0, so a piece of the row placed under the
-    mask is that piece of the tile, in six passes over the tile for eight."""
-    return tuple(
-        jnp.where(one_hot, piece.astype(jnp.float32), 0.0).astype(jnp.bfloat16)
-        for piece in _split3(row)
-    )
+def _weighted_sum(rows_t, weight, hot):
+    """[R, block] gathered rows, transposed (a lane an entry), and their
+    [1, block] weights -> [R, tile]: each node's sum of `weight_e * row_e`
+    over its entries. The product is one float32 multiplication an element on
+    the VPU, as `_attention_xla` and the plain references make it; only the
+    one-hot, exact in bfloat16, goes through the MXU, against the exact split
+    of the product: `_reduce`'s three passes, where a split [tile, block]
+    weight tile against the split rows spent six (until PR 34)."""
+    return _reduce(rows_t * weight, hot)
 
 
 def plan_blocks(plan: EdgePlan, items: int) -> int:
@@ -665,7 +688,7 @@ def _attention_sum_kernel(
         total = jnp.maximum(_by_direction(d, own[0:1], own[1:2]), 1e-30)
         alpha = jnp.where(inside, _row(state_ref, ROW_P) / total, 0.0)
         _add_row(next_ref, ROW_ALPHA, alpha)
-        out_ref[...] += _dot6(_weights3(one_hot, alpha), _split3(msg_ref[...]), _NN)
+        out_ref[...] += _weighted_sum(msg_ref[...].T, alpha, hot)
 
 
 def _attention_edge_dot_kernel(
@@ -693,8 +716,9 @@ def _attention_backward_kernel(
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
-        msg3 = _split3(msg_ref[...])  # g of the neighbour, then its t, max, sum, c
-        nbr = _neighbour_rows(msg_ref, width)
+        msg = msg_ref[...]  # g of the neighbour, then its t, max, sum, c
+        msg_t = msg.T
+        nbr = msg_t[width : width + ATT_ROWS, :]
         own = _expand(nrow_ref[...], hot)  # s and c of the owner, and a row of ones
         inside = own[4:5] > 0.5
         # this entry's own softmax: d z = alpha (d alpha - c) leaky'(z)
@@ -711,7 +735,7 @@ def _attention_backward_kernel(
         cm = _by_direction(d, nbr[7:8], nbr[6:7])
         pm = jnp.exp(jnp.clip(_leaky(zm, leak) - shift, -60.0, 0.0))
         alpha_m = jnp.where(inside, pm / total, 0.0)
-        dots = _dot6(_split3(hw_ref[...]), msg3, _NT)  # <hw[owner], g[neighbour]>
+        dots = _dot6(_split3(hw_ref[...]), _split3(msg), _NT)  # <hw[owner], g[neighbour]>
         dalpha_m = jnp.sum(jnp.where(one_hot, dots, 0.0), axis=0, keepdims=True)
         dzm = alpha_m * (dalpha_m - cm) * jnp.where(zm >= 0, 1.0, leak)
         # the mirror's direction is 1 - d: its d s lands in the other row
@@ -722,7 +746,7 @@ def _attention_backward_kernel(
             ),
             hot,
         )
-        dhw_ref[...] += _dot6(_weights3(one_hot, alpha_m), msg3, _NN)
+        dhw_ref[...] += _weighted_sum(msg_t, alpha_m, hot)
 
 
 def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bool):
@@ -757,8 +781,14 @@ def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bo
     def lanes_of(kind, a):
         return a.shape[0] if kind in ("entry", "node_rows") else a.shape[1]
 
+    def counted(*refs):
+        before = _mxu_calls.n
+        kernel(*refs)
+        with _route_lock:  # a new dict: `route_stats()` hands out the old one
+            _route_counts["mxu_products"] = {**_route_counts["mxu_products"], name: _mxu_calls.n - before}
+
     return pl.pallas_call(
-        kernel,
+        counted,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(plan.item_tile.shape[0],),
@@ -854,10 +884,10 @@ def _attention_pallas_fwd(plan: EdgePlan, hw, s, t, leak: float, interpret: bool
             ("node_rows", _node_rows(nodes, total[:, 0], total[:, 1])),
             ("message", msg),
         ],
-        [("entry", ATT_ROWS), ("node", lanes)], interpret,
+        [("entry", ATT_ROWS), ("node_rows", lanes)], interpret,
     )
     saved = (hw, s, t, msg, state, top, total)
-    return out[:n, :width].astype(hw.dtype), saved
+    return out[:width, :n].T.astype(hw.dtype), saved
 
 
 def _attention_pallas_bwd(plan: EdgePlan, leak: float, interpret: bool, saved, g):
@@ -880,11 +910,11 @@ def _attention_pallas_bwd(plan: EdgePlan, leak: float, interpret: bool, saved, g
             ("node", _node_table(nodes, lanes, hw)),
             ("node_rows", _node_rows(nodes, s[:, 0], s[:, 1], c[:, 0], c[:, 1])),
         ],
-        [("node", lanes), ("node_rows", ATT_ROWS)], interpret,
+        [("node_rows", lanes), ("node_rows", ATT_ROWS)], interpret,
     )
     dst = dst[:4, :n].T
     return (
-        dhw[:n, :width].astype(hw.dtype),
+        dhw[:width, :n].T.astype(hw.dtype),
         dst[:, 0:2].astype(s.dtype),
         dst[:, 2:4].astype(t.dtype),
     )

@@ -38,11 +38,18 @@ Mosaic kernel that the device trace names:
   of the neighbour meets the owner's q where the owner calls, q meets k where
   it is called), six bfloat16 passes of depth 128, then the one-hot picks each
   entry's owner.
-  No bfloat16 ROW enters it or the weighted sums: a float32 value goes as
-  three bfloat16 pieces whose products are summed in float32
-  (`sparse._dot6`), because neither is a matrix product of the model (a gated
-  sum fed bfloat16 rows moves the first slot's loss by what
-  `benchmarks/reference/stlgt.py` records).
+  No bfloat16 ROW enters it or the weighted sums, because neither is a matrix
+  product of the model (a gated sum fed bfloat16 rows moves the first slot's
+  loss by what `benchmarks/reference/stlgt.py` records). The dot product's
+  operands go as three bfloat16 pieces each, their products summed in float32
+  (`sparse._dot6`, six passes). A weighted sum (`num`, `d v`, `[d q | d k]`)
+  multiplies on the VPU, one float32 multiplication an element as the
+  reference makes it, and only the one-hot, exact in bfloat16, goes through
+  the MXU, against the exact split of the product (`sparse._weighted_sum`,
+  three passes; six until PR 34, when the weight went through the MXU too).
+  `num` and `d v` are transposed back tile by tile in the walk: left as node
+  rows for XLA to transpose, as GAT's sums are, the walks were 0.15 ms
+  shorter and XLA's part 0.43 ms longer a slot update (PERF.md, PR 34).
 - The transposed sums need no permutation: every entry has a mirror, the same
   edge seen from its other end, with the same gate. `d v[j]`, a sum over the
   entries whose NEIGHBOUR is j, is the sum over the entries OWNED by j of
@@ -51,9 +58,9 @@ Mosaic kernel that the device trace names:
   at hand in either entry's item: one product of `[v | g]` of the tile against
   `[g | v]` of the block (the lanes `g` leaves free are those `v` was gathered
   into). `d q[o]` sums `d a_e * k[n]` over o's out-entries
-  and `d k[o]` sums `d a_e * q[n]` over its in-entries: ONE product of the
-  block's `_by_half` rows against the weights, written
-  transposed (`[2 halves, nodes]`), so no second pass over the messages.
+  and `d k[o]` sums `d a_e * q[n]` over its in-entries: ONE weighted sum of
+  the block's `_by_half` rows, written transposed (`[2 halves, nodes]`), so
+  no second pass over the messages.
   `d b_edge` is the sum of `d a_e` over the out-entries, one per edge.
 
 Off the TPU the same mathematics is plain XLA over the plan's sorted entries
@@ -87,7 +94,6 @@ from kmamiz_tpu.ops.sparse import (
     _expand,
     _gather_rows,
     _item,
-    _neighbour_rows,
     _node_rows,
     _node_tiles,
     _pad_to,
@@ -96,7 +102,7 @@ from kmamiz_tpu.ops.sparse import (
     _rows,
     _split3,
     _walk_call,
-    _weights3,
+    _weighted_sum,
 )
 
 #: rows of the entries' state that these walks write or read: the gate
@@ -169,7 +175,7 @@ def _gated_sum_kernel(
         a = _picked(one_hot, dots) * scale + _row(state_ref, ROW_B)
         gate = jnp.where(inside, 1.0 / (1.0 + jnp.exp(-a)), 0.0)
         _add_row(next_ref, ROW_GATE, gate)
-        num_ref[...] += _dot6(_weights3(one_hot, gate), _split3(nv_ref[...]), _NN)
+        num_ref[...] += _weighted_sum(nv_ref[...].T, gate, hot).T
         den_ref[...] += _reduce(_rows(gate), hot)
 
 
@@ -184,18 +190,17 @@ def _gated_backward_kernel(
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
         ng = ng_ref[...]  # [g | g_den] of the neighbour; `nv_ref` holds its [0 | v]
+        ng_t = ng.T
         lane = jax.lax.broadcasted_iota(jnp.int32, ng.shape, 1)
         # <v[o], g[n]> + <g[o], v[n]>: the entry's d gate and its mirror's
         dots = _dot6(_split3(vg_ref[...]), _split3(jnp.where(lane < half, ng, nv_ref[...])), _NT)
-        den = _expand(grow_ref[...], hot)[0:1] + _neighbour_rows(ng_ref, half)[0:1]  # g_den[o] + g_den[n]
+        den = _expand(grow_ref[...], hot)[0:1] + ng_t[half : half + 1, :]  # g_den[o] + g_den[n]
         gate = _row(state_ref, ROW_GATE)
         da = jnp.where(inside, (_picked(one_hot, dots) + den) * gate * (1.0 - gate), 0.0)
         _add_row(next_ref, ROW_DA, da)
-        dv_ref[...] += _dot6(_weights3(one_hot, gate), _split3(ng), _NN)
+        dv_ref[...] += _weighted_sum(ng_t, gate, hot).T
         # [d q | d k] of the tile, transposed
-        dqk_ref[...] += _dot6(
-            _split3(_by_half(nqk_ref[...], d, half)), _weights3(one_hot, da * scale), _NT
-        )
+        dqk_ref[...] += _weighted_sum(_by_half(nqk_ref[...], d, half), da * scale, hot)
 
 
 def _shapes(plan: EdgePlan, width: int) -> Tuple[int, int, float]:

@@ -401,7 +401,9 @@ class TestChipSmoke:
         assert d["segment_stats_matmul"] == d["planned_neighbor_sum"] == "interpret"
         assert d["planned_attention"] == d["planned_neighbor_sum_w126"] == "interpret"
         assert d["planned_attention_w124"] == "interpret"  # the spare lanes past the first 128
-        assert set(d["routes"]) == {"backend", "planned", "attention"}
+        assert d["planned_gated_sum"] == "interpret"  # STLGT's two walks against the XLA items
+        assert set(d["routes"]) == {"backend", "planned", "attention", "mxu_products"}
+        assert d["routes"]["mxu_products"]["planned_attention_sum"] == 4  # three passes and the expand
         assert d["routes"]["planned"] > d["routes"]["attention"] > 0
         assert not [k for k in d if "fused" in k]
         assert len(a["graph"]["signature"]) == 64

@@ -142,12 +142,16 @@ def _three_by_three_graph():
 _GRAPHS = {"hub": _hub_graph, "shared_block": _shared_block_graph, "three_by_three": _three_by_three_graph}
 
 #: sha256 (first 16 hex digits) of the bytes of `planned_attention`'s value and
-#: of its gradients to hw, s and t, interpreted kernels on the CPU, recorded on
-#: the code of PR 30 (commit 89811fd) BEFORE PR 31 touched a kernel: what the
-#: walks fetch, stack or transpose may change, the bits may not
-_PARENT_DIGESTS = {
-    "hub": ("2ba86974ebb01028", "3f72998f822aee43", "776b5f7784021467", "85c2effe97a90e06"),
-    "shared_block": ("5358e1ebef0a3839", "727cf208a09012be", "b52ebe1373d0c361", "1fac167762b54cfd"),
+#: of its gradients to hw, s and t, interpreted kernels on the CPU. Those of
+#: d s and d t were recorded on the code of PR 30 (commit 89811fd) BEFORE PR 31
+#: touched a kernel and have stood since: what makes them (`alpha`, `d alpha`,
+#: `c`, `_reduce`) keeps its bits. Those of the value and of d hw are PR 34's,
+#: recorded on its final tree (parent commit 014ad83): their weighted sums
+#: multiply on the VPU and send the product through the one-hot in three
+#: passes, so a term is rounded once to float32, as the oracle's is
+_DIGESTS = {
+    "hub": ("aebc210415e35939", "c5139565ff963696", "776b5f7784021467", "85c2effe97a90e06"),
+    "shared_block": ("8e60f886afeae48d", "583ee12ca7e2e9fe", "b52ebe1373d0c361", "1fac167762b54cfd"),
 }
 _DIGEST_SEEDS = {"hub": 21, "shared_block": 22}
 
@@ -163,12 +167,56 @@ def _direct(graph, seed, impl, width=WIDTH):
     return [np.asarray(x) for x in (out, *pull(ct))]
 
 
+def _float64_value_and_d_hw(graph, seed, width=WIDTH):
+    """`_direct`'s value and d hw in float64 numpy, entry by entry: the
+    softmax of each (owner, direction) run, `out[i] = sum alpha_e hw[j]` and
+    `d hw[j] = sum alpha_e ct[i]` over the entries (i, j, d)."""
+    src, dst, mask, nb = graph
+    plan, entries, _ = sparse.build_edge_plan(src, dst, mask, nb)
+    rng = np.random.default_rng(seed)
+    hw, ct = (rng.normal(size=(nb, width)).astype(np.float32).astype(np.float64) for _ in range(2))
+    s, t = (rng.normal(size=(nb, 2)).astype(np.float32).astype(np.float64) for _ in range(2))
+    own, nbr, d = plan.owner[0, :entries], plan.neighbour[:entries], plan.direction[0, :entries]
+    z = s[nbr, d] + t[own, d]
+    score = np.where(z >= 0, z, np.float64(np.float32(0.2)) * z)
+    run = own.astype(np.int64) * 2 + d
+    top = np.full(2 * nb, -np.inf)
+    np.maximum.at(top, run, score)
+    p = np.exp(np.clip(score - top[run], -60.0, 0.0))
+    total = np.zeros(2 * nb)
+    np.add.at(total, run, p)
+    alpha = p / total[run]
+    out, d_hw = np.zeros((nb, width)), np.zeros((nb, width))
+    np.add.at(out, own, alpha[:, None] * hw[nbr])
+    np.add.at(d_hw, nbr, alpha[:, None] * ct[own])
+    return out, d_hw
+
+
 class TestTheKernelsKeepTheirBits:
-    @pytest.mark.parametrize("name", sorted(_PARENT_DIGESTS))
+    @pytest.mark.parametrize("name", sorted(_DIGESTS))
     def test_value_and_gradients_reproduce_the_digests_recorded_on_the_parent(self, name):
         got = _direct(_GRAPHS[name](), _DIGEST_SEEDS[name], "pallas_interpret")
         digests = tuple(hashlib.sha256(x.tobytes()).hexdigest()[:16] for x in got)
-        assert digests == _PARENT_DIGESTS[name]
+        assert digests == _DIGESTS[name]
+
+    @pytest.mark.parametrize("name", ("hub", "shared_block", "three_by_three", "zipf_heavy"))
+    def test_the_weighted_sums_are_as_close_to_a_float64_sum_as_the_oracles(self, name):
+        """The value and d hw, whose products are float32 multiplications on
+        the VPU summed through the one-hot: against the same sums in float64
+        they are no further off than `_attention_xla`'s (largest and root
+        mean square), and the two agree. The oracle adds a run's terms one
+        after another, so over the 1,124 in-entries of `three_by_three`'s hub
+        its own d hw is 2.5e-6 off: hence 5e-6 between the two there."""
+        graph = _GRAPHS[name]() if name in _GRAPHS else _case(name)
+        seed = 24
+        kernel, xla = (_direct(graph, seed, impl) for impl in ("pallas_interpret", "xla"))
+        exact = _float64_value_and_d_hw(graph, seed)
+        for k, x, want, what, agree in zip(kernel, xla, exact, ("value", "d hw"), (2e-6, 5e-6)):
+            _close(k, want, 2e-6, what)
+            _close(k, x, agree, what)
+            off_kernel, off_xla = (np.abs(a.astype(np.float64) - want) for a in (k, x))
+            assert off_kernel.max() <= off_xla.max(), what
+            assert np.sqrt((off_kernel**2).mean()) <= np.sqrt((off_xla**2).mean()), what
 
     def test_a_block_met_by_three_tiles_and_a_tile_that_meets_three_blocks(self):
         graph = _three_by_three_graph()
@@ -337,6 +385,33 @@ class TestPlannedAttention:
         sparse.reset_for_tests()
         assert sparse.route_stats()["attention"] == 0
 
+    def test_route_stats_counts_the_mxu_products_of_each_of_the_seven_walks(self):
+        """`_mxu` calls of each walk's kernel when it was last traced: an
+        expand is one, a `_reduce` three, a per-entry dot product (`_dot6`)
+        six, and since PR 34 a weighted sum three where it was six. The three
+        `dot`s of `planned_neighbor_sum`'s kernel are its own and not counted."""
+        from kmamiz_tpu.ops import sparse_gated
+
+        _src, _dst, _mask, nb, plan = _plan("padded")
+        assert sparse.route_stats()["mxu_products"] == {}
+        hw, s, t = jnp.ones((nb, 4)), jnp.zeros((nb, 2)), jnp.zeros((nb, 2))
+        attention = lambda h, s_, t_: sparse.planned_attention(plan, h, s_, t_, 0.2, "pallas_interpret").sum()  # noqa: E731
+        jax.eval_shape(attention, hw, s, t)
+        forward = {"planned_attention_max": 1, "planned_attention_softmax": 4, "planned_attention_sum": 4}
+        assert sparse.route_stats()["mxu_products"] == forward
+        jax.eval_shape(jax.grad(attention, argnums=(0, 1, 2)), hw, s, t)
+        gat_walks = {**forward, "planned_attention_edge_dot": 9, "planned_attention_backward": 13}
+        assert sparse.route_stats()["mxu_products"] == gat_walks
+        gated = lambda q, k, v, b: sparse_gated.planned_gated_sum(plan, q, k, v, b, "pallas_interpret").sum()  # noqa: E731
+        jax.eval_shape(jax.grad(gated, argnums=(0, 1, 2, 3)), hw, hw, hw, jnp.zeros((1,)))
+        all_seven = {**gat_walks, "planned_gated_sum": 12, "planned_gated_backward": 13}
+        assert sparse.route_stats()["mxu_products"] == all_seven
+        jax.eval_shape(lambda h: sparse.planned_neighbor_sum(plan, h, "pallas_interpret"), hw)
+        sparse.planned_attention(plan, hw, s, t, 0.2, "xla")  # XLA's formulation traces no kernel
+        assert sparse.route_stats()["mxu_products"] == all_seven
+        sparse.reset_for_tests()
+        assert sparse.route_stats()["mxu_products"] == {}
+
     def test_no_scatter_and_no_1d_gather_over_the_entries_in_the_kernel_path(self):
         """What the TPU runs, lowered here with the kernels interpreted: the
         interpreter's own loops aside, the glue XLA is left with holds two
@@ -474,3 +549,20 @@ class TestGatTrainingThroughThePlan:
         assert plan_counts["entries_out"] == plan_counts["entries_in"] == int(real.sum())
         assert plan_counts["runs"] == runs and noted["refresh.stack"]["plan_runs"] == runs
         assert plan_counts["blocks"] == noted["refresh.stack"]["plan_blocks"] == st.plan_blocks
+        assert plan_counts["mxu_products"] == {}  # no walk traced yet in this process
+
+    def test_the_plan_span_carries_the_walks_mxu_products_as_last_traced(self):
+        """The count is static, so it is one at trace time: a process that
+        has traced walks before it builds a plan finds their products on the
+        plan's span, beside the entries they will walk."""
+        _src, _dst, _mask, nb, plan = _plan("padded")
+        hw, st_ = jnp.ones((nb, 4)), jnp.zeros((nb, 2))
+        jax.eval_shape(lambda h: sparse.planned_attention(plan, h, st_, st_, 0.2, "pallas_interpret"), hw)
+        stacked.stack_dataset(_dataset())
+        noted = [
+            dict(tb.counts.get(i, {}))
+            for tb in TRACER.traces() for i, span in enumerate(tb.spans) if span[0] == "refresh.stack.plan"
+        ]
+        assert noted[-1]["mxu_products"] == sparse.route_stats()["mxu_products"] == {
+            "planned_attention_max": 1, "planned_attention_softmax": 4, "planned_attention_sum": 4,
+        }

@@ -13,12 +13,12 @@ import jax.numpy as jnp
 import optax
 
 
-def make_loss_fn(forward, pos_weight: float = 1.0):
-    """Masked MSE (latency) + masked sigmoid BCE (anomaly) over a head's
-    forward function. pos_weight scales the positive-class BCE term —
-    anomalies are rare (a few fault-window slots per day), and unweighted
-    BCE drives the head into predicting the base rate, never crossing any
-    useful threshold."""
+def make_loss_fn(forward, pos_weight: float = 1.0, axis_name=None):
+    """Masked MSE (latency) + masked sigmoid BCE (anomaly) over a head's forward. pos_weight scales
+    the positive-class BCE term: anomalies are rare (a few fault-window slots per day), and unweighted
+    BCE drives the head into predicting the base rate, never crossing any useful threshold.
+    `axis_name`: each device of that mesh axis holds a share of the nodes (`_over`, below)."""
+    share, total = _over(axis_name)
 
     def loss_fn(
         params,
@@ -31,18 +31,18 @@ def make_loss_fn(forward, pos_weight: float = 1.0):
         node_mask,
     ):
         pred_latency, anomaly_logit = forward(
-            params, features, src_ep, dst_ep, edge_mask
+            share(params), features, src_ep, dst_ep, edge_mask
         )
         w = node_mask.astype(jnp.float32)
-        denom = jnp.maximum(w.sum(), 1.0)
-        latency_loss = jnp.sum(w * (pred_latency - target_latency) ** 2) / denom
+        denom = jnp.maximum(total(w.sum()), 1.0)
+        latency_loss = total(jnp.sum(w * (pred_latency - target_latency) ** 2)) / denom
         class_w = 1.0 + (pos_weight - 1.0) * target_anomaly
         anomaly_loss = (
-            jnp.sum(
+            total(jnp.sum(
                 w
                 * class_w
                 * optax.sigmoid_binary_cross_entropy(anomaly_logit, target_anomaly)
-            )
+            ))
             / denom
         )
         return latency_loss + anomaly_loss, (latency_loss, anomaly_loss)
@@ -102,3 +102,17 @@ def make_train_step(optimizer, loss_fn):
         return params, opt_state, loss, aux
 
     return train_step
+
+
+def _over(axis_name):
+    """(share, total) of a loss whose nodes are cut over the devices of a mesh
+    axis: `share` hands a device the replicated parameters and sums the
+    devices' gradients, `total` adds the devices' partial sums and counts, so
+    a masked mean over nodes is total(sum) / total(count), the same number on
+    every device, and its gradient the whole one. With no axis both are the
+    identity and the loss traces to what it always did."""
+    if axis_name is None:
+        return (lambda x: x), (lambda x: x)
+    from kmamiz_tpu.parallel import mesh
+
+    return partial(mesh.shared, axis=axis_name), partial(mesh.total, axis=axis_name)

@@ -249,6 +249,10 @@ from kmamiz_tpu.models import common as _common  # noqa: E402
 
 loss_fn = _common.make_loss_fn(forward)  # unweighted default
 make_optimizer = _common.make_optimizer
+#: the fused trainer may cut this head's history by nodes over a mesh
+#: (models/stacked.py): every reduction over the graph is a planned sum, which
+#: all-gathers its table, and the loss is the family's, which sums over the axis
+TAKES_NODE_SHARDS = True
 
 
 def make_train_step(optimizer, pos_weight: float = 1.0):

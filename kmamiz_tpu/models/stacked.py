@@ -60,6 +60,31 @@ device-native:
   may: the head says its `forward` takes the sum (`TAKES_NEIGHBOR_SUM_1`),
   the parameters hold no node embedding, and there is a plan; every other
   call runs the flat scan.
+- the NODE SHARDS: a history that one device cannot hold is cut by NODES
+  over the local devices (`parallel/mesh.node_shards`: the fewest power of
+  two whose share is at most half a device's memory; one wherever one
+  holds it, and then everything here is what it was). The rule is the
+  data's: `stack_dataset` weighs the stack against the device and no caller
+  says anything. Each device gets its rows of every slot `[S, Nb / D, ..]`,
+  filled and handed over shard by shard (the whole never lies on one device,
+  nor twice on the host), and ONE edge plan: the entries whose owner it
+  holds (`sparse.build_shard_plans`; the node ranges are cut where the
+  entries divide evenly). Datasets over one graph share a plan and with it
+  its layout, so the head of a sharded history is sharded as it is.
+  `node_sharded_epoch_runner` runs the one-device block's own body under
+  `shard_map` over the `nodes` axis: parameters, optimizer state and losses
+  replicated, a layer's neighbour table all-gathered in float32
+  (`sparse.sharded_neighbor_sum`), the loss's sums and counts and the
+  parameter gradients summed over the devices (`common.make_loss_fn`'s
+  `axis_name`). The schedule does not change: one update a slot, in slot
+  order, over all endpoints; the slot group works on the sharded table.
+  GraphSAGE says it can (`TAKES_NODE_SHARDS`); the heads that do not yet
+  are refused by name (`trainer.train`), as are the vmapped paths.
+  What crosses between devices in a slot update is layer 2's table and its
+  cotangent, `[Nb, hidden]` float32 each, the slot group's `[Nb, G * F]`
+  once a group, and a sum of the parameter gradients; the features, the
+  targets and the plans never move. A shard's `refresh.stack.device_put`
+  ends with its transfer, so the host holds one shard's copy at a time.
 
 Bit discipline: with the default batch size of 1 the scan body performs
 the identical per-slot update sequence as the legacy Python loop; only
@@ -71,17 +96,20 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kmamiz_tpu.core import programs
 from kmamiz_tpu.core.spans import _pad_size
 from kmamiz_tpu.models import common
 from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.parallel import mesh as mesh_mod
 from kmamiz_tpu.telemetry.registry import REGISTRY
 from kmamiz_tpu.telemetry.tracing import TRACER, operation_span, phase_span
 
@@ -109,9 +137,9 @@ def _resolve_epoch_runner(key: str):
     rebuild the jitted epoch block for a persisted training config."""
     import importlib
 
-    mod, lr, pw = key.split("|")
-    if not mod.startswith("kmamiz_tpu.models."):
-        return None
+    mod, lr, pw, *nodes = key.split("|")
+    if nodes or not mod.startswith("kmamiz_tpu.models."):
+        return None  # a node-sharded block is bound to its mesh: no replay from a hint
     return epoch_runner(importlib.import_module(mod), float(lr), float(pw))
 
 
@@ -157,11 +185,15 @@ class StackedDataset:
     num_edges: int  # real E (<= bucket_edges)
     bucket_nodes: int
     bucket_edges: int
-    plan: Optional[sparse.EdgePlan] = None  # of (src, dst, edge_mask)
+    plan: Optional[sparse.EdgePlan] = None  # of (src, dst, edge_mask); sharded: [shards, ...], a device a plan
     plan_entries: int = 0  # real (owner, neighbour) entries: 2 x real edges
     plan_items: int = 0  # real (node tile, edge block) products of one sum
     plan_blocks: int = 0  # edge blocks those items visit: message blocks a walk fetches
     plan_runs: int = 0  # (owner, direction) runs that hold an entry: the softmaxes
+    shards: int = 1  # devices the node axis is cut over (1: everything on one, as ever)
+    node_cuts: Tuple[int, ...] = ()  # [shards + 1]: shard d holds nodes cuts[d] .. cuts[d + 1] - 1
+    #: the `nodes` mesh of a sharded stack, a device a shard; None on one device
+    mesh: Optional[jax.sharding.Mesh] = None
 
     def layout(self) -> dict:
         """The shape contract a checkpoint records (and resume validates):
@@ -200,9 +232,10 @@ def stack_dataset(dataset) -> StackedDataset:
 
     Traced as `refresh.stack` (a trace of its own when called alone, a
     child of `refresh.train` inside a refresh), a build split into
-    `refresh.stack.host_fill`, `refresh.stack.plan` (the edge plan, where
-    no memo holds it) and `refresh.stack.device_put`. The spans end where
-    the calls return: the wait for the transfer is whoever blocks next."""
+    `refresh.stack.plan` (the edge plan, where no memo holds it) and, once a
+    shard, `refresh.stack.host_fill` and `refresh.stack.device_put`. On one
+    device the spans end where the calls return: the wait for the transfer
+    is whoever blocks next; a shard's `device_put` ends with its transfer."""
     with operation_span("refresh.stack"):
         cached = getattr(dataset, "_stacked_cache", None)
         if cached is not None and cached.layout() == dataset_layout(dataset):
@@ -225,44 +258,73 @@ def stack_dataset(dataset) -> StackedDataset:
         return stacked
 
 
-#: edge plans by the identity of the edge arrays they were made from (and
-#: the node bucket): [(src, dst, edge_mask, bucket_nodes, plan, (entries,
-#: items, blocks, runs))], newest last. The arrays are held so that their ids stay
-#: theirs; like the stack's memo it trusts that nobody writes into them.
+#: edge plans by the identity of the edge arrays they were made from, the
+#: node bucket and the shards: [(src, dst, edge_mask, bucket_nodes, _Planned)],
+#: newest last. The arrays are held so that their ids stay theirs; like the
+#: stack's memo it trusts that nobody writes into them.
 _PLAN_MEMO: list = []
 _PLAN_MEMO_SIZE = 4
 
 
-def _edge_plan(dataset, src, dst, e_mask, nb: int):
-    """(device EdgePlan, (entries, items, blocks, runs)) of a dataset's padded
-    edge list. The span counts what the directed head walks: the entries of
-    each direction (an edge out of its owner, an edge into it), the (owner,
-    direction) runs, one softmax each, and the edge blocks the items visit."""
+class _Planned(NamedTuple):
+    """A topology's plan as a stack holds it."""
+
+    plan: sparse.EdgePlan  # on the device; with a leading [shards] axis over the mesh where shards > 1
+    counts: Tuple[int, int, int, int]  # real entries, items, blocks, (owner, direction) runs, over all shards
+    shards: int
+    #: [shards + 1] node indices: shard d holds nodes cuts[d] .. cuts[d + 1] - 1, from row d * (bucket // shards)
+    cuts: Tuple[int, ...]
+
+
+def _edge_plan(dataset, src, dst, e_mask, n: int, nb: int, shards: int) -> _Planned:
+    """The plan of a dataset's padded edge list, for at least `shards` node
+    shards: a memoised plan of this topology decides (the widest), so that
+    datasets over one graph lie on the devices alike. The span counts what
+    the directed head walks: the entries of each direction (an edge out of
+    its owner, an edge into it), the (owner, direction) runs, one softmax
+    each, the edge blocks the items visit, and each shard's share of them."""
     key = (dataset.src, dataset.dst, dataset.edge_mask)
-    for *held, held_nb, plan, counts in _PLAN_MEMO:
-        if held_nb == nb and all(a is b for a, b in zip(held, key)):
-            _PLAN_HITS.inc()
-            return plan, counts
+    held = [
+        planned for *arrays, held_nb, planned in _PLAN_MEMO
+        if held_nb == nb and planned.shards >= shards and all(a is b for a, b in zip(arrays, key))
+    ]
+    if held:
+        _PLAN_HITS.inc()
+        return max(held, key=lambda planned: planned.shards)
     _PLAN_BUILDS.inc()
     with phase_span("refresh.stack.plan"):
-        host_plan, entries, items = sparse.build_edge_plan(src, dst, e_mask, nb)
-        run_key = host_plan.owner[0, :entries] * 2 + host_plan.direction[0, :entries]
-        runs = int(np.count_nonzero(np.diff(run_key))) + 1 if entries else 0
-        entries_in = int(host_plan.direction[0, :entries].sum())
-        blocks = sparse.plan_blocks(host_plan, items)
-        plan = jax.tree_util.tree_map(jnp.asarray, host_plan)
+        host, cuts, entries_by, items_by = sparse.build_shard_plans(src, dst, e_mask, n, nb, shards)
+        runs = entries_in = 0
+        blocks_by = []
+        for d, (entries, items) in enumerate(zip(entries_by, items_by)):
+            one = jax.tree_util.tree_map(lambda a: a[d], host)
+            run_key = one.owner[0, :entries] * 2 + one.direction[0, :entries]
+            runs += int(np.count_nonzero(np.diff(run_key))) + 1 if entries else 0
+            entries_in += int(one.direction[0, :entries].sum())
+            blocks_by.append(sparse.plan_blocks(one, items))
+        if shards == 1:
+            plan = jax.tree_util.tree_map(lambda a: jnp.asarray(a[0]), host)
+        else:
+            over = NamedSharding(mesh_mod.nodes_mesh(shards), P(mesh_mod.NODES_AXIS))
+            plan = jax.tree_util.tree_map(lambda a: jax.device_put(a, over), host)
+        counts = (sum(entries_by), sum(items_by), sum(blocks_by), runs)
         TRACER.note(
-            entries=entries,
-            items=items,
-            blocks=blocks,
-            entries_out=entries - entries_in,
+            entries=counts[0],
+            items=counts[1],
+            blocks=counts[2],
+            entries_out=counts[0] - entries_in,
             entries_in=entries_in,
             runs=runs, mxu_products=sparse.route_stats()["mxu_products"],
         )
-    counts = (entries, items, blocks, runs)
-    _PLAN_MEMO.append((*key, nb, plan, counts))
+        if shards > 1:
+            TRACER.note(
+                shards=shards, shard_entries=list(entries_by), shard_items=list(items_by),
+                shard_blocks=blocks_by, shard_nodes=np.diff(cuts).tolist(),
+            )
+    planned = _Planned(plan, counts, shards, tuple(int(c) for c in cuts))
+    _PLAN_MEMO.append((*key, nb, planned))
     del _PLAN_MEMO[:-_PLAN_MEMO_SIZE]
-    return plan, counts
+    return planned
 
 
 def plan_for(model, stacked: StackedDataset) -> Optional[sparse.EdgePlan]:
@@ -282,13 +344,18 @@ def head_loss_fn(model, pos_weight: float, **forward_args):
     squared error and weighted cross-entropy over `model.forward`
     (`common.make_loss_fn`). `forward_args` (the block's `plan`, a slot
     group's `neighbor_sum_1`) are bound by keyword: to the forward, or to a
-    head's own loss, which hands them to its forward."""
+    head's own loss, which hands them to its forward. Under a
+    `sparse.ShardPlan` the family's loss sums over the plan's mesh axis."""
     make = getattr(model, "make_loss_fn", None)
+    # a `sparse.ShardPlan` names the mesh axis the nodes are cut over
+    axis = getattr(forward_args.get("plan"), "axis", None)
     if make is None:
         forward = model.forward
         if forward_args:
             forward = functools.partial(forward, **forward_args)
-        return common.make_loss_fn(forward, pos_weight)
+        return common.make_loss_fn(forward, pos_weight, axis_name=axis)
+    if axis is not None:
+        raise NotImplementedError(f"{model.__name__}'s own loss knows no mesh axis yet")
     loss_fn = make(pos_weight)
     return functools.partial(loss_fn, **forward_args) if forward_args else loss_fn
 
@@ -317,73 +384,6 @@ def slot_group(model, params, features, plan) -> int:
     n_slots, _, width = features.shape
     group = min(ROW_LANES // max(width, 1), n_slots)
     return group if group > 1 else 0
-
-
-def _build_stack(dataset) -> StackedDataset:
-    s = len(dataset.features)
-    n = dataset.num_nodes
-    f = (
-        int(np.asarray(dataset.features[0]).shape[1])
-        if s
-        else 0
-    )
-    e = int(np.asarray(dataset.src).shape[0])
-    nb, eb = _pad_size(n), _pad_size(e)
-
-    with phase_span("refresh.stack.host_fill"):
-        feats = np.zeros((s, nb, f), dtype=np.float32)
-        t_lat = np.zeros((s, nb), dtype=np.float32)
-        t_ano = np.zeros((s, nb), dtype=np.float32)
-        n_mask = np.zeros((s, nb), dtype=bool)
-        for i in range(s):
-            feats[i, :n] = np.asarray(dataset.features[i], dtype=np.float32)
-            t_lat[i, :n] = np.asarray(dataset.target_latency[i], dtype=np.float32)
-            t_ano[i, :n] = np.asarray(dataset.target_anomaly[i], dtype=np.float32)
-            n_mask[i, :n] = np.asarray(dataset.node_mask[i], dtype=bool)
-
-        src = np.zeros(eb, dtype=np.int32)
-        dst = np.zeros(eb, dtype=np.int32)
-        e_mask = np.zeros(eb, dtype=bool)
-        src[:e] = np.asarray(dataset.src, dtype=np.int32)
-        dst[:e] = np.asarray(dataset.dst, dtype=np.int32)
-        e_mask[:e] = np.asarray(dataset.edge_mask, dtype=bool)
-        nbytes = sum(
-            a.nbytes for a in (feats, t_lat, t_ano, n_mask, src, dst, e_mask)
-        )
-        TRACER.note(bytes=nbytes)
-    plan, (plan_entries, plan_items, plan_blocks, plan_runs) = _edge_plan(
-        dataset, src, dst, e_mask, nb
-    )
-    with phase_span("refresh.stack.device_put"):
-        stacked = StackedDataset(
-            features=jnp.asarray(feats),
-            target_latency=jnp.asarray(t_lat),
-            target_anomaly=jnp.asarray(t_ano),
-            node_mask=jnp.asarray(n_mask),
-            src=jnp.asarray(src),
-            dst=jnp.asarray(dst),
-            edge_mask=jnp.asarray(e_mask),
-            num_slots=s,
-            num_nodes=n,
-            num_edges=e,
-            bucket_nodes=nb,
-            bucket_edges=eb,
-            plan=plan,
-            plan_entries=plan_entries,
-            plan_items=plan_items,
-            plan_blocks=plan_blocks,
-            plan_runs=plan_runs,
-        )
-        TRACER.note(bytes=nbytes)
-    TRACER.note(  # on refresh.stack
-        hit=0,
-        bytes=nbytes,
-        plan_entries=plan_entries,
-        plan_items=plan_items,
-        plan_blocks=plan_blocks,
-        plan_runs=plan_runs,
-    )
-    return stacked
 
 
 # ---------------------------------------------------------------------------
@@ -519,6 +519,199 @@ def epoch_runner(model, lr: float, pos_weight: float):
 
 
 # ---------------------------------------------------------------------------
+# the same epochs over a history sharded by nodes
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=8)
+def node_sharded_epoch_runner(model, lr: float, pos_weight: float, mesh):
+    """`epoch_runner`'s program for a stack cut by nodes over `mesh`: the
+    one-device block's own body, a device a shard, under `shard_map`.
+
+    Takes what that block takes, the stack's arrays and its plan as
+    `stack_dataset` laid them over the mesh (`[S, Nb, ..]` cut along nodes,
+    the plan with a leading device axis), and returns the same: parameters,
+    optimizer state and losses replicated. Inside, a device sees its rows
+    and its plan as a `sparse.ShardPlan`, which is all the body needs to
+    know: the planned sums all-gather their table, the loss adds the
+    devices' sums and counts, the gradients come out summed, so every
+    device makes the same update, one a slot, in slot order. One dispatch a
+    call, registered like the block it wraps (`|nodes<D>` on its key)."""
+    axis, = mesh.axis_names
+    block = epoch_runner(model, lr, pos_weight).fn.__wrapped__
+    rows, whole = P(None, axis), P()
+
+    @functools.partial(
+        jax.jit,
+        static_argnames=("n_epochs", "group"),
+        donate_argnames=("params", "opt_state"),
+    )
+    def sage_epoch_block(
+        params, opt_state, features, target_latency, target_anomaly, node_mask,
+        src, dst, edge_mask, n_epochs: int, plan=None, group: Optional[int] = None,
+    ):
+        if plan is None:
+            raise ValueError("a node-sharded stack trains over its edge plan; none was handed over")
+        if group is None:
+            group = slot_group(model, params, features, plan)
+
+        def shard(params, opt_state, features, target_latency, target_anomaly, node_mask,
+                  src, dst, edge_mask, plan):
+            mine = sparse.ShardPlan(jax.tree_util.tree_map(lambda a: a[0], plan), axis)
+            return block(
+                params, opt_state, features, target_latency, target_anomaly, node_mask,
+                src, dst, edge_mask, n_epochs, mine, group,
+            )
+
+        return shard_map(
+            shard, mesh=mesh,
+            in_specs=(whole, whole, rows, rows, rows, rows, whole, whole, whole, P(axis)),
+            out_specs=whole, check_vma=False,
+        )(params, opt_state, features, target_latency, target_anomaly, node_mask,
+          src, dst, edge_mask, plan)
+
+    return programs.register_instance(
+        "models.sage_epoch_block",
+        f"{model.__name__}|{lr}|{pos_weight}|nodes{mesh.devices.size}",
+        sage_epoch_block,
+    )
+
+
+def runner_for(stacked: StackedDataset, model, lr: float, pos_weight: float):
+    """The epoch block of a stack as it lies: `epoch_runner`'s on one device,
+    `node_sharded_epoch_runner`'s where `stack_dataset` cut it by nodes."""
+    if stacked.mesh is None:
+        return epoch_runner(model, lr, pos_weight)
+    return node_sharded_epoch_runner(model, lr, pos_weight, stacked.mesh)
+
+
+def require_node_shards(stacked: StackedDataset, model, name: str, params, plan) -> None:
+    """Refuse, by name, what cannot train over a node-sharded stack yet;
+    nothing to say about a stack on one device."""
+    if stacked.shards == 1:
+        return
+    where = f"a history cut by nodes over {stacked.shards} devices"
+    if not getattr(model, "TAKES_NODE_SHARDS", False):
+        raise NotImplementedError(
+            f"{name} cannot train over {where} yet: its reductions over the graph "
+            "know no mesh axis (GraphSAGE's do)"
+        )
+    if getattr(params, "embedding", None) is not None:
+        raise NotImplementedError(
+            f"node embeddings cannot train over {where} yet: the embedding table is a "
+            "parameter with a row a node, and parameters are replicated"
+        )
+    if plan is None:
+        raise NotImplementedError(
+            f"{where} trains over its edge plan alone, and KMAMIZ_SPARSE=xla hands none over"
+        )
+
+
+# ---------------------------------------------------------------------------
+# building the stack: the host fill and the hand-over, shard by shard
+# ---------------------------------------------------------------------------
+
+
+def _build_stack(dataset) -> StackedDataset:
+    s = len(dataset.features)
+    n = dataset.num_nodes
+    f = (
+        int(np.asarray(dataset.features[0]).shape[1])
+        if s
+        else 0
+    )
+    e = int(np.asarray(dataset.src).shape[0])
+    nb, eb = _pad_size(n), _pad_size(e)
+
+    src = np.zeros(eb, dtype=np.int32)
+    dst = np.zeros(eb, dtype=np.int32)
+    e_mask = np.zeros(eb, dtype=bool)
+    src[:e] = np.asarray(dataset.src, dtype=np.int32)
+    dst[:e] = np.asarray(dataset.dst, dtype=np.int32)
+    e_mask[:e] = np.asarray(dataset.edge_mask, dtype=bool)
+    # the layout is the data's: the stack's bytes against a device's memory
+    planned = _edge_plan(
+        dataset, src, dst, e_mask, n, nb, mesh_mod.node_shards(s * nb * (4 * f + 9))
+    )
+    shards, cuts = planned.shards, planned.cuts
+    mesh = mesh_mod.nodes_mesh(shards) if shards > 1 else None
+    rows = nb // shards
+    nbytes = src.nbytes + dst.nbytes + e_mask.nbytes
+    parts = []
+    for d in range(shards):
+        lo, hi = cuts[d], cuts[d + 1]
+        with phase_span("refresh.stack.host_fill"):
+            feats = np.zeros((s, rows, f), dtype=np.float32)
+            t_lat = np.zeros((s, rows), dtype=np.float32)
+            t_ano = np.zeros((s, rows), dtype=np.float32)
+            n_mask = np.zeros((s, rows), dtype=bool)
+            for i in range(s):
+                feats[i, : hi - lo] = np.asarray(dataset.features[i], dtype=np.float32)[lo:hi]
+                t_lat[i, : hi - lo] = np.asarray(dataset.target_latency[i], dtype=np.float32)[lo:hi]
+                t_ano[i, : hi - lo] = np.asarray(dataset.target_anomaly[i], dtype=np.float32)[lo:hi]
+                n_mask[i, : hi - lo] = np.asarray(dataset.node_mask[i], dtype=bool)[lo:hi]
+            filled = sum(a.nbytes for a in (feats, t_lat, t_ano, n_mask))
+            nbytes += filled
+            TRACER.note(bytes=filled + (src.nbytes + dst.nbytes + e_mask.nbytes if d == 0 else 0))
+        with phase_span("refresh.stack.device_put"):
+            if mesh is None:
+                parts.append(tuple(jnp.asarray(a) for a in (feats, t_lat, t_ano, n_mask)))
+                TRACER.note(bytes=nbytes)
+            else:
+                # a shard to its device, and there before the next is filled:
+                # the host never holds two, and the span ends with the transfer
+                parts.append(tuple(jax.device_put(a, mesh.devices[d]) for a in (feats, t_lat, t_ano, n_mask)))
+                jax.block_until_ready(parts[-1])
+                TRACER.note(bytes=filled, shard=d)
+            if d == 0:  # the edge list rides with the first: whole on every device of a mesh
+                to = jnp.asarray if mesh is None else functools.partial(jax.device_put, device=NamedSharding(mesh, P()))
+                edges = tuple(to(a) for a in (src, dst, e_mask))
+        del feats, t_lat, t_ano, n_mask
+    if mesh is None:
+        (features, target_latency, target_anomaly, node_mask), = parts
+    else:
+        by_nodes = NamedSharding(mesh, P(None, mesh_mod.NODES_AXIS))
+        features, target_latency, target_anomaly, node_mask = (
+            jax.make_array_from_single_device_arrays((s, nb) + part[0].shape[2:], by_nodes, list(part))
+            for part in zip(*parts)
+        )
+    plan_entries, plan_items, plan_blocks, plan_runs = planned.counts
+    stacked = StackedDataset(
+        features=features,
+        target_latency=target_latency,
+        target_anomaly=target_anomaly,
+        node_mask=node_mask,
+        src=edges[0],
+        dst=edges[1],
+        edge_mask=edges[2],
+        num_slots=s,
+        num_nodes=n,
+        num_edges=e,
+        bucket_nodes=nb,
+        bucket_edges=eb,
+        plan=planned.plan,
+        plan_entries=plan_entries,
+        plan_items=plan_items,
+        plan_blocks=plan_blocks,
+        plan_runs=plan_runs,
+        shards=shards,
+        node_cuts=cuts,
+        mesh=mesh,
+    )
+    TRACER.note(  # on refresh.stack
+        hit=0,
+        bytes=nbytes,
+        plan_entries=plan_entries,
+        plan_items=plan_items,
+        plan_blocks=plan_blocks,
+        plan_runs=plan_runs,
+    )
+    if shards > 1:
+        TRACER.note(shards=shards, nodes_per_shard=rows)
+    return stacked
+
+
+# ---------------------------------------------------------------------------
 # data-parallel epochs (slot microbatches, optionally mesh-sharded)
 # ---------------------------------------------------------------------------
 
@@ -529,6 +722,11 @@ def batch_slots_arrays(
     """Regroup the stacked slot arrays into [n_batches, batch, ...] with a
     per-slot weight array ([n_batches, batch], 0.0 on padding slots) so
     the last partial batch contributes only its real slots."""
+    if stacked.shards > 1:
+        raise NotImplementedError(
+            "slot microbatches regroup the whole stack on one device; a history cut by "
+            f"nodes over {stacked.shards} devices trains one slot an update (batch_slots=1, no mesh)"
+        )
     s = stacked.num_slots
     nb = -(-s // batch)  # ceil
     pad = nb * batch - s
@@ -685,6 +883,10 @@ def predict_all(
     if not len(dataset.features):
         return None
     st = stack_dataset(dataset)
+    if st.shards > 1:
+        raise NotImplementedError(
+            f"no batched forward over a history cut by nodes over {st.shards} devices yet"
+        )
     lat, logit = _batched_forward(model)(
         params, st.features, st.src, st.dst, st.edge_mask
     )

@@ -404,7 +404,7 @@ def train(
                 return runner(p, s, *batched, st.src, st.dst, st.edge_mask, n_ep)
 
         else:
-            runner = stacked_mod.epoch_runner(model, lr, pos_weight)
+            runner = stacked_mod.runner_for(st, model, lr, pos_weight)
             plan = stacked_mod.plan_for(model, st)
             group = stacked_mod.slot_group(model, params, st.features, plan)
 
@@ -423,6 +423,13 @@ def train(
                     plan,
                 )
 
+        # where the stack lies is the stack's own decision (stack_dataset)
+        stacked_mod.require_node_shards(st, model, model_name, params, plan)
+        TRACER.note(
+            shards=st.shards,
+            nodes_per_shard=st.bucket_nodes // st.shards,
+            layout="nodes" if st.shards > 1 else "device",
+        )
         save_every = checkpoint_every if checkpoint_dir else 0
         for e0, e1 in _epoch_blocks(start_epoch, epochs, save_every):
             slot_updates = (e1 - e0) * st.num_slots

@@ -72,7 +72,7 @@ _backend_cache: Optional[str] = None
 _route_lock = threading.Lock()
 #: reductions over an edge plan since process start (trace-time counts:
 #: the consumers decide inside their jit traces); `_walk_call`'s products
-_route_counts = {"planned": 0, "attention": 0, "mxu_products": {}}
+_route_counts = {"planned": 0, "attention": 0, "sharded": 0, "mxu_products": {}}
 
 
 def backend() -> str:
@@ -96,7 +96,7 @@ def reset_for_tests() -> None:
     global _backend_cache
     _backend_cache = None
     with _route_lock:
-        _route_counts.update(planned=0, attention=0, mxu_products={})
+        _route_counts.update(planned=0, attention=0, sharded=0, mxu_products={})
 
 
 def use_sparse() -> bool:
@@ -345,7 +345,7 @@ def _planned_sum(plan: EdgePlan, h, impl: str):
         out = _planned_reduce_xla(plan, messages)
     else:
         out = _planned_reduce_pallas(plan, messages, impl == "pallas_interpret")
-    return out[: h.shape[0]].astype(h.dtype)
+    return out[: min(h.shape[0], plan.degree.shape[0])].astype(h.dtype)  # a shard's plan owns fewer rows than its table holds
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -366,12 +366,12 @@ _planned_sum_vjp.defvjp(_planned_sum_fwd, _planned_sum_bwd)
 
 
 def planned_neighbor_sum(plan: EdgePlan, h: jnp.ndarray, impl: Optional[str] = None):
-    """Sum of neighbour rows over both edge directions, [N, W] -> [N, W]: what
-    `neighbor_mean`'s two gathers, mask multiplies and segment sums make,
-    from a prepared plan. `impl` is for tests and timing; callers leave it
-    to `planned_impl`. Counted in `route_stats()["planned"]` (trace time)."""
+    """Sum of neighbour rows over both edge directions, [N, W] -> [N, W], from a prepared plan
+    (a `ShardPlan`: of this device's rows, below). `impl` is for tests; counted in `route_stats()`."""
     with _route_lock:
         _route_counts["planned"] += 1
+    if isinstance(plan, ShardPlan):
+        return sharded_neighbor_sum(plan, h, impl)
     return _planned_sum_vjp(plan, h, impl or planned_impl())
 
 
@@ -1038,3 +1038,171 @@ def exclusive_cumsum(flags: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(flags.astype(jnp.int32))]
     )
+
+
+# ---------------------------------------------------------------------------
+# the planned neighbour sum over a history sharded by nodes
+# ---------------------------------------------------------------------------
+#
+# Where one device cannot hold a history (models/stacked.py decides), the
+# node axis is cut into `shards` ranges of rows, one a device of a mesh. A
+# device holds the rows of its range of every slot and ONE plan: the entries
+# whose OWNER it holds, the owner as a row of the shard, the neighbour as a
+# row of the whole table. A layer's sum is then an all-gather of the devices'
+# rows into the table (float32, as they are stored: what crosses between
+# chips is the configuration's precision) and the device's own planned sum
+# over it. A is symmetric, so the cotangent of the local rows is the same
+# thing of the cotangent: all-gathered, summed over the SAME per-device plan.
+# No scatter, no reduce-scatter, and nothing but the table crosses.
+#
+# The ranges are cut where the plan's ENTRIES divide evenly, not the nodes: a
+# walk's time goes with its entries, every device waits for the slowest at
+# each all-gather, and endpoints' degrees are heavy-tailed. A range holds at
+# most `bucket_nodes // shards` nodes, its rows start at a multiple of that,
+# and the rows past its nodes are padding that owns nothing.
+
+
+@jax.tree_util.register_pytree_node_class
+class ShardPlan:
+    """One device's EdgePlan inside a `shard_map` over `axis`: what
+    `planned_neighbor_sum` takes where the rows it is given are the device's
+    share of the nodes. The axis is static; the plan's arrays are the leaves."""
+
+    def __init__(self, plan: EdgePlan, axis: str):
+        self.plan, self.axis = plan, axis
+
+    @property
+    def degree(self):
+        return self.plan.degree
+
+    def tree_flatten(self):
+        return (self.plan,), self.axis
+
+    @classmethod
+    def tree_unflatten(cls, axis, children):
+        return cls(children[0], axis)
+
+
+def _sharded_sum(plan: EdgePlan, h, impl: str, axis: str):
+    table = jax.lax.all_gather(h, axis, axis=0, tiled=True)  # [shards * n, W], rows as stored
+    return _planned_sum(plan, table, impl)  # [n, W]: the rows this device owns
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _sharded_sum_vjp(plan: EdgePlan, h, impl: str, axis: str):
+    return _sharded_sum(plan, h, impl, axis)
+
+
+def _sharded_sum_fwd(plan, h, impl, axis):
+    return _sharded_sum(plan, h, impl, axis), plan
+
+
+def _sharded_sum_bwd(impl, axis, plan, g):
+    # A is symmetric: the rows of A @ G that this device owns, G all-gathered
+    return None, _sharded_sum(plan, g, impl, axis)
+
+
+_sharded_sum_vjp.defvjp(_sharded_sum_fwd, _sharded_sum_bwd)
+
+
+def sharded_neighbor_sum(plan: ShardPlan, h: jnp.ndarray, impl: Optional[str] = None):
+    """`planned_neighbor_sum` of a node-sharded table, inside the `shard_map`
+    over `plan.axis`: `h` is this device's rows `[n, W]`, the result the sums
+    of their neighbours' rows wherever those live. One all-gather and one
+    planned sum, forward and backward. Counted in `route_stats()["sharded"]`."""
+    with _route_lock:
+        _route_counts["sharded"] += 1
+    return _sharded_sum_vjp(plan.plan, h, impl or planned_impl(), plan.axis)
+
+
+def shard_cuts(degree: np.ndarray, shards: int, rows: int) -> np.ndarray:
+    """`[shards + 1]` node indices that cut `len(degree)` nodes into ranges
+    of even total degree, none longer than `rows`. A node's entries all go
+    with it, so the ranges' entries are even to within one node's degree."""
+    n = int(degree.shape[0])
+    if n > shards * rows:
+        raise ValueError(f"{n} nodes do not fit {shards} shards of {rows} rows")
+    reach = np.cumsum(degree, dtype=np.int64)
+    total = int(reach[-1]) if n else 0
+    cuts = np.zeros(shards + 1, dtype=np.int64)
+    cuts[shards] = n
+    for d in range(1, shards):
+        even = int(np.searchsorted(reach, total * d / shards, side="right")) if total else n * d // shards
+        # no range longer than its rows, and the nodes left must fit the ranges left
+        cuts[d] = np.clip(even, max(cuts[d - 1], n - (shards - d) * rows), min(cuts[d - 1] + rows, n))
+    return cuts
+
+
+def _work_list(counts: np.ndarray, entries: int):
+    """The items of one plan from its owners' entry counts (`build_edge_plan`
+    says what an item is): (item_tile, item_block, item_flag, real items)."""
+    tn, be = PLAN_NODE_TILE, PLAN_EDGE_BLOCK
+    node_tiles, edge_blocks = -(-counts.shape[0] // tn), entries // be
+    row_ptr = np.zeros(node_tiles * tn + 1, dtype=np.int64)
+    np.cumsum(counts, out=row_ptr[1 : counts.shape[0] + 1])
+    row_ptr[counts.shape[0] + 1 :] = row_ptr[counts.shape[0]]
+    lo, hi = row_ptr[:-1:tn], row_ptr[tn::tn]
+    first = np.minimum(lo // be, edge_blocks - 1)
+    last = np.where(hi > lo, (hi - 1) // be, first)
+    per_tile = last - first + 1
+    n_items = int(per_tile.sum())
+    starts = np.cumsum(per_tile) - per_tile
+    items = node_tiles + edge_blocks
+    item_tile = np.full(items, node_tiles - 1, dtype=np.int32)
+    item_block = np.empty(items, dtype=np.int32)
+    item_flag = np.full(items, -1, dtype=np.int32)
+    tiles = np.repeat(np.arange(node_tiles), per_tile)
+    item_tile[:n_items] = tiles
+    item_block[:n_items] = first[tiles] + np.arange(n_items) - starts[tiles]
+    item_block[n_items:] = item_block[n_items - 1]
+    item_flag[:n_items] = 0
+    item_flag[starts] = 1
+    return item_tile, item_block, item_flag, n_items
+
+
+def build_shard_plans(src, dst, edge_mask, num_nodes: int, bucket_nodes: int, shards: int):
+    """Host arrays of a (bucket-padded) edge list over `num_nodes` nodes ->
+    (EdgePlan of numpy arrays with a leading `[shards]` axis, the node cuts
+    `[shards + 1]`, each shard's real entries, each shard's real items).
+
+    Node i of range d lives in row `d * (bucket_nodes // shards) + i - cuts[d]`
+    of the table; shard d's plan holds the entries whose owner is in its
+    range, owner as a row of the shard, neighbour as a row of the table,
+    sorted as `build_edge_plan` sorts them (it makes the sort: with one shard
+    the one plan IS its plan). All shards' plans have one shape: that of the
+    plan of `bucket_nodes // shards` nodes and `edge bucket // shards` edges
+    while the fullest shard's entries fit that, which even cuts see to, and
+    wider by eighths of it where a hub does not let them."""
+    src = np.asarray(src, dtype=np.int64)
+    dst = np.asarray(dst, dtype=np.int64)
+    n, nb = int(num_nodes), int(bucket_nodes)
+    rows = nb // shards
+    real = np.asarray(edge_mask, dtype=bool) & (src >= 0) & (src < n) & (dst >= 0) & (dst < n)
+    degree = np.bincount(np.concatenate([src[real], dst[real]]), minlength=n)[:n]
+    cuts = shard_cuts(degree, shards, rows)
+    shard_of = np.searchsorted(cuts, np.arange(n), side="right") - 1
+    row_of = np.concatenate([shard_of * rows + np.arange(n) - cuts[shard_of], np.full(1, nb)])
+    at = lambda ends: row_of[np.where((ends >= 0) & (ends < n), ends, n)]  # noqa: E731 - out of range: out of the bucket
+    whole, n_real, n_items = build_edge_plan(at(src), at(dst), edge_mask, nb)
+    if shards == 1:
+        return jax.tree_util.tree_map(lambda a: a[None], whole), cuts, [n_real], [n_items]
+
+    owner = whole.owner[0, :n_real]
+    bounds = np.searchsorted(owner, np.arange(shards + 1) * rows)
+    held = np.diff(bounds)
+    base, _tiles, _items = plan_shapes(rows, src.shape[0] // shards)
+    entries = max(base, _pad_to(int(held.max()), max(base // 8, PLAN_EDGE_BLOCK)))
+    plans, items = [], []
+    for d in range(shards):
+        lo, hi = int(bounds[d]), int(bounds[d + 1])
+        own = np.full(entries, -(-rows // PLAN_NODE_TILE) * PLAN_NODE_TILE, dtype=np.int32)  # parked
+        neighbour = np.zeros(entries, dtype=np.int32)
+        direction = np.zeros(entries, dtype=np.int32)
+        own[: hi - lo] = owner[lo:hi] - d * rows
+        neighbour[: hi - lo] = whole.neighbour[lo:hi]
+        direction[: hi - lo] = whole.direction[0, lo:hi]
+        counts = whole.degree[d * rows : (d + 1) * rows]
+        item_tile, item_block, item_flag, n_items = _work_list(counts.astype(np.int64), entries)
+        plans.append(EdgePlan(own[None, :], neighbour, counts, item_tile, item_block, item_flag, direction[None, :]))
+        items.append(n_items)
+    return jax.tree_util.tree_map(lambda *a: np.stack(a), *plans), cuts, held.tolist(), items

@@ -741,3 +741,77 @@ def sharded_service_scores(
     return scorer_ops.score_tuple_rows(
         o, l, dr, dd, ml, valid, is_gateway, num_services=num_services
     )
+
+
+# ---------------------------------------------------------------------------
+# the `nodes` mesh: a history that one device cannot hold (models/stacked.py)
+#
+# The hourly history of a mesh is [slots, nodes, ...]; where its bytes pass a
+# device's memory the NODE axis is cut over the local devices, each holding
+# its rows of every slot, and the schedule stays what it is on one device:
+# one optimizer update per slot, in slot order, over all endpoints. Parameters
+# and optimizer state are replicated; a layer's neighbour table is
+# all-gathered (ops/sparse.sharded_neighbor_sum). Sharded by SLOTS instead
+# (`make_sharded_slot_grad` above) four devices take four slots an update,
+# which is another schedule.
+# ---------------------------------------------------------------------------
+
+NODES_AXIS = "nodes"
+
+
+def device_bytes_limit(device=None) -> Optional[int]:
+    """What one local device may hold, as its allocator reports it; None
+    where it reports nothing (a CPU), which reads as: it holds whatever the
+    host does."""
+    device = device or jax.local_devices()[0]
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+def node_shards(nbytes: int) -> int:
+    """Over how many local devices a history of `nbytes` is cut by nodes: the
+    fewest power of two whose share is at most HALF a device's memory (the
+    other half is the epoch block's: gathered tables, messages, the slot
+    group), so 1 wherever one device holds it. Raises where the machine has
+    too few devices for that."""
+    limit = device_bytes_limit()
+    if not limit:
+        return 1
+    shards, have = 1, len(jax.local_devices())
+    while nbytes / shards > limit / 2:
+        shards *= 2
+    if shards > have:
+        raise RuntimeError(
+            f"a history of {nbytes:,} B needs {shards} devices of {limit:,} B to hold a shard "
+            f"in half a device's memory; this machine has {have}"
+        )
+    return shards
+
+
+@_lru_cache(maxsize=8)
+def nodes_mesh(shards: int) -> Mesh:
+    """The first `shards` local devices as a one-axis mesh over `nodes`."""
+    return Mesh(np.asarray(jax.local_devices()[:shards]), (NODES_AXIS,))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1,))
+def shared(x, axis: str):
+    """A replicated value (the parameters) where a device starts its share of
+    a computation from it: itself, and the devices' cotangents summed."""
+    return x
+
+
+def _shared_bwd(axis, _, g):
+    return (jax.tree_util.tree_map(lambda a: jax.lax.psum(a, axis), g),)
+
+
+shared.defvjp(lambda x, axis: (x, None), _shared_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1,))
+def total(x, axis: str):
+    """The devices' partial sums added up (a loss's sums and counts): every
+    device gets the total, and hands each partial sum the total's cotangent."""
+    return jax.lax.psum(x, axis)
+
+
+total.defvjp(lambda x, axis: (jax.lax.psum(x, axis), None), lambda axis, _, g: (g,))

@@ -295,7 +295,7 @@ def test_a_sound_quantile_run_is_correct_and_its_traced_line_reads_the_new_kerne
 def test_the_manifest_holds_the_new_configuration_and_its_cell():
     doc = json.loads((ROOT / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in doc["workloads"]}
-    assert list(cells) == ["mv100k-sage.refresh", "mv100k-gat.refresh", "mv100k-stlgt.refresh"]
+    assert list(cells)[:3] == ["mv100k-sage.refresh", "mv100k-gat.refresh", "mv100k-stlgt.refresh"]
     assert cells["mv100k-stlgt.refresh"] == dict(
         cells["mv100k-stlgt.refresh"], config="mv100k-stlgt", traffic="refresh", chips=1
     )
@@ -317,7 +317,7 @@ def test_the_manifest_holds_the_new_configuration_and_its_cell():
     assert [m["name"] for m in new] == ["kernel.gated_sum_ms_per_slot", "kernel.gated_sum_hbm_roofline"]
     # the cell builds the edge plan in set-up as GAT's does, so the accepted metric of that span lists it too
     plan_s = {m["name"]: m for m in doc["per_layer"]}["setup.plan_s"]
-    assert plan_s["workloads"] == ["mv100k-gat.refresh", "mv100k-stlgt.refresh"]
+    assert plan_s["workloads"][:2] == ["mv100k-gat.refresh", "mv100k-stlgt.refresh"]  # later cells append
     # the work file's terms, and no share can pass 100% by arithmetic alone: the gated terms are part of the whole
     work = _load_work("stlgt")
     terms = work.terms(cfg)

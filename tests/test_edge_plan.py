@@ -655,14 +655,19 @@ class TestSlotGroup:
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler here: nothing to check
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -792,3 +797,55 @@ def test_gated_sum_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, wid
             for body in text.split("\n}\n") if " gather(" in body
         ]
         assert len(tables) == 3 and all(f"f32[{nb},{lanes}]" in t and "S(1)}" in t for t in tables), tables
+
+
+def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
+    """`mv400k-sage`'s epoch block for the four chips of a described host: the
+    one-device block's body under `shard_map`, 432 slots x 524,288 nodes cut
+    four ways, a plan a chip. What the chip's compiler makes of it: the three
+    planned sums as Mosaic kernels, their tables all-gathered in float32,
+    nothing reduce-scattered, and a chip's share inside a chip's memory."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(topo.devices), ("nodes",))
+    slots, nb, eb, width, shards = 432, 524288, 2097152, 18, 4
+    entries, _tiles, items = sparse.plan_shapes(nb // shards, eb // shards)
+    assert (entries, items) == (1048576, 1024 + 2048)  # a chip's plan is the 100k cell's
+
+    def arg(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    plan = _described_plan(lambda shape, dtype: arg((shards,) + shape, dtype, P("nodes")), nb // shards, entries, items)
+    params = jax.eval_shape(lambda: graphsage.init_params(jax.random.PRNGKey(0), hidden=64, num_features=width))
+    opt_state = jax.eval_shape(lambda p: graphsage.make_optimizer(1e-2).init(p), params)
+    whole = lambda tree: jax.tree_util.tree_map(lambda a: arg(a.shape, a.dtype), tree)  # noqa: E731
+    rows = P(None, "nodes")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
+    compilation_cache.reset_cache()
+    real_impl, sparse.planned_impl = sparse.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
+    try:
+        compiled = stacked.node_sharded_epoch_runner(graphsage, 1e-2, 10.0, mesh).fn.lower(
+            whole(params), whole(opt_state), arg((slots, nb, width), jnp.float32, rows),
+            arg((slots, nb), jnp.float32, rows), arg((slots, nb), jnp.float32, rows), arg((slots, nb), jnp.bool_, rows),
+            arg((eb,), jnp.int32), arg((eb,), jnp.int32), arg((eb,), jnp.bool_), 1, plan,
+        ).compile()
+    finally:
+        sparse.planned_impl = real_impl
+        stacked.node_sharded_epoch_runner.cache_clear()
+        stacked.epoch_runner.cache_clear()
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 and "planned_neighbor_sum" in text
+    gathers = re.findall(r"= (\w+)\[(\d+),(\d+)\]\S* all-gather\(", text)
+    assert gathers and {g[0] for g in gathers} == {"f32"} and {g[1] for g in gathers} == {str(nb)}
+    assert {g[2] for g in gathers} == {"64", "126"}  # layer 2's table and its cotangent; the slot group's
+    assert "reduce-scatter" not in text and "all-to-all" not in text
+    memory = compiled.memory_analysis()
+    stack = slots * (nb // shards) * 81
+    assert memory.argument_size_in_bytes >= stack > 4 * 2**30
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 8 * 2**30  # half a chip

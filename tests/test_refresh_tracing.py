@@ -109,14 +109,16 @@ class TestRefreshTrace:
         assert tb.spans[0][0] == "refresh.train" and tb.spans[0][3] == -1
         assert _names(tb) == FUSED_CHILDREN
         stack_idx = [i for i, s in _children(tb) if s[0] == "refresh.stack"][0]
-        assert _names(tb, stack_idx) == [
-            "refresh.stack.host_fill",
+        assert _names(tb, stack_idx) == [  # the plan first: it says where the node axis is cut
             "refresh.stack.plan",
+            "refresh.stack.host_fill",
             "refresh.stack.device_put",
         ]
         _assert_nested(tb)
+        st_nodes = stacked.stack_dataset(ds).bucket_nodes
         assert tb.counts[0] == {
             "model": "graphsage", "loss": "mse+bce", "epochs": 2, "slots": 5, "batch_slots": 1, "fused": 1,
+            "shards": 1, "nodes_per_shard": st_nodes, "layout": "device",
         }
         by_name = {tb.spans[i][0]: c for i, c in tb.counts.items()}
         assert by_name["refresh.pos_weight"]["slots"] == 5
@@ -214,7 +216,7 @@ class TestRefreshTrace:
         stacked.stack_dataset(ds)
         build, hit = TRACER.traces()
         assert [s[0] for s in build.spans] == [
-            "refresh.stack", "refresh.stack.host_fill", "refresh.stack.plan",
+            "refresh.stack", "refresh.stack.plan", "refresh.stack.host_fill",
             "refresh.stack.device_put",
         ]
         assert [s[0] for s in hit.spans] == ["refresh.stack"]
@@ -237,7 +239,7 @@ class TestRefreshTrace:
     def test_refresh_names_have_histograms_and_the_program_avoids_the_benchmarks_name(self):
         wanted = {
             "refresh.train", "refresh.init", "refresh.resume", "refresh.pos_weight",
-            "refresh.stack", "refresh.stack.host_fill", "refresh.stack.plan",
+            "refresh.stack", "refresh.stack.plan", "refresh.stack.host_fill",
             "refresh.stack.device_put", "refresh.epoch_block", "refresh.loss_fetch", "refresh.checkpoint_save",
             "refresh.legacy_epoch",
         }

@@ -67,14 +67,14 @@ device-native:
   data's: `stack_dataset` weighs the stack against the device and no caller
   says anything. Each device gets its rows of every slot `[S, Nb / D, ..]`,
   filled and handed over shard by shard (the whole never lies on one device,
-  nor twice on the host), and ONE edge plan: the entries whose owner it
-  holds (`sparse.build_shard_plans`; the node ranges are cut where the
-  entries divide evenly). Datasets over one graph share a plan and with it
+  nor twice on the host), and ITS edge plan: the entries whose owner it holds,
+  a sub-plan a source device (`sparse.build_shard_plans`; the node ranges
+  are cut where the entries divide evenly). Datasets over one graph share a plan and with it
   its layout, so the head of a sharded history is sharded as it is.
   `node_sharded_epoch_runner` runs the one-device block's own body under
   `shard_map` over the `nodes` axis: parameters, optimizer state and losses
-  replicated, a layer's neighbour table all-gathered in float32
-  (`sparse.sharded_neighbor_sum`), the loss's sums and counts and the
+  replicated, a layer's neighbour tables all-gathered in float32 and gathered
+  from one source's at a time (`sparse.sharded_neighbor_sum`), the loss's sums and counts and the
   parameter gradients summed over the devices (`common.make_loss_fn`'s
   `axis_name`). The schedule does not change: one update a slot, in slot
   order, over all endpoints; the slot group works on the sharded table.
@@ -294,10 +294,8 @@ def _edge_plan(dataset, src, dst, e_mask, n: int, nb: int, shards: int) -> _Plan
     _PLAN_BUILDS.inc()
     with phase_span("refresh.stack.plan"):
         host, cuts, entries_by, items_by = sparse.build_shard_plans(src, dst, e_mask, n, nb, shards)
-        runs = entries_in = 0
-        blocks_by = []
-        for d, (entries, items) in enumerate(zip(entries_by, items_by)):
-            one = jax.tree_util.tree_map(lambda a: a[d], host)
+        runs, entries_in, blocks_by = 0, 0, []
+        for one, entries, items in zip(sparse.sub_plans(host), entries_by, items_by):  # a sub-plan an (owner, source) pair
             run_key = one.owner[0, :entries] * 2 + one.direction[0, :entries]
             runs += int(np.count_nonzero(np.diff(run_key))) + 1 if entries else 0
             entries_in += int(one.direction[0, :entries].sum())
@@ -317,9 +315,11 @@ def _edge_plan(dataset, src, dst, e_mask, n: int, nb: int, shards: int) -> _Plan
             runs=runs, mxu_products=sparse.route_stats()["mxu_products"],
         )
         if shards > 1:
+            by_owner = lambda a: np.reshape(a, (shards, shards)).sum(axis=1).tolist()  # noqa: E731 - the counts are owner-major
             TRACER.note(
-                shards=shards, shard_entries=list(entries_by), shard_items=list(items_by),
-                shard_blocks=blocks_by, shard_nodes=np.diff(cuts).tolist(),
+                shards=shards, shard_entries=by_owner(entries_by), shard_items=by_owner(items_by),
+                shard_blocks=by_owner(blocks_by), shard_nodes=np.diff(cuts).tolist(),
+                source_tables=shards, source_entries=list(entries_by), source_items=list(items_by),
             )
     planned = _Planned(plan, counts, shards, tuple(int(c) for c in cuts))
     _PLAN_MEMO.append((*key, nb, planned))

@@ -1046,14 +1046,31 @@ def exclusive_cumsum(flags: jnp.ndarray) -> jnp.ndarray:
 #
 # Where one device cannot hold a history (models/stacked.py decides), the
 # node axis is cut into `shards` ranges of rows, one a device of a mesh. A
-# device holds the rows of its range of every slot and ONE plan: the entries
-# whose OWNER it holds, the owner as a row of the shard, the neighbour as a
-# row of the whole table. A layer's sum is then an all-gather of the devices'
-# rows into the table (float32, as they are stored: what crosses between
-# chips is the configuration's precision) and the device's own planned sum
-# over it. A is symmetric, so the cotangent of the local rows is the same
-# thing of the cotangent: all-gathered, summed over the SAME per-device plan.
-# No scatter, no reduce-scatter, and nothing but the table crosses.
+# device holds the rows of its range of every slot and the entries whose
+# OWNER it holds, cut once more by the SOURCE: the shard their neighbour's
+# row lives on. That is `shards` sub-plans of one shape, each the plan of the
+# device's rows over ONE source's table: the owner a row of the shard, the
+# neighbour a row of the source's `[rows, W]` table. A layer's sum is then an
+# all-gather of the devices' rows (float32, as they are stored: what crosses
+# between chips is the configuration's precision) into `[shards, rows, W]`,
+# and source by source a row gather from that source's table, the planned
+# reduction of its entries, and the parts added. A is symmetric, so the
+# cotangent of the local rows is the same thing of the cotangent:
+# all-gathered, summed over the SAME sub-plans. No scatter, no
+# reduce-scatter, and nothing but the tables crosses.
+#
+# Why a source at a time (PERF.md, PR 33 and PR 36): a row gather costs
+# 1.8 ns a row while its TABLE lies in the chip's fast memory (128 MiB) and
+# 10 ns from HBM. One source's table at 128 lanes is 64 MiB at the cells'
+# size; the whole mesh's is 256 MiB. XLA keeps a table there (`S(1)` in the
+# compiled layout) only while one is alive at a time, so the sources are
+# chained by `optimization_barrier`: a source's gather is done before the
+# next source's table is cut out of the all-gathered array. Every entry is
+# still summed once, in float32, through the same reducer; an owner's sum
+# comes out as `shards` partial sums added, an order and not a precision.
+# A head that reduces over ALL of an owner's entries with a softmax or a
+# gate would meet them in `shards` pieces and has to merge a running maximum
+# and sum across the sources (ROADMAP R2).
 #
 # The ranges are cut where the plan's ENTRIES divide evenly, not the nodes: a
 # walk's time goes with its entries, every device waits for the slowest at
@@ -1064,9 +1081,11 @@ def exclusive_cumsum(flags: jnp.ndarray) -> jnp.ndarray:
 
 @jax.tree_util.register_pytree_node_class
 class ShardPlan:
-    """One device's EdgePlan inside a `shard_map` over `axis`: what
+    """One device's sub-plans inside a `shard_map` over `axis`: what
     `planned_neighbor_sum` takes where the rows it is given are the device's
-    share of the nodes. The axis is static; the plan's arrays are the leaves."""
+    share of the nodes. `plan` is an EdgePlan whose leaves, but for the
+    degree, have a leading axis over the sources (`build_shard_plans`). The
+    axis is static; the plan's arrays are the leaves."""
 
     def __init__(self, plan: EdgePlan, axis: str):
         self.plan, self.axis = plan, axis
@@ -1083,9 +1102,21 @@ class ShardPlan:
         return cls(children[0], axis)
 
 
+def _source_plan(plan: EdgePlan, source: int) -> EdgePlan:
+    """One source's sub-plan of a device's plan: every leaf but the degree has
+    a leading `[shards]` axis over the shard its entries' NEIGHBOURS live on."""
+    return EdgePlan(*(a if name == "degree" else a[source] for name, a in zip(EdgePlan._fields, plan)))
+
+
 def _sharded_sum(plan: EdgePlan, h, impl: str, axis: str):
-    table = jax.lax.all_gather(h, axis, axis=0, tiled=True)  # [shards * n, W], rows as stored
-    return _planned_sum(plan, table, impl)  # [n, W]: the rows this device owns
+    tables = jax.lax.all_gather(h, axis, axis=0, tiled=False)  # [shards, n, W], rows as stored
+    out = None
+    for source in range(plan.neighbour.shape[0]):
+        part = _planned_sum(_source_plan(plan, source), tables[source], impl)  # gathers from ONE shard's table
+        # the chain: a source is done with its table before the next source's is cut out of `tables`
+        part, tables = jax.lax.optimization_barrier((part, tables))
+        out = part if out is None else out + part
+    return out  # [n, W]: the rows this device owns
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -1108,8 +1139,9 @@ _sharded_sum_vjp.defvjp(_sharded_sum_fwd, _sharded_sum_bwd)
 def sharded_neighbor_sum(plan: ShardPlan, h: jnp.ndarray, impl: Optional[str] = None):
     """`planned_neighbor_sum` of a node-sharded table, inside the `shard_map`
     over `plan.axis`: `h` is this device's rows `[n, W]`, the result the sums
-    of their neighbours' rows wherever those live. One all-gather and one
-    planned sum, forward and backward. Counted in `route_stats()["sharded"]`."""
+    of their neighbours' rows wherever those live. One all-gather, and a row
+    gather and a planned reduction a source, forward and backward. Counted
+    in `route_stats()["sharded"]`."""
     with _route_lock:
         _route_counts["sharded"] += 1
     return _sharded_sum_vjp(plan.plan, h, impl or planned_impl(), plan.axis)
@@ -1160,19 +1192,36 @@ def _work_list(counts: np.ndarray, entries: int):
     return item_tile, item_block, item_flag, n_items
 
 
+def sub_plans(plans: EdgePlan):
+    """The sub-plans of `build_shard_plans`' host plans, in the order of its
+    counts: one a shard, or one an (owner, source) pair, owner-major."""
+    shards = plans.neighbour.shape[0]
+    for d in range(shards):
+        one = jax.tree_util.tree_map(lambda a: a[d], plans)
+        yield from [one] if shards == 1 else (_source_plan(one, s) for s in range(shards))
+
+
 def build_shard_plans(src, dst, edge_mask, num_nodes: int, bucket_nodes: int, shards: int):
     """Host arrays of a (bucket-padded) edge list over `num_nodes` nodes ->
     (EdgePlan of numpy arrays with a leading `[shards]` axis, the node cuts
-    `[shards + 1]`, each shard's real entries, each shard's real items).
+    `[shards + 1]`, the real entries and the real items of each sub-plan).
 
     Node i of range d lives in row `d * (bucket_nodes // shards) + i - cuts[d]`
-    of the table; shard d's plan holds the entries whose owner is in its
-    range, owner as a row of the shard, neighbour as a row of the table,
-    sorted as `build_edge_plan` sorts them (it makes the sort: with one shard
-    the one plan IS its plan). All shards' plans have one shape: that of the
-    plan of `bucket_nodes // shards` nodes and `edge bucket // shards` edges
-    while the fullest shard's entries fit that, which even cuts see to, and
-    wider by eighths of it where a hub does not let them."""
+    of the table. With one shard the one plan IS `build_edge_plan`'s (it makes
+    the sort), and the counts are one number each. With more, shard d's
+    entries (owner in its range) are cut once more by the SOURCE, the shard
+    their neighbour lives on: `shards` sub-plans on a second axis, leaves
+    `[shards (owner's), shards (source), ...]`, each the plan of the owner's
+    rows over ONE source's `[bucket_nodes // shards, W]` table: owner a row
+    of the shard, neighbour a row of the source's table, sorted within the
+    source as `build_edge_plan` sorts, its own work list. `degree` stays the
+    owner's whole degree, `[shards, rows]` (a mean divides by it). The counts
+    are flat, owner-major: `[d * shards + s]`. All sub-plans have one shape:
+    that of the plan of `bucket_nodes // shards` nodes and `edge bucket //
+    shards // shards` edges while the fullest (owner, source) pair fits it,
+    which even cuts see to (degrees are symmetric, so the sources' shares of
+    a shard's entries are even too), and wider by eighths of it where a hub
+    does not let them."""
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)
     n, nb = int(num_nodes), int(bucket_nodes)
@@ -1187,22 +1236,29 @@ def build_shard_plans(src, dst, edge_mask, num_nodes: int, bucket_nodes: int, sh
     if shards == 1:
         return jax.tree_util.tree_map(lambda a: a[None], whole), cuts, [n_real], [n_items]
 
-    owner = whole.owner[0, :n_real]
-    bounds = np.searchsorted(owner, np.arange(shards + 1) * rows)
+    owner, neighbour = whole.owner[0, :n_real], whole.neighbour[:n_real]
+    pair = owner // rows * shards + neighbour // rows
+    order = np.argsort(pair, kind="stable")  # within a pair: by owner, then direction, as `whole` is
+    bounds = np.searchsorted(pair[order], np.arange(shards * shards + 1))
     held = np.diff(bounds)
-    base, _tiles, _items = plan_shapes(rows, src.shape[0] // shards)
+    base, _tiles, _items = plan_shapes(rows, src.shape[0] // shards // shards)
     entries = max(base, _pad_to(int(held.max()), max(base // 8, PLAN_EDGE_BLOCK)))
-    plans, items = [], []
-    for d in range(shards):
-        lo, hi = int(bounds[d]), int(bounds[d + 1])
-        own = np.full(entries, -(-rows // PLAN_NODE_TILE) * PLAN_NODE_TILE, dtype=np.int32)  # parked
-        neighbour = np.zeros(entries, dtype=np.int32)
+    parked = -(-rows // PLAN_NODE_TILE) * PLAN_NODE_TILE
+    subs, items = [], []
+    for i in range(shards * shards):
+        d, s = divmod(i, shards)
+        mine = order[bounds[i] : bounds[i + 1]]
+        own = np.full(entries, parked, dtype=np.int32)
+        nei = np.zeros(entries, dtype=np.int32)
         direction = np.zeros(entries, dtype=np.int32)
-        own[: hi - lo] = owner[lo:hi] - d * rows
-        neighbour[: hi - lo] = whole.neighbour[lo:hi]
-        direction[: hi - lo] = whole.direction[0, lo:hi]
-        counts = whole.degree[d * rows : (d + 1) * rows]
-        item_tile, item_block, item_flag, n_items = _work_list(counts.astype(np.int64), entries)
-        plans.append(EdgePlan(own[None, :], neighbour, counts, item_tile, item_block, item_flag, direction[None, :]))
+        own[: mine.size] = owner[mine] - d * rows
+        nei[: mine.size] = neighbour[mine] - s * rows
+        direction[: mine.size] = whole.direction[0, mine]
+        *work, n_items = _work_list(np.bincount(own[: mine.size], minlength=rows), entries)
+        subs.append((own[None, :], nei, *work, direction[None, :]))
         items.append(n_items)
-    return jax.tree_util.tree_map(lambda *a: np.stack(a), *plans), cuts, held.tolist(), items
+    own, nei, item_tile, item_block, item_flag, direction = (
+        np.stack(a).reshape(shards, shards, *a[0].shape) for a in zip(*subs)
+    )
+    plans = EdgePlan(own, nei, whole.degree.reshape(shards, rows), item_tile, item_block, item_flag, direction)
+    return plans, cuts, held.tolist(), items

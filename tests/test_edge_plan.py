@@ -802,9 +802,12 @@ def test_gated_sum_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, wid
 def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
     """`mv400k-sage`'s epoch block for the four chips of a described host: the
     one-device block's body under `shard_map`, 432 slots x 524,288 nodes cut
-    four ways, a plan a chip. What the chip's compiler makes of it: the three
-    planned sums as Mosaic kernels, their tables all-gathered in float32,
-    nothing reduce-scattered, and a chip's share inside a chip's memory."""
+    four ways, a chip's plan cut once more by the chip its neighbours live
+    on. What the chip's compiler makes of it: a Mosaic reducer a source and
+    sum, the tables all-gathered in float32, nothing reduce-scattered, every
+    row gather from ONE source's table of 131,072 rows that lies in the fast
+    memory (`S(1)`: 1.8 ns a row; the 524,288-row table in HBM cost 10.3,
+    PERF.md PR 36), and a chip's share inside a chip's memory."""
     import re
 
     from jax.experimental.compilation_cache import compilation_cache
@@ -812,25 +815,30 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
 
     mesh = Mesh(np.asarray(topo.devices), ("nodes",))
     slots, nb, eb, width, shards = 432, 524288, 2097152, 18, 4
-    entries, _tiles, items = sparse.plan_shapes(nb // shards, eb // shards)
-    assert (entries, items) == (1048576, 1024 + 2048)  # a chip's plan is the 100k cell's
+    rows = nb // shards
+    entries, _tiles, items = sparse.plan_shapes(rows, eb // shards // shards)
+    assert (entries, items) == (262144, 1024 + 512)  # a quarter of the 100k cell's entries a source
 
     def arg(shape, dtype, spec=P()):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
 
-    plan = _described_plan(lambda shape, dtype: arg((shards,) + shape, dtype, P("nodes")), nb // shards, entries, items)
+    def leaf(shape, dtype):  # [owner's shard, source, ...]; the degree is the owner's whole
+        lead = (shards,) if shape == (rows,) else (shards, shards)
+        return arg(lead + shape, dtype, P("nodes"))
+
+    plan = _described_plan(leaf, rows, entries, items)
     params = jax.eval_shape(lambda: graphsage.init_params(jax.random.PRNGKey(0), hidden=64, num_features=width))
     opt_state = jax.eval_shape(lambda p: graphsage.make_optimizer(1e-2).init(p), params)
     whole = lambda tree: jax.tree_util.tree_map(lambda a: arg(a.shape, a.dtype), tree)  # noqa: E731
-    rows = P(None, "nodes")
+    cut = P(None, "nodes")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
     compilation_cache.reset_cache()
     real_impl, sparse.planned_impl = sparse.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
     try:
         compiled = stacked.node_sharded_epoch_runner(graphsage, 1e-2, 10.0, mesh).fn.lower(
-            whole(params), whole(opt_state), arg((slots, nb, width), jnp.float32, rows),
-            arg((slots, nb), jnp.float32, rows), arg((slots, nb), jnp.float32, rows), arg((slots, nb), jnp.bool_, rows),
+            whole(params), whole(opt_state), arg((slots, nb, width), jnp.float32, cut),
+            arg((slots, nb), jnp.float32, cut), arg((slots, nb), jnp.float32, cut), arg((slots, nb), jnp.bool_, cut),
             arg((eb,), jnp.int32), arg((eb,), jnp.int32), arg((eb,), jnp.bool_), 1, plan,
         ).compile()
     finally:
@@ -840,12 +848,99 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3 and "planned_neighbor_sum" in text
-    gathers = re.findall(r"= (\w+)\[(\d+),(\d+)\]\S* all-gather\(", text)
-    assert gathers and {g[0] for g in gathers} == {"f32"} and {g[1] for g in gathers} == {str(nb)}
-    assert {g[2] for g in gathers} == {"64", "126"}  # layer 2's table and its cotangent; the slot group's
+    # the group's sum, layer 2's and its cotangent's: a reducer a source each
+    assert text.count("tpu_custom_call") == 3 * shards and "planned_neighbor_sum" in text
+    gathered = re.findall(r"= (\w+)\[([\d,]+)\]\S* all-gather\(", text)
+    assert gathered and {g[0] for g in gathered} == {"f32"}
+    # layer 2's tables and their cotangents', the slot group's: every chip's rows, as `[4, rows, W]` or the same bytes flat
+    assert {g[1].split(",")[-1] for g in gathered} == {"64", "126"}
+    assert all(g[1].split(",")[:-1] in ([str(shards), str(rows)], [str(nb)]) for g in gathered), gathered
     assert "reduce-scatter" not in text and "all-to-all" not in text
+    # every row gather reads ONE source's table, and that table lies in the fast memory
+    tables = [
+        next(line for line in body.splitlines() if " parameter(0)" in line)
+        for body in text.split("\n}\n") if " gather(" in body
+    ]
+    assert len(tables) == 3 * shards and f"f32[{nb}," not in "".join(tables), tables
+    assert all(re.search(rf"f32\[{rows},(64|126)\]\S*S\(1\)\}}", t) for t in tables), tables
+    assert not any(f"[{nb}," in line for line in text.splitlines() if " gather(" in line)
     memory = compiled.memory_analysis()
-    stack = slots * (nb // shards) * 81
+    stack = slots * rows * 81
     assert memory.argument_size_in_bytes >= stack > 4 * 2**30
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 8 * 2**30  # half a chip
+
+
+# -- the one-chip blocks stay the programs they were ---------------------------
+
+#: sha256 of each one-chip epoch block as it LOWERS for a described v5e at the cells' shapes (`_block_fingerprint`).
+#: A Mosaic kernel's source locations are in its bytecode and so in the compile cache's key: a PR that moves a line at
+#: or above a kernel of `ops/sparse.py`, `ops/sparse_gated.py`, a head's forward or the block's body in `models/stacked.py`
+#: changes these, pays a cold compile in every one-chip cell (13 s in PR 30) and says so; one that means to leave
+#: the one-chip path alone (PR 35, PR 36: the sharded path) keeps them. The failing assertion prints the new value.
+ONE_CHIP_BLOCKS = {
+    "graphsage": "562da3db8046784dd881ae0e261f8d7240293eee4077d2f252e3e9420a199ebb",
+    "gat": "7a0afc8614ddb839998805e573454070731fbd4fc9c8b2970b5dbfd2b0ddc0ac",
+    "stlgt": "ac23db41cc634da9d489adfc30ad01f35237be29ec96b1d1a197435cb8b05927",
+}
+
+
+def _block_fingerprint(text: str) -> str:
+    """A lowered block's StableHLO with every Mosaic kernel's bytecode read back as text, and the kernels' source
+    locations inside the package (file, line, columns) relative to the checkout, so that it is the same wherever
+    the checkout lies and whoever called (the caller's own frames are in the bytecode too, and are left out)."""
+    import base64
+    import hashlib
+    import re
+    from pathlib import Path
+
+    from jax._src.lib.mlir import ir
+
+    root = str(Path(sparse.__file__).resolve().parents[2])
+    places = set()
+
+    def body(match):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True  # the serialised kernel is in Mosaic's versioned dialect
+        with ctx:
+            op = ir.Module.parse(base64.b64decode(match.group(1))).operation
+            asm, located = op.get_asm(enable_debug_info=False), op.get_asm(enable_debug_info=True)
+        places.update(re.findall(r'loc\("' + re.escape(root) + r'/(kmamiz_tpu/[^"]+)":([\d: to]+)\)', located))
+        return "body: " + hashlib.sha256(asm.encode()).hexdigest()
+
+    plain = re.sub(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+    assert places and root not in plain
+    return hashlib.sha256((plain + repr(sorted(places))).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("head", sorted(ONE_CHIP_BLOCKS))
+def test_the_one_chip_blocks_lower_to_what_they_lowered_to(one_chip, head):
+    """The three one-chip cells' epoch blocks at the cells' shapes (432 slots, 131,072 nodes, a plan of 1,048,576
+    entries), the kernels' locations included: what PR 35 and PR 36 compared by hand against their parents."""
+    from kmamiz_tpu.models import gat
+    from kmamiz_tpu.models.stlgt import model as stlgt_model
+
+    model = {"graphsage": graphsage, "gat": gat, "stlgt": stlgt_model}[head]
+    slots, nb, eb, width = 432, 131072, 524288, 18
+    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), hidden=64, num_features=width))
+    opt_state = jax.eval_shape(lambda p: model.make_optimizer(1e-2).init(p), params)
+    whole = lambda tree: jax.tree_util.tree_map(lambda a: arg(a.shape, a.dtype), tree)  # noqa: E731
+    real_impl, sparse.planned_impl = sparse.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
+    # a helper two families share (`_weighted_sum`) keeps the call stack of whoever traced it FIRST in the process, and
+    # that stack is in the kernel's bytecode: trace afresh, as a process that refreshes one head does
+    jax.clear_caches()
+    try:
+        text = stacked.epoch_runner(model, 1e-2, 10.0).fn.lower(
+            whole(params), whole(opt_state), arg((slots, nb, width), jnp.float32),
+            arg((slots, nb), jnp.float32), arg((slots, nb), jnp.float32), arg((slots, nb), jnp.bool_),
+            arg((eb,), jnp.int32), arg((eb,), jnp.int32), arg((eb,), jnp.bool_), 1, _described_plan(arg, nb, entries, items),
+        ).as_text()
+    finally:
+        sparse.planned_impl = real_impl
+        stacked.epoch_runner.cache_clear()
+    assert "tpu_custom_call" in text
+    assert _block_fingerprint(text) == ONE_CHIP_BLOCKS[head]

@@ -118,9 +118,12 @@ class TestShardPlans:
             assert got.shape == (1,) + want.shape and got.dtype == want.dtype
             np.testing.assert_array_equal(got[0], want)
 
-    @pytest.mark.parametrize("name", GRAPHS)
+    @pytest.mark.parametrize("name", GRAPHS + ("roomy_hub",))
     @pytest.mark.parametrize("shards", (2, 4))
     def test_every_entry_lies_with_its_owner_once_and_shapes_are_one(self, name, shards):
+        """A shard's entries (owner in its range) cut once more by the SOURCE,
+        the shard their neighbour lives on: every real entry in exactly one
+        (owner, source) sub-plan, the neighbour a row of that source's table."""
         src, dst, mask, n, nb = _graph(name)
         plans, cuts, entries, items = sparse.build_shard_plans(src, dst, mask, n, nb, shards)
         rows = nb // shards
@@ -130,26 +133,40 @@ class TestShardPlans:
             [(row_of[s], row_of[d], 0) for s, d in zip(src[mask], dst[mask])]
             + [(row_of[d], row_of[s], 1) for s, d in zip(src[mask], dst[mask])]
         )
+        assert len(entries) == len(items) == shards * shards  # flat, owner-major
+        assert plans.degree.shape == (shards, rows) and plans.neighbour.shape[:2] == (shards, shards)
         got = []
-        for d in range(shards):
-            one = jax.tree_util.tree_map(lambda a: a[d], plans)
-            k = entries[d]
-            owner = one.owner[0]
-            assert (np.diff(owner[:k]) >= 0).all() and (owner[:k] < cuts[d + 1] - cuts[d]).all()
+        for i, one in enumerate(sparse.sub_plans(plans)):
+            d, source = divmod(i, shards)
+            k, held = entries[i], cuts[d + 1] - cuts[d]
+            owner, neighbour = one.owner[0], one.neighbour
+            assert (np.diff(owner[:k]) >= 0).all() and (owner[:k] < held).all()  # owners ascend within a source
+            assert (np.diff(owner[:k] * 2 + one.direction[0, :k]) >= 0).all()  # and the directions within an owner
             assert (owner[k:] == rows).all()  # parked past every tile
-            got += [(d * rows + o, nb_, dr) for o, nb_, dr in zip(owner[:k], one.neighbour[:k], one.direction[0, :k])]
-            np.testing.assert_array_equal(one.degree[: cuts[d + 1] - cuts[d]], np.bincount(owner[:k], minlength=rows)[: cuts[d + 1] - cuts[d]])
-            assert (one.item_flag[: items[d]] >= 0).all() and (one.item_flag[items[d]:] == -1).all()
+            assert (neighbour[:k] < cuts[source + 1] - cuts[source]).all() and (neighbour[k:] == 0).all()  # a row of the SOURCE's table
+            got += [(d * rows + o, source * rows + nb_, dr) for o, nb_, dr in zip(owner[:k], neighbour[:k], one.direction[0, :k])]
+            assert (one.item_flag[: items[i]] >= 0).all() and (one.item_flag[items[i]:] == -1).all()
+            np.testing.assert_array_equal(one.degree, plans.degree[d])  # a shard's whole degree, whatever the source
+            if k == 0:  # nothing of this source for this owner: a plan that writes zeros
+                zeros = sparse._planned_reduce_xla(
+                    jax.tree_util.tree_map(jnp.asarray, one), jnp.ones((owner.shape[0], 4), jnp.float32))
+                assert zeros.shape[0] >= rows and not np.asarray(zeros).any()
         assert sorted(got) == want and sum(entries) == 2 * int(mask.sum())
-        # one shape, a function of the buckets while the entries fit it
-        base, tiles, n_items = sparse.plan_shapes(rows, src.shape[0] // shards)
-        assert plans.owner.shape[2] % sparse.PLAN_EDGE_BLOCK == 0 and plans.owner.shape[2] >= max(base, max(entries))
-        if max(entries) <= base:
-            assert plans.owner.shape == (shards, 1, base) and plans.item_tile.shape == (shards, n_items)
+        by_owner = np.reshape(entries, (shards, shards)).sum(axis=1)
+        for d in range(shards):  # the sources' entries add up to the owner's, and to its degree
+            assert by_owner[d] == plans.degree[d].sum() == sum(1 for o, _n, _d in want if o // rows == d)
+        # one shape, a function of the buckets while the fullest pair fits it, wider by eighths where a hub does not let it
+        base, tiles, n_items = sparse.plan_shapes(rows, src.shape[0] // shards // shards)
+        step = max(base // 8, sparse.PLAN_EDGE_BLOCK)
+        width = plans.owner.shape[3]
+        assert plans.owner.shape == (shards, shards, 1, width) and plans.item_tile.shape == (shards, shards, tiles + width // sparse.PLAN_EDGE_BLOCK)
+        assert width == max(base, -(-max(entries) // step) * step)
+        assert (width > base) == (name == "full_bucket")  # 40 nodes draw every edge: a pair outgrows the base
 
     def test_the_cuts_follow_the_entries_not_the_nodes(self):
         src, dst, mask, n, nb = _graph("roomy_hub")
-        _plans, cuts, entries, _items = sparse.build_shard_plans(src, dst, mask, n, nb, 4)
+        _plans, cuts, by_source, _items = sparse.build_shard_plans(src, dst, mask, n, nb, 4)
+        entries = np.reshape(by_source, (4, 4)).sum(axis=1).tolist()  # an owner's, over its sources
         degree = np.bincount(np.concatenate([src[mask], dst[mask]]), minlength=n)
         assert degree.max() > 600 and np.diff(cuts).max() < 256
         assert max(entries) - min(entries) <= degree.max()  # even to within the heaviest node's entries
@@ -214,20 +231,38 @@ class TestShardedSum:
         # the table forward, the cotangent backward and once more for `forward_of_ct`; nothing is sent back
         assert hlo.count("stablehlo.all_gather") == 3 and "reduce_scatter" not in hlo and "all_reduce" not in hlo
 
-    def test_the_interpreted_kernel_sums_a_shards_plan_over_the_whole_table(self):
-        """The Mosaic reducer on a plan whose table has more rows than it owns."""
+    def test_the_interpreted_kernel_sums_a_sub_plan_over_its_sources_table(self):
+        """The Mosaic reducer on one (owner, source) sub-plan against that
+        source's own table; the sources' parts add up to the owner's rows."""
         (src, dst, mask, n, nb), plans, cuts, h, table = _laid_out("hub", 4, width=18)
-        row_of = _rows(cuts, n, nb // 4)
+        rows = nb // 4
         want = _exact_sum(h, src, dst, mask)
         for d in (0, 3):
-            one = jax.tree_util.tree_map(lambda a: a[d], plans)
-            got = np.asarray(sparse.planned_neighbor_sum(one, jnp.asarray(table), "pallas_interpret"))
-            again = np.asarray(sparse.planned_neighbor_sum(one, jnp.asarray(table), "xla"))
-            assert got.shape == (nb // 4, 18)
+            total = np.zeros((rows, 18), np.float32)
+            for source in range(4):
+                one = sparse._source_plan(jax.tree_util.tree_map(lambda a: a[d], plans), source)
+                mine = jnp.asarray(table[source * rows : (source + 1) * rows])
+                got = np.asarray(sparse.planned_neighbor_sum(one, mine, "pallas_interpret"))
+                again = np.asarray(sparse.planned_neighbor_sum(one, mine, "xla"))
+                assert got.shape == (rows, 18)
+                np.testing.assert_allclose(got, again, rtol=1e-5, atol=1e-5)
+                total += got
             k = cuts[d + 1] - cuts[d]
-            np.testing.assert_allclose(got[:k], want[cuts[d] : cuts[d + 1]], rtol=1e-5, atol=1e-5)
-            np.testing.assert_allclose(got, again, rtol=1e-5, atol=1e-5)
-            assert not got[k:].any()
+            np.testing.assert_allclose(total[:k], want[cuts[d] : cuts[d + 1]], rtol=1e-5, atol=1e-5)
+            assert not total[k:].any()
+
+    @pytest.mark.parametrize("impl", ("xla", "pallas_interpret"))
+    def test_the_sharded_sum_walks_the_sources_through_either_reducer(self, impl):
+        """`sharded_neighbor_sum` itself, the Mosaic reducer interpreted: four
+        gathers from four tables, four reductions, the parts added."""
+        (src, dst, mask, n, nb), plans, cuts, h, table = _laid_out("hub", 4, width=18)
+        sharded = _over_the_mesh(
+            4, lambda p, rows: sparse.planned_neighbor_sum(sparse.ShardPlan(_first(p), AXIS), rows, impl), P(AXIS), P(AXIS)
+        )
+        lowered = jax.jit(sharded).lower(plans, jnp.asarray(table)).as_text()
+        assert lowered.count("stablehlo.all_gather") == 1 and lowered.count("optimization_barrier") == 4
+        got = np.asarray(jax.jit(sharded)(plans, jnp.asarray(table)))
+        np.testing.assert_allclose(got[_rows(cuts, n, nb // 4)], _exact_sum(h, src, dst, mask), rtol=1e-5, atol=1e-5)
 
     def test_every_row_that_crosses_is_float32(self):
         (_g, plans, _cuts, _h, table) = _laid_out("hub", 4)
@@ -511,7 +546,7 @@ class TestLayout:
 
     def test_spans_and_counts_of_a_sharded_build(self, four_shards):
         ds = _dataset(slots=3)
-        stacked.stack_dataset(ds)
+        st = stacked.stack_dataset(ds)
         tb = TRACER.traces()[-1]
         assert [s[0] for s in tb.spans] == ["refresh.stack", "refresh.stack.plan"] + [
             "refresh.stack.host_fill", "refresh.stack.device_put"
@@ -522,6 +557,11 @@ class TestLayout:
         plan = by_name["refresh.stack.plan"][0]
         assert plan["shards"] == 4 and sum(plan["shard_entries"]) == plan["entries"] == 2 * len(ds.src)
         assert len(plan["shard_items"]) == len(plan["shard_blocks"]) == 4 and sum(plan["shard_nodes"]) == 1000
+        # the cut by sources: a table a source, the (owner, source) pairs' real entries and items, flat and owner-major
+        assert plan["source_tables"] == 4 and len(plan["source_entries"]) == len(plan["source_items"]) == 16
+        assert np.reshape(plan["source_entries"], (4, 4)).sum(axis=1).tolist() == plan["shard_entries"]
+        assert np.reshape(plan["source_items"], (4, 4)).sum(axis=1).tolist() == plan["shard_items"] and sum(plan["source_items"]) == plan["items"]
+        assert st.plan.owner.shape[:2] == (4, 4) and st.plan.degree.shape == (4, 256)
         assert [c["shard"] for c in by_name["refresh.stack.device_put"]] == [0, 1, 2, 3]
         assert by_name["refresh.stack"][0]["shards"] == 4 and by_name["refresh.stack"][0]["nodes_per_shard"] == 256
         fills = [c["bytes"] for c in by_name["refresh.stack.host_fill"]]

@@ -14,6 +14,11 @@ the jitted callable in a :class:`Program` proxy that
   persistent XLA cache (core.compile_cache), so a restarted process can
   prewarm exactly the (program, bucket) pairs production traffic
   exercised;
+- keeps, of every call that compiled, the call's abstract arguments, and
+  on demand reads that signature's named scopes back from its executable
+  (:meth:`Program.scope_tables`: which HLO instruction belongs to which
+  ``jax.named_scope`` phase of ``SCOPE_PHASES``), so that a device trace
+  can be summed by the program's own names;
 - replays those specs at boot with zero-filled arguments
   (:meth:`Program.prewarm_spec`). A replayed dispatch populates the jit
   *dispatch* cache — unlike ``fn.lower(...).compile()``, which AOT-fills
@@ -41,6 +46,7 @@ import importlib
 import json
 import logging
 import os
+import re
 import threading
 import time
 from collections import deque
@@ -61,6 +67,11 @@ except Exception:  # noqa: BLE001 - profiling is optional at this layer
 
 _MAX_HINTS_PER_PROGRAM = 16
 _RECENT_RUNS = 64
+
+#: the phases a program names its device time by: the LAST `jax.named_scope`
+#: component of an op that is one of these is its phase (docs/OBSERVABILITY.md
+#: says what lies in each; `scope_of` reads them back from the compiled HLO)
+SCOPE_PHASES = ("gather", "reduce", "collective", "dense", "loss", "optimizer", "group")
 
 _registry_lock = threading.Lock()
 _REGISTRY: Dict[str, "Program"] = {}
@@ -175,6 +186,133 @@ def _bucket_label(spec: Any) -> str:
 
 
 # ---------------------------------------------------------------------------
+# named scopes, read back from a compiled program
+#
+# `jax.named_scope` costs nothing at run time: it is the `op_name` in the
+# metadata of every HLO instruction traced under it, which XLA's passes carry
+# along (a fusion takes its root's) and do not read. So the optimized HLO says
+# which instruction belongs to which stretch of the program, in the program's
+# own words, and a device trace's events (named by instruction) can be summed
+# by them.
+# ---------------------------------------------------------------------------
+
+#: what JAX wraps around a scope when it differentiates or batches the code
+#: under it: `transpose(jvp(graphsage))/layer2/...` (a wrapper holds the scope
+#: that follows it; older versions wrapped the whole path)
+_TRANSFORMS = frozenset({"jvp", "transpose", "vmap"})
+#: path components that are the tracer's, not a scope a program opened
+_STRUCTURAL = re.compile(r"^(jit\(.*\)|while|body|cond|closed_call|shard_map)$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+) \(.*\{\s*$")
+_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+#: a computation that runs INSIDE one instruction, and is timed as that one: a
+#: fusion's body, a reducer (`call` alone applies a computation as a program does)
+_INNER = re.compile(r"(?:\bcalls|\bto_apply)=%?([\w.\-]+)")
+#: instructions that move or name values and run nothing: in no table
+_NO_WORK = frozenset(
+    {"parameter", "tuple", "get-tuple-element", "constant", "bitcast", "while", "conditional", "call"}
+)
+_SPLAT = re.compile(r"\sbroadcast\(%?constant[\w.\-]*\)")  # a constant, spelled out
+#: an `op_name` that no line of the program wrote: a loop's own counter, test,
+#: slice of its inputs and stack of its outputs stand right under `body` or
+#: `cond` (what a scan's body traced stands under `closed_call`), the
+#: partitioner's own arithmetic right under `shard_map` or under no path at
+#: all, and where XLA merged instructions the name may end in no primitive
+_NOT_THE_PROGRAMS = re.compile(
+    r"(?:^|/)(?:body|cond|shard_map)/[^/]+$|^[^/]*$|(?:^|/)(?:closed_call|body|cond|shard_map|jit\([^/]*\))$"
+)
+
+
+ScopeTable = Dict[str, Tuple[str, Optional[str], bool]]
+
+
+def scope_of(op_name: str) -> Tuple[str, Optional[str], bool]:
+    """An instruction's `op_name` -> (scope path, phase, backward).
+
+    The path is what the program's `jax.named_scope`s spell, without the
+    tracer's own components (`jit(..)`, `while/body`, `shard_map`), without
+    JAX's wrapping (`transpose(jvp(a))/b/gather/mul` -> `a/b/gather`, and
+    backward: an op under a `transpose` is the backward pass's; the tests
+    read it to pin that a custom-VJP rule opens its scope itself) and without
+    the last component, the primitive's name. The phase is the last component
+    of the path that `SCOPE_PHASES` holds, None where there is none."""
+    out, opened, word, backward = [], [], "", False
+    for ch in op_name:
+        if ch == "(":
+            wrapper = word in _TRANSFORMS
+            backward = backward or word == "transpose"
+            opened.append(wrapper)
+            out.append("" if wrapper else word + "(")
+            word = ""
+        elif ch == ")":
+            out.append(word)
+            word = ""
+            if not opened or not opened.pop():
+                out.append(")")
+        elif ch == "/":
+            out.append(word + "/")
+            word = ""
+        else:
+            word += ch
+    out.append(word)
+    path = [c for c in "".join(out).split("/")[:-1] if c and not _STRUCTURAL.match(c)]
+    phase = next((c for c in reversed(path) if c in SCOPE_PHASES), None)
+    return "/".join(path), phase, backward
+
+
+def scope_table_of(hlo_text: str) -> ScopeTable:
+    """Optimized HLO text -> {instruction name: (scope path, phase, backward)}
+    for every instruction that a line of the program traced and that a device
+    runs and a trace times as one: none of a fusion's body or of a reducer, no
+    parameter, tuple, constant or control flow (a loop's own counter, test,
+    slices and stacks among it). An instruction is credited to the scope ITS
+    metadata carries: a fusion to its root's. What XLA put in itself (a copy,
+    with no metadata) is in no table: a reader counts its time as unscoped, as
+    it does a row whose phase is None, which is what a scope has to cure."""
+    table: ScopeTable = {}
+    inside: Dict[str, str] = {}  # instruction -> the computation it stands in
+    inner, computation = set(), ""
+    for line in hlo_text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header is not None:
+            computation = header.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        name, rest = m.groups()
+        opcode = _OPCODE.search(" " + rest)
+        if opcode is None:
+            continue
+        if opcode.group(1) != "call":
+            inner.update(_INNER.findall(rest))
+        if opcode.group(1) in _NO_WORK or _SPLAT.search(" " + rest):
+            continue
+        named = _OP_NAME.search(rest)
+        if named is None or _NOT_THE_PROGRAMS.search(named.group(1)):
+            continue
+        table[name] = scope_of(named.group(1))
+        inside[name] = computation
+    return {name: row for name, row in table.items() if inside[name] not in inner}
+
+
+def _abstract(x: Any) -> Any:
+    """An array leaf as its `jax.ShapeDtypeStruct`; anything else (a static
+    argument) as it is. A committed array keeps its sharding; one that lies
+    wherever JAX put it keeps none, as jit itself sees it, so that lowering
+    the abstract call again finds jit's own lowering and executable."""
+    if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+        return x
+    import jax
+
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding, weak_type=bool(getattr(x, "weak_type", False))
+    )
+
+
+# ---------------------------------------------------------------------------
 # Program proxy
 # ---------------------------------------------------------------------------
 
@@ -214,6 +352,9 @@ class Program:
         # training labels (run_ms 0.0 until a warm call lands)
         self._labels: Dict[str, Tuple[Any, float, float]] = {}
         self._suppress_record = False
+        # [abstract (args, kwargs), scope table once somebody has asked] of
+        # every call that compiled, a signature once, oldest first
+        self._compiled_calls: List[list] = []
 
     # -- delegation ---------------------------------------------------------
     def __call__(self, *args, **kwargs):
@@ -244,6 +385,10 @@ class Program:
         if grew:
             if _prof_device_attr is not None:
                 _prof_device_attr.note_compile(self.name, grew, elapsed_ms)
+            try:
+                self._keep_abstract(args, kwargs)
+            except Exception as e:  # noqa: BLE001 - the call itself succeeded
+                logger.debug("%s: signature not kept: %s", self.name, e)
             if not self._suppress_record:
                 self._record_spec(args, kwargs, compile_ms=elapsed_ms)
         return out
@@ -276,6 +421,49 @@ class Program:
             return int(self.fn._cache_size())
         except Exception:  # noqa: BLE001 - non-jit callables track calls only
             return None
+
+    # -- named scopes -------------------------------------------------------
+    def _keep_abstract(self, args, kwargs) -> None:
+        """Keep the signature of a call that compiled (never of a warm
+        dispatch): every array leaf as a `jax.ShapeDtypeStruct`, static
+        arguments as they are. No buffer is held, donated or not."""
+        import jax
+
+        if not jax.core.trace_ctx.is_top_level():
+            return  # inner-jit retrace: the leaves are tracers
+        kept = jax.tree_util.tree_map(_abstract, (tuple(args), dict(kwargs)))
+        with self._lock:
+            if all(kept != known for known, _table in self._compiled_calls):
+                self._compiled_calls.append([kept, None])
+                del self._compiled_calls[:-_MAX_HINTS_PER_PROGRAM]
+
+    def _scope_table_of(self, kept: list) -> ScopeTable:
+        if kept[1] is None:
+            args, kwargs = kept[0]
+            kept[1] = scope_table_of(self.fn.lower(*args, **kwargs).compile().as_text())
+        return kept[1]
+
+    def scope_tables(self) -> List[ScopeTable]:
+        """{instruction name: (scope path, phase, backward)} (`scope_table_of`) of
+        every signature of this program that a call compiled, oldest first:
+        a program compiles anew for other shapes, and the executables of two
+        signatures share most instruction names with another numbering, so a
+        reader of a device trace takes the table whose names are the trace's
+        (benchmarks/harness/program_scopes). A table is made on the first ask
+        and kept. Lowering a kept signature finds jit's own lowering of that
+        call and with it the executable that runs (nothing is compiled or
+        loaded twice); where jit has dropped it, the program is lowered and
+        compiled again, a load from the compile cache where one is set up."""
+        with self._lock:
+            kept = list(self._compiled_calls)
+        return [self._scope_table_of(entry) for entry in kept]
+
+    def scope_table(self) -> ScopeTable:
+        """The scope table of the newest signature that compiled, and of no
+        other; empty where no call has compiled."""
+        with self._lock:
+            newest = self._compiled_calls[-1:]
+        return self._scope_table_of(newest[0]) if newest else {}
 
     # -- shape hints --------------------------------------------------------
     def _record_spec(self, args, kwargs, compile_ms: float = 0.0) -> None:

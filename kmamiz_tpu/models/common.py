@@ -33,19 +33,20 @@ def make_loss_fn(forward, pos_weight: float = 1.0, axis_name=None):
         pred_latency, anomaly_logit = forward(
             share(params), features, src_ep, dst_ep, edge_mask
         )
-        w = node_mask.astype(jnp.float32)
-        denom = jnp.maximum(total(w.sum()), 1.0)
-        latency_loss = total(jnp.sum(w * (pred_latency - target_latency) ** 2)) / denom
-        class_w = 1.0 + (pos_weight - 1.0) * target_anomaly
-        anomaly_loss = (
-            total(jnp.sum(
-                w
-                * class_w
-                * optax.sigmoid_binary_cross_entropy(anomaly_logit, target_anomaly)
-            ))
-            / denom
-        )
-        return latency_loss + anomaly_loss, (latency_loss, anomaly_loss)
+        with jax.named_scope("loss"):  # the masked sums; `total`'s adding over devices is `collective` beneath
+            w = node_mask.astype(jnp.float32)
+            denom = jnp.maximum(total(w.sum()), 1.0)
+            latency_loss = total(jnp.sum(w * (pred_latency - target_latency) ** 2)) / denom
+            class_w = 1.0 + (pos_weight - 1.0) * target_anomaly
+            anomaly_loss = (
+                total(jnp.sum(
+                    w
+                    * class_w
+                    * optax.sigmoid_binary_cross_entropy(anomaly_logit, target_anomaly)
+                ))
+                / denom
+            )
+            return latency_loss + anomaly_loss, (latency_loss, anomaly_loss)
 
     return loss_fn
 

@@ -167,23 +167,28 @@ def forward(
     two segment softmaxes and weighted sums are then one pass over the
     plan's sorted entries (sparse.planned_attention), the same mathematics
     in another order. Without one the edge list is reduced as it comes."""
-    x = common.concat_embedding(features, params.embedding)
-    h1 = _layer(
-        x, src_ep, dst_ep, edge_mask,
-        params.w_1, params.a_src_1, params.a_dst_1,
-        params.a_src_1r, params.a_dst_1r, params.b_1, plan,
-    )
-    h2 = _layer(
-        h1, src_ep, dst_ep, edge_mask,
-        params.w_2, params.a_src_2, params.a_dst_2,
-        params.a_src_2r, params.a_dst_2r, params.b_2, plan,
-    )
-    latency = (
-        h2 @ params.w_latency + features @ params.w_latency_skip + params.b_latency
-    )[:, 0]
-    anomaly_logit = (
-        h2 @ params.w_anomaly + features @ params.w_anomaly_skip + params.b_anomaly
-    )[:, 0]
+    # the device's names for these stretches (docs/OBSERVABILITY.md): a layer is
+    # `dense` but for what the planned attention names `gather` and `reduce` beneath it
+    with jax.named_scope("gat/layer1/dense"):
+        x = common.concat_embedding(features, params.embedding)
+        h1 = _layer(
+            x, src_ep, dst_ep, edge_mask,
+            params.w_1, params.a_src_1, params.a_dst_1,
+            params.a_src_1r, params.a_dst_1r, params.b_1, plan,
+        )
+    with jax.named_scope("gat/layer2/dense"):
+        h2 = _layer(
+            h1, src_ep, dst_ep, edge_mask,
+            params.w_2, params.a_src_2, params.a_dst_2,
+            params.a_src_2r, params.a_dst_2r, params.b_2, plan,
+        )
+    with jax.named_scope("gat/readout/dense"):
+        latency = (
+            h2 @ params.w_latency + features @ params.w_latency_skip + params.b_latency
+        )[:, 0]
+        anomaly_logit = (
+            h2 @ params.w_anomaly + features @ params.w_anomaly_skip + params.b_anomaly
+        )[:, 0]
     return latency, anomaly_logit
 
 

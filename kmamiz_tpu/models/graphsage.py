@@ -224,23 +224,28 @@ def forward(
     stands for the sum of `features` alone, so a head with embeddings (whose
     layer-1 input holds parameters) must not be given one. Layer 2 always
     makes its own: `h1` depends on the parameters."""
-    x = _common.concat_embedding(features, params.embedding)
-    if plan is None:
-        deg = neighbor_degree(features.shape[0], src_ep, dst_ep, edge_mask)
-    else:
-        deg = plan.degree
-    agg1 = neighbor_mean(x, src_ep, dst_ep, edge_mask, deg, plan, neighbor_sum_1)
-    h1 = jax.nn.relu(
-        x @ params.w_self_1 + agg1 @ params.w_neigh_1 + params.b_1
-    )
-    agg2 = neighbor_mean(h1, src_ep, dst_ep, edge_mask, deg, plan)
-    h2 = jax.nn.relu(h1 @ params.w_self_2 + agg2 @ params.w_neigh_2 + params.b_2)
-    latency = (
-        h2 @ params.w_latency + features @ params.w_latency_skip + params.b_latency
-    )[:, 0]
-    anomaly_logit = (
-        h2 @ params.w_anomaly + features @ params.w_anomaly_skip + params.b_anomaly
-    )[:, 0]
+    # the device's names for these stretches (docs/OBSERVABILITY.md): a layer is
+    # `dense` but for what a planned sum names `gather` and `reduce` beneath it
+    with jax.named_scope("graphsage/layer1/dense"):
+        x = _common.concat_embedding(features, params.embedding)
+        if plan is None:
+            deg = neighbor_degree(features.shape[0], src_ep, dst_ep, edge_mask)
+        else:
+            deg = plan.degree
+        agg1 = neighbor_mean(x, src_ep, dst_ep, edge_mask, deg, plan, neighbor_sum_1)
+        h1 = jax.nn.relu(
+            x @ params.w_self_1 + agg1 @ params.w_neigh_1 + params.b_1
+        )
+    with jax.named_scope("graphsage/layer2/dense"):
+        agg2 = neighbor_mean(h1, src_ep, dst_ep, edge_mask, deg, plan)
+        h2 = jax.nn.relu(h1 @ params.w_self_2 + agg2 @ params.w_neigh_2 + params.b_2)
+    with jax.named_scope("graphsage/readout/dense"):
+        latency = (
+            h2 @ params.w_latency + features @ params.w_latency_skip + params.b_latency
+        )[:, 0]
+        anomaly_logit = (
+            h2 @ params.w_anomaly + features @ params.w_anomaly_skip + params.b_anomaly
+        )[:, 0]
     return latency, anomaly_logit
 
 

@@ -86,6 +86,14 @@ device-native:
   targets and the plans never move. A shard's `refresh.stack.device_put`
   ends with its transfer, so the host holds one shard's copy at a time.
 
+- the DEVICE'S NAMES: the block's body, the heads' forwards and the planned
+  reductions stand under `jax.named_scope`s of one small taxonomy
+  (`core/programs.SCOPE_PHASES`: gather, reduce, collective, dense, loss,
+  optimizer, group; docs/OBSERVABILITY.md says what lies in each). They are
+  metadata of the compiled program and cost nothing; `Program.scope_table()`
+  of a block reads them back, so a device trace's time can be summed by the
+  program's own words. Always there: no option, no branch.
+
 Bit discipline: with the default batch size of 1 the scan body performs
 the identical per-slot update sequence as the legacy Python loop; only
 array padding (masked, zero-contribution) and float32 loss averaging
@@ -454,9 +462,11 @@ def epoch_runner(model, lr: float, pos_weight: float):
             (loss, (lat_l, ano_l)), grads = slot_grad(
                 p, f, src, dst, edge_mask, tl, ta, nm
             )
-            updates, s = optimizer.update(grads, s, p)
-            p = optax.apply_updates(p, updates)
-            return (p, s), jnp.stack([loss, lat_l, ano_l])
+            with jax.named_scope("optimizer"):
+                updates, s = optimizer.update(grads, s, p)
+                p = optax.apply_updates(p, updates)
+            with jax.named_scope("loss"):
+                return (p, s), jnp.stack([loss, lat_l, ano_l])
 
         def epoch_step(carry, _):
             carry, per_slot = jax.lax.scan(
@@ -464,7 +474,8 @@ def epoch_runner(model, lr: float, pos_weight: float):
                 carry,
                 (features, target_latency, target_anomaly, node_mask),
             )
-            return carry, per_slot.mean(axis=0)
+            with jax.named_scope("loss"):
+                return carry, per_slot.mean(axis=0)
 
         n_slots, n_nodes, width = features.shape
         at = functools.partial(jax.lax.dynamic_index_in_dim, keepdims=False)
@@ -474,34 +485,41 @@ def epoch_runner(model, lr: float, pos_weight: float):
             neighbour sums in one planned sum, then their updates in order."""
             # the last group reaches back to stay inside the stack, and its
             # loop starts at the first slot that is the group's own
-            start = jnp.minimum(first, n_slots - group)
-            packed = jax.lax.dynamic_slice_in_dim(features, start, group)
-            table = jnp.moveaxis(packed, 0, 1).reshape(n_nodes, group * width)
-            sums = sparse.planned_neighbor_sum(plan, table)  # [Nb, group * F]
+            with jax.named_scope("group"):  # its gather and its reducer name themselves beneath it
+                start = jnp.minimum(first, n_slots - group)
+                packed = jax.lax.dynamic_slice_in_dim(features, start, group)
+                table = jnp.moveaxis(packed, 0, 1).reshape(n_nodes, group * width)
+                sums = sparse.planned_neighbor_sum(plan, table)  # [Nb, group * F]
+                own = first - start  # where the loop below starts
 
             def member_step(j, carry):
                 carry, per_slot = carry
-                t = start + j
-                # the features from the group's slice, not from the stack:
-                # with per-slot reads of the stack beside the group's, XLA
-                # re-laid the whole stack slot-major first (5.4 GB, PERF.md)
-                xs = (
-                    at(packed, j),
-                    at(target_latency, t),
-                    at(target_anomaly, t),
-                    at(node_mask, t),
-                )
-                mine = jax.lax.dynamic_slice_in_dim(sums, j * width, width, axis=1)
+                with jax.named_scope("group"):
+                    t = start + j
+                    # the features from the group's slice, not from the stack:
+                    # with per-slot reads of the stack beside the group's, XLA
+                    # re-laid the whole stack slot-major first (5.4 GB, PERF.md)
+                    xs = (
+                        at(packed, j),
+                        at(target_latency, t),
+                        at(target_anomaly, t),
+                        at(node_mask, t),
+                    )
+                    mine = jax.lax.dynamic_slice_in_dim(sums, j * width, width, axis=1)
                 carry, losses = slot_step(carry, xs, neighbor_sum_1=mine)
-                return carry, per_slot.at[t].set(losses)
+                with jax.named_scope("loss"):
+                    return carry, per_slot.at[t].set(losses)
 
-            return jax.lax.fori_loop(first - start, group, member_step, carry), None
+            return jax.lax.fori_loop(own, group, member_step, carry), None
 
         def grouped_epoch_step(carry, _):
-            firsts = jnp.arange(0, n_slots, group, dtype=jnp.int32)
-            per_slot = jnp.zeros((n_slots, 3), jnp.float32)
+            with jax.named_scope("group"):
+                firsts = jnp.arange(0, n_slots, group, dtype=jnp.int32)
+            with jax.named_scope("loss"):
+                per_slot = jnp.zeros((n_slots, 3), jnp.float32)
             (carry, per_slot), _ = jax.lax.scan(group_step, (carry, per_slot), firsts)
-            return carry, per_slot.mean(axis=0)
+            with jax.named_scope("loss"):
+                return carry, per_slot.mean(axis=0)
 
         (params, opt_state), losses = jax.lax.scan(
             grouped_epoch_step if group else epoch_step,

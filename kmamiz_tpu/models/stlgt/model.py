@@ -153,14 +153,17 @@ def encode(
     list, for whoever asks; under the loss nobody does, and XLA drops
     them."""
     n = features.shape[0]
-    # lane mask: a padded slot has an all-zero feature row; real slots
-    # always carry at least the hour-of-day cos column
-    lane = (jnp.abs(features).sum(axis=1) > 0).astype(jnp.float32)
+    # the device's names for the stretches below (docs/OBSERVABILITY.md): each is
+    # `dense` but for what the gated sum names `gather` and `reduce` beneath it
+    with jax.named_scope("stlgt/input/dense"):
+        # lane mask: a padded slot has an all-zero feature row; real slots
+        # always carry at least the hour-of-day cos column
+        lane = (jnp.abs(features).sum(axis=1) > 0).astype(jnp.float32)
 
-    x = jax.nn.relu(features @ params.w_in + params.b_in)
-    q = _phi(x @ params.w_q) * lane[:, None]
-    k = _phi(x @ params.w_k) * lane[:, None]
-    v = (x @ params.w_v) * lane[:, None]
+        x = jax.nn.relu(features @ params.w_in + params.b_in)
+        q = _phi(x @ params.w_q) * lane[:, None]
+        k = _phi(x @ params.w_k) * lane[:, None]
+        v = (x @ params.w_v) * lane[:, None]
 
     # global linear attention: O(N·H²) — softmax-free. kv and z are sums
     # over EVERY endpoint, so they sit in every endpoint's state: where a
@@ -171,39 +174,42 @@ def encode(
     # slot in seven (PERF.md, PR 33). The two products that READ the sums
     # are therefore made at float32 ([N, H] x [H, H]: six passes of nothing);
     # k.T @ v itself rounds per endpoint, which averages out, and stays.
-    kv = k.T @ v  # [H, H]
-    z = k.sum(axis=0)  # [H]
-    exact = jax.lax.Precision.HIGHEST
-    attn = jnp.matmul(q, kv, precision=exact) / (jnp.matmul(q, z, precision=exact) + 1e-6)[:, None]
+    with jax.named_scope("stlgt/linear_attention/dense"):
+        kv = k.T @ v  # [H, H]
+        z = k.sum(axis=0)  # [H]
+        exact = jax.lax.Precision.HIGHEST
+        attn = jnp.matmul(q, kv, precision=exact) / (jnp.matmul(q, z, precision=exact) + 1e-6)[:, None]
 
     # neighbor bias from the CSR edge list: gated messages over both
     # directions (callers and callees are both signal), sentinel-indexed
     # like graphsage.neighbor_mean so padded edges contribute nothing.
-    em = edge_mask.astype(jnp.float32)
-    src_c = jnp.minimum(src_ep, n - 1)
-    dst_c = jnp.minimum(dst_ep, n - 1)
-    affinity = (q[src_c] * k[dst_c]).sum(axis=1) / jnp.sqrt(
-        jnp.float32(q.shape[1])
-    )
-    gate = jax.nn.sigmoid(affinity + params.b_edge[0]) * em
-    if plan is not None:
-        bias = sparse_gated.planned_gated_sum(plan, q, k, v, params.b_edge)
-    else:
-        src_s = jnp.where(edge_mask, src_ep, n)
-        dst_s = jnp.where(edge_mask, dst_ep, n)
-        msg_fwd = v[src_c] * gate[:, None]
-        msg_bwd = v[dst_c] * gate[:, None]
-        bias = jax.ops.segment_sum(msg_fwd, dst_s, num_segments=n + 1)[:-1]
-        bias = bias + jax.ops.segment_sum(msg_bwd, src_s, num_segments=n + 1)[:-1]
-        deg = jax.ops.segment_sum(gate, dst_s, num_segments=n + 1)[:-1]
-        deg = deg + jax.ops.segment_sum(gate, src_s, num_segments=n + 1)[:-1]
-        bias = bias / jnp.maximum(deg, 1.0)[:, None]
+    with jax.named_scope("stlgt/neighbor_bias/dense"):
+        em = edge_mask.astype(jnp.float32)
+        src_c = jnp.minimum(src_ep, n - 1)
+        dst_c = jnp.minimum(dst_ep, n - 1)
+        affinity = (q[src_c] * k[dst_c]).sum(axis=1) / jnp.sqrt(
+            jnp.float32(q.shape[1])
+        )
+        gate = jax.nn.sigmoid(affinity + params.b_edge[0]) * em
+        if plan is not None:
+            bias = sparse_gated.planned_gated_sum(plan, q, k, v, params.b_edge)
+        else:
+            src_s = jnp.where(edge_mask, src_ep, n)
+            dst_s = jnp.where(edge_mask, dst_ep, n)
+            msg_fwd = v[src_c] * gate[:, None]
+            msg_bwd = v[dst_c] * gate[:, None]
+            bias = jax.ops.segment_sum(msg_fwd, dst_s, num_segments=n + 1)[:-1]
+            bias = bias + jax.ops.segment_sum(msg_bwd, src_s, num_segments=n + 1)[:-1]
+            deg = jax.ops.segment_sum(gate, dst_s, num_segments=n + 1)[:-1]
+            deg = deg + jax.ops.segment_sum(gate, src_s, num_segments=n + 1)[:-1]
+            bias = bias / jnp.maximum(deg, 1.0)[:, None]
 
-    h1 = x + jax.nn.relu((attn + bias) @ params.w_o)
-    h2 = h1 + jax.nn.relu(
-        jax.nn.relu(h1 @ params.w_f1 + params.b_f1) @ params.w_f2 + params.b_f2
-    )
-    return h2 * lane[:, None], gate
+    with jax.named_scope("stlgt/ffn/dense"):
+        h1 = x + jax.nn.relu((attn + bias) @ params.w_o)
+        h2 = h1 + jax.nn.relu(
+            jax.nn.relu(h1 @ params.w_f1 + params.b_f1) @ params.w_f2 + params.b_f2
+        )
+        return h2 * lane[:, None], gate
 
 
 def forward_quantiles(
@@ -221,14 +227,15 @@ def forward_quantiles(
     each later level adds a softplus increment — a crossed quantile pair
     cannot be emitted, so coverage scoring never needs to re-sort."""
     h, gate = encode(params, features, src_ep, dst_ep, edge_mask, plan)
-    raw = h @ params.w_quant + features @ params.w_quant_skip + params.b_quant
-    q50 = raw[:, 0]
-    q95 = q50 + jax.nn.softplus(raw[:, 1])
-    q99 = q95 + jax.nn.softplus(raw[:, 2])
-    quantiles = jnp.stack([q50, q95, q99], axis=1)
-    anomaly_logit = (
-        h @ params.w_anomaly + features @ params.w_anomaly_skip + params.b_anomaly
-    )[:, 0]
+    with jax.named_scope("stlgt/readout/dense"):
+        raw = h @ params.w_quant + features @ params.w_quant_skip + params.b_quant
+        q50 = raw[:, 0]
+        q95 = q50 + jax.nn.softplus(raw[:, 1])
+        q99 = q95 + jax.nn.softplus(raw[:, 2])
+        quantiles = jnp.stack([q50, q95, q99], axis=1)
+        anomaly_logit = (
+            h @ params.w_anomaly + features @ params.w_anomaly_skip + params.b_anomaly
+        )[:, 0]
     return quantiles, anomaly_logit, gate
 
 
@@ -276,23 +283,24 @@ def make_pinball_loss_fn(
         pred_q, anomaly_logit, _gate = forward_quantiles(
             params, features, src_ep, dst_ep, edge_mask, plan
         )
-        w = node_mask.astype(jnp.float32)
-        denom = jnp.maximum(w.sum(), 1.0)
-        diff = target_latency[:, None] - pred_q  # [N, Q]
-        pinball = jnp.maximum(taus * diff, (taus - 1.0) * diff)
-        quant_loss = jnp.sum(w[:, None] * pinball) / denom
-        class_w = 1.0 + (pos_weight - 1.0) * target_anomaly
-        anomaly_loss = (
-            jnp.sum(
-                w
-                * class_w
-                * optax.sigmoid_binary_cross_entropy(
-                    anomaly_logit, target_anomaly
+        with jax.named_scope("loss"):
+            w = node_mask.astype(jnp.float32)
+            denom = jnp.maximum(w.sum(), 1.0)
+            diff = target_latency[:, None] - pred_q  # [N, Q]
+            pinball = jnp.maximum(taus * diff, (taus - 1.0) * diff)
+            quant_loss = jnp.sum(w[:, None] * pinball) / denom
+            class_w = 1.0 + (pos_weight - 1.0) * target_anomaly
+            anomaly_loss = (
+                jnp.sum(
+                    w
+                    * class_w
+                    * optax.sigmoid_binary_cross_entropy(
+                        anomaly_logit, target_anomaly
+                    )
                 )
+                / denom
             )
-            / denom
-        )
-        return quant_loss + anomaly_loss, (quant_loss, anomaly_loss)
+            return quant_loss + anomaly_loss, (quant_loss, anomaly_loss)
 
     return loss_fn
 

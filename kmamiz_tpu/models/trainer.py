@@ -15,7 +15,9 @@ works on any simulation config.
 """
 from __future__ import annotations
 
+import gc
 import logging
+import statistics
 from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
 
@@ -45,6 +47,14 @@ _EPOCH_BLOCKS = REGISTRY.counter(
     "kmamiz_model_refresh_epoch_blocks_total",
     "Fused epoch-block programs dispatched by model refreshes",
 )
+
+_SLOW_CALLS = REGISTRY.counter(
+    "kmamiz_model_refresh_slow_calls_total",
+    "Epoch-block runs that took over SLOW_CALL_RATIO times the median of the program's previous runs of their size",
+)
+SLOW_CALL_RATIO = 1.25  # of the median of at least SLOW_CALL_MIN_RUNS previous runs
+SLOW_CALL_MIN_RUNS = 3
+SLOW_CALL_MIN_EXCESS_MS = 25.0  # a run of milliseconds (a toy mesh) jitters by more than a quarter and means nothing
 
 ANOMALY_ERROR_SHARE = 0.10  # next-slot 5xx share that counts as anomalous
 SLOT_SECONDS = 3600.0  # simulator slots are hourly
@@ -205,6 +215,42 @@ def _epoch_blocks(start: int, total: int, every: int) -> List[Tuple[int, int]]:
     return blocks
 
 
+def _call_start():
+    """What `_slow_call` compares the end of a call with: the registry's compile counts
+    and the garbage collector's collections a generation."""
+    return programs.snapshot(), [g["collections"] for g in gc.get_stats()]
+
+
+def _slow_call(runner, run_ms: float, units: int, start) -> None:
+    """Leave a record of an epoch-block run that took over SLOW_CALL_RATIO times the median
+    of the program's previous runs of the same `units` (before `note_run` adds this one) and
+    at least SLOW_CALL_MIN_EXCESS_MS longer:
+    ONE warning line that says where the call's time went, so that a stalled call of a run
+    nobody traced says whether it was the host's (a span, a collection, a compile) or lay
+    between dispatch and losses; the slow-call counter; and the same text to the flight
+    recorder (debounced and gated as every trigger is)."""
+    previous = [ms for _end_s, ms, u in runner.recent_runs() if u == units]
+    median = statistics.median(previous) if len(previous) >= SLOW_CALL_MIN_RUNS else float("inf")
+    if run_ms <= max(SLOW_CALL_RATIO * median, median + SLOW_CALL_MIN_EXCESS_MS):
+        return
+    from kmamiz_tpu.telemetry import device as tel_device
+    from kmamiz_tpu.telemetry.profiling import recorder
+
+    compiled, collections = start
+    spans = {name: round(ms, 1) for name, ms in TRACER.children_ms().items()}
+    with phase_span("refresh.slow_call"):  # the record's own cost (a flight artifact is a file) lies in a phase too
+        gc_now = [g["collections"] - before for g, before in zip(gc.get_stats(), collections)]
+        detail = (
+            f"slow refresh call: {runner.name} ran {run_ms:.1f} ms for {units} slot updates, "
+            f"{run_ms / median:.2f} times the median of its {len(previous)} previous runs; "
+            f"spans_ms={spans} compiles={programs.new_compiles_since(compiled)} gc_collections={gc_now} "
+            f"bytes_in_use={(tel_device.device_memory_stats() or {}).get('bytes_in_use')}"
+        )
+        logger.warning(detail)
+        _SLOW_CALLS.inc()
+        recorder.record("refresh-slow-call", detail)
+
+
 @operation_span("refresh.train")
 def train(
     dataset: GraphDataset,
@@ -250,6 +296,7 @@ def train(
     model_name = getattr(model, "NAME", model.__name__.rsplit(".", 1)[-1])
     num_slots = len(dataset.features) if dataset is not None else 0
     _REFRESHES.inc()
+    call_start = _call_start()
     TRACER.note(
         model=model_name,
         loss=getattr(model, "LOSS", "mse+bce"),  # the head's own, where it states one
@@ -446,9 +493,9 @@ def train(
             with phase_span("refresh.loss_fetch"):
                 block = np.asarray(block, dtype=np.float64)  # [e1-e0, 3]
             if isinstance(runner, programs.Program):  # a mesh runner is none
-                runner.note_run(
-                    (prof_events.now_ns() - dispatched_ns) / 1e6, slot_updates
-                )
+                run_ms = (prof_events.now_ns() - dispatched_ns) / 1e6
+                _slow_call(runner, run_ms, slot_updates, call_start)
+                runner.note_run(run_ms, slot_updates)
             _EPOCH_BLOCKS.inc()
             _SLOT_UPDATES.inc(slot_updates)
             losses.extend(block[:, 0].tolist())

@@ -340,12 +340,15 @@ def planned_impl() -> str:
 
 
 def _planned_sum(plan: EdgePlan, h, impl: str):
-    messages = h.astype(jnp.float32)[plan.neighbour]  # [L, W]; parked read row 0
-    if impl == "xla":
-        out = _planned_reduce_xla(plan, messages)
-    else:
-        out = _planned_reduce_pallas(plan, messages, impl == "pallas_interpret")
-    return out[: min(h.shape[0], plan.degree.shape[0])].astype(h.dtype)  # a shard's plan owns fewer rows than its table holds
+    # `gather` and `reduce`: the phases a device trace is summed by (docs/OBSERVABILITY.md), here and below
+    with jax.named_scope("gather"):
+        messages = h.astype(jnp.float32)[plan.neighbour]  # [L, W]; parked read row 0
+    with jax.named_scope("reduce"):
+        if impl == "xla":
+            out = _planned_reduce_xla(plan, messages)
+        else:
+            out = _planned_reduce_pallas(plan, messages, impl == "pallas_interpret")
+        return out[: min(h.shape[0], plan.degree.shape[0])].astype(h.dtype)  # a shard's plan owns fewer rows than its table holds
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -787,7 +790,7 @@ def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bo
         with _route_lock:  # a new dict: `route_stats()` hands out the old one
             _route_counts["mxu_products"] = {**_route_counts["mxu_products"], name: _mxu_calls.n - before}
 
-    return pl.pallas_call(
+    walk = pl.pallas_call(
         counted,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -813,7 +816,9 @@ def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bo
         ),
         name=name,
         interpret=interpret,
-    )(plan.item_tile, plan.item_block, plan.item_flag, *(a for _kind, a in inputs))
+    )
+    with jax.named_scope("reduce"):
+        return walk(plan.item_tile, plan.item_block, plan.item_flag, *(a for _kind, a in inputs))
 
 
 def _node_table(nodes: int, lanes: int, *parts):
@@ -827,7 +832,8 @@ def _gather_rows(table, neighbour):
     XLA otherwise gathers the columns that are not padding and pads the
     [entries, lanes] result in a pass of its own, which costs more than the
     gather saves (a gathered row fills its 128 lanes in memory either way)."""
-    return jax.lax.optimization_barrier(table)[neighbour]
+    with jax.named_scope("gather"):
+        return jax.lax.optimization_barrier(table)[neighbour]
 
 
 def _node_rows(nodes: int, *columns):
@@ -958,11 +964,15 @@ def _attention_xla(plan: EdgePlan, hw, s, t, leak: float):
     top = jnp.where(top > neg / 2, top, 0.0)
     p = jnp.where(real, jnp.exp(jnp.clip(score - top[run], -60.0, 0.0)), 0.0)
     alpha = p / jnp.maximum(seg(p, run)[run], 1e-30)
-    out = jax.ops.segment_sum(
-        alpha[:, None] * hw[nbr], jnp.where(real, owner, nodes),
-        num_segments=nodes + 1, indices_are_sorted=True,
-    )
-    return out[:n]
+    with jax.named_scope("reduce"):
+        weights = alpha[:, None]
+        with jax.named_scope("gather"):
+            rows = hw[nbr]
+        out = jax.ops.segment_sum(
+            weights * rows, jnp.where(real, owner, nodes),
+            num_segments=nodes + 1, indices_are_sorted=True,
+        )
+        return out[:n]
 
 
 def planned_attention(
@@ -1109,13 +1119,18 @@ def _source_plan(plan: EdgePlan, source: int) -> EdgePlan:
 
 
 def _sharded_sum(plan: EdgePlan, h, impl: str, axis: str):
-    tables = jax.lax.all_gather(h, axis, axis=0, tiled=False)  # [shards, n, W], rows as stored
+    with jax.named_scope("collective"):
+        tables = jax.lax.all_gather(h, axis, axis=0, tiled=False)  # [shards, n, W], rows as stored
     out = None
     for source in range(plan.neighbour.shape[0]):
-        part = _planned_sum(_source_plan(plan, source), tables[source], impl)  # gathers from ONE shard's table
+        mine = _source_plan(plan, source)
+        with jax.named_scope("collective"):
+            table = tables[source]  # the slice out of the gathered array
+        part = _planned_sum(mine, table, impl)  # gathers from ONE shard's table
         # the chain: a source is done with its table before the next source's is cut out of `tables`
         part, tables = jax.lax.optimization_barrier((part, tables))
-        out = part if out is None else out + part
+        with jax.named_scope("reduce"):
+            out = part if out is None else out + part
     return out  # [n, W]: the rows this device owns
 
 

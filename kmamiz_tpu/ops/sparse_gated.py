@@ -278,13 +278,19 @@ def _gated_xla(plan: EdgePlan, q, k, v, b):
     real = _real(plan)
     own = jnp.minimum(owner, n - 1)
     caller = (d == 0)[:, None]
-    dots = (jnp.where(caller, q[own], k[own]) * jnp.where(caller, k[nbr], q[nbr])).sum(axis=1)
-    gate = jnp.where(real, jax.nn.sigmoid(dots * scale + b), 0.0)
-    seg = partial(
-        jax.ops.segment_sum, segment_ids=jnp.where(real, owner, nodes),
-        num_segments=nodes + 1, indices_are_sorted=True,
-    )
-    return seg(gate[:, None] * v[nbr])[:n], seg(gate)[:n]
+    with jax.named_scope("reduce"):  # the phases of the kernels' path (docs/OBSERVABILITY.md), on this one too
+        with jax.named_scope("gather"):
+            mine, theirs = jnp.where(caller, q[own], k[own]), jnp.where(caller, k[nbr], q[nbr])
+        dots = (mine * theirs).sum(axis=1)
+        gate = jnp.where(real, jax.nn.sigmoid(dots * scale + b), 0.0)
+        seg = partial(
+            jax.ops.segment_sum, segment_ids=jnp.where(real, owner, nodes),
+            num_segments=nodes + 1, indices_are_sorted=True,
+        )
+        weights = gate[:, None]
+        with jax.named_scope("gather"):
+            rows = v[nbr]
+        return seg(weights * rows)[:n], seg(gate)[:n]
 
 
 def planned_gated_sum(plan: EdgePlan, q, k, v, b_edge, impl: Optional[str] = None) -> jnp.ndarray:

@@ -801,17 +801,23 @@ def shared(x, axis: str):
 
 
 def _shared_bwd(axis, _, g):
-    return (jax.tree_util.tree_map(lambda a: jax.lax.psum(a, axis), g),)
+    with jax.named_scope("collective"):
+        return (jax.tree_util.tree_map(lambda a: jax.lax.psum(a, axis), g),)
 
 
 shared.defvjp(lambda x, axis: (x, None), _shared_bwd)
+
+
+def _total(x, axis: str):
+    with jax.named_scope("collective"):
+        return jax.lax.psum(x, axis)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
 def total(x, axis: str):
     """The devices' partial sums added up (a loss's sums and counts): every
     device gets the total, and hands each partial sum the total's cotangent."""
-    return jax.lax.psum(x, axis)
+    return _total(x, axis)
 
 
-total.defvjp(lambda x, axis: (jax.lax.psum(x, axis), None), lambda axis, _, g: (g,))
+total.defvjp(lambda x, axis: (_total(x, axis), None), lambda axis, _, g: (g,))

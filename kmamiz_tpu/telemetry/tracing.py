@@ -81,6 +81,7 @@ PHASES = (
     "refresh.stack.device_put",
     "refresh.epoch_block",
     "refresh.loss_fetch",
+    "refresh.slow_call",
     "refresh.checkpoint_save",
     "refresh.legacy_epoch",
 )
@@ -245,6 +246,23 @@ class TickTracer:
         builder = self.current()
         if builder is not None and builder._stack:
             builder.counts.setdefault(builder._stack[-1], {}).update(counts)
+
+    def children_ms(self) -> Dict[str, float]:
+        """Where the innermost open span of this thread's trace has spent
+        its time so far: the durations (ms) of its closed direct children
+        by name and, under "self", what of it they do not cover. Empty
+        outside a trace. For a record of ONE slow call (models/trainer)."""
+        builder = self.current()
+        if builder is None or not builder._stack:
+            return {}
+        idx = builder._stack[-1]
+        out: Dict[str, float] = {}
+        for name, _start, dur_ns, parent in builder.spans:
+            if parent == idx and dur_ns >= 0:
+                out[name] = out.get(name, 0.0) + dur_ns / 1e6
+        so_far_ns = time.perf_counter_ns() - builder.t0_ns - builder.spans[idx][1]
+        out["self"] = so_far_ns / 1e6 - sum(out.values())
+        return out
 
     def annotate_last(self, name: str, dur_ms: float) -> None:
         """Append a post-tick span (e.g. encode-serve, which happens

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from kmamiz_tpu.core import programs
 from kmamiz_tpu.models import gat, graphsage, stacked, trainer
 from kmamiz_tpu.ops import sparse
 from kmamiz_tpu.telemetry import REGISTRY
@@ -850,6 +851,14 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
     text = compiled.as_text()
     # the group's sum, layer 2's and its cotangent's: a reducer a source each
     assert text.count("tpu_custom_call") == 3 * shards and "planned_neighbor_sum" in text
+    # the chip's program under the program's own names (PR 37): every reducer under phase `reduce`, every collective
+    # XLA made of the all-gathers and the summed loss (fused `async-collective-*` among them) under `collective`
+    table = programs.scope_table_of(text)
+    phases = {name: phase for name, (_path, phase, _backward) in table.items()}
+    assert {p for p in phases.values()} == {"gather", "reduce", "collective", "dense", "loss", "optimizer", "group"}
+    assert [p for n, p in phases.items() if n.startswith("planned_neighbor_sum")] == ["reduce"] * 3 * shards
+    wires = [n for n in phases if n.startswith(("all-gather", "all-reduce", "psum", "async-collective"))]
+    assert len(wires) >= 4 and {phases[n] for n in wires} == {"collective"}, wires
     gathered = re.findall(r"= (\w+)\[([\d,]+)\]\S* all-gather\(", text)
     assert gathered and {g[0] for g in gathered} == {"f32"}
     # layer 2's tables and their cotangents', the slot group's: every chip's rows, as `[4, rows, W]` or the same bytes flat
@@ -875,12 +884,13 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
 #: sha256 of each one-chip epoch block as it LOWERS for a described v5e at the cells' shapes (`_block_fingerprint`).
 #: A Mosaic kernel's source locations are in its bytecode and so in the compile cache's key: a PR that moves a line at
 #: or above a kernel of `ops/sparse.py`, `ops/sparse_gated.py`, a head's forward or the block's body in `models/stacked.py`
-#: changes these, pays a cold compile in every one-chip cell (13 s in PR 30) and says so; one that means to leave
-#: the one-chip path alone (PR 35, PR 36: the sharded path) keeps them. The failing assertion prints the new value.
+#: changes these, pays a cold compile in every one-chip cell (13 s in PR 30) and says so (PR 37: the named scopes in
+#: the block, the heads and the reductions moved lines in all of them); one that means to leave the one-chip path
+#: alone (PR 35, PR 36: the sharded path) keeps them. The failing assertion prints the new value.
 ONE_CHIP_BLOCKS = {
-    "graphsage": "562da3db8046784dd881ae0e261f8d7240293eee4077d2f252e3e9420a199ebb",
-    "gat": "7a0afc8614ddb839998805e573454070731fbd4fc9c8b2970b5dbfd2b0ddc0ac",
-    "stlgt": "ac23db41cc634da9d489adfc30ad01f35237be29ec96b1d1a197435cb8b05927",
+    "graphsage": "465c08fcd8e735b31552480be83bc072c0f29f4a2b539758380ebeb833ae4c52",
+    "gat": "aea4cb2cac461fb6ed5bcd4247e44bff59011c02c681ecd1d140370b0130f9c6",
+    "stlgt": "fd36b5224303cd195c1e57b424afc26eecca219205364aaf69a6a74df99ffee5",
 }
 
 

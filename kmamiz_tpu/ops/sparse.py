@@ -431,15 +431,32 @@ def planned_neighbor_sum(plan: EdgePlan, h: jnp.ndarray, impl: Optional[str] = N
 #   (a term was then kept to 2^-24 of the SUM; now it is rounded once to
 #   float32, as the reference's is). The rows meet their weights transposed
 #   (a lane an entry, so the [1, block] weight row broadcasts along sublanes):
-#   `_backward` transposes its block anyway, `_sum` now does. GAT's two sums
-#   leave their walks as they come out of `_reduce`, [lanes, nodes], and XLA
+#   `_backward` transposes its block anyway, `_sum` now does. The sums leave
+#   their walks as they come out of `_reduce`, [lanes, nodes], and XLA
 #   transposes them once: the tile transposed back in every item cost 0.2 ms
 #   a walk, and XLA's glue around a [nodes, lanes] output 2 ms a layer more
-#   (PERF.md, PR 34; the gated walks transpose back in the item: there XLA's
-#   part grew). The weight as a column against untransposed rows cost more;
-# - the per-entry DOT products (`_dot6`: `_edge_dot`'s, `_backward`'s and the
-#   gated walks' `dots`) keep their six passes, and `_reduce` its three and
-#   their order: stacking those changes the bits;
+#   (PERF.md, PR 34; the gated walks' too since PR 38: under the layout their
+#   block has now, 0.34 ms a slot update less). The weight as a column
+#   against untransposed rows cost more;
+# - a per-entry DOT product (`_entry_dot`: `_edge_dot`'s <g[i], hw[j]>,
+#   `_backward`'s <hw[i], g[j]>, the gated walks' two) is no [tile, block]
+#   matrix product for the one-hot to pick from. An entry has ONE owner, so
+#   the owner's row goes to its entries through the one-hot, exactly
+#   (`_expand` at 128 rows: one product, 4 stationary tile loads and 1,536
+#   streamed rows), and meets the gathered row on the VPU, one float32
+#   multiplication an element and a float32 sum over the sublanes, as the
+#   oracle and the plain references make it. Until PR 38 `_dot6` made all
+#   128 x 512 products of an item in six passes (24 tile loads, 3,072 rows)
+#   and threw 127 of every 128 away: 1.1 ms a slot update in `_edge_dot`
+#   and 1.4 in `_backward`. Rows an item expands anyway ride in the same
+#   product (`_backward`'s s, c and ones: 0.23 ms; the gated backward's
+#   g_den measured nothing and stays apart). The owner's rows arrive as
+#   `node_rows` [lanes, nodes]: transposed in the item on the XLU they cost
+#   a walk 0.2 ms more AND XLA's part 0.8 ms a slot update (in STLGT 4.7:
+#   one [nodes, 128] operand holds the whole block's [nodes, 64] arrays to
+#   the layout that pads 64 floats to 128 lanes; PERF.md, PR 38);
+# - `_reduce` keeps its three passes and their order: stacking those changes
+#   the bits;
 # - a float32 block is split where it is used, once an item. Splitting it
 #   once a block or a tile into VMEM, or once a layer into HBM, measured
 #   SLOWER on the v5e (the split hides behind the MXU; a conditional region
@@ -477,15 +494,6 @@ _mxu_calls = _MxuCalls()
 def _mxu(a, b, dims):
     _mxu_calls.n += 1
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
-
-
-def _dot6(a3, b3, dims):
-    """The float32 product of two split arrays in six bfloat16 passes (the
-    three left out are below 2^-24 of the result), small terms first."""
-    a1, a2, a3_ = a3
-    b1, b2, b3_ = b3
-    low = _mxu(a1, b3_, dims) + _mxu(a3_, b1, dims) + _mxu(a2, b2, dims)
-    return low + (_mxu(a1, b2, dims) + _mxu(a2, b1, dims)) + _mxu(a1, b1, dims)
 
 
 def _expand(node_rows, hot):
@@ -545,6 +553,23 @@ def _weighted_sum(rows_t, weight, hot):
     of the product: `_reduce`'s three passes, where a split [tile, block]
     weight tile against the split rows spent six (until PR 34)."""
     return _reduce(rows_t * weight, hot)
+
+
+def _entry_dot(own_rows, entry_rows_t):
+    """[R, block] rows of each entry's OWNER, as `_expand` carries them through
+    the one-hot (exactly: an entry has one owner), and the [R, block] rows of
+    the entries themselves, transposed (a lane an entry) -> [1, block]: each
+    entry's dot product, +0.0 where no row of the tile owns it. One float32
+    multiplication an element on the VPU and a float32 sum over the sublanes,
+    as `_attention_xla`, `_gated_xla` and the plain references make it: by
+    halves down to a vreg's eight sublanes, a pairwise sum in an order the
+    source fixes. Until PR 38 a split [tile, R] tile against the split rows
+    made every owner's product with every entry in six passes, and the one-hot
+    picked one in 128."""
+    terms = own_rows * entry_rows_t
+    while terms.shape[0] % (2 * ATT_ROWS) == 0:
+        terms = terms[: terms.shape[0] // 2] + terms[terms.shape[0] // 2 :]
+    return jnp.sum(terms, axis=0, keepdims=True)
 
 
 def plan_blocks(plan: EdgePlan, items: int) -> int:
@@ -695,7 +720,7 @@ def _attention_sum_kernel(
 
 
 def _attention_edge_dot_kernel(
-    tile_ref, block_ref, flag_ref, state_ref, msg_ref, g_ref,
+    tile_ref, block_ref, flag_ref, state_ref, msg_ref, grow_ref,
     next_ref, c_ref,
 ):
     real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (c_ref,))
@@ -703,8 +728,8 @@ def _attention_edge_dot_kernel(
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
-        dots = _dot6(_split3(g_ref[...]), _split3(msg_ref[...]), _NT)  # [tile, block]
-        dalpha = jnp.sum(jnp.where(one_hot, dots, 0.0), axis=0, keepdims=True)
+        g, msg_t = grow_ref[...], msg_ref[...].T  # g of the tile's nodes; hw of the neighbours, a lane an entry
+        dalpha = _entry_dot(_expand(g, hot), msg_t)  # <g[owner], hw[neighbour]>
         _add_row(next_ref, ROW_DALPHA, dalpha)
         y = _row(state_ref, ROW_ALPHA) * dalpha  # 0 for an entry of another tile
         c_ref[...] += _reduce(_rows_by_direction(d, y), hot)
@@ -712,24 +737,25 @@ def _attention_edge_dot_kernel(
 
 def _attention_backward_kernel(
     tile_ref, block_ref, flag_ref, state_ref,
-    msg_ref, hw_ref, nrow_ref, dhw_ref, dst_ref, *, width: int, leak: float,
+    msg_ref, hwrow_ref, nrow_ref, dhw_ref, dst_ref, *, width: int, leak: float,
 ):
     real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, None, (dhw_ref, dst_ref))
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
-        msg = msg_ref[...]  # g of the neighbour, then its t, max, sum, c
-        msg_t = msg.T
+        msg_t = msg_ref[...].T  # g of the neighbour, then its t, max, sum, c
         nbr = msg_t[width : width + ATT_ROWS, :]
-        own = _expand(nrow_ref[...], hot)  # s and c of the owner, and a row of ones
+        # hw of the owner and, in the same product, its s and c and a row of ones
+        lanes = hwrow_ref.shape[0]
+        own = _expand(jnp.concatenate([hwrow_ref[...], nrow_ref[...]], axis=0), hot)
+        dalpha_m, own = _entry_dot(own[:lanes], msg_t), own[lanes:]  # <hw[owner], g[neighbour]>
         inside = own[4:5] > 0.5
         # this entry's own softmax: d z = alpha (d alpha - c) leaky'(z)
         z = _row(state_ref, ROW_Z)
         c = _by_direction(d, own[2:3], own[3:4])
         alpha, dalpha = _row(state_ref, ROW_ALPHA), _row(state_ref, ROW_DALPHA)
-        dz = alpha * (dalpha - c) * jnp.where(z >= 0, 1.0, leak)
-        dz = jnp.where(inside, dz, 0.0)
+        dz = jnp.where(inside, alpha * (dalpha - c) * jnp.where(z >= 0, 1.0, leak), 0.0)
         # its mirror's, in the other direction: s of the owner, the rest the
         # neighbour's (rows t0 t1 max0 max1 sum0 sum1 c0 c1)
         zm = _by_direction(d, own[1:2] + nbr[1:2], own[0:1] + nbr[0:1])
@@ -738,8 +764,6 @@ def _attention_backward_kernel(
         cm = _by_direction(d, nbr[7:8], nbr[6:7])
         pm = jnp.exp(jnp.clip(_leaky(zm, leak) - shift, -60.0, 0.0))
         alpha_m = jnp.where(inside, pm / total, 0.0)
-        dots = _dot6(_split3(hw_ref[...]), _split3(msg), _NT)  # <hw[owner], g[neighbour]>
-        dalpha_m = jnp.sum(jnp.where(one_hot, dots, 0.0), axis=0, keepdims=True)
         dzm = alpha_m * (dalpha_m - cm) * jnp.where(zm >= 0, 1.0, leak)
         # the mirror's direction is 1 - d: its d s lands in the other row
         dst_ref[...] += _reduce(
@@ -903,7 +927,7 @@ def _attention_pallas_bwd(plan: EdgePlan, leak: float, interpret: bool, saved, g
     g = g.astype(jnp.float32)
     state, c = _walk_call(
         plan, _attention_edge_dot_kernel, "planned_attention_edge_dot",
-        [("entry", state), ("message", msg), ("node", _node_table(nodes, lanes, g))],
+        [("entry", state), ("message", msg), ("node_rows", _node_table(nodes, lanes, g).T)],
         [("entry", ATT_ROWS), ("node_rows", ATT_ROWS)], interpret,
     )
     c = c[:2, :n].T  # [n, 2]: the sum of alpha * d alpha over each run
@@ -913,7 +937,7 @@ def _attention_pallas_bwd(plan: EdgePlan, leak: float, interpret: bool, saved, g
         "planned_attention_backward",
         [
             ("entry", state), ("message", g_msg),
-            ("node", _node_table(nodes, lanes, hw)),
+            ("node_rows", _node_table(nodes, lanes, hw).T),
             ("node_rows", _node_rows(nodes, s[:, 0], s[:, 1], c[:, 0], c[:, 1])),
         ],
         [("node_rows", lanes), ("node_rows", ATT_ROWS)], interpret,

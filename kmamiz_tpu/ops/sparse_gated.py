@@ -32,32 +32,40 @@ Mosaic kernel that the device trace names:
   operand fetched ahead beside the second, one of the gathers took 10.8 ms
   for 1.9), and ONE gather of `[x | v]` rows from a table stacked by
   direction, `[q | v]` over `[k | v]` (134 MB), took 10.8 ms too (PR 33).
-- The dot product of an entry is one float32-exact product on the MXU, as
-  GAT's `_edge_dot`: the owner tile `[q | k]` against the block's rows,
-  transposed, each entry's under the half its direction reads (`_by_half`: k
-  of the neighbour meets the owner's q where the owner calls, q meets k where
-  it is called), six bfloat16 passes of depth 128, then the one-hot picks each
-  entry's owner.
+- The dot product of an entry is GAT's `_edge_dot`'s (`sparse._entry_dot`,
+  PR 38): the owner's row `[q | k]`, handed in as node rows `[128, nodes]`,
+  goes to its entries through the one-hot in ONE stacked MXU product, exactly,
+  and meets the block's rows on the VPU, transposed, each entry's under the
+  half its direction reads (`_by_half`: k of the neighbour meets the owner's
+  q where the owner calls, q meets k where it is called): one float32
+  multiplication an element and a float32 sum over the sublanes, as
+  `_gated_xla` and `benchmarks/reference/stlgt.py` make it. Until PR 38 it
+  was all 128 x 512 products of the tile against the block in six bfloat16
+  passes of depth 128 (`sparse._dot6`), of which the one-hot picked one in 128.
   No bfloat16 ROW enters it or the weighted sums, because neither is a matrix
   product of the model (a gated sum fed bfloat16 rows moves the first slot's
-  loss by what `benchmarks/reference/stlgt.py` records). The dot product's
-  operands go as three bfloat16 pieces each, their products summed in float32
-  (`sparse._dot6`, six passes). A weighted sum (`num`, `d v`, `[d q | d k]`)
-  multiplies on the VPU, one float32 multiplication an element as the
-  reference makes it, and only the one-hot, exact in bfloat16, goes through
-  the MXU, against the exact split of the product (`sparse._weighted_sum`,
-  three passes; six until PR 34, when the weight went through the MXU too).
-  `num` and `d v` are transposed back tile by tile in the walk: left as node
-  rows for XLA to transpose, as GAT's sums are, the walks were 0.15 ms
-  shorter and XLA's part 0.43 ms longer a slot update (PERF.md, PR 34).
+  loss by what `benchmarks/reference/stlgt.py` records). A weighted sum
+  (`num`, `d v`, `[d q | d k]`) multiplies on the VPU too, and only the
+  one-hot, exact in bfloat16, goes through the MXU, against the exact split
+  of the product (`sparse._weighted_sum`, three passes; six until PR 34,
+  when the weight went through the MXU too).
+  Every per-node operand and result of the two walks is node rows `[lanes,
+  nodes]`, as GAT's are: with the owner's tables handed in that way XLA keeps
+  the block's `[nodes, 64]` arrays in the layout whose minor dimension is the
+  nodes, and `num` and `d v` left as they come out of `_reduce` are slices of
+  it (0.34 ms a slot update less than transposed back in the item, PERF.md,
+  PR 38; under the other layout, in PR 34, it was the other way round by 0.28).
 - The transposed sums need no permutation: every entry has a mirror, the same
   edge seen from its other end, with the same gate. `d v[j]`, a sum over the
   entries whose NEIGHBOUR is j, is the sum over the entries OWNED by j of
   `gate * g[neighbour]`: the forward's weighted sum with `g` for `v`. An
   edge's `d gate` is its two entries' `<g[o], v[n]> + g_den[o]`, and both are
-  at hand in either entry's item: one product of `[v | g]` of the tile against
-  `[g | v]` of the block (the lanes `g` leaves free are those `v` was gathered
-  into). `d q[o]` sums `d a_e * k[n]` over o's out-entries
+  at hand in either entry's item: one `_entry_dot` of `[v | g]` of the tile
+  (node rows again: as a `[nodes, 128]` operand it held XLA's whole backward
+  pass to the layout that pads 64 floats to 128 lanes, 4.7 ms a slot update)
+  against `[g | v]` of the block (`_g_over_v`: the lanes `g` leaves free are
+  those `v` was gathered into; both blocks are transposed for it). `d q[o]`
+  sums `d a_e * k[n]` over o's out-entries
   and `d k[o]` sums `d a_e * q[n]` over its in-entries: ONE weighted sum of
   the block's `_by_half` rows, written transposed (`[2 halves, nodes]`), so
   no second pass over the messages.
@@ -86,10 +94,8 @@ from kmamiz_tpu.ops.sparse import (
     PLAN_NODE_TILE,
     ROW_OWNER,
     EdgePlan,
-    _NN,
-    _NT,
     _add_row,
-    _dot6,
+    _entry_dot,
     _entry_state,
     _expand,
     _gather_rows,
@@ -100,7 +106,6 @@ from kmamiz_tpu.ops.sparse import (
     _reduce,
     _row,
     _rows,
-    _split3,
     _walk_call,
     _weighted_sum,
 )
@@ -156,13 +161,16 @@ def _by_half(qk, d, half: int):
     )
 
 
-def _picked(one_hot, dots):
-    """[tile, block] products -> [1, block]: each entry's, with its owner."""
-    return jnp.sum(jnp.where(one_hot, dots, 0.0), axis=0, keepdims=True)
+def _g_over_v(ng_t, nv_t, half: int):
+    """Blocks of gathered `[g | g_den]` and `[0 | v]` rows, transposed, as the
+    neighbours' side of the backward's dot product: `[2 half, block]`, g[n]
+    (which meets the owner's v) over v[n] (which meets its g)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, ng_t.shape, 0)
+    return jnp.where(row < half, ng_t, nv_t)
 
 
 def _gated_sum_kernel(
-    tile_ref, block_ref, flag_ref, state_ref, nqk_ref, nv_ref, qk_ref,
+    tile_ref, block_ref, flag_ref, state_ref, nqk_ref, nv_ref, qkrow_ref,
     next_ref, num_ref, den_ref, *, half: int, scale: float,
 ):
     real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (num_ref, den_ref))
@@ -171,16 +179,17 @@ def _gated_sum_kernel(
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
-        dots = _dot6(_split3(qk_ref[...]), _split3(_by_half(nqk_ref[...], d, half)), _NN)
-        a = _picked(one_hot, dots) * scale + _row(state_ref, ROW_B)
+        mine, theirs = qkrow_ref[...], _by_half(nqk_ref[...], d, half)
+        dots = _entry_dot(_expand(mine, hot), theirs)
+        a = dots * scale + _row(state_ref, ROW_B)
         gate = jnp.where(inside, 1.0 / (1.0 + jnp.exp(-a)), 0.0)
         _add_row(next_ref, ROW_GATE, gate)
-        num_ref[...] += _weighted_sum(nv_ref[...].T, gate, hot).T
+        num_ref[...] += _weighted_sum(nv_ref[...].T, gate, hot)
         den_ref[...] += _reduce(_rows(gate), hot)
 
 
 def _gated_backward_kernel(
-    tile_ref, block_ref, flag_ref, state_ref, nqk_ref, nv_ref, ng_ref, vg_ref, grow_ref,
+    tile_ref, block_ref, flag_ref, state_ref, nqk_ref, nv_ref, ng_ref, vgrow_ref, grow_ref,
     next_ref, dv_ref, dqk_ref, *, half: int, scale: float,
 ):
     real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (dv_ref, dqk_ref))
@@ -189,16 +198,15 @@ def _gated_backward_kernel(
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
-        ng = ng_ref[...]  # [g | g_den] of the neighbour; `nv_ref` holds its [0 | v]
-        ng_t = ng.T
-        lane = jax.lax.broadcasted_iota(jnp.int32, ng.shape, 1)
+        ng_t = ng_ref[...].T  # [g | g_den] of the neighbour; `nv_ref` holds its [0 | v]
         # <v[o], g[n]> + <g[o], v[n]>: the entry's d gate and its mirror's
-        dots = _dot6(_split3(vg_ref[...]), _split3(jnp.where(lane < half, ng, nv_ref[...])), _NT)
+        mine, theirs = vgrow_ref[...], _g_over_v(ng_t, nv_ref[...].T, half)
+        dots = _entry_dot(_expand(mine, hot), theirs)
         den = _expand(grow_ref[...], hot)[0:1] + ng_t[half : half + 1, :]  # g_den[o] + g_den[n]
         gate = _row(state_ref, ROW_GATE)
-        da = jnp.where(inside, (_picked(one_hot, dots) + den) * gate * (1.0 - gate), 0.0)
+        da = jnp.where(inside, (dots + den) * gate * (1.0 - gate), 0.0)
         _add_row(next_ref, ROW_DA, da)
-        dv_ref[...] += _weighted_sum(ng_t, gate, hot).T
+        dv_ref[...] += _weighted_sum(ng_t, gate, hot)
         # [d q | d k] of the tile, transposed
         dqk_ref[...] += _weighted_sum(_by_half(nqk_ref[...], d, half), da * scale, hot)
 
@@ -219,10 +227,10 @@ def _gated_pallas_fwd(plan: EdgePlan, q, k, v, b, interpret: bool):
     nv, q, k = jax.lax.optimization_barrier((nv, q, k))
     state, num, den = _walk_call(
         plan, partial(_gated_sum_kernel, half=half, scale=scale), "planned_gated_sum",
-        [("entry", _state(plan, b)), ("message", nqk), ("message", nv), ("node", _halves(nodes, half, q, k))],
-        [("entry", ATT_ROWS), ("node", 2 * half), ("node_rows", ATT_ROWS)], interpret,
+        [("entry", _state(plan, b)), ("message", nqk), ("message", nv), ("node_rows", _halves(nodes, half, q, k).T)],
+        [("entry", ATT_ROWS), ("node_rows", 2 * half), ("node_rows", ATT_ROWS)], interpret,
     )
-    return (num[:n, half : half + width], den[0, :n]), (q, v, nqk, nv, state)
+    return (num[half : half + width, :n].T, den[0, :n]), (q, v, nqk, nv, state)
 
 
 def _gated_pallas_bwd(plan: EdgePlan, interpret: bool, saved, cotangents):
@@ -236,16 +244,16 @@ def _gated_pallas_bwd(plan: EdgePlan, interpret: bool, saved, cotangents):
         plan, partial(_gated_backward_kernel, half=half, scale=scale), "planned_gated_backward",
         [
             ("entry", state), ("message", nqk), ("message", nv), ("message", ng),
-            ("node", _halves(nodes, half, v, g)), ("node_rows", _node_rows(nodes, g_den)),
+            ("node_rows", _halves(nodes, half, v, g).T), ("node_rows", _node_rows(nodes, g_den)),
         ],
-        [("entry", ATT_ROWS), ("node", 2 * half), ("node_rows", 2 * half)], interpret,
+        [("entry", ATT_ROWS), ("node_rows", 2 * half), ("node_rows", 2 * half)], interpret,
     )
     # one out-entry an edge, and its d a is the edge's
     db = jnp.sum(jnp.where(_real(plan) & (plan.direction[0] == 0), state[ROW_DA], 0.0))
     return (
         dqk[:width, :n].T.astype(q.dtype),
         dqk[half : half + width, :n].T.astype(q.dtype),
-        dv[:n, :width].astype(v.dtype),
+        dv[:width, :n].T.astype(v.dtype),
         db,
     )
 

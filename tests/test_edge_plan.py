@@ -886,11 +886,12 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
 #: or above a kernel of `ops/sparse.py`, `ops/sparse_gated.py`, a head's forward or the block's body in `models/stacked.py`
 #: changes these, pays a cold compile in every one-chip cell (13 s in PR 30) and says so (PR 37: the named scopes in
 #: the block, the heads and the reductions moved lines in all of them); one that means to leave the one-chip path
-#: alone (PR 35, PR 36: the sharded path) keeps them. The failing assertion prints the new value.
+#: alone (PR 35, PR 36: the sharded path) keeps them. PR 38 changed GAT's and STLGT's walks (their per-entry dot
+#: products) and kept GraphSAGE's. The failing assertion prints the new value.
 ONE_CHIP_BLOCKS = {
     "graphsage": "465c08fcd8e735b31552480be83bc072c0f29f4a2b539758380ebeb833ae4c52",
-    "gat": "aea4cb2cac461fb6ed5bcd4247e44bff59011c02c681ecd1d140370b0130f9c6",
-    "stlgt": "fd36b5224303cd195c1e57b424afc26eecca219205364aaf69a6a74df99ffee5",
+    "gat": "22d00a1ea1495d5a36bcfa33a7c7687dbab3ba6741524bde8f227a69e8c73f5c",
+    "stlgt": "9d44096ba0050056ec7a36ff0040a4015b41b94c8936e3820dc077258fec492f",
 }
 
 
@@ -922,14 +923,9 @@ def _block_fingerprint(text: str) -> str:
     return hashlib.sha256((plain + repr(sorted(places))).encode()).hexdigest()
 
 
-@pytest.mark.parametrize("head", sorted(ONE_CHIP_BLOCKS))
-def test_the_one_chip_blocks_lower_to_what_they_lowered_to(one_chip, head):
-    """The three one-chip cells' epoch blocks at the cells' shapes (432 slots, 131,072 nodes, a plan of 1,048,576
-    entries), the kernels' locations included: what PR 35 and PR 36 compared by hand against their parents."""
-    from kmamiz_tpu.models import gat
-    from kmamiz_tpu.models.stlgt import model as stlgt_model
-
-    model = {"graphsage": graphsage, "gat": gat, "stlgt": stlgt_model}[head]
+def _lower_one_chip_block(one_chip, model):
+    """A one-chip cell's epoch block lowered for a described v5e at the cells' shapes (432 slots, 131,072 nodes, a plan
+    of 1,048,576 entries)."""
     slots, nb, eb, width = 432, 131072, 524288, 18
     entries, _tiles, items = sparse.plan_shapes(nb, eb)
 
@@ -944,13 +940,47 @@ def test_the_one_chip_blocks_lower_to_what_they_lowered_to(one_chip, head):
     # that stack is in the kernel's bytecode: trace afresh, as a process that refreshes one head does
     jax.clear_caches()
     try:
-        text = stacked.epoch_runner(model, 1e-2, 10.0).fn.lower(
+        return stacked.epoch_runner(model, 1e-2, 10.0).fn.lower(
             whole(params), whole(opt_state), arg((slots, nb, width), jnp.float32),
             arg((slots, nb), jnp.float32), arg((slots, nb), jnp.float32), arg((slots, nb), jnp.bool_),
             arg((eb,), jnp.int32), arg((eb,), jnp.int32), arg((eb,), jnp.bool_), 1, _described_plan(arg, nb, entries, items),
-        ).as_text()
+        )
     finally:
         sparse.planned_impl = real_impl
         stacked.epoch_runner.cache_clear()
+
+
+@pytest.mark.parametrize("head", sorted(ONE_CHIP_BLOCKS))
+def test_the_one_chip_blocks_lower_to_what_they_lowered_to(one_chip, head):
+    """The three one-chip cells' epoch blocks at the cells' shapes, the kernels' locations included: what PR 35 and
+    PR 36 compared by hand against their parents."""
+    from kmamiz_tpu.models import gat
+    from kmamiz_tpu.models.stlgt import model as stlgt_model
+
+    text = _lower_one_chip_block(one_chip, {"graphsage": graphsage, "gat": gat, "stlgt": stlgt_model}[head]).as_text()
     assert "tpu_custom_call" in text
     assert _block_fingerprint(text) == ONE_CHIP_BLOCKS[head]
+
+
+def test_stlgt_block_keeps_its_node_arrays_in_the_layout_that_pads_nothing(one_chip):
+    """What the chip's compiler makes of `mv100k-stlgt`'s block around the two gated walks: every `f32[131072,64]`
+    array (q, k, v, the bias, their cotangents: three hundred instructions) in the layout whose minor dimension is the
+    NODES, `{0,1}`. Until PR 38 the walks took `[q | k]` and `[v | g]` of the owner as `[nodes, 128]` operands, and that
+    held 284 of the 298 to `{1,0}`, where 64 floats are padded to the 128 lanes of a tile: 4.65 ms a slot update of
+    XLA's own part (PERF.md, PR 38), two thirds of that PR's gain in the cell. One such operand brings it back."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from kmamiz_tpu.models.stlgt import model as stlgt_model
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
+    compilation_cache.reset_cache()
+    try:
+        text = _lower_one_chip_block(one_chip, stlgt_model).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    layouts = re.findall(r"= f32\[131072,64\]\{([0-9,]+)", text)
+    assert len(layouts) > 250 and set(layouts) == {"0,1"}, {layout: layouts.count(layout) for layout in set(layouts)}

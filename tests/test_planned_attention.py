@@ -143,15 +143,17 @@ _GRAPHS = {"hub": _hub_graph, "shared_block": _shared_block_graph, "three_by_thr
 
 #: sha256 (first 16 hex digits) of the bytes of `planned_attention`'s value and
 #: of its gradients to hw, s and t, interpreted kernels on the CPU. Those of
-#: d s and d t were recorded on the code of PR 30 (commit 89811fd) BEFORE PR 31
-#: touched a kernel and have stood since: what makes them (`alpha`, `d alpha`,
-#: `c`, `_reduce`) keeps its bits. Those of the value and of d hw are PR 34's,
-#: recorded on its final tree (parent commit 014ad83): their weighted sums
-#: multiply on the VPU and send the product through the one-hot in three
-#: passes, so a term is rounded once to float32, as the oracle's is
+#: the value and of d hw are PR 34's (its final tree, parent commit 014ad83:
+#: the weighted sums multiply on the VPU and go through the one-hot in three
+#: passes) and reproduce: no dot product feeds them (a mirror's alpha is
+#: recomputed, not read). Those of d s and d t stood from PR 30 (89811fd) to
+#: PR 37 and are PR 38's now, recorded on its final tree (parent commit
+#: 4a46e2e): `d alpha = <g[i], hw[j]>` and the mirror's `<hw[i], g[j]>` are
+#: float32 sums of float32 products, by halves (`_entry_dot`), where `_dot6`
+#: kept a product to 2^-24 of the sum; `alpha`, `c` and `_reduce` as before
 _DIGESTS = {
-    "hub": ("aebc210415e35939", "c5139565ff963696", "776b5f7784021467", "85c2effe97a90e06"),
-    "shared_block": ("8e60f886afeae48d", "583ee12ca7e2e9fe", "b52ebe1373d0c361", "1fac167762b54cfd"),
+    "hub": ("aebc210415e35939", "c5139565ff963696", "6dbcbb9687956b8f", "bb48a2701f031b4a"),
+    "shared_block": ("8e60f886afeae48d", "583ee12ca7e2e9fe", "4a6926adbe225e42", "cc246e6e1cc11a2c"),
 }
 _DIGEST_SEEDS = {"hub": 21, "shared_block": 22}
 
@@ -251,6 +253,123 @@ class TestTheKernelsKeepTheirBits:
         np.testing.assert_array_equal(state[sparse.ROW_DIR].view(np.int32), np.asarray(plan.direction)[0, :seen])
         assert (state[sparse.ROW_ALPHA, :real] > 0).all() and (state[sparse.ROW_ALPHA, real:] == 0).all()
         assert (state[sparse.ROW_DALPHA] == 0).all()  # the backward pass's row
+
+
+def _item_dots(host_plan, items, owner_table, entry_rows_t_of):
+    """What a walk's items make of `sparse._entry_dot`, item by item as a
+    kernel calls it: the tile's rows of the `[nodes, R]` owner table
+    transposed and expanded through the item's one-hot in bfloat16, against
+    the block's rows transposed (`entry_rows_t_of(block, d)`, `[R, block]`). Returns the entries' sums
+    over the items `[L]` and every item's own `[1, block]` row with the mask of
+    the entries its tile owns."""
+    owner, d = np.asarray(host_plan.owner), np.asarray(host_plan.direction)
+    dot = jax.jit(lambda owner_rows_t, entry_rows_t, hot: sparse._entry_dot(sparse._expand(owner_rows_t, hot), entry_rows_t))
+    total, per_item = np.zeros(owner.shape[1], np.float32), []
+    for i in range(items):
+        tile, block = int(host_plan.item_tile[i]), int(host_plan.item_block[i])
+        at = slice(block * BE, (block + 1) * BE)
+        mine = owner[0, at][None, :] - tile * TN == np.arange(TN)[:, None]  # [tile, block]
+        row = np.asarray(dot(
+            jnp.asarray(owner_table[tile * TN : (tile + 1) * TN]).T,
+            entry_rows_t_of(block, jnp.asarray(d[:, at])),
+            jnp.asarray(mine).astype(jnp.bfloat16),
+        ))
+        total[at] += row[0]
+        per_item.append((row, mine.any(axis=0)))
+    return total, per_item
+
+
+def _blocks_transposed(rows):
+    """`entry_rows_t_of` of `_item_dots` for rows a walk takes as they were gathered, `[L, lanes]`."""
+    rows = np.asarray(rows)
+    return lambda block, _d: jnp.asarray(rows[block * BE : (block + 1) * BE]).T
+
+
+def _dot_is_as_close_as_the_oracles(got, oracle, owner_rows, entry_rows):
+    """A walk's per-entry dot products `[E]` against the float64 sum of the
+    same float32 operands `[E, R]`: the root mean square no further off than
+    the oracle's float32 `sum(a * b, axis=1)`, and every entry, the largest
+    with them, inside the bound of the sum's own shape: one rounding of the
+    product, four halvings of 128 rows and seven adds over a vreg's eight
+    sublanes, 2^-24 each, of the sum of the terms' sizes. The LARGEST is not
+    held to the oracle's largest: the two are float32 sums of the same
+    products in different orders, and which holds the worst of a few thousand
+    entries turns with the seed (PERF.md, PR 38, has the readings)."""
+    a, b = owner_rows.astype(np.float64), entry_rows.astype(np.float64)
+    want = (a * b).sum(axis=1)
+    off, off_oracle = np.abs(got.astype(np.float64) - want), np.abs(oracle.astype(np.float64) - want)
+    assert np.sqrt((off**2).mean()) <= np.sqrt((off_oracle**2).mean())
+    assert (off <= 12 * 2.0**-24 * np.abs(a * b).sum(axis=1) + 1e-30).all()
+
+
+_DOT_GRAPHS = ("hub", "shared_block", "three_by_three", "zipf_heavy")
+
+
+class TestTheEntryDot:
+    """PR 38: a walk's per-entry dot product is the owner's row expanded
+    through the one-hot (one stacked MXU product, exact) times the entry's row
+    on the VPU, summed over the sublanes in float32, where `_dot6` made all
+    tile x block products in six passes and the one-hot picked."""
+
+    @staticmethod
+    def _operands(name, seed=26, width=64):
+        graph = _GRAPHS[name]() if name in _GRAPHS else _case(name)
+        host, entries, items = sparse.build_edge_plan(*graph)
+        plan, nb = _device(host), graph[3]
+        rng = np.random.default_rng(seed)
+        hw, g = (jnp.asarray(rng.normal(size=(nb, width)).astype(np.float32)) for _ in range(2))
+        scalars = [jnp.asarray(rng.normal(size=(nb, 2)).astype(np.float32)) for _ in range(4)]
+        nodes, lanes = sparse._attention_shapes(plan, hw)
+        return host, entries, items, plan, hw, g, scalars, nodes, lanes
+
+    @pytest.mark.parametrize("name", _DOT_GRAPHS)
+    def test_edge_dots_product_of_g_of_the_owner_and_hw_of_the_neighbour(self, name):
+        host, entries, items, plan, hw, g, scalars, nodes, lanes = self._operands(name)
+        msg = sparse._gather_rows(sparse._node_table(nodes, lanes, hw, scalars[0]), plan.neighbour)
+        got, _ = _item_dots(host, items, np.asarray(sparse._node_table(nodes, lanes, g)), _blocks_transposed(msg))
+        own, nbr = host.owner[0, :entries], host.neighbour[:entries]
+        oracle = np.asarray((g[own] * hw[nbr]).sum(axis=1))
+        _dot_is_as_close_as_the_oracles(got[:entries], oracle, np.asarray(g)[own], np.asarray(hw)[nbr])
+        assert not got[entries:].any()
+
+    @pytest.mark.parametrize("name", _DOT_GRAPHS)
+    def test_backwards_product_of_hw_of_the_owner_and_g_of_the_neighbour(self, name):
+        """The block's rows are `[g | t, max, sum, c]` of the neighbour: the
+        lanes past the width meet zeros of the owner's table."""
+        host, entries, items, plan, hw, g, scalars, nodes, lanes = self._operands(name, seed=27)
+        msg = sparse._gather_rows(sparse._node_table(nodes, lanes, g, *scalars), plan.neighbour)
+        got, _ = _item_dots(host, items, np.asarray(sparse._node_table(nodes, lanes, hw)), _blocks_transposed(msg))
+        own, nbr = host.owner[0, :entries], host.neighbour[:entries]
+        oracle = np.asarray((hw[own] * g[nbr]).sum(axis=1))
+        _dot_is_as_close_as_the_oracles(got[:entries], oracle, np.asarray(hw)[own], np.asarray(g)[nbr])
+
+    def test_the_walk_writes_what_its_items_make_bit_for_bit(self):
+        """`planned_attention_edge_dot`, interpreted, leaves each entry's dot
+        product in its row of the state: the items' own, a block met by four
+        tiles (`three_by_three`) summed from one product and three zeros."""
+        host, entries, items, plan, hw, g, scalars, nodes, lanes = self._operands("three_by_three", width=WIDTH)
+        _, saved = sparse._attention_pallas_fwd(plan, hw, scalars[0], scalars[1], 0.2, True)
+        msg, state = saved[3], saved[4]
+        state, _c = sparse._walk_call(
+            plan, sparse._attention_edge_dot_kernel, "planned_attention_edge_dot",
+            [("entry", state), ("message", msg), ("node_rows", sparse._node_table(nodes, lanes, g).T)],
+            [("entry", sparse.ATT_ROWS), ("node_rows", sparse.ATT_ROWS)], True,
+        )
+        got, _ = _item_dots(host, items, np.asarray(sparse._node_table(nodes, lanes, g)), _blocks_transposed(msg))
+        np.testing.assert_array_equal(np.asarray(state)[sparse.ROW_DALPHA, :entries], got[:entries])
+
+    @pytest.mark.parametrize("name", ("shared_block", "three_by_three"))
+    def test_an_entry_of_another_tile_dots_to_exactly_zero_and_two_runs_give_the_same_bits(self, name):
+        host, entries, items, plan, hw, g, scalars, nodes, lanes = self._operands(name, seed=28)
+        msg = sparse._gather_rows(sparse._node_table(nodes, lanes, hw, scalars[0]), plan.neighbour)
+        table = np.asarray(sparse._node_table(nodes, lanes, g))
+        (first, per_item), (second, _) = (_item_dots(host, items, table, _blocks_transposed(msg)) for _ in range(2))
+        np.testing.assert_array_equal(first.view(np.int32), second.view(np.int32))
+        strangers = 0
+        for row, mine in per_item:
+            assert not row[0, ~mine].view(np.int32).any()  # +0.0, not a small number and not -0.0
+            strangers += int((~mine).sum())
+        assert strangers > BE  # the blocks several tiles meet hold entries of each of them
 
 
 class TestPlannedAttention:
@@ -387,9 +506,12 @@ class TestPlannedAttention:
 
     def test_route_stats_counts_the_mxu_products_of_each_of_the_seven_walks(self):
         """`_mxu` calls of each walk's kernel when it was last traced: an
-        expand is one, a `_reduce` three, a per-entry dot product (`_dot6`)
-        six, and since PR 34 a weighted sum three where it was six. The three
-        `dot`s of `planned_neighbor_sum`'s kernel are its own and not counted."""
+        expand is one, a `_reduce` or a weighted sum three (six until PR 34),
+        a per-entry dot product ONE since PR 38 (`_entry_dot`: the owner's
+        rows stacked through the one-hot; `_dot6` spent six), and none more
+        where the rows an item expands anyway ride in it (`_backward`'s). The
+        three `dot`s of `planned_neighbor_sum`'s kernel are its own and not
+        counted."""
         from kmamiz_tpu.ops import sparse_gated
 
         _src, _dst, _mask, nb, plan = _plan("padded")
@@ -400,11 +522,11 @@ class TestPlannedAttention:
         forward = {"planned_attention_max": 1, "planned_attention_softmax": 4, "planned_attention_sum": 4}
         assert sparse.route_stats()["mxu_products"] == forward
         jax.eval_shape(jax.grad(attention, argnums=(0, 1, 2)), hw, s, t)
-        gat_walks = {**forward, "planned_attention_edge_dot": 9, "planned_attention_backward": 13}
+        gat_walks = {**forward, "planned_attention_edge_dot": 4, "planned_attention_backward": 7}
         assert sparse.route_stats()["mxu_products"] == gat_walks
         gated = lambda q, k, v, b: sparse_gated.planned_gated_sum(plan, q, k, v, b, "pallas_interpret").sum()  # noqa: E731
         jax.eval_shape(jax.grad(gated, argnums=(0, 1, 2, 3)), hw, hw, hw, jnp.zeros((1,)))
-        all_seven = {**gat_walks, "planned_gated_sum": 12, "planned_gated_backward": 13}
+        all_seven = {**gat_walks, "planned_gated_sum": 7, "planned_gated_backward": 8}
         assert sparse.route_stats()["mxu_products"] == all_seven
         jax.eval_shape(lambda h: sparse.planned_neighbor_sum(plan, h, "pallas_interpret"), hw)
         sparse.planned_attention(plan, hw, s, t, 0.2, "xla")  # XLA's formulation traces no kernel
@@ -557,7 +679,8 @@ class TestGatTrainingThroughThePlan:
         plan's span, beside the entries they will walk."""
         _src, _dst, _mask, nb, plan = _plan("padded")
         hw, st_ = jnp.ones((nb, 4)), jnp.zeros((nb, 2))
-        jax.eval_shape(lambda h: sparse.planned_attention(plan, h, st_, st_, 0.2, "pallas_interpret"), hw)
+        attention = lambda h: sparse.planned_attention(plan, h, st_, st_, 0.2, "pallas_interpret").sum()  # noqa: E731
+        jax.eval_shape(jax.grad(attention), hw)
         stacked.stack_dataset(_dataset())
         noted = [
             dict(tb.counts.get(i, {}))
@@ -565,4 +688,5 @@ class TestGatTrainingThroughThePlan:
         ]
         assert noted[-1]["mxu_products"] == sparse.route_stats()["mxu_products"] == {
             "planned_attention_max": 1, "planned_attention_softmax": 4, "planned_attention_sum": 4,
+            "planned_attention_edge_dot": 4, "planned_attention_backward": 7,
         }

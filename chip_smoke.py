@@ -1329,6 +1329,26 @@ def child_kernels(sizes: dict) -> None:
         np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
     out["planned_gated_sum"] = out["planned_attention"]
 
+    # -- PNA's multi-aggregate over the same plan (PR 39): the three walks,
+    # the four aggregates and the gradient to the message rows (a shared
+    # maximum's split equally), against the XLA items; an extreme to the bit
+    from kmamiz_tpu.ops import sparse_pna
+
+    def aggregate(impl):
+        tables, pull = jax.vjp(lambda m: sparse_pna.planned_aggregate(plan, m, impl), qkv[0])
+        return [np.asarray(a) for a in (*tables, *pull((ct, ct, ct, ct)))]
+
+    for name, got, want in zip(
+        ("aggregate.sum", "aggregate.squares", "aggregate.max", "aggregate.min", "aggregate.d_m"),
+        aggregate(kernel_impl), aggregate("xla"),
+    ):
+        if name in ("aggregate.max", "aggregate.min"):
+            np.testing.assert_array_equal(got, want, name)
+            continue
+        scale = max(float(np.abs(want).max()), 1.0)
+        np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
+    out["planned_aggregate"] = out["planned_attention"]
+
     # -- the width the epoch block's slot group sums at (PR 29): seven slots'
     # 18 features side by side. Mosaic against the XLA items, and every
     # slot's columns against the kernel's sum of that slot alone, bit for bit

@@ -96,7 +96,7 @@ class ModelHandler(IRequestHandler):
                 import jax
 
                 from kmamiz_tpu.models import checkpoint as ckpt
-                from kmamiz_tpu.models import gat, graphsage
+                from kmamiz_tpu.models import gat, graphsage, pna
                 from kmamiz_tpu.models.stlgt import model as stlgt_model
 
                 step = ckpt.latest_complete_step(directory)
@@ -122,7 +122,7 @@ class ModelHandler(IRequestHandler):
                     return None
                 # the head the checkpoint names (trainer.train's metadata);
                 # a quantile head serves its p50 through this legacy shape
-                model = {"gat": gat, "stlgt": stlgt_model}.get(
+                model = {"gat": gat, "pna": pna, "stlgt": stlgt_model}.get(
                     meta.get("model"), graphsage
                 )
                 template = model.init_params(

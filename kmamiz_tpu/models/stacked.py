@@ -39,7 +39,9 @@ device-native:
   (`TAKES_PLAN`, read by `plan_for`): GraphSAGE sums neighbour rows over
   it (`sparse.planned_neighbor_sum`), GAT runs its directed segment
   softmax and weighted sums over it (`sparse.planned_attention`), STLGT
-  its sigmoid-gated neighbour bias (`sparse_gated.planned_gated_sum`). The
+  its sigmoid-gated neighbour bias (`sparse_gated.planned_gated_sum`), PNA
+  its mean, deviation, maximum and minimum (`sparse_pna.planned_aggregate`;
+  the plan carries the scalers' constant, `mean_log_degree`). The
   vmapped paths (`dp_epoch_runner`, `predict_all`) pass none and reduce the
   edge list as it comes.
 - the HEAD'S OWN LOSS: a head module may state `make_loss_fn(pos_weight)`,
@@ -338,7 +340,7 @@ def _edge_plan(dataset, src, dst, e_mask, n: int, nb: int, shards: int) -> _Plan
 def plan_for(model, stacked: StackedDataset) -> Optional[sparse.EdgePlan]:
     """The stack's edge plan, for a head that says its `forward` (and its
     own loss, where it states one) takes one as `plan=`: `TAKES_PLAN` on the
-    head's module (GraphSAGE, GAT, STLGT). None for a head that says nothing
+    head's module (GraphSAGE, GAT, STLGT, PNA). None for a head that says nothing
     and under KMAMIZ_SPARSE=xla (the legacy formulation everywhere). What
     `train()` hands the epoch block."""
     if not sparse.use_sparse() or not getattr(model, "TAKES_PLAN", False):

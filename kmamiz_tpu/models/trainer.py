@@ -578,10 +578,12 @@ def _score_predictions(dataset, predict) -> EvalResult:
         pred_log = np.asarray(pred_latency)
         target_log = np.asarray(dataset.target_latency[i])
         err = pred_log - target_log
-        sq_err_sum += float((mask * err**2).sum())
-        abs_ms_sum += float(
-            (mask * np.abs(np.expm1(pred_log) - np.expm1(target_log))).sum()
-        )
+        # `where`, not a product: an endpoint outside the mask is outside the loss
+        # too, its prediction is unconstrained, and 0 x expm1's overflow is no number
+        sq_err_sum += float(np.where(mask, err**2, 0.0).sum())
+        with np.errstate(over="ignore", invalid="ignore"):
+            abs_ms = np.abs(np.expm1(pred_log) - np.expm1(target_log))
+        abs_ms_sum += float(np.where(mask, abs_ms, 0.0).sum())
         weight_sum += float(mask.sum())
 
         names = [

@@ -164,6 +164,7 @@ class EdgePlan(NamedTuple):
     item_block: jnp.ndarray  # [I] int32
     item_flag: jnp.ndarray  # [I] int32: 1 first of its tile, 0 adds, -1 no-op
     direction: jnp.ndarray  # [1, L] int32: 0 owner is the source, 1 the destination
+    mean_log_degree: jnp.ndarray  # [] float32: `mean_log_degree(degree)`, a constant of the topology (PNA's delta)
 
 
 def plan_shapes(bucket_nodes: int, bucket_edges: int) -> Tuple[int, int, int]:
@@ -231,6 +232,7 @@ def build_edge_plan(src, dst, edge_mask, bucket_nodes: int):
         item_block=item_block,
         item_flag=item_flag,
         direction=direction[None, :],
+        mean_log_degree=np.asarray(mean_log_degree(counts)),
     )
     return plan, n_real, n_items
 
@@ -1117,8 +1119,8 @@ def exclusive_cumsum(flags: jnp.ndarray) -> jnp.ndarray:
 class ShardPlan:
     """One device's sub-plans inside a `shard_map` over `axis`: what
     `planned_neighbor_sum` takes where the rows it is given are the device's
-    share of the nodes. `plan` is an EdgePlan whose leaves, but for the
-    degree, have a leading axis over the sources (`build_shard_plans`). The
+    share of the nodes. `plan` is an EdgePlan whose leaves, but for the owner's
+    degree and its mean, have a leading axis over the sources (`build_shard_plans`). The
     axis is static; the plan's arrays are the leaves."""
 
     def __init__(self, plan: EdgePlan, axis: str):
@@ -1137,9 +1139,10 @@ class ShardPlan:
 
 
 def _source_plan(plan: EdgePlan, source: int) -> EdgePlan:
-    """One source's sub-plan of a device's plan: every leaf but the degree has
+    """One source's sub-plan of a device's plan: every leaf but the owner's two has
     a leading `[shards]` axis over the shard its entries' NEIGHBOURS live on."""
-    return EdgePlan(*(a if name == "degree" else a[source] for name, a in zip(EdgePlan._fields, plan)))
+    whole = ("degree", "mean_log_degree")  # the owner's, whatever the source
+    return EdgePlan(*(a if name in whole else a[source] for name, a in zip(EdgePlan._fields, plan)))
 
 
 def _sharded_sum(plan: EdgePlan, h, impl: str, axis: str):
@@ -1299,5 +1302,24 @@ def build_shard_plans(src, dst, edge_mask, num_nodes: int, bucket_nodes: int, sh
     own, nei, item_tile, item_block, item_flag, direction = (
         np.stack(a).reshape(shards, shards, *a[0].shape) for a in zip(*subs)
     )
-    plans = EdgePlan(own, nei, whole.degree.reshape(shards, rows), item_tile, item_block, item_flag, direction)
+    plans = EdgePlan(
+        own, nei, whole.degree.reshape(shards, rows), item_tile, item_block, item_flag, direction,
+        np.full(shards, whole.mean_log_degree),
+    )
     return plans, cuts, held.tolist(), items
+
+
+def mean_log_degree(degree) -> jnp.ndarray:
+    """The mean of log(d + 1) over the endpoints that have a neighbour, 1 where
+    none has: the constant PNA's degree scalers `log(d + 1) / delta` divide by
+    (models/pna.py). Made ONCE a topology, where its plan is built, and in the
+    arithmetic of the device that divides its own logarithms by it: the same
+    `jnp.log`, in float32, so that the scalers' mean over those endpoints is 1
+    as that device counts (on the v5e its sum of 100,000 logarithms lies 1.4e-5
+    from the float64 one, which moves the first slot's loss by 2e-5: PERF.md,
+    PR 39). Endpoints without a neighbour are left out because they aggregate
+    nothing, and so that the rows which pad a node bucket do not enter it."""
+    degree = jnp.asarray(degree, jnp.float32)
+    held = degree > 0
+    total = jnp.sum(jnp.where(held, jnp.log(degree + 1.0), 0.0))
+    return jnp.where(jnp.any(held), total / jnp.maximum(jnp.sum(held), 1), 1.0)

@@ -1031,6 +1031,37 @@ class TestModelRoutes:
         probs = [r["anomalyProbability"] for r in eps]
         assert probs == sorted(probs, reverse=True)
 
+    @pytest.mark.parametrize("head", ("gat", "pna"))
+    def test_a_checkpoint_is_served_by_the_head_its_metadata_names(self, pdas_traces, tmp_path, head):
+        """`trainer.train(model=<head>)` writes the head's name into the checkpoint's metadata; the handler loads
+        that head's parameters and serves its forward (bucket-padded, no plan: models/serving.py)."""
+        import importlib
+
+        from kmamiz_tpu.api.app import build_router as _build
+        from kmamiz_tpu.server.initializer import AppContext, Initializer
+        from kmamiz_tpu.server.processor import DataProcessor
+        from kmamiz_tpu.server.storage import MemoryStore
+
+        model = importlib.import_module(f"kmamiz_tpu.models.{head}")
+        _train_tiny_checkpoint(tmp_path, epochs=2, model=model)
+        dp = DataProcessor(trace_source=_prefixed_trace_source(pdas_traces, head), use_device_stats=False)
+        settings = Settings()
+        settings.external_data_processor = ""
+        settings.model_dir = str(tmp_path)
+        ctx = AppContext.build(app_settings=settings, store=MemoryStore(), processor=dp)
+        Initializer(ctx).register_data_caches()
+        model_router = _build(ctx)
+        H = 3_600_000
+        dp.collect({"uniqueId": "h1", "lookBack": 30_000, "time": 910 * H})
+        dp.collect({"uniqueId": "h2", "lookBack": 30_000, "time": 911 * H})
+        status = model_router.dispatch("GET", "/api/v1/model/status").payload
+        assert status["modelLoaded"] is True and status["checkpoint"]["model"] == head
+        res = model_router.dispatch("GET", "/api/v1/model/forecast")
+        assert res.status == 200, res.payload
+        eps = res.payload["endpoints"]
+        assert eps and len(eps) == len(dp.graph.interner.endpoints)
+        assert all(0.0 <= row["anomalyProbability"] <= 1.0 and row["predictedLatencyMs"] >= 0.0 for row in eps)
+
     def test_forecast_memo_label_epoch_invalidation(
         self, pdas_traces, tmp_path
     ):

@@ -2,7 +2,7 @@
 `jax.named_scope` (one taxonomy, `programs.SCOPE_PHASES`; docs/OBSERVABILITY.md),
 the registry reads them back from the compiled block (`Program.scope_table`),
 and a slow call leaves a record (`trainer._slow_call`). On the CPU, at toy
-sizes: the three heads on one device, GraphSAGE with node embeddings (no slot
+sizes: the four heads on one device, GraphSAGE with node embeddings (no slot
 group), and GraphSAGE over four of the host's devices."""
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from kmamiz_tpu.core import programs
-from kmamiz_tpu.models import gat, graphsage, stacked, trainer
+from kmamiz_tpu.models import gat, graphsage, pna, stacked, trainer
 from kmamiz_tpu.models.stlgt import model as stlgt_model
 from kmamiz_tpu.parallel import mesh as mesh_mod
 from kmamiz_tpu.telemetry.tracing import TRACER, operation_span, phase_span
@@ -26,6 +26,7 @@ BLOCKS = {
     "graphsage": (graphsage, False, 1, EVERY | {"group"}),
     "graphsage_embedding": (graphsage, True, 1, EVERY),
     "gat": (gat, False, 1, EVERY),
+    "pna": (pna, False, 1, EVERY),
     "stlgt": (stlgt_model, False, 1, EVERY),
     "graphsage_nodes4": (graphsage, False, 4, EVERY | {"group", "collective"}),
 }
@@ -337,7 +338,7 @@ def one_chip():
 
 
 #: head -> (module, the Mosaic kernels a compiled block holds: forward and backward)
-KERNELS = {"graphsage": (graphsage, 3), "gat": (gat, 10), "stlgt": (stlgt_model, 2)}
+KERNELS = {"graphsage": (graphsage, 3), "gat": (gat, 10), "pna": (pna, 6), "stlgt": (stlgt_model, 2)}
 
 
 @pytest.mark.parametrize("head", sorted(KERNELS))
@@ -360,7 +361,7 @@ def test_the_chips_own_program_reads_under_the_programs_names(one_chip, head):
     plan = sparse.EdgePlan(
         owner=arg((1, entries), jnp.int32), neighbour=arg((entries,), jnp.int32), degree=arg((nb,), jnp.float32),
         item_tile=arg((items,), jnp.int32), item_block=arg((items,), jnp.int32), item_flag=arg((items,), jnp.int32),
-        direction=arg((1, entries), jnp.int32),
+        direction=arg((1, entries), jnp.int32), mean_log_degree=arg((), jnp.float32),
     )
     params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), hidden=64, num_features=width))
     opt_state = jax.eval_shape(lambda p: model.make_optimizer(1e-2).init(p), params)

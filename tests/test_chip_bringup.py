@@ -402,6 +402,7 @@ class TestChipSmoke:
         assert d["planned_attention"] == d["planned_neighbor_sum_w126"] == "interpret"
         assert d["planned_attention_w124"] == "interpret"  # the spare lanes past the first 128
         assert d["planned_gated_sum"] == "interpret"  # STLGT's two walks against the XLA items
+        assert d["planned_aggregate"] == "interpret"  # PNA's three walks against the XLA items
         assert set(d["routes"]) == {"backend", "planned", "attention", "sharded", "mxu_products"}
         assert d["routes"]["mxu_products"]["planned_attention_sum"] == 4  # three passes and the expand
         assert d["routes"]["planned"] > d["routes"]["attention"] > 0

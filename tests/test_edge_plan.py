@@ -677,7 +677,7 @@ def _described_plan(arg, nb, entries, items):
         owner=arg((1, entries), jnp.int32), neighbour=arg((entries,), jnp.int32),
         degree=arg((nb,), jnp.float32), item_tile=arg((items,), jnp.int32),
         item_block=arg((items,), jnp.int32), item_flag=arg((items,), jnp.int32),
-        direction=arg((1, entries), jnp.int32),
+        direction=arg((1, entries), jnp.int32), mean_log_degree=arg((), jnp.float32),
     )
 
 
@@ -800,6 +800,49 @@ def test_gated_sum_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, wid
         assert len(tables) == 3 and all(f"f32[{nb},{lanes}]" in t and "S(1)}" in t for t in tables), tables
 
 
+@pytest.mark.parametrize("width", (64, 40))
+def test_aggregate_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, width):
+    """The three walks of `sparse_pna.planned_aggregate`, forward and backward, at the width of `mv100k-pna` (a row
+    `[m | -m]` fills the 128 lanes) and at one whose halves are padded: the lane shifts of the running maximum, the
+    `[64, 128]` output tile of the mirror walk and its three message blocks are what interpreting does not refuse."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from kmamiz_tpu.ops import sparse_pna
+
+    nb, eb = 131072, 524288
+    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(m, plan):
+        return sum((a ** 2).sum() for a in sparse_pna.planned_aggregate(plan, m, "pallas"))
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(jax.grad(loss)).lower(arg((nb, width)), _described_plan(arg, nb, entries, items)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("planned_aggregate", "planned_aggregate_ties", "planned_aggregate_backward"):
+        assert f'"{name}"' in text or f"{name}." in text or f"{name} " in text, name
+    assert "scatter" not in text
+    # four row gathers (one forward, three backward), each from a table of the node bucket's rows at the full lane
+    # width, and each TABLE (67 MB) held in the chip's fast memory, `S(1)`, while it gathers: the backward's three
+    # are chained for it (1.8 ns a row from there, 10 ns from HBM: PERF.md, PR 33)
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert len(gathers) == 4 and all(f"f32[{entries},128]" in line for line in gathers), gathers
+    tables = [
+        next(line for line in body.splitlines() if " parameter(0)" in line)
+        for body in text.split("\n}\n") if " gather(" in body
+    ]
+    assert len(tables) == 4 and all(f"f32[{nb},128]" in t and "S(1)}" in t for t in tables), tables
+
+
 def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
     """`mv400k-sage`'s epoch block for the four chips of a described host: the
     one-device block's body under `shard_map`, 432 slots x 524,288 nodes cut
@@ -823,8 +866,8 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
     def arg(shape, dtype, spec=P()):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
 
-    def leaf(shape, dtype):  # [owner's shard, source, ...]; the degree is the owner's whole
-        lead = (shards,) if shape == (rows,) else (shards, shards)
+    def leaf(shape, dtype):  # [owner's shard, source, ...]; the degree and its mean are the owner's whole
+        lead = (shards,) if shape in ((rows,), ()) else (shards, shards)
         return arg(lead + shape, dtype, P("nodes"))
 
     plan = _described_plan(leaf, rows, entries, items)
@@ -887,11 +930,15 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
 #: changes these, pays a cold compile in every one-chip cell (13 s in PR 30) and says so (PR 37: the named scopes in
 #: the block, the heads and the reductions moved lines in all of them); one that means to leave the one-chip path
 #: alone (PR 35, PR 36: the sharded path) keeps them. PR 38 changed GAT's and STLGT's walks (their per-entry dot
-#: products) and kept GraphSAGE's. The failing assertion prints the new value.
+#: products) and kept GraphSAGE's. PR 39 gave the plan a leaf (`mean_log_degree`) and so moved every kernel of
+#: `ops/sparse.py` two lines down: all three changed, GAT's and STLGT's in their locations alone, GraphSAGE's also by
+#: one scalar that its slot group's loops hand on with the plan (the compiled block is the parent's instruction for
+#: instruction, compared in PR 39); `pna` is new. The failing assertion prints the new value.
 ONE_CHIP_BLOCKS = {
-    "graphsage": "465c08fcd8e735b31552480be83bc072c0f29f4a2b539758380ebeb833ae4c52",
-    "gat": "22d00a1ea1495d5a36bcfa33a7c7687dbab3ba6741524bde8f227a69e8c73f5c",
-    "stlgt": "9d44096ba0050056ec7a36ff0040a4015b41b94c8936e3820dc077258fec492f",
+    "graphsage": "3cf6ea2861cdd0862ac02579c2d28aed16fd6a901eabcf7fa37d751c8433e982",
+    "gat": "21cc8349f75ef8676b9eabc9a03a4af0aa7c195d7cfb616f0290854bc641defa",
+    "stlgt": "191c1ef0f1b612b63642205f0b64b05a82871602b6030360a4a3393929436f70",
+    "pna": "dd287426f2994bd5c2643b6d6ddafb5a1b8a195d616f0c5fc6ac986ac03772f2",
 }
 
 
@@ -952,12 +999,13 @@ def _lower_one_chip_block(one_chip, model):
 
 @pytest.mark.parametrize("head", sorted(ONE_CHIP_BLOCKS))
 def test_the_one_chip_blocks_lower_to_what_they_lowered_to(one_chip, head):
-    """The three one-chip cells' epoch blocks at the cells' shapes, the kernels' locations included: what PR 35 and
+    """The four one-chip cells' epoch blocks at the cells' shapes, the kernels' locations included: what PR 35 and
     PR 36 compared by hand against their parents."""
-    from kmamiz_tpu.models import gat
+    from kmamiz_tpu.models import gat, pna
     from kmamiz_tpu.models.stlgt import model as stlgt_model
 
-    text = _lower_one_chip_block(one_chip, {"graphsage": graphsage, "gat": gat, "stlgt": stlgt_model}[head]).as_text()
+    heads = {"graphsage": graphsage, "gat": gat, "pna": pna, "stlgt": stlgt_model}
+    text = _lower_one_chip_block(one_chip, heads[head]).as_text()
     assert "tpu_custom_call" in text
     assert _block_fingerprint(text) == ONE_CHIP_BLOCKS[head]
 

@@ -669,6 +669,22 @@ class TestFusedTraining:
         assert got.anomaly_precision == want.anomaly_precision
         assert got.anomaly_recall == want.anomaly_recall
 
+    def test_an_endpoint_outside_the_mask_cannot_poison_the_scores(self):
+        """An endpoint that is inactive in the next slot is outside the loss, so nothing holds its prediction: a
+        log-latency of 95 overflows `expm1`, and the mask must select, not multiply (0 x inf is no number; PR 39:
+        the PNA head's degree scalers put one such endpoint at 91.6 on the 1k-endpoint evaluation)."""
+        ds = _synthetic_dataset(n_slots=2)
+        ds.node_mask[0] = ds.node_mask[0].at[3].set(False)
+
+        def predict(i):
+            lat = np.asarray(ds.target_latency[i]) + 0.5
+            lat[3] = 95.0 if i == 0 else lat[3]
+            return lat, np.zeros(lat.shape, bool)
+
+        got = trainer._score_predictions(ds, predict)
+        assert np.isfinite(got.latency_mae_ms) and np.isfinite(got.latency_mse)
+        assert got.latency_mse == pytest.approx(0.25, rel=1e-5)
+
     @pytest.mark.slow
     def test_fused_convergence_on_simulation(self, simulation):
         """Long-epoch convergence check on the simulator mesh — slow

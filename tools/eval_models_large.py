@@ -514,7 +514,7 @@ def main() -> None:
     )
     args = parser.parse_args()
 
-    from kmamiz_tpu.models import gat, graphsage, trainer
+    from kmamiz_tpu.models import gat, graphsage, pna, trainer
     from kmamiz_tpu.simulator.simulator import Simulator
 
     rng = np.random.default_rng(args.seed)
@@ -544,6 +544,7 @@ def main() -> None:
     for name, model in (
         (f"GraphSAGE{suffix}", graphsage),
         (f"GAT{suffix}", gat),
+        (f"PNA{suffix}", pna),
     ):
         t1 = time.perf_counter()
         res, metrics, dataset = trainer.train_on_simulation(

@@ -1349,6 +1349,26 @@ def child_kernels(sizes: dict) -> None:
         np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
     out["planned_aggregate"] = out["planned_attention"]
 
+    # -- GATv2's dynamic attention over the same plan: the five walks, the
+    # value and the cotangents of xl, xr and both attention vectors, against
+    # the XLA items
+    from kmamiz_tpu.ops import sparse_gatv2
+
+    a_v2 = jnp.asarray(rng.normal(size=(2, feat)).astype(np.float32))
+
+    def dynamic(impl):
+        att, pull = jax.vjp(
+            lambda xl, xr, a: sparse_gatv2.planned_gatv2(plan, xl, xr, a, 0.2, impl), qkv[0], qkv[1], a_v2
+        )
+        return [np.asarray(a) for a in (att, *pull(ct))]
+
+    for name, got, want in zip(
+        ("gatv2", "gatv2.d_xl", "gatv2.d_xr", "gatv2.d_a"), dynamic(kernel_impl), dynamic("xla")
+    ):
+        scale = max(float(np.abs(want).max()), 1.0)
+        np.testing.assert_allclose(got, want, rtol=0, atol=5e-5 * scale, err_msg=name)
+    out["planned_gatv2"] = out["planned_attention"]
+
     # -- the width the epoch block's slot group sums at (PR 29): seven slots'
     # 18 features side by side. Mosaic against the XLA items, and every
     # slot's columns against the kernel's sum of that slot alone, bit for bit

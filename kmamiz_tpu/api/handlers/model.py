@@ -96,8 +96,7 @@ class ModelHandler(IRequestHandler):
                 import jax
 
                 from kmamiz_tpu.models import checkpoint as ckpt
-                from kmamiz_tpu.models import gat, graphsage, pna
-                from kmamiz_tpu.models.stlgt import model as stlgt_model
+                from kmamiz_tpu.models import heads
 
                 step = ckpt.latest_complete_step(directory)
                 if step is None:
@@ -120,11 +119,10 @@ class ModelHandler(IRequestHandler):
                         "set (retrain without --embeddings)"
                     )
                     return None
-                # the head the checkpoint names (trainer.train's metadata);
+                # the head the checkpoint names (trainer.train's metadata; none
+                # is GraphSAGE, a name no head has is a load error that says so);
                 # a quantile head serves its p50 through this legacy shape
-                model = {"gat": gat, "pna": pna, "stlgt": stlgt_model}.get(
-                    meta.get("model"), graphsage
-                )
+                model = heads.by_name(meta.get("model"))
                 template = model.init_params(
                     jax.random.PRNGKey(0),
                     hidden=int(meta["hidden"]),

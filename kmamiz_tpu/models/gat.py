@@ -200,3 +200,7 @@ def make_train_step(optimizer, pos_weight: float = 1.0):
     if pos_weight == 1.0:
         return common.make_train_step(optimizer, loss_fn)
     return common.make_train_step(optimizer, common.make_loss_fn(forward, pos_weight))
+
+
+#: the head's name in a checkpoint's metadata and on its spans (models/heads.py)
+NAME = "gat"

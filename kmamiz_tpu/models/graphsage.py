@@ -305,3 +305,7 @@ def features_from_stats(
         total > 0,
         hour_of_day=hour_of_day,
     )
+
+
+#: the head's name in a checkpoint's metadata and on its spans (models/heads.py)
+NAME = "graphsage"

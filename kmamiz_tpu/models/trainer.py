@@ -292,8 +292,7 @@ def train(
     the code already returns or already waits; none adds a sync."""
     from kmamiz_tpu.models import checkpoint as ckpt
 
-    # a head in a package of its own names itself (stlgt/model.py: "stlgt")
-    model_name = getattr(model, "NAME", model.__name__.rsplit(".", 1)[-1])
+    model_name = model.NAME  # what the served process loads the checkpoint by (models/heads.py)
     num_slots = len(dataset.features) if dataset is not None else 0
     _REFRESHES.inc()
     call_start = _call_start()

@@ -72,7 +72,7 @@ _backend_cache: Optional[str] = None
 _route_lock = threading.Lock()
 #: reductions over an edge plan since process start (trace-time counts:
 #: the consumers decide inside their jit traces); `_walk_call`'s products
-_route_counts = {"planned": 0, "attention": 0, "sharded": 0, "mxu_products": {}}
+_route_counts = {"planned": 0, "attention": 0, "gatv2": 0, "sharded": 0, "mxu_products": {}}
 
 
 def backend() -> str:
@@ -96,7 +96,7 @@ def reset_for_tests() -> None:
     global _backend_cache
     _backend_cache = None
     with _route_lock:
-        _route_counts.update(planned=0, attention=0, sharded=0, mxu_products={})
+        _route_counts.update(planned=0, attention=0, gatv2=0, sharded=0, mxu_products={})
 
 
 def use_sparse() -> bool:

@@ -1031,7 +1031,7 @@ class TestModelRoutes:
         probs = [r["anomalyProbability"] for r in eps]
         assert probs == sorted(probs, reverse=True)
 
-    @pytest.mark.parametrize("head", ("gat", "pna"))
+    @pytest.mark.parametrize("head", ("gat", "pna", "gatv2"))
     def test_a_checkpoint_is_served_by_the_head_its_metadata_names(self, pdas_traces, tmp_path, head):
         """`trainer.train(model=<head>)` writes the head's name into the checkpoint's metadata; the handler loads
         that head's parameters and serves its forward (bucket-padded, no plan: models/serving.py)."""
@@ -1061,6 +1061,44 @@ class TestModelRoutes:
         eps = res.payload["endpoints"]
         assert eps and len(eps) == len(dp.graph.interner.endpoints)
         assert all(0.0 <= row["anomalyProbability"] <= 1.0 and row["predictedLatencyMs"] >= 0.0 for row in eps)
+
+    @pytest.mark.parametrize("named", (None, "gcn"), ids=("no_name", "unknown_name"))
+    def test_a_checkpoint_that_names_no_head_is_graphsage_and_one_that_names_an_unknown_head_is_refused(
+        self, tmp_path, named
+    ):
+        """Checkpoints from before the metadata named a head are GraphSAGE's; a name that no head of
+        `models/heads.py` has is a load error that names it, never another head's template."""
+        import json
+
+        from kmamiz_tpu.api.app import build_router as _build
+        from kmamiz_tpu.models import checkpoint, heads
+        from kmamiz_tpu.server.initializer import AppContext, Initializer
+        from kmamiz_tpu.server.processor import DataProcessor
+        from kmamiz_tpu.server.storage import MemoryStore
+
+        _train_tiny_checkpoint(tmp_path)
+        step = checkpoint.latest_complete_step(str(tmp_path))
+        sidecar = tmp_path / f"step_{step}.meta.json"
+        meta = json.loads(sidecar.read_text())
+        assert meta["model"] == "graphsage"
+        meta.pop("model")
+        if named:
+            meta["model"] = named
+        sidecar.write_text(json.dumps(meta))
+        settings = Settings()
+        settings.external_data_processor = ""
+        settings.model_dir = str(tmp_path)
+        dp = DataProcessor(trace_source=lambda *a: [], use_device_stats=False)
+        ctx = AppContext.build(app_settings=settings, store=MemoryStore(), processor=dp)
+        Initializer(ctx).register_data_caches()
+        status = _build(ctx).dispatch("GET", "/api/v1/model/status").payload
+        if named is None:
+            assert status["modelLoaded"] is True and status["error"] is None, status
+        else:
+            assert status["modelLoaded"] is False and "'gcn'" in status["error"], status
+        assert [heads.by_name(m.NAME) for m in heads._modules()] == list(heads._modules())
+        with pytest.raises(ValueError, match="'gcn'"):
+            heads.by_name("gcn")
 
     def test_forecast_memo_label_epoch_invalidation(
         self, pdas_traces, tmp_path

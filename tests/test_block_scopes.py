@@ -2,7 +2,7 @@
 `jax.named_scope` (one taxonomy, `programs.SCOPE_PHASES`; docs/OBSERVABILITY.md),
 the registry reads them back from the compiled block (`Program.scope_table`),
 and a slow call leaves a record (`trainer._slow_call`). On the CPU, at toy
-sizes: the four heads on one device, GraphSAGE with node embeddings (no slot
+sizes: the five heads on one device, GraphSAGE with node embeddings (no slot
 group), and GraphSAGE over four of the host's devices."""
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from kmamiz_tpu.core import programs
-from kmamiz_tpu.models import gat, graphsage, pna, stacked, trainer
+from kmamiz_tpu.models import gat, gatv2, graphsage, pna, stacked, trainer
 from kmamiz_tpu.models.stlgt import model as stlgt_model
 from kmamiz_tpu.parallel import mesh as mesh_mod
 from kmamiz_tpu.telemetry.tracing import TRACER, operation_span, phase_span
@@ -26,6 +26,7 @@ BLOCKS = {
     "graphsage": (graphsage, False, 1, EVERY | {"group"}),
     "graphsage_embedding": (graphsage, True, 1, EVERY),
     "gat": (gat, False, 1, EVERY),
+    "gatv2": (gatv2, False, 1, EVERY),
     "pna": (pna, False, 1, EVERY),
     "stlgt": (stlgt_model, False, 1, EVERY),
     "graphsage_nodes4": (graphsage, False, 4, EVERY | {"group", "collective"}),
@@ -338,7 +339,7 @@ def one_chip():
 
 
 #: head -> (module, the Mosaic kernels a compiled block holds: forward and backward; PNA's two a layer since PR 40)
-KERNELS = {"graphsage": (graphsage, 3), "gat": (gat, 10), "pna": (pna, 4), "stlgt": (stlgt_model, 2)}
+KERNELS = {"graphsage": (graphsage, 3), "gat": (gat, 10), "gatv2": (gatv2, 10), "pna": (pna, 4), "stlgt": (stlgt_model, 2)}
 
 
 @pytest.mark.parametrize("head", sorted(KERNELS))
@@ -384,6 +385,10 @@ def test_the_chips_own_program_reads_under_the_programs_names(one_chip, head):
     table = programs.scope_table_of(text)
     walks = {name: row for name, row in table.items() if name.startswith("planned_")}
     assert len(walks) == kernels == text.count("tpu_custom_call")
+    if head == "gatv2":  # its own names, GAT's kernel bodies among them: `planned_attention_*` is GAT's alone
+        assert {name.split(".")[0] for name in walks} == {
+            f"planned_gatv2_{w}" for w in ("score", "softmax", "sum", "edge_dot", "backward")
+        }
     for name, (path, phase, _backward) in walks.items():
         assert phase == "reduce" and path.rsplit("/", 1)[-1] == name.split(".")[0], (name, path)
     phases = [phase for _path, phase, _backward in table.values()]

@@ -403,7 +403,8 @@ class TestChipSmoke:
         assert d["planned_attention_w124"] == "interpret"  # the spare lanes past the first 128
         assert d["planned_gated_sum"] == "interpret"  # STLGT's two walks against the XLA items
         assert d["planned_aggregate"] == "interpret"  # PNA's two walks against the XLA items
-        assert set(d["routes"]) == {"backend", "planned", "attention", "sharded", "mxu_products"}
+        assert d["planned_gatv2"] == "interpret"  # GATv2's five walks against the XLA items
+        assert set(d["routes"]) == {"backend", "planned", "attention", "gatv2", "sharded", "mxu_products"}
         assert d["routes"]["mxu_products"]["planned_attention_sum"] == 4  # three passes and the expand
         assert d["routes"]["planned"] > d["routes"]["attention"] > 0
         assert not [k for k in d if "fused" in k]

@@ -845,6 +845,50 @@ def test_aggregate_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, wid
     assert len(tables) == 4 and all(f"f32[{nb},128]" in t and "S(1)}" in t for t in tables), tables
 
 
+@pytest.mark.parametrize("width", (64, 40))
+def test_gatv2_walks_compile_for_the_v5e_at_the_cells_shapes(one_chip, width):
+    """The five walks of a differentiated `sparse_gatv2.planned_gatv2`, at the width of `mv100k-gatv2` (a row
+    `[xl | xr]` fills the 128 lanes) and at one that is padded to a multiple of eight: the `[64, 512]` per-entry
+    vector work, the `a` columns of a tile's block, the lane sums of `d a` and the two message blocks of the backward
+    walk are what interpreting does not refuse. Two row gathers, both at the full lane width, and no scatter."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from kmamiz_tpu.ops import sparse_gatv2
+
+    nb, eb = 131072, 524288
+    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(xl, xr, a, plan):
+        return (sparse_gatv2.planned_gatv2(plan, xl, xr, a, 0.2, "pallas") ** 2).sum()
+
+    # three of the walks are GAT's kernel bodies, and a kernel's bytecode holds the call stack of whoever traced its
+    # body FIRST in the process (GAT's `planned_attention` in a process that refreshed GAT): trace afresh, as a process
+    # that refreshes this head alone does
+    jax.clear_caches()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = (
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            .lower(arg((nb, width)), arg((nb, width)), arg((2, width)), _described_plan(arg, nb, entries, items))
+            .compile()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5
+    for name in ("score", "softmax", "sum", "edge_dot", "backward"):
+        assert f"planned_gatv2_{name}" in text
+    assert "planned_attention" not in text and "scatter" not in text
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert len(gathers) == 2 and all(f"f32[{entries},128]" in line for line in gathers), gathers
+
+
 def test_the_aggregate_primal_compiles_for_the_v5e_and_counts_no_ties(one_chip):
     """A forward that takes no VJP (serving, `predict_all`, evaluation) runs the custom VJP's primal: ONE walk,
     `planned_aggregate`, with two outputs, and not the forward rule's counted walk."""
@@ -965,12 +1009,14 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
 #: `ops/sparse.py` two lines down: all three changed, GAT's and STLGT's in their locations alone, GraphSAGE's also by
 #: one scalar that its slot group's loops hand on with the plan (the compiled block is the parent's instruction for
 #: instruction, compared in PR 39); `pna` is new. PR 40 changed `pna`'s alone (`ops/sparse_pna.py`: the ties counted in
-#: the forward walk, one walk fewer). The failing assertion prints the new value.
+#: the forward walk, one walk fewer). `gatv2` came in its own file and left the other four as they were. The failing
+#: assertion prints the new value.
 ONE_CHIP_BLOCKS = {
     "graphsage": "3cf6ea2861cdd0862ac02579c2d28aed16fd6a901eabcf7fa37d751c8433e982",
     "gat": "21cc8349f75ef8676b9eabc9a03a4af0aa7c195d7cfb616f0290854bc641defa",
     "stlgt": "191c1ef0f1b612b63642205f0b64b05a82871602b6030360a4a3393929436f70",
     "pna": "6d9a1939c6a09b171b656eaa8e13d1e62a57ef534e56bde784fde76f62803d55",
+    "gatv2": "2014547782fb49c992aa04ff65d94525ed145b94b1f311210b0b58bafd6fba27",
 }
 
 
@@ -1031,12 +1077,12 @@ def _lower_one_chip_block(one_chip, model):
 
 @pytest.mark.parametrize("head", sorted(ONE_CHIP_BLOCKS))
 def test_the_one_chip_blocks_lower_to_what_they_lowered_to(one_chip, head):
-    """The four one-chip cells' epoch blocks at the cells' shapes, the kernels' locations included: what PR 35 and
-    PR 36 compared by hand against their parents."""
-    from kmamiz_tpu.models import gat, pna
+    """The one-chip cells' epoch blocks at the cells' shapes, the kernels' locations included: what was once compared
+    by hand against the parent commits."""
+    from kmamiz_tpu.models import gat, gatv2, pna
     from kmamiz_tpu.models.stlgt import model as stlgt_model
 
-    heads = {"graphsage": graphsage, "gat": gat, "pna": pna, "stlgt": stlgt_model}
+    heads = {"graphsage": graphsage, "gat": gat, "gatv2": gatv2, "pna": pna, "stlgt": stlgt_model}
     text = _lower_one_chip_block(one_chip, heads[head]).as_text()
     assert "tpu_custom_call" in text
     assert _block_fingerprint(text) == ONE_CHIP_BLOCKS[head]

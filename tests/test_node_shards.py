@@ -23,7 +23,7 @@ import pytest
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from kmamiz_tpu.models import common, gat, graphsage, pna, stacked, trainer
+from kmamiz_tpu.models import common, gat, gatv2, graphsage, pna, stacked, trainer
 from kmamiz_tpu.ops import sparse
 from kmamiz_tpu.parallel import mesh as mesh_mod
 from kmamiz_tpu.telemetry.tracing import TRACER
@@ -487,6 +487,8 @@ class TestShardedRefresh:
             trainer.train(ds, epochs=1, hidden=8, model=stlgt_model)
         with pytest.raises(NotImplementedError, match=r"pna cannot train over a history cut by nodes over 4 devices"):
             trainer.train(ds, epochs=1, hidden=8, model=pna)  # a maximum over an owner's entries knows no mesh axis
+        with pytest.raises(NotImplementedError, match=r"gatv2 cannot train over a history cut by nodes over 4 devices"):
+            trainer.train(ds, epochs=1, hidden=8, model=gatv2)  # a softmax over an owner's entries knows no mesh axis
         with pytest.raises(NotImplementedError, match="node embeddings cannot train"):
             trainer.train(ds, epochs=1, hidden=8, use_node_embeddings=True)
         with pytest.raises(NotImplementedError, match="slot microbatches"):

@@ -208,7 +208,7 @@ class TestPlannedAggregate:
         plan = _plan(src, dst, mask, nb)
         m, _weights = _messages(nb, 8)
         sparse_pna.planned_aggregate(plan, m)
-        assert sparse.route_stats() == {"backend": "sparse", "planned": 1, "attention": 0, "sharded": 0, "mxu_products": {}}
+        assert sparse.route_stats() == {"backend": "sparse", "planned": 1, "attention": 0, "gatv2": 0, "sharded": 0, "mxu_products": {}}
         with pytest.raises(Exception):  # Mosaic cannot target a CPU: nothing interprets silently
             jax.block_until_ready(sparse_pna.planned_aggregate(plan, m, "pallas"))
         with pytest.raises(NotImplementedError, match="merge a running maximum across the sources"):

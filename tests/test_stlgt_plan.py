@@ -260,7 +260,7 @@ class TestPlannedGatedSum:
         plan = _plan(src, dst, mask, nb)
         q, k, v, b, _w = _tables(nb, 8)
         sparse_gated.planned_gated_sum(plan, q, k, v, b)
-        assert sparse.route_stats() == {"backend": "sparse", "planned": 1, "attention": 0, "sharded": 0, "mxu_products": {}}
+        assert sparse.route_stats() == {"backend": "sparse", "planned": 1, "attention": 0, "gatv2": 0, "sharded": 0, "mxu_products": {}}
         with pytest.raises(Exception):  # Mosaic cannot target a CPU: nothing interprets silently
             jax.block_until_ready(sparse_gated.planned_gated_sum(plan, q, k, v, b, "pallas"))
 

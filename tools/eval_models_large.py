@@ -4,7 +4,7 @@ Generates a layered MicroViSim mesh (default 100 services / 1k endpoints,
 BASELINE config 3) with a rich programmatic fault schedule — recurring
 nightly windows, overlapping multi-endpoint incidents, probabilistic
 windows, and gateway traffic bursts that push services into overload —
-then trains/evaluates the GraphSAGE and GAT heads against the
+then trains/evaluates the GraphSAGE, GAT, GATv2 and PNA heads against the
 persistence skyline and naive baselines.
 
 Beyond thresholded P/R/F1 this reports threshold-free ROC-AUC and PR-AUC
@@ -514,7 +514,7 @@ def main() -> None:
     )
     args = parser.parse_args()
 
-    from kmamiz_tpu.models import gat, graphsage, pna, trainer
+    from kmamiz_tpu.models import gat, gatv2, graphsage, pna, trainer
     from kmamiz_tpu.simulator.simulator import Simulator
 
     rng = np.random.default_rng(args.seed)
@@ -544,6 +544,7 @@ def main() -> None:
     for name, model in (
         (f"GraphSAGE{suffix}", graphsage),
         (f"GAT{suffix}", gat),
+        (f"GATv2{suffix}", gatv2),
         (f"PNA{suffix}", pna),
     ):
         t1 = time.perf_counter()

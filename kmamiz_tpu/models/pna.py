@@ -25,7 +25,9 @@ part leaves every aggregator as a constant (it moves mean, maximum and
 minimum by W_o h_i and the deviation not at all): it is folded into the
 update's own h_i term and not computed per edge. One tower, one linear layer
 before and after the aggregation, no normalisation, no residual. Every
-matrix product of the head is a float32 product (`EXACT`, below).
+matrix product of the head is a float32 product (`EXACT`, below). The
+update's product reads the four aggregates once, against W_a's three row
+blocks side by side (`_update`): its [N, 12H] input is never made.
 
 `delta` is the mean of log(d + 1) over the endpoints that HAVE a neighbour
 (PNAConv takes it over every node of the training graphs, isolated ones
@@ -164,8 +166,19 @@ def _layer(h, src_ep, dst_ep, edge_mask, w_msg, w_self, w_agg, b, plan):
     deviation = jnp.sqrt(jax.nn.relu(squares / d - mean * mean) + EPS)
     a = jnp.concatenate([mean, deviation, top, bottom], axis=1)
     scale = jnp.log(d + 1.0) / delta
-    update = _dot(jnp.concatenate([a, scale * a, a / scale], axis=1), w_agg)
-    return jax.nn.relu(_dot(h, w_self) + update + b)
+    return jax.nn.relu(_dot(h, w_self) + _update(a, scale, w_agg) + b)
+
+
+def _update(a, scale, w_agg):
+    """`[a | s a | a / s] W_a` as `a W_0 + s (a W_1) + (a W_2) / s`, `W_k` the k-th of W_a's three row blocks: ONE
+    float32 product of the four aggregates `a` [N, 4H] against the blocks side by side (`W_cat` [4H, 3H]), then a
+    per-node combine. The [N, 12H] input is never made, kept or differentiated: autodiff's `d a` is
+    `[g | s g | g / s] W_cat^T`, and `d W_a` is `a^T [g | s g | g / s]` laid back into W_a's rows. The same
+    mathematics, rounded in another order."""
+    width, hidden = a.shape[1], w_agg.shape[1]
+    w_cat = w_agg.reshape(3, width, hidden).transpose(1, 0, 2).reshape(width, 3 * hidden)  # [W_0 | W_1 | W_2]
+    parts = _dot(a, w_cat)
+    return parts[:, :hidden] + scale * parts[:, hidden : 2 * hidden] + parts[:, 2 * hidden :] / scale
 
 
 def forward(

@@ -1009,13 +1009,14 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
 #: `ops/sparse.py` two lines down: all three changed, GAT's and STLGT's in their locations alone, GraphSAGE's also by
 #: one scalar that its slot group's loops hand on with the plan (the compiled block is the parent's instruction for
 #: instruction, compared in PR 39); `pna` is new. PR 40 changed `pna`'s alone (`ops/sparse_pna.py`: the ties counted in
-#: the forward walk, one walk fewer). `gatv2` came in its own file and left the other four as they were. The failing
-#: assertion prints the new value.
+#: the forward walk, one walk fewer). `gatv2` came in its own file and left the other four as they were. The update's
+#: product over the four aggregates alone (`pna._update`) changed `pna`'s alone. The failing assertion prints the new
+#: value.
 ONE_CHIP_BLOCKS = {
     "graphsage": "3cf6ea2861cdd0862ac02579c2d28aed16fd6a901eabcf7fa37d751c8433e982",
     "gat": "21cc8349f75ef8676b9eabc9a03a4af0aa7c195d7cfb616f0290854bc641defa",
     "stlgt": "191c1ef0f1b612b63642205f0b64b05a82871602b6030360a4a3393929436f70",
-    "pna": "6d9a1939c6a09b171b656eaa8e13d1e62a57ef534e56bde784fde76f62803d55",
+    "pna": "7d9bec0b2d73937e93e11471f879b5dda1185bfdc677a61ca73be187f3df554c",
     "gatv2": "2014547782fb49c992aa04ff65d94525ed145b94b1f311210b0b58bafd6fba27",
 }
 
@@ -1088,6 +1089,20 @@ def test_the_one_chip_blocks_lower_to_what_they_lowered_to(one_chip, head):
     assert _block_fingerprint(text) == ONE_CHIP_BLOCKS[head]
 
 
+def _compiled_one_chip_block(one_chip, model) -> str:
+    """The optimized HLO of a one-chip cell's epoch block, compiled for a described v5e."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
+    compilation_cache.reset_cache()
+    try:
+        return _lower_one_chip_block(one_chip, model).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 def test_stlgt_block_keeps_its_node_arrays_in_the_layout_that_pads_nothing(one_chip):
     """What the chip's compiler makes of `mv100k-stlgt`'s block around the two gated walks: every `f32[131072,64]`
     array (q, k, v, the bias, their cotangents: three hundred instructions) in the layout whose minor dimension is the
@@ -1096,17 +1111,24 @@ def test_stlgt_block_keeps_its_node_arrays_in_the_layout_that_pads_nothing(one_c
     XLA's own part (PERF.md, PR 38), two thirds of that PR's gain in the cell. One such operand brings it back."""
     import re
 
-    from jax.experimental.compilation_cache import compilation_cache
-
     from kmamiz_tpu.models.stlgt import model as stlgt_model
 
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
-    compilation_cache.reset_cache()
-    try:
-        text = _lower_one_chip_block(one_chip, stlgt_model).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    text = _compiled_one_chip_block(one_chip, stlgt_model)
     layouts = re.findall(r"= f32\[131072,64\]\{([0-9,]+)", text)
     assert len(layouts) > 250 and set(layouts) == {"0,1"}, {layout: layouts.count(layout) for layout in set(layouts)}
+
+
+def test_pna_block_never_makes_the_updates_concatenated_input(one_chip):
+    """What the chip's compiler makes of `mv100k-pna`'s block around the update: no `f32[131072,768]` array. The
+    update's input `[a | s a | a / s]` was one in each layer, made by in-place slices, kept for `d w_agg`, and met by a
+    cotangent of the same shape (58 such instructions, 402 MB each). Now the four aggregates feed ONE product of
+    192 columns a layer and the cotangent `[g | s g | g / s]` one of 256, each fused with what makes its operand and
+    each in the layout whose minor dimension is the nodes."""
+    import re
+
+    from kmamiz_tpu.models import pna
+
+    text = _compiled_one_chip_block(one_chip, pna)
+    assert "f32[131072,768]" not in text
+    products = re.findall(r"= f32\[131072,(192|256|768)\]\{([0-9,]+)[^}]*\} convolution\(", text)
+    assert sorted(products) == [("192", "0,1")] * 2 + [("256", "0,1")] * 2, products

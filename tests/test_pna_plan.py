@@ -368,6 +368,28 @@ class TestHeadOnThePlan:
         assert len(found) == 10 + 10 + 6  # ten products, their weights' gradients, their inputs' (the features take none)
         assert all(p in (highest, (highest, highest)) for p in found), found
 
+    @pytest.mark.parametrize("planned", (True, False), ids=("plan", "no_plan"))
+    def test_no_value_is_as_wide_as_the_updates_concatenated_input(self, planned):
+        """The update reads the four aggregates once (`pna._update`): no value of the loss or of its gradient is
+        12 x hidden wide, the width of `[a | s a | a / s]`, which the cell's block once made, kept and split as
+        [131072, 768] in each layer (PERF.md, section 5)."""
+        hidden = 8
+        st = stacked.stack_dataset(_dataset())
+        params = pna.init_params(jax.random.PRNGKey(0), hidden=hidden, num_features=18)
+        slot = (st.features[0], st.src, st.dst, st.edge_mask, st.target_latency[0], st.target_anomaly[0], st.node_mask[0])
+        loss = stacked.head_loss_fn(pna, 3.0, **({"plan": st.plan} if planned else {}))
+        traced = jax.make_jaxpr(jax.grad(loss, has_aux=True))(params, *slot)
+
+        def shapes(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield from (v.aval.shape for v in eqn.outvars if hasattr(v.aval, "shape"))
+                for inner in jax.core.jaxprs_in_params(eqn.params):
+                    yield from shapes(inner)
+
+        found = set(shapes(traced.jaxpr))
+        assert (st.bucket_nodes, 4 * hidden) in found and (st.bucket_nodes, 3 * hidden) in found  # a, and a W_cat
+        assert not [s for s in found if s and s[-1] == 12 * hidden], found
+
     def test_the_head_says_what_it_takes(self):
         assert pna.NAME == "pna" and pna.TAKES_PLAN
         assert not getattr(pna, "TAKES_NEIGHBOR_SUM_1", False) and not getattr(pna, "TAKES_NODE_SHARDS", False)
@@ -376,6 +398,58 @@ class TestHeadOnThePlan:
         assert stacked.plan_for(pna, st) is st.plan
         assert stacked.slot_group(pna, params, st.features, st.plan) == 0  # a maximum does not commute with a product
         assert params.w_agg_1.shape == (12 * 8, 8) and params.w_msg_1.shape == (18, 8)
+
+
+#: the scalers the update meets: every owner at the degree clamp (`d = 1`), a hub's (up to 4.5 at the cell's largest
+#: hubs), and owners with no neighbour, whose aggregates are zero but for the deviation's `sqrt(EPS)`
+UPDATE_CASES = ("degree_clamp", "hub", "isolated")
+#: float32 epsilons of the sum of the absolute terms an element adds (no cancellation can hide an error under it)
+_UPDATE_ULPS = 4
+
+
+def _update_case(name, seed=0, n=64, hidden=8):
+    """(a [n, 4H], scale [n, 1], w_agg [12H, H], a cotangent [n, H]) as the layer makes them."""
+    rng = np.random.default_rng(seed)
+    a = rng.normal(size=(n, 4 * hidden)).astype(np.float32)
+    degree, delta = np.ones(n), 1.0
+    if name == "hub":
+        degree = np.round(np.geomspace(1, 5000, n))
+        delta = np.log(5001.0) / 4.5  # the largest hub's scale is 4.5
+    elif name == "isolated":
+        a[::2] = 0.0  # no neighbour: nothing aggregated, and `d` clamped to 1
+        a[::2, hidden : 2 * hidden] = np.sqrt(pna.EPS)
+    scale = jnp.log(jnp.maximum(jnp.asarray(degree, jnp.float32), 1.0)[:, None] + 1.0) / jnp.float32(delta)
+    w_agg = (rng.normal(size=(12 * hidden, hidden)) * np.sqrt(2.0 / (13 * hidden))).astype(np.float32)
+    g = rng.normal(size=(n, hidden)).astype(np.float32)
+    return jnp.asarray(a), scale, jnp.asarray(w_agg), jnp.asarray(g)
+
+
+def _concatenated_update(a, scale, w_agg):
+    """The layer's update as the paper and the reference write it: one product over `[a | s a | a / s]`."""
+    return jnp.dot(jnp.concatenate([a, scale * a, a / scale], axis=1), w_agg, precision=jax.lax.Precision.HIGHEST)
+
+
+def _update_and_vjp(update, a, scale, w_agg, g):
+    out, vjp = jax.vjp(lambda a, w: update(a, scale, w), a, w_agg)
+    return (out, *vjp(g))
+
+
+class TestFactoredUpdate:
+    @pytest.mark.parametrize("seed", (0, 1, 2))
+    @pytest.mark.parametrize("case", UPDATE_CASES)
+    def test_it_is_the_concatenated_product_forward_and_backward(self, case, seed):
+        """`pna._update` (one product over the four aggregates, then the per-node combine) against the product over
+        `[a | s a | a / s]`: the output and the VJP to `a` and to `w_agg`, each element within a few float32 ulps of
+        the sum of the absolute terms it adds (the same two computations over |a|, |w_agg| and |g|)."""
+        a, scale, w_agg, g = _update_case(case, seed)
+        assert float(scale.max()) == pytest.approx(4.5 if case == "hub" else np.log(2.0), rel=1e-6)
+        got = jax.jit(partial(_update_and_vjp, pna._update))(a, scale, w_agg, g)
+        want = jax.jit(partial(_update_and_vjp, _concatenated_update))(a, scale, w_agg, g)
+        size = _update_and_vjp(_concatenated_update, jnp.abs(a), scale, jnp.abs(w_agg), jnp.abs(g))
+        for name, x, y, m in zip(("update", "d a", "d w_agg"), got, want, size):
+            assert x.shape == y.shape
+            bound = _UPDATE_ULPS * np.finfo(np.float32).eps * np.asarray(m)
+            assert (np.abs(np.asarray(x) - np.asarray(y)) <= bound).all(), name
 
 
 def _reference_run(ds, seed, hidden):

@@ -320,8 +320,8 @@ def _edge_plan(dataset, src, dst, e_mask, n: int, nb: int, shards: int) -> _Plan
             entries=counts[0],
             items=counts[1],
             blocks=counts[2],
-            entries_out=counts[0] - entries_in,
-            entries_in=entries_in,
+            block_reuse_share=round((counts[1] - counts[2]) / max(counts[1], 1), 4),  # items that find their block's work made
+            entries_out=counts[0] - entries_in, entries_in=entries_in,
             runs=runs, mxu_products=sparse.route_stats()["mxu_products"],
         )
         if shards > 1:

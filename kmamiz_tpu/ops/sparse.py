@@ -70,9 +70,9 @@ _VALID_BACKENDS = ("xla", "sparse")
 _backend_cache: Optional[str] = None
 
 _route_lock = threading.Lock()
-#: reductions over an edge plan since process start (trace-time counts:
-#: the consumers decide inside their jit traces); `_walk_call`'s products
-_route_counts = {"planned": 0, "attention": 0, "gatv2": 0, "sharded": 0, "mxu_products": {}}
+#: reductions over an edge plan since process start (trace-time counts: the consumers decide inside their
+#: jit traces); `_walk_call`'s products, and the arrays a walk keeps an edge block (`scratch`)
+_route_counts = {"planned": 0, "attention": 0, "gatv2": 0, "sharded": 0, "mxu_products": {}, "block_arrays": {}}
 
 
 def backend() -> str:
@@ -96,7 +96,7 @@ def reset_for_tests() -> None:
     global _backend_cache
     _backend_cache = None
     with _route_lock:
-        _route_counts.update(planned=0, attention=0, gatv2=0, sharded=0, mxu_products={})
+        _route_counts.update(planned=0, attention=0, gatv2=0, sharded=0, mxu_products={}, block_arrays={})
 
 
 def use_sparse() -> bool:
@@ -105,7 +105,7 @@ def use_sparse() -> bool:
 
 
 def route_stats() -> dict:
-    """Backend, the planned reductions' counters, the walks' MXU products (/timings)."""
+    """Backend, the planned reductions' counters, the walks' MXU products and kept block arrays (/timings)."""
     with _route_lock:
         counts = dict(_route_counts)
     return {"backend": backend(), **counts}
@@ -778,14 +778,14 @@ def _attention_backward_kernel(
         dhw_ref[...] += _weighted_sum(msg_t, alpha_m, hot)
 
 
-def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bool):
-    """One walk of the plan's items. `inputs` and `outputs` are (kind, array
-    or lane width) pairs; the kind says how a block follows the item: "entry"
-    [R, L] by edge block, "message" [L, lanes] by edge block, "node_rows"
-    [R, nodes] by tile, "node" [nodes, lanes] by tile. A "message_ring" input
-    stays in HBM whole: the kernel fetches its blocks itself
-    (`_message_block`), into a ring and with the semaphores it is handed
-    after its outputs."""
+def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bool, scratch=()):
+    """One walk of the plan's items. `inputs` and `outputs` are (kind, array or lane width) pairs; the kind says how a
+    block follows the item: "entry" [R, L] by edge block, "message" [L, lanes] by edge block, "node_rows" [R, nodes] by
+    tile, "node" [nodes, lanes] by tile. A "message_ring" input stays in HBM whole: the kernel fetches its blocks itself
+    (`_message_block`), into a ring and with the semaphores it is handed after its outputs. `scratch`: (rows, dtype)
+    pairs, [rows, block] arrays in VMEM handed last, which outlive the item: what a walk makes once an edge block."""
+    with _route_lock:  # a new dict, as for the products; a walk that keeps nothing is not named
+        _route_counts["block_arrays"] = {**_route_counts["block_arrays"], **({name: len(scratch)} if scratch else {})}
     tn, be = PLAN_NODE_TILE, PLAN_EDGE_BLOCK
     entries, nodes = plan.owner.shape[1], _node_tiles(plan) * tn
 
@@ -831,7 +831,7 @@ def _walk_call(plan: EdgePlan, kernel, name: str, inputs, outputs, interpret: bo
                     pltpu.VMEM((MSG_RING, be, a.shape[1]), jnp.float32),
                     pltpu.SemaphoreType.DMA((MSG_RING,)),
                 )
-            ],
+            ] + [pltpu.VMEM((rows, be), dtype) for rows, dtype in scratch],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(shape(kind, lanes), jnp.float32)

@@ -52,8 +52,8 @@ device trace names:
   (`benchmarks/reference/pna.py`) therefore does. The ties are a count over
   an OWNER's entries, made in the forward walk (`planned_aggregate_counted`):
   each block's maximum of an owner is expanded back to the block's entries
-  through the one-hot (exact), compared with the block's `[m | -m]` already
-  in registers, and the matches, exact in bfloat16, counted in one pass; the
+  through the one-hot (exact), compared with the block's `[m | -m]` (kept
+  in VMEM), and the matches, exact in bfloat16, counted in one pass; the
   tile keeps its count where its maximum stands (`old == new`) and adds the
   block's where the block's maximum is the tile's (`top == new`), so a block
   whose maximum a later block exceeds leaves nothing. Integers, so the count
@@ -70,6 +70,16 @@ device trace names:
   at a time (PERF.md, PR 33 and PR 36: 1.8 ns a gathered row from there, 10 ns
   from HBM), so the three gathers are chained by `optimization_barrier`: a
   table is made when the gather before it is done.
+- Work once an edge block. A walk's items are (node tile, edge block) pairs,
+  and a block meets 1.5 tiles on the cells' plan, but what the forward walk
+  makes of a block before its one-hot (the transposition, the squares, the
+  running maximum, the splits into bfloat16 pieces) depends on the block
+  alone. The block's first item makes it, uses it and keeps it in VMEM
+  scratch (`_walk_call`'s `scratch`, counted in
+  `sparse.route_stats()["block_arrays"]`); the block's other items read it
+  there: the same operations in the same order, so the same bits. The
+  backward walk's three transpositions a block are left where they are: kept
+  the same way they saved nothing on the v5e (PERF.md, section 6).
 
 Off the TPU the same mathematics is plain XLA over the plan's sorted entries
 (`_aggregate_xla`: `jax.ops.segment_sum`, `segment_max`, `segment_min`),
@@ -94,6 +104,7 @@ from kmamiz_tpu.ops import sparse
 from kmamiz_tpu.ops.sparse import (
     _NEG,
     _NT,
+    ATT_ROWS,
     PLAN_NODE_TILE,
     ROW_OWNER,
     EdgePlan,
@@ -102,11 +113,13 @@ from kmamiz_tpu.ops.sparse import (
     _gather_rows,
     _item,
     _mxu,
+    _new_block,
     _node_tiles,
     _pad_to,
     _reduce,
     _row,
     _rows,
+    _split3,
     _walk_call,
 )
 from kmamiz_tpu.ops.sparse_gated import _halves
@@ -134,28 +147,51 @@ def _running_max(x, state):
     return x, last
 
 
+def _pieces(rows):
+    """[R, block] float32 entry rows as `_reduce` splits them: the three
+    bfloat16 pieces, in the order `_reduce` sums them."""
+    return tuple(reversed(_split3(rows)))
+
+
+def _reduce_pieces(pieces, hot):
+    """`_reduce` of rows already split (`_pieces`): the same three passes,
+    summed in the same order, so the same bits."""
+    return sum(_mxu(piece, hot, _NT) for piece in pieces)
+
+
+def _forward_block(half: int, counted: bool):
+    """What the forward walk keeps of an edge block (`_walk_call`'s `scratch`):
+    the three pieces of the `[sum | sq]` entry rows, the three of each owner's
+    running maximum on its last entry, the last entries as a row table, and
+    where the ties are counted `[m | -m]` itself."""
+    return [(2 * half, jnp.bfloat16)] * 6 + [(ATT_ROWS, jnp.float32)] + ([(2 * half, jnp.float32)] if counted else [])
+
+
 def _aggregate_kernel(
-    tile_ref, block_ref, flag_ref, state_ref, msg_ref, sums_ref, tops_ref, ties_ref=None, *, half: int,
+    tile_ref, block_ref, flag_ref, state_ref, msg_ref, sums_ref, tops_ref, *refs, half: int, counted: bool,
 ):
-    """`ties_ref`: the counted walk's third output (the forward rule's), how
-    many of an owner's entries hold its `[max | -min]`, lane by lane."""
-    counted = () if ties_ref is None else (ties_ref,)
-    real, one_hot, _d = _item(tile_ref, block_ref, flag_ref, state_ref, None, (sums_ref, *counted))
+    """`counted`: the forward rule's walk, whose third output is how many of
+    an owner's entries hold its `[max | -min]`, lane by lane. What depends on
+    the edge block alone (its transposition, the squares, the running maximum,
+    the splits into pieces) is made by the block's first item, which uses it
+    at once and keeps it in the scratch after the outputs (`_forward_block`);
+    the block's other items read it there (a block meets 1.5 node tiles on the
+    cells' plan). Made in a region of its own and read back by every item,
+    the same work cost the walk 7% more on the v5e (PERF.md, section 6)."""
+    ties, block = (refs[:1], refs[1:]) if counted else ((), refs)
+    real, one_hot, _d = _item(tile_ref, block_ref, flag_ref, state_ref, None, (sums_ref, *ties))
+    new_block = _new_block(block_ref)
 
     @pl.when(flag_ref[pl.program_id(0)] == 1)
     def _new_tile():
         tops_ref[...] = jnp.full_like(tops_ref, _NEG)
 
-    @pl.when(real)
-    def _walk():
+    def walk(sum_pieces, top_pieces, last_rows, x):
         hot = one_hot.astype(jnp.bfloat16)
-        x = msg_ref[...].T  # [m | -m] of the neighbour, a lane an entry
-        upper = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) < half
-        sums_ref[...] += _reduce(jnp.where(upper, x, x * x), hot)  # [sum | sum of squares]
-        run, last = _running_max(x, state_ref[...])
+        sums_ref[...] += _reduce_pieces(sum_pieces, hot)  # [sum | sum of squares]
         # the owners this block holds: each has ONE last entry in it
-        held = _mxu(_rows(last.astype(jnp.float32)).astype(jnp.bfloat16), hot, _NT)[0:1] > 0.5
-        top = _reduce(jnp.where(last, run, 0.0), hot)  # one term an owner: the term itself
+        held = _mxu(last_rows.astype(jnp.bfloat16), hot, _NT)[0:1] > 0.5
+        top = _reduce_pieces(top_pieces, hot)
         old = tops_ref[...]
         new = jnp.where(held, jnp.maximum(old, top), old)
         tops_ref[...] = new
@@ -163,7 +199,27 @@ def _aggregate_kernel(
             # the block's entries at their owner's maximum OF THE BLOCK, merged as the maximum is (the
             # module's docstring); an owner the block does not hold counts 0 there and keeps its own
             count = _mxu((x == _expand(top, hot)).astype(jnp.float32).astype(jnp.bfloat16), hot, _NT)
-            ties_ref[...] = jnp.where(old == new, ties_ref[...], 0.0) + jnp.where(top == new, count, 0.0)
+            ties[0][...] = jnp.where(old == new, ties[0][...], 0.0) + jnp.where(top == new, count, 0.0)
+
+    @pl.when(real & new_block)
+    def _first_item():
+        x = msg_ref[...].T  # [m | -m] of the neighbour, a lane an entry
+        upper = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) < half
+        run, last = _running_max(x, state_ref[...])
+        sum_pieces, top_pieces = _pieces(jnp.where(upper, x, x * x)), _pieces(jnp.where(last, run, 0.0))
+        made = (*sum_pieces, *top_pieces, _rows(last.astype(jnp.float32))) + ((x,) if counted else ())
+        for ref, value in zip(block, made, strict=True):
+            ref[...] = value
+        walk(sum_pieces, top_pieces, made[6], x)
+
+    products = sparse._mxu_calls.n
+
+    @pl.when(real & jnp.logical_not(new_block))
+    def _later_item():
+        kept = [ref[...] for ref in block]
+        walk(tuple(kept[0:3]), tuple(kept[3:6]), kept[6], kept[7] if counted else None)
+
+    sparse._mxu_calls.n = products  # an item runs one of the two bodies: its products are counted once
 
 
 def _aggregate_backward_kernel(
@@ -197,9 +253,10 @@ def _aggregate_pallas_fwd(plan: EdgePlan, m, interpret: bool, counted: bool):
     nodes, half = _shapes(plan, width)
     msg = _gather_rows(_halves(nodes, half, m, -m), plan.neighbour)  # [L, 2 half], float32
     sums, tops, *ties = _walk_call(
-        plan, partial(_aggregate_kernel, half=half), "planned_aggregate_counted" if counted else "planned_aggregate",
+        plan, partial(_aggregate_kernel, half=half, counted=counted),
+        "planned_aggregate_counted" if counted else "planned_aggregate",
         [("entry", _entry_state(plan)), ("message", msg)],
-        [("node_rows", 2 * half)] * (3 if counted else 2), interpret,
+        [("node_rows", 2 * half)] * (3 if counted else 2), interpret, _forward_block(half, counted),
     )
     held = _held(plan, n)
     out = (

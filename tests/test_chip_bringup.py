@@ -404,7 +404,7 @@ class TestChipSmoke:
         assert d["planned_gated_sum"] == "interpret"  # STLGT's two walks against the XLA items
         assert d["planned_aggregate"] == "interpret"  # PNA's two walks against the XLA items
         assert d["planned_gatv2"] == "interpret"  # GATv2's five walks against the XLA items
-        assert set(d["routes"]) == {"backend", "planned", "attention", "gatv2", "sharded", "mxu_products"}
+        assert set(d["routes"]) == {"backend", "planned", "attention", "gatv2", "sharded", "mxu_products", "block_arrays"}
         assert d["routes"]["mxu_products"]["planned_attention_sum"] == 4  # three passes and the expand
         assert d["routes"]["planned"] > d["routes"]["attention"] > 0
         assert not [k for k in d if "fused" in k]

@@ -1010,13 +1010,14 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
 #: one scalar that its slot group's loops hand on with the plan (the compiled block is the parent's instruction for
 #: instruction, compared in PR 39); `pna` is new. PR 40 changed `pna`'s alone (`ops/sparse_pna.py`: the ties counted in
 #: the forward walk, one walk fewer). `gatv2` came in its own file and left the other four as they were. The update's
-#: product over the four aggregates alone (`pna._update`) changed `pna`'s alone. The failing assertion prints the new
-#: value.
+#: product over the four aggregates alone (`pna._update`) changed `pna`'s alone. PNA's walks keeping an edge block's own
+#: work in VMEM scratch (`_walk_call`'s `scratch`, added in place: no line of `ops/sparse.py` moved) changed `pna`'s
+#: alone. The failing assertion prints the new value.
 ONE_CHIP_BLOCKS = {
     "graphsage": "3cf6ea2861cdd0862ac02579c2d28aed16fd6a901eabcf7fa37d751c8433e982",
     "gat": "21cc8349f75ef8676b9eabc9a03a4af0aa7c195d7cfb616f0290854bc641defa",
     "stlgt": "191c1ef0f1b612b63642205f0b64b05a82871602b6030360a4a3393929436f70",
-    "pna": "7d9bec0b2d73937e93e11471f879b5dda1185bfdc677a61ca73be187f3df554c",
+    "pna": "64c79a1aea080b56adef8d51933527cee346bfad1e961a9dce94bdb4ddcadec5",
     "gatv2": "2014547782fb49c992aa04ff65d94525ed145b94b1f311210b0b58bafd6fba27",
 }
 

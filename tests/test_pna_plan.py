@@ -33,6 +33,8 @@ def _topology(name):
     """(src, dst, edge_mask, bucket_nodes) of a graph of either test file, or this one's."""
     if name == "maxima_in_later_blocks":
         return _two_hubs()
+    if name in TILES_A_BLOCK:
+        return _tiles_a_block(name)
     if name in ("heavier_than_a_block", "self_loops_and_repeats"):
         return _case(name)
     src, dst, mask, _n, nb = _graph(name)
@@ -47,6 +49,35 @@ def _two_hubs():
     src, dst = rng.integers(0, n, e), rng.integers(0, n, e)
     src[: 2 * BE + 100] = 7
     src[2 * BE + 100 : 2 * BE + 550] = 300
+    pad = eb - e
+    return (
+        np.concatenate([src, np.zeros(pad, int)]).astype(np.int32),
+        np.concatenate([dst, np.zeros(pad, int)]).astype(np.int32),
+        np.concatenate([np.ones(e, bool), np.zeros(pad, bool)]),
+        nb,
+    )
+
+
+#: plans whose busiest edge block meets this many node tiles: the walks make a block's own work on its first item
+#: and every other item of the block reads it (`sparse_pna`'s docstring); a hub's entries across three blocks
+TILES_A_BLOCK = {"a_tile_a_block": 1, "two_tiles_a_block": 2, "four_tiles_a_block": 4, "heavier_than_a_block": 1}
+
+
+def _tiles_a_block(name):
+    """(src, dst, edge_mask, bucket_nodes): each block one tile's (three blocks of one tile), the first block two
+    tiles', or one block four tiles' (512 owners of degree 1); each bucket wide enough that no-op items follow."""
+    if name == "heavier_than_a_block":
+        return _case(name)
+    rng = np.random.default_rng(44)
+    if name == "a_tile_a_block":
+        n, nb, e, eb = 120, 128, 600, 1024
+        src, dst = rng.integers(0, n, e), rng.integers(0, n, e)
+    elif name == "two_tiles_a_block":
+        n, nb, e, eb = 256, 256, 400, 512
+        src, dst = rng.integers(0, n, e), rng.integers(0, n, e)
+    else:  # each owner's one entry: the block holds 512 owners, the four tiles of 128
+        n, nb, e, eb = 512, 512, 256, 512
+        src, dst = np.arange(0, n, 2), np.arange(1, n, 2)
     pad = eb - e
     return (
         np.concatenate([src, np.zeros(pad, int)]).astype(np.int32),
@@ -127,6 +158,22 @@ def _float64_aggregates_and_gradient(plan, entries, m, weights):
     return (total, squares, np.where(held, top, 0.0), np.where(held, bottom, 0.0)), grad, (ties_top, ties_bottom)
 
 
+def _assert_near_float64(host, entries, m, got, got_grad, want, want_grad):
+    """The kernels' four aggregates and gradient against the float64 oracle's: the sums within the roundings they
+    make, the extremes to the bit."""
+    # a float32 sum of float32 terms: a rounding an entry and three for the pieces, each of what it rounds
+    rows, owners = np.abs(np.asarray(m, np.float64))[host.neighbour[:entries]], host.owner[0, :entries]
+    for a, c, terms in zip(got[:2], want[:2], (rows, 2 * rows * rows)):  # a square is rounded before it is summed
+        size = np.zeros_like(c)
+        np.add.at(size, owners, terms)
+        assert (np.abs(a - c) <= (np.asarray(host.degree)[:, None] + 3) * 2.0**-24 * size).all()
+    for a, c in zip(got[2:], want[2:]):  # an extreme is one of the rows, to the bit; 0 where there is none
+        np.testing.assert_array_equal(a, c.astype(np.float32))
+    size = np.zeros_like(want_grad)
+    np.add.at(size, host.neighbour[:entries], 1.0)
+    np.testing.assert_allclose(got_grad, want_grad, rtol=1e-5, atol=1e-5 * (1.0 + size.max()))
+
+
 def _aggregates_and_gradient(plan, m, weights, impl):
     def weighed(m):
         out = sparse_pna.planned_aggregate(plan, m, impl)
@@ -149,17 +196,7 @@ class TestPlannedAggregate:
         want, want_grad, (ties_top, _ties_bottom) = _float64_aggregates_and_gradient(host, entries, m, weights)
         assert (ties_top > 1).any()  # the case holds a shared maximum
         got, got_grad = _aggregates_and_gradient(_plan(src, dst, mask, nb), m, weights, impl)
-        # a float32 sum of float32 terms: a rounding an entry and three for the pieces, each of what it rounds
-        rows, owners = np.abs(np.asarray(m, np.float64))[host.neighbour[:entries]], host.owner[0, :entries]
-        for a, c, terms in zip(got[:2], want[:2], (rows, 2 * rows * rows)):  # a square is rounded before it is summed
-            size = np.zeros_like(c)
-            np.add.at(size, owners, terms)
-            assert (np.abs(a - c) <= (np.asarray(host.degree)[:, None] + 3) * 2.0**-24 * size).all()
-        for a, c in zip(got[2:], want[2:]):  # an extreme is one of the rows, to the bit; 0 where there is none
-            np.testing.assert_array_equal(a, c.astype(np.float32))
-        size = np.zeros_like(want_grad)
-        np.add.at(size, host.neighbour[:entries], 1.0)
-        np.testing.assert_allclose(got_grad, want_grad, rtol=1e-5, atol=1e-5 * (1.0 + size.max()))
+        _assert_near_float64(host, entries, m, got, got_grad, want, want_grad)
 
     def test_a_hubs_entries_span_several_blocks_and_its_maximum_is_merged(self):
         src, dst, mask, nb = _topology("heavier_than_a_block")
@@ -208,7 +245,7 @@ class TestPlannedAggregate:
         plan = _plan(src, dst, mask, nb)
         m, _weights = _messages(nb, 8)
         sparse_pna.planned_aggregate(plan, m)
-        assert sparse.route_stats() == {"backend": "sparse", "planned": 1, "attention": 0, "gatv2": 0, "sharded": 0, "mxu_products": {}}
+        assert sparse.route_stats() == {"backend": "sparse", "planned": 1, "attention": 0, "gatv2": 0, "sharded": 0, "mxu_products": {}, "block_arrays": {}}
         with pytest.raises(Exception):  # Mosaic cannot target a CPU: nothing interprets silently
             jax.block_until_ready(sparse_pna.planned_aggregate(plan, m, "pallas"))
         with pytest.raises(NotImplementedError, match="merge a running maximum across the sources"):
@@ -252,6 +289,78 @@ class TestPlannedAggregate:
         size = np.zeros_like(want_grad)
         np.add.at(size, host.neighbour[:entries], 1.0)
         np.testing.assert_allclose(got_grad, want_grad, rtol=1e-5, atol=1e-5 * (1.0 + size.max()))
+
+
+class TestABlocksWorkOnce:
+    @pytest.mark.parametrize("name", sorted(TILES_A_BLOCK))
+    def test_every_item_of_a_block_reads_the_work_its_first_item_made(self, name):
+        """The interpreted walks on plans whose blocks meet one tile, two and four, a hub's entries across blocks,
+        no-op items after the last block: the four aggregates, the ties and the gradient against float64 and
+        against the XLA oracle."""
+        src, dst, mask, nb = _topology(name)
+        host, entries, items = sparse.build_edge_plan(src, dst, mask, nb)
+        blocks = np.asarray(host.item_block)
+        assert np.bincount(blocks[:items]).max() == TILES_A_BLOCK[name]  # the tiles the busiest block meets
+        assert items < blocks.size and (blocks[items:] == blocks[items - 1]).all()  # the no-ops repeat the last block
+        if name == "heavier_than_a_block":
+            assert np.unique(np.flatnonzero(host.owner[0, :entries] == 7) // BE).size >= 3
+        m, weights = _messages(nb, 8, seed=len(name))
+        want, want_grad, (ties_top, ties_bottom) = _float64_aggregates_and_gradient(host, entries, m, weights)
+        plan = _plan(src, dst, mask, nb)
+        got, got_grad = _aggregates_and_gradient(plan, m, weights, "pallas_interpret")
+        _assert_near_float64(host, entries, m, got, got_grad, want, want_grad)
+        xla, xla_grad = _aggregates_and_gradient(plan, m, weights, "xla")
+        for a, c in zip(got[:2], xla[:2]):
+            np.testing.assert_allclose(a, c, rtol=1e-5, atol=1e-5)
+        for a, c in zip(got[2:], xla[2:]):
+            np.testing.assert_array_equal(a, c)
+        np.testing.assert_allclose(got_grad, xla_grad, rtol=1e-5, atol=1e-4)
+        forward_rule = jax.jit(sparse_pna._aggregate_pallas_fwd_rule, static_argnums=2)
+        _out, (_plan_saved, (_m, _tops, ties)) = forward_rule(plan, m, True)
+        ties, half = np.asarray(ties), np.asarray(ties).shape[0] // 2
+        np.testing.assert_array_equal(ties[:8, :nb].T, ties_top)
+        np.testing.assert_array_equal(ties[half : half + 8, :nb].T, ties_bottom)
+
+    @pytest.mark.parametrize(
+        "differentiated,want",
+        [
+            # six bfloat16 pieces, the last entries, and for the count [m | -m]; the backward walk keeps nothing
+            (True, {"planned_aggregate_counted": 8}),
+            (False, {"planned_aggregate": 7}),
+        ],
+        ids=("forward_rule", "primal"),
+    )
+    def test_route_stats_names_the_walks_that_keep_a_blocks_work(self, differentiated, want):
+        src, dst, mask, nb = _topology("wide_bucket")
+        plan = _plan(src, dst, mask, nb)
+        m, weights = _messages(nb, 8)
+        assert sparse.route_stats()["block_arrays"] == {}
+        if differentiated:
+            _aggregates_and_gradient(plan, m, weights, "pallas_interpret")
+        else:
+            jax.jit(partial(sparse_pna.planned_aggregate, impl="pallas_interpret"))(plan, m)
+        assert sparse.route_stats()["block_arrays"] == want
+
+    def test_the_other_walks_keep_nothing(self):
+        src, dst, mask, nb = _topology("wide_bucket")
+        plan = _plan(src, dst, mask, nb)
+        h = jnp.ones((nb, 8))
+        jax.grad(lambda h: sparse.planned_neighbor_sum(plan, h, "pallas_interpret").sum())(h)
+        jax.grad(lambda h: sparse.planned_attention(plan, h, h[:, :2], h[:, :2], 0.2, "pallas_interpret").sum())(h)
+        assert sparse.route_stats()["mxu_products"] and sparse.route_stats()["block_arrays"] == {}
+
+    @pytest.mark.parametrize("n_nodes,share", ((100, 0.0), (150, 0.25)))
+    def test_the_plan_span_notes_the_share_of_items_that_find_their_blocks_work_made(self, n_nodes, share):
+        """(items - blocks) / items: 0 where every block meets one tile (one tile of 128 here), a quarter where
+        two of three blocks meet one tile and the third two (two tiles of 128)."""
+        st = stacked.stack_dataset(_dataset(n_nodes=n_nodes, n_edges=4 * n_nodes))
+        blocks = np.asarray(st.plan.item_block)[: st.plan_items]
+        noted = [
+            dict(tb.counts.get(i, {}))
+            for tb in TRACER.traces() for i, span in enumerate(tb.spans) if span[0] == "refresh.stack.plan"
+        ][-1]
+        assert noted["items"] == st.plan_items and noted["blocks"] == np.unique(blocks).size == st.plan_blocks
+        assert noted["block_reuse_share"] == pytest.approx((st.plan_items - st.plan_blocks) / st.plan_items) == share
 
 
 class TestThePlansDelta:

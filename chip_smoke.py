@@ -1185,7 +1185,7 @@ def child_kernels(sizes: dict) -> None:
     import numpy as np
 
     out = {"device": _child_common()}
-    from kmamiz_tpu.ops import sparse, window
+    from kmamiz_tpu.ops import edge_plan, sparse, sparse_gat, sparse_sum, walk, window
 
     on_tpu = jax.default_backend() == "tpu"
 
@@ -1243,33 +1243,33 @@ def child_kernels(sizes: dict) -> None:
     kernel_impl = "pallas" if on_tpu else "pallas_interpret"
     plan = jax.tree_util.tree_map(
         jnp.asarray,
-        sparse.build_edge_plan(np.asarray(src), np.asarray(dst), np.asarray(mask), nodes)[0],
+        edge_plan.build_edge_plan(np.asarray(src), np.asarray(dst), np.asarray(mask), nodes)[0],
     )
     s_t = jnp.asarray(rng.normal(size=(2, nodes, 2)).astype(np.float32))
     ct = jnp.asarray(rng.normal(size=(nodes, feat)).astype(np.float32))
 
     def planned(impl):
-        total, pull_sum = jax.vjp(lambda x: sparse.planned_neighbor_sum(plan, x, impl), h)
+        total, pull_sum = jax.vjp(lambda x: sparse_sum.planned_neighbor_sum(plan, x, impl), h)
         att, pull_att = jax.vjp(
-            lambda x, s, t: sparse.planned_attention(plan, x, s, t, 0.2, impl),
+            lambda x, s, t: sparse_gat.planned_attention(plan, x, s, t, 0.2, impl),
             h, s_t[0], s_t[1],
         )
         return [np.asarray(a) for a in (total, *pull_sum(ct), att, *pull_att(ct))]
 
     check(
-        sparse.planned_impl() == ("pallas" if on_tpu else "xla"),
-        f"planned_impl() is {sparse.planned_impl()!r} on {jax.default_backend()}",
+        walk.planned_impl() == ("pallas" if on_tpu else "xla"),
+        f"planned_impl() is {walk.planned_impl()!r} on {jax.default_backend()}",
     )
     check(
         mosaic(
-            jax.jit(jax.grad(lambda x: sparse.planned_neighbor_sum(plan, x).sum())).lower(h)
+            jax.jit(jax.grad(lambda x: sparse_sum.planned_neighbor_sum(plan, x).sum())).lower(h)
         ) == on_tpu,
         "the planned sum a caller gets holds no Mosaic kernel on this TPU",
     )
     check(
         mosaic(
             jax.jit(
-                jax.grad(lambda x: sparse.planned_attention(
+                jax.grad(lambda x: sparse_gat.planned_attention(
                     plan, x, s_t[0], s_t[1], 0.2, kernel_impl).sum())
             ).lower(h)
         ) == on_tpu,
@@ -1294,7 +1294,7 @@ def child_kernels(sizes: dict) -> None:
 
     def attention_wide(impl):
         att, pull = jax.vjp(
-            lambda x, s, t: sparse.planned_attention(plan, x, s, t, 0.2, impl),
+            lambda x, s, t: sparse_gat.planned_attention(plan, x, s, t, 0.2, impl),
             wide, s_t[0], s_t[1],
         )
         return [np.asarray(a) for a in (att, *pull(ct_wide))]
@@ -1374,12 +1374,12 @@ def child_kernels(sizes: dict) -> None:
     # slot's columns against the kernel's sum of that slot alone, bit for bit
     slots = rng.normal(size=(7, nodes, 18)).astype(np.float32)
     table = jnp.asarray(np.moveaxis(slots, 0, 1).reshape(nodes, 126))
-    packed = np.asarray(sparse.planned_neighbor_sum(plan, table, kernel_impl))
-    want = np.asarray(sparse.planned_neighbor_sum(plan, table, "xla"))
+    packed = np.asarray(sparse_sum.planned_neighbor_sum(plan, table, kernel_impl))
+    want = np.asarray(sparse_sum.planned_neighbor_sum(plan, table, "xla"))
     scale = max(float(np.abs(want).max()), 1.0)
     np.testing.assert_allclose(packed, want, rtol=0, atol=5e-5 * scale, err_msg="sum.w126")
     for j in range(7):
-        alone = sparse.planned_neighbor_sum(plan, jnp.asarray(slots[j]), kernel_impl)
+        alone = sparse_sum.planned_neighbor_sum(plan, jnp.asarray(slots[j]), kernel_impl)
         np.testing.assert_array_equal(
             packed[:, j * 18 : (j + 1) * 18], np.asarray(alone), f"slot {j} of the packed sum"
         )

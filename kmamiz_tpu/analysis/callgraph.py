@@ -38,11 +38,19 @@ DEFAULT_SEED_MODULES = (
     "kmamiz_tpu/control/admission.py",
     "kmamiz_tpu/control/policy.py",
     "kmamiz_tpu/control/warmup.py",
-    # the edge plan's reductions and the counting primitives sit under
-    # every sparse-backend consumer (scorers, packed walk, graphsage, gat) — seed the
-    # module itself so the hot-path rules see its helpers even when the
-    # consumer dispatch is behind the KMAMIZ_SPARSE knob
+    # the edge plan, its walks and reductions and the counting primitives
+    # sit under every sparse-backend consumer (scorers, packed walk, the
+    # heads' refresh) — seed the modules themselves so the hot-path rules
+    # see their helpers even when the consumer dispatch is behind the
+    # KMAMIZ_SPARSE knob or a head's plan
     "kmamiz_tpu/ops/sparse.py",
+    "kmamiz_tpu/ops/edge_plan.py",
+    "kmamiz_tpu/ops/walk.py",
+    "kmamiz_tpu/ops/sparse_sum.py",
+    "kmamiz_tpu/ops/sparse_gat.py",
+    "kmamiz_tpu/ops/sparse_gated.py",
+    "kmamiz_tpu/ops/sparse_gatv2.py",
+    "kmamiz_tpu/ops/sparse_pna.py",
     # graftstream: the micro-tick engine's produce/consume loops run
     # every prepared window through prepare/merge/finish — hot by seed
     # so the hot-path rules reach it even though the dispatch sits

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from kmamiz_tpu.models import common
 from kmamiz_tpu.models.graphsage import EMB_DIM, NUM_FEATURES
-from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.ops import edge_plan, sparse_gat
 
 LEAK = 0.2
 #: `forward` takes the stack's edge plan as `plan=` (models/stacked.plan_for)
@@ -146,7 +146,7 @@ def _layer(h, src, dst, edge_mask, w, a_s, a_d, a_sr, a_dr, b, plan=None):
         # bfloat16 pass and move the loss by 1e-5 (PERF.md, PR 28)
         s = jnp.stack([hw @ a_sr, hw @ a_s], axis=1)  # [N, 2], of the neighbour
         t = jnp.stack([hw @ a_dr, hw @ a_d], axis=1)  # [N, 2], of the owner
-        return jax.nn.elu(hw + sparse.planned_attention(plan, hw, s, t, LEAK) + b)
+        return jax.nn.elu(hw + sparse_gat.planned_attention(plan, hw, s, t, LEAK) + b)
     fwd = _attend(hw, src, dst, edge_mask, a_s, a_d)
     rev = _attend(hw, dst, src, edge_mask, a_sr, a_dr)
     return jax.nn.elu(hw + fwd + rev + b)
@@ -158,14 +158,14 @@ def forward(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
-    plan: sparse.EdgePlan = None,
+    plan: edge_plan.EdgePlan = None,
 ):
     """Two attention layers -> (latency prediction [N], anomaly logits [N]).
 
     `plan` is the edge plan of (src_ep, dst_ep, edge_mask) where the caller
     has prepared one (the training refresh: models/stacked.py): each layer's
     two segment softmaxes and weighted sums are then one pass over the
-    plan's sorted entries (sparse.planned_attention), the same mathematics
+    plan's sorted entries (sparse_gat.planned_attention), the same mathematics
     in another order. Without one the edge list is reduced as it comes."""
     # the device's names for these stretches (docs/OBSERVABILITY.md): a layer is
     # `dense` but for what the planned attention names `gather` and `reduce` beneath it

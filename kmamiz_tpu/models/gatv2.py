@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from kmamiz_tpu.models import common
 from kmamiz_tpu.models.gat import _segment_softmax
 from kmamiz_tpu.models.graphsage import EMB_DIM, NUM_FEATURES
-from kmamiz_tpu.ops import sparse, sparse_gatv2
+from kmamiz_tpu.ops import edge_plan, sparse_gatv2
 
 NAME = "gatv2"
 #: `forward` takes the stack's edge plan as `plan=` (models/stacked.plan_for).
@@ -144,7 +144,7 @@ def forward(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
-    plan: sparse.EdgePlan = None,
+    plan: edge_plan.EdgePlan = None,
 ):
     """Two GATv2 layers -> (latency prediction [N], anomaly logits [N]).
 

@@ -20,7 +20,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.ops import edge_plan, sparse_sum
 
 NUM_FEATURES = 10  # incl. sin/cos hour-of-day
 #: what the fused trainer may hand `forward` (models/stacked.py reads these,
@@ -162,7 +162,7 @@ def neighbor_mean(
     dst_ep: jnp.ndarray,  # [E]
     edge_mask: jnp.ndarray,  # [E]
     deg: jnp.ndarray = None,  # [N] precomputed neighbor_degree
-    plan: sparse.EdgePlan = None,  # the topology prepared per dataset
+    plan: edge_plan.EdgePlan = None,  # the topology prepared per dataset
     neighbor_sum: jnp.ndarray = None,  # [N, F] the sum of h's neighbour rows
 ) -> jnp.ndarray:
     """Mean of neighbor states over both edge directions (segment mean).
@@ -178,7 +178,7 @@ def neighbor_mean(
     A caller that holds an edge plan of this topology (the training
     refresh: models/stacked.py builds one per dataset) passes it, and the
     sum is one row gather and one owner-sorted reduction with its own VJP
-    (sparse.planned_neighbor_sum), the degree the plan's. Without a plan
+    (sparse_sum.planned_neighbor_sum), the degree the plan's. Without a plan
     (the tick's one-off graphs) the edge list is reduced as it comes, by
     XLA's masked gathers and segment sums."""
     n = h.shape[0]
@@ -187,7 +187,7 @@ def neighbor_mean(
             deg = neighbor_degree(n, src_ep, dst_ep, edge_mask, dtype=h.dtype)
         return neighbor_sum / jnp.maximum(deg, 1.0)[:, None]
     if plan is not None:
-        agg = sparse.planned_neighbor_sum(plan, h)
+        agg = sparse_sum.planned_neighbor_sum(plan, h)
         if deg is None:
             deg = plan.degree.astype(h.dtype)
         return agg / jnp.maximum(deg, 1.0)[:, None]
@@ -208,7 +208,7 @@ def forward(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
-    plan: sparse.EdgePlan = None,
+    plan: edge_plan.EdgePlan = None,
     neighbor_sum_1: jnp.ndarray = None,
 ):
     """Two SAGE layers -> (latency prediction [N], anomaly logits [N]).

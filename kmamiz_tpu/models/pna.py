@@ -37,7 +37,7 @@ have none, so counted that way the constant is the graph's and not the
 bucket's: the served forward, the batched evaluation and the refresh read
 the same number from the same topology. It is a constant of the topology:
 the refresh takes it from the stack's edge plan, where it is made once
-(`sparse.EdgePlan.mean_log_degree`, `sparse.mean_log_degree`), in the
+(`edge_plan.EdgePlan.mean_log_degree`, `edge_plan.mean_log_degree`), in the
 device's own float32 logarithms, as the scalers' numerators are.
 
 A maximum that several neighbours share hands its gradient to them in equal
@@ -60,7 +60,7 @@ import jax.numpy as jnp
 
 from kmamiz_tpu.models import common
 from kmamiz_tpu.models.graphsage import EMB_DIM, NUM_FEATURES
-from kmamiz_tpu.ops import sparse, sparse_pna
+from kmamiz_tpu.ops import edge_plan, sparse_pna
 
 NAME = "pna"
 #: `forward` takes the stack's edge plan as `plan=` (models/stacked.plan_for).
@@ -160,7 +160,7 @@ def _layer(h, src_ep, dst_ep, edge_mask, w_msg, w_self, w_agg, b, plan):
         delta = plan.mean_log_degree
     else:
         (total, squares, top, bottom), degree = _edge_aggregate(m, src_ep, dst_ep, edge_mask)
-        delta = sparse.mean_log_degree(degree)
+        delta = edge_plan.mean_log_degree(degree)
     d = jnp.maximum(degree, 1.0)[:, None]
     mean = total / d
     deviation = jnp.sqrt(jax.nn.relu(squares / d - mean * mean) + EPS)
@@ -187,7 +187,7 @@ def forward(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
-    plan: sparse.EdgePlan = None,
+    plan: edge_plan.EdgePlan = None,
 ):
     """Two PNA layers -> (latency prediction [N], anomaly logits [N]).
 

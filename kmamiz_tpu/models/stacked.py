@@ -29,7 +29,7 @@ device-native:
 - `predict_all` vmaps a head's forward over every stacked slot in one
   jitted call — the batched evaluation path shared by trainer.evaluate
   and trainer.calibrate_threshold.
-- every stack carries the EDGE PLAN of its topology (ops/sparse.py:
+- every stack carries the EDGE PLAN of its topology (ops/edge_plan.py:
   the neighbour list sorted by owner and, within an owner, by the edge's
   direction, the degree, a tiled reducer's work list), built once on the
   host and memoised by the identity of the edge arrays, so datasets over
@@ -37,8 +37,8 @@ device-native:
   `epoch_runner`'s block takes it as a loop constant beside
   src/dst/edge_mask and hands it to a head that says it takes one
   (`TAKES_PLAN`, read by `plan_for`): GraphSAGE sums neighbour rows over
-  it (`sparse.planned_neighbor_sum`), GAT runs its directed segment
-  softmax and weighted sums over it (`sparse.planned_attention`), STLGT
+  it (`sparse_sum.planned_neighbor_sum`), GAT runs its directed segment
+  softmax and weighted sums over it (`sparse_gat.planned_attention`), STLGT
   its sigmoid-gated neighbour bias (`sparse_gated.planned_gated_sum`), PNA
   its mean, deviation, maximum and minimum (`sparse_pna.planned_aggregate`;
   the plan carries the scalers' constant, `mean_log_degree`). The
@@ -70,13 +70,13 @@ device-native:
   says anything. Each device gets its rows of every slot `[S, Nb / D, ..]`,
   filled and handed over shard by shard (the whole never lies on one device,
   nor twice on the host), and ITS edge plan: the entries whose owner it holds,
-  a sub-plan a source device (`sparse.build_shard_plans`; the node ranges
+  a sub-plan a source device (`edge_plan.build_shard_plans`; the node ranges
   are cut where the entries divide evenly). Datasets over one graph share a plan and with it
   its layout, so the head of a sharded history is sharded as it is.
   `node_sharded_epoch_runner` runs the one-device block's own body under
   `shard_map` over the `nodes` axis: parameters, optimizer state and losses
   replicated, a layer's neighbour tables all-gathered in float32 and gathered
-  from one source's at a time (`sparse.sharded_neighbor_sum`), the loss's sums and counts and the
+  from one source's at a time (`sparse_sum.sharded_neighbor_sum`), the loss's sums and counts and the
   parameter gradients summed over the devices (`common.make_loss_fn`'s
   `axis_name`). The schedule does not change: one update a slot, in slot
   order, over all endpoints; the slot group works on the sharded table.
@@ -118,7 +118,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from kmamiz_tpu.core import programs
 from kmamiz_tpu.core.spans import _pad_size
 from kmamiz_tpu.models import common
-from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.ops import edge_plan, sparse, sparse_sum
 from kmamiz_tpu.parallel import mesh as mesh_mod
 from kmamiz_tpu.telemetry.registry import REGISTRY
 from kmamiz_tpu.telemetry.tracing import TRACER, operation_span, phase_span
@@ -195,7 +195,7 @@ class StackedDataset:
     num_edges: int  # real E (<= bucket_edges)
     bucket_nodes: int
     bucket_edges: int
-    plan: Optional[sparse.EdgePlan] = None  # of (src, dst, edge_mask); sharded: [shards, ...], a device a plan
+    plan: Optional[edge_plan.EdgePlan] = None  # of (src, dst, edge_mask); sharded: [shards, ...], a device a plan
     plan_entries: int = 0  # real (owner, neighbour) entries: 2 x real edges
     plan_items: int = 0  # real (node tile, edge block) products of one sum
     plan_blocks: int = 0  # edge blocks those items visit: message blocks a walk fetches
@@ -279,7 +279,7 @@ _PLAN_MEMO_SIZE = 4
 class _Planned(NamedTuple):
     """A topology's plan as a stack holds it."""
 
-    plan: sparse.EdgePlan  # on the device; with a leading [shards] axis over the mesh where shards > 1
+    plan: edge_plan.EdgePlan  # on the device; with a leading [shards] axis over the mesh where shards > 1
     counts: Tuple[int, int, int, int]  # real entries, items, blocks, (owner, direction) runs, over all shards
     shards: int
     #: [shards + 1] node indices: shard d holds nodes cuts[d] .. cuts[d + 1] - 1, from row d * (bucket // shards)
@@ -303,13 +303,13 @@ def _edge_plan(dataset, src, dst, e_mask, n: int, nb: int, shards: int) -> _Plan
         return max(held, key=lambda planned: planned.shards)
     _PLAN_BUILDS.inc()
     with phase_span("refresh.stack.plan"):
-        host, cuts, entries_by, items_by = sparse.build_shard_plans(src, dst, e_mask, n, nb, shards)
+        host, cuts, entries_by, items_by = edge_plan.build_shard_plans(src, dst, e_mask, n, nb, shards)
         runs, entries_in, blocks_by = 0, 0, []
-        for one, entries, items in zip(sparse.sub_plans(host), entries_by, items_by):  # a sub-plan an (owner, source) pair
+        for one, entries, items in zip(edge_plan.sub_plans(host), entries_by, items_by):  # a sub-plan an (owner, source) pair
             run_key = one.owner[0, :entries] * 2 + one.direction[0, :entries]
             runs += int(np.count_nonzero(np.diff(run_key))) + 1 if entries else 0
             entries_in += int(one.direction[0, :entries].sum())
-            blocks_by.append(sparse.plan_blocks(one, items))
+            blocks_by.append(edge_plan.plan_blocks(one, items))
         if shards == 1:
             plan = jax.tree_util.tree_map(lambda a: jnp.asarray(a[0]), host)
         else:
@@ -337,7 +337,7 @@ def _edge_plan(dataset, src, dst, e_mask, n: int, nb: int, shards: int) -> _Plan
     return planned
 
 
-def plan_for(model, stacked: StackedDataset) -> Optional[sparse.EdgePlan]:
+def plan_for(model, stacked: StackedDataset) -> Optional[edge_plan.EdgePlan]:
     """The stack's edge plan, for a head that says its `forward` (and its
     own loss, where it states one) takes one as `plan=`: `TAKES_PLAN` on the
     head's module (GraphSAGE, GAT, STLGT, PNA). None for a head that says nothing
@@ -355,9 +355,9 @@ def head_loss_fn(model, pos_weight: float, **forward_args):
     (`common.make_loss_fn`). `forward_args` (the block's `plan`, a slot
     group's `neighbor_sum_1`) are bound by keyword: to the forward, or to a
     head's own loss, which hands them to its forward. Under a
-    `sparse.ShardPlan` the family's loss sums over the plan's mesh axis."""
+    `edge_plan.ShardPlan` the family's loss sums over the plan's mesh axis."""
     make = getattr(model, "make_loss_fn", None)
-    # a `sparse.ShardPlan` names the mesh axis the nodes are cut over
+    # a `edge_plan.ShardPlan` names the mesh axis the nodes are cut over
     axis = getattr(forward_args.get("plan"), "axis", None)
     if make is None:
         forward = model.forward
@@ -491,7 +491,7 @@ def epoch_runner(model, lr: float, pos_weight: float):
                 start = jnp.minimum(first, n_slots - group)
                 packed = jax.lax.dynamic_slice_in_dim(features, start, group)
                 table = jnp.moveaxis(packed, 0, 1).reshape(n_nodes, group * width)
-                sums = sparse.planned_neighbor_sum(plan, table)  # [Nb, group * F]
+                sums = sparse_sum.planned_neighbor_sum(plan, table)  # [Nb, group * F]
                 own = first - start  # where the loop below starts
 
             def member_step(j, carry):
@@ -552,7 +552,7 @@ def node_sharded_epoch_runner(model, lr: float, pos_weight: float, mesh):
     `stack_dataset` laid them over the mesh (`[S, Nb, ..]` cut along nodes,
     the plan with a leading device axis), and returns the same: parameters,
     optimizer state and losses replicated. Inside, a device sees its rows
-    and its plan as a `sparse.ShardPlan`, which is all the body needs to
+    and its plan as a `edge_plan.ShardPlan`, which is all the body needs to
     know: the planned sums all-gather their table, the loss adds the
     devices' sums and counts, the gradients come out summed, so every
     device makes the same update, one a slot, in slot order. One dispatch a
@@ -577,7 +577,7 @@ def node_sharded_epoch_runner(model, lr: float, pos_weight: float, mesh):
 
         def shard(params, opt_state, features, target_latency, target_anomaly, node_mask,
                   src, dst, edge_mask, plan):
-            mine = sparse.ShardPlan(jax.tree_util.tree_map(lambda a: a[0], plan), axis)
+            mine = edge_plan.ShardPlan(jax.tree_util.tree_map(lambda a: a[0], plan), axis)
             return block(
                 params, opt_state, features, target_latency, target_anomaly, node_mask,
                 src, dst, edge_mask, n_epochs, mine, group,

@@ -53,7 +53,7 @@ import optax
 
 from kmamiz_tpu.models import common as _common
 from kmamiz_tpu.models.graphsage import NUM_FEATURES, assemble_features  # noqa: F401 - re-export: one feature layout for every head
-from kmamiz_tpu.ops import sparse, sparse_gated
+from kmamiz_tpu.ops import edge_plan, sparse_gated
 
 NAME = "stlgt"
 #: `forward`, `forward_quantiles`, `encode` and the loss take `plan=`
@@ -137,7 +137,7 @@ def encode(
     src_ep: jnp.ndarray,  # [E]
     dst_ep: jnp.ndarray,  # [E]
     edge_mask: jnp.ndarray,  # [E]
-    plan: sparse.EdgePlan = None,
+    plan: edge_plan.EdgePlan = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One linear-transformer block -> (node states [N, H], edge gates
     [E]). Padded lanes (all-zero feature rows — the pow2 bucket padding
@@ -218,7 +218,7 @@ def forward_quantiles(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
-    plan: sparse.EdgePlan = None,
+    plan: edge_plan.EdgePlan = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Full STLGT surface -> (latency quantiles [N, NUM_QUANTILES] in
     log1p-ms, anomaly logits [N], per-edge attribution gates [E]).
@@ -245,7 +245,7 @@ def forward(
     src_ep: jnp.ndarray,
     dst_ep: jnp.ndarray,
     edge_mask: jnp.ndarray,
-    plan: sparse.EdgePlan = None,
+    plan: edge_plan.EdgePlan = None,
 ):
     """Model-module compatibility surface: (p50 latency, anomaly logit) —
     the (latency, logit) pair every existing consumer expects

@@ -1,6 +1,6 @@
 """The gated neighbour sum on the edge plan: STLGT's neighbour bias.
 
-Per plan entry e = (owner o, neighbour n, direction d) of `ops/sparse.py`'s
+Per plan entry e = (owner o, neighbour n, direction d) of `ops/edge_plan.py`'s
 `EdgePlan` (d = 0: o is the edge's source, the caller; 1: its destination):
 
     gate_e  = sigmoid((q[o] . k[n] if d == 0 else q[n] . k[o]) / sqrt(H) + b_edge)
@@ -8,7 +8,7 @@ Per plan entry e = (owner o, neighbour n, direction d) of `ops/sparse.py`'s
 
 which is what `models/stlgt/model.encode` makes of an edge list with two
 gathers of `q`, two of `k`, two of `v` and four unsorted segment sums. Unlike
-GAT's attention (`sparse.planned_attention`) there is no softmax, so no
+GAT's attention (`sparse_gat.planned_attention`) there is no softmax, so no
 maximum and no normaliser walk; but the weight of an entry is a PRODUCT OF
 TWO GATHERED ROWS where GAT's score is a sum of two per-node scalars, both
 entries of an edge carry the same gate, the normaliser is the gates' own sum,
@@ -32,7 +32,7 @@ Mosaic kernel that the device trace names:
   operand fetched ahead beside the second, one of the gathers took 10.8 ms
   for 1.9), and ONE gather of `[x | v]` rows from a table stacked by
   direction, `[q | v]` over `[k | v]` (134 MB), took 10.8 ms too (PR 33).
-- The dot product of an entry is GAT's `_edge_dot`'s (`sparse._entry_dot`,
+- The dot product of an entry is GAT's `_edge_dot`'s (`walk.entry_dot`,
   PR 38): the owner's row `[q | k]`, handed in as node rows `[128, nodes]`,
   goes to its entries through the one-hot in ONE stacked MXU product, exactly,
   and meets the block's rows on the VPU, transposed, each entry's under the
@@ -41,18 +41,18 @@ Mosaic kernel that the device trace names:
   multiplication an element and a float32 sum over the sublanes, as
   `_gated_xla` and `benchmarks/reference/stlgt.py` make it. Until PR 38 it
   was all 128 x 512 products of the tile against the block in six bfloat16
-  passes of depth 128 (`sparse._dot6`), of which the one-hot picked one in 128.
+  passes of depth 128 (`_dot6`), of which the one-hot picked one in 128.
   No bfloat16 ROW enters it or the weighted sums, because neither is a matrix
   product of the model (a gated sum fed bfloat16 rows moves the first slot's
   loss by what `benchmarks/reference/stlgt.py` records). A weighted sum
   (`num`, `d v`, `[d q | d k]`) multiplies on the VPU too, and only the
   one-hot, exact in bfloat16, goes through the MXU, against the exact split
-  of the product (`sparse._weighted_sum`, three passes; six until PR 34,
+  of the product (`walk.weighted_sum`, three passes; six until PR 34,
   when the weight went through the MXU too).
   Every per-node operand and result of the two walks is node rows `[lanes,
   nodes]`, as GAT's are: with the owner's tables handed in that way XLA keeps
   the block's `[nodes, 64]` arrays in the layout whose minor dimension is the
-  nodes, and `num` and `d v` left as they come out of `_reduce` are slices of
+  nodes, and `num` and `d v` left as they come out of `walk.reduce` are slices of
   it (0.34 ms a slot update less than transposed back in the item, PERF.md,
   PR 38; under the other layout, in PR 34, it was the other way round by 0.28).
 - The transposed sums need no permutation: every entry has a mirror, the same
@@ -60,7 +60,7 @@ Mosaic kernel that the device trace names:
   entries whose NEIGHBOUR is j, is the sum over the entries OWNED by j of
   `gate * g[neighbour]`: the forward's weighted sum with `g` for `v`. An
   edge's `d gate` is its two entries' `<g[o], v[n]> + g_den[o]`, and both are
-  at hand in either entry's item: one `_entry_dot` of `[v | g]` of the tile
+  at hand in either entry's item: one `entry_dot` of `[v | g]` of the tile
   (node rows again: as a `[nodes, 128]` operand it held XLA's whole backward
   pass to the layout that pads 64 floats to 128 lanes, 4.7 ms a slot update)
   against `[g | v]` of the block (`_g_over_v`: the lanes `g` leaves free are
@@ -73,11 +73,7 @@ Mosaic kernel that the device trace names:
 
 Off the TPU the same mathematics is plain XLA over the plan's sorted entries
 (`_gated_xla`), differentiated by JAX: the CPU's path and the tests' oracle.
-`sparse.planned_impl()` picks, by platform alone.
-
-A module of its own (ROADMAP D1), and because a Mosaic kernel's file and
-line are in the compile cache's key: nothing here moves a line of
-`ops/sparse.py`.
+`walk.planned_impl()` picks, by platform alone.
 """
 from __future__ import annotations
 
@@ -88,26 +84,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from kmamiz_tpu.ops import sparse
-from kmamiz_tpu.ops.sparse import (
-    ATT_ROWS,
-    PLAN_NODE_TILE,
-    ROW_OWNER,
-    EdgePlan,
-    _add_row,
-    _entry_dot,
-    _entry_state,
-    _expand,
-    _gather_rows,
-    _item,
-    _node_rows,
-    _node_tiles,
-    _pad_to,
-    _reduce,
-    _row,
-    _rows,
-    _walk_call,
-    _weighted_sum,
+from kmamiz_tpu.ops import walk
+from kmamiz_tpu.ops.edge_plan import PLAN_NODE_TILE, EdgePlan, pad_to, plan_tiles
+from kmamiz_tpu.ops.walk import (
+    ATT_ROWS, ROW_OWNER, add_row, entry_dot, entry_state, expand, gather_rows, halves, item, node_rows, reduce, row,
+    rows, walk_call, weighted_sum,
 )
 
 #: rows of the entries' state that these walks write or read: the gate
@@ -116,37 +97,25 @@ from kmamiz_tpu.ops.sparse import (
 ROW_GATE, ROW_DA, ROW_B = 0, 1, 2
 
 
-def _halves(nodes: int, half: int, left, right):
-    """[n, w] `left` and `right` side by side, each padded to `half` lanes,
-    rows padded to `nodes`: a table of two halves. None is a half of zeros."""
-    def pad(a):
-        if a is None:
-            return jnp.zeros((nodes, half), jnp.float32)
-        a = a.astype(jnp.float32)
-        return jnp.pad(a, ((0, nodes - a.shape[0]), (0, half - a.shape[1])))
-
-    return jnp.concatenate([pad(left), pad(right)], axis=1)
-
-
 def _state(plan: EdgePlan, b):
     """The entries' state before the forward walk: b_edge on every entry."""
-    state = _entry_state(plan)
-    rows = jax.lax.broadcasted_iota(jnp.int32, state.shape, 0)
-    return jnp.where(rows == ROW_B, b.astype(jnp.float32), state)
+    state = entry_state(plan)
+    rid = jax.lax.broadcasted_iota(jnp.int32, state.shape, 0)
+    return jnp.where(rid == ROW_B, b.astype(jnp.float32), state)
 
 
 def _real(plan: EdgePlan):
     """[L]: the entries a tile owns. The parked ones past them lie in blocks
     that no item visits, so no walk ever writes their rows of the state."""
-    return plan.owner[0] < _node_tiles(plan) * PLAN_NODE_TILE
+    return plan.owner[0] < plan_tiles(plan) * PLAN_NODE_TILE
 
 
 def _inside(tile_ref, state_ref):
     """[1, block]: the entries some row of the item's tile owns."""
     tn = PLAN_NODE_TILE
-    owner = jax.lax.bitcast_convert_type(_row(state_ref, ROW_OWNER), jnp.int32)
-    row = owner - tile_ref[pl.program_id(0)] * tn
-    return (row >= 0) & (row < tn)
+    owner = jax.lax.bitcast_convert_type(row(state_ref, ROW_OWNER), jnp.int32)
+    at = owner - tile_ref[pl.program_id(0)] * tn
+    return (at >= 0) & (at < tn)
 
 
 def _by_half(qk, d, half: int):
@@ -173,26 +142,26 @@ def _gated_sum_kernel(
     tile_ref, block_ref, flag_ref, state_ref, nqk_ref, nv_ref, qkrow_ref,
     next_ref, num_ref, den_ref, *, half: int, scale: float,
 ):
-    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (num_ref, den_ref))
+    real, one_hot, d = item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (num_ref, den_ref))
     inside = _inside(tile_ref, state_ref)
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
         mine, theirs = qkrow_ref[...], _by_half(nqk_ref[...], d, half)
-        dots = _entry_dot(_expand(mine, hot), theirs)
-        a = dots * scale + _row(state_ref, ROW_B)
+        dots = entry_dot(expand(mine, hot), theirs)
+        a = dots * scale + row(state_ref, ROW_B)
         gate = jnp.where(inside, 1.0 / (1.0 + jnp.exp(-a)), 0.0)
-        _add_row(next_ref, ROW_GATE, gate)
-        num_ref[...] += _weighted_sum(nv_ref[...].T, gate, hot)
-        den_ref[...] += _reduce(_rows(gate), hot)
+        add_row(next_ref, ROW_GATE, gate)
+        num_ref[...] += weighted_sum(nv_ref[...].T, gate, hot)
+        den_ref[...] += reduce(rows(gate), hot)
 
 
 def _gated_backward_kernel(
     tile_ref, block_ref, flag_ref, state_ref, nqk_ref, nv_ref, ng_ref, vgrow_ref, grow_ref,
     next_ref, dv_ref, dqk_ref, *, half: int, scale: float,
 ):
-    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (dv_ref, dqk_ref))
+    real, one_hot, d = item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (dv_ref, dqk_ref))
     inside = _inside(tile_ref, state_ref)
 
     @pl.when(real)
@@ -201,19 +170,19 @@ def _gated_backward_kernel(
         ng_t = ng_ref[...].T  # [g | g_den] of the neighbour; `nv_ref` holds its [0 | v]
         # <v[o], g[n]> + <g[o], v[n]>: the entry's d gate and its mirror's
         mine, theirs = vgrow_ref[...], _g_over_v(ng_t, nv_ref[...].T, half)
-        dots = _entry_dot(_expand(mine, hot), theirs)
-        den = _expand(grow_ref[...], hot)[0:1] + ng_t[half : half + 1, :]  # g_den[o] + g_den[n]
-        gate = _row(state_ref, ROW_GATE)
+        dots = entry_dot(expand(mine, hot), theirs)
+        den = expand(grow_ref[...], hot)[0:1] + ng_t[half : half + 1, :]  # g_den[o] + g_den[n]
+        gate = row(state_ref, ROW_GATE)
         da = jnp.where(inside, (dots + den) * gate * (1.0 - gate), 0.0)
-        _add_row(next_ref, ROW_DA, da)
-        dv_ref[...] += _weighted_sum(ng_t, gate, hot)
+        add_row(next_ref, ROW_DA, da)
+        dv_ref[...] += weighted_sum(ng_t, gate, hot)
         # [d q | d k] of the tile, transposed
-        dqk_ref[...] += _weighted_sum(_by_half(nqk_ref[...], d, half), da * scale, hot)
+        dqk_ref[...] += weighted_sum(_by_half(nqk_ref[...], d, half), da * scale, hot)
 
 
 def _shapes(plan: EdgePlan, width: int) -> Tuple[int, int, float]:
     """(nodes the tiles cover, lanes of one half of a row, the dot product's scale)."""
-    return _node_tiles(plan) * PLAN_NODE_TILE, _pad_to(2 * width, 128) // 2, float(width) ** -0.5
+    return plan_tiles(plan) * PLAN_NODE_TILE, pad_to(2 * width, 128) // 2, float(width) ** -0.5
 
 
 def _gated_pallas_fwd(plan: EdgePlan, q, k, v, b, interpret: bool):
@@ -221,13 +190,13 @@ def _gated_pallas_fwd(plan: EdgePlan, q, k, v, b, interpret: bool):
     nodes, half, scale = _shapes(plan, width)
     # one table at a time (the module's docstring): v waits for the first
     # gather, and the walk's own `[q | k]` is made after the second
-    nqk = _gather_rows(_halves(nodes, half, q, k), plan.neighbour)
+    nqk = gather_rows(halves(nodes, half, q, k), plan.neighbour)
     nqk, v = jax.lax.optimization_barrier((nqk, v))
-    nv = _gather_rows(_halves(nodes, half, None, v), plan.neighbour)
+    nv = gather_rows(halves(nodes, half, None, v), plan.neighbour)
     nv, q, k = jax.lax.optimization_barrier((nv, q, k))
-    state, num, den = _walk_call(
+    state, num, den = walk_call(
         plan, partial(_gated_sum_kernel, half=half, scale=scale), "planned_gated_sum",
-        [("entry", _state(plan, b)), ("message", nqk), ("message", nv), ("node_rows", _halves(nodes, half, q, k).T)],
+        [("entry", _state(plan, b)), ("message", nqk), ("message", nv), ("node_rows", halves(nodes, half, q, k).T)],
         [("entry", ATT_ROWS), ("node_rows", 2 * half), ("node_rows", ATT_ROWS)], interpret,
     )
     return (num[half : half + width, :n].T, den[0, :n]), (q, v, nqk, nv, state)
@@ -239,12 +208,12 @@ def _gated_pallas_bwd(plan: EdgePlan, interpret: bool, saved, cotangents):
     n, width = q.shape
     nodes, half, scale = _shapes(plan, width)
     g = g.astype(jnp.float32)
-    ng = _gather_rows(_halves(nodes, half, g, g_den[:, None]), plan.neighbour)
-    state, dv, dqk = _walk_call(
+    ng = gather_rows(halves(nodes, half, g, g_den[:, None]), plan.neighbour)
+    state, dv, dqk = walk_call(
         plan, partial(_gated_backward_kernel, half=half, scale=scale), "planned_gated_backward",
         [
             ("entry", state), ("message", nqk), ("message", nv), ("message", ng),
-            ("node_rows", _halves(nodes, half, v, g).T), ("node_rows", _node_rows(nodes, g_den)),
+            ("node_rows", halves(nodes, half, v, g).T), ("node_rows", node_rows(nodes, g_den)),
         ],
         [("entry", ATT_ROWS), ("node_rows", 2 * half), ("node_rows", 2 * half)], interpret,
     )
@@ -297,8 +266,8 @@ def _gated_xla(plan: EdgePlan, q, k, v, b):
         )
         weights = gate[:, None]
         with jax.named_scope("gather"):
-            rows = v[nbr]
-        return seg(weights * rows)[:n], seg(gate)[:n]
+            gathered = v[nbr]
+        return seg(weights * gathered)[:n], seg(gate)[:n]
 
 
 def planned_gated_sum(plan: EdgePlan, q, k, v, b_edge, impl: Optional[str] = None) -> jnp.ndarray:
@@ -309,11 +278,10 @@ def planned_gated_sum(plan: EdgePlan, q, k, v, b_edge, impl: Optional[str] = Non
     the same gate, the edge's attribution; whoever wants it per EDGE makes it
     from the edge list (`models/stlgt/model.encode`), since the plan keeps no
     way back from an entry to its edge. `impl` as for
-    `sparse.planned_neighbor_sum`. Counted in
+    `sparse_sum.planned_neighbor_sum`. Counted in
     `sparse.route_stats()["planned"]` (trace time)."""
-    with sparse._route_lock:
-        sparse._route_counts["planned"] += 1
-    impl = impl or sparse.planned_impl()
+    walk.count("planned")
+    impl = impl or walk.planned_impl()
     b = jnp.reshape(b_edge, ())
     if impl == "xla":
         num, den = _gated_xla(plan, q, k, v, b)

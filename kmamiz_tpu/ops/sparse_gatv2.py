@@ -1,6 +1,6 @@
 """Dynamic attention on the edge plan: GATv2's score over both edge directions.
 
-Per plan entry e = (owner i, neighbour j, direction d) of `ops/sparse.py`'s
+Per plan entry e = (owner i, neighbour j, direction d) of `ops/edge_plan.py`'s
 `EdgePlan` (d = 0: i is the edge's source, the caller; 1: its destination) and
 two tables of rows `xl = h W_l`, `xr = h W_r` `[N, W]`:
 
@@ -11,7 +11,7 @@ two tables of rows `xl = h W_l`, `xr = h W_r` `[N, W]`:
 
 which is Brody, Alon and Yahav's GATv2 (arXiv:2105.14491; PyTorch Geometric
 `GATv2Conv`), `models/gatv2.py`'s aggregation. GAT's score
-(`sparse.planned_attention`) is `leaky(s[j, d] + t[i, d])`, two numbers made
+(`sparse_gat.planned_attention`) is `leaky(s[j, d] + t[i, d])`, two numbers made
 once a node; here the nonlinearity comes BEFORE the dot product with `a_d`,
 so the score is no sum of per-node parts and is made entry by entry from a
 W-wide row.
@@ -30,16 +30,16 @@ forward and backward, each a Mosaic kernel the device trace names:
   of the neighbour (saved for the backward pass, as GAT's forward table is);
   backward `[g | max0 max1 sum0 sum1 c0 c1]` of the neighbour (70 lanes).
 - `_score` expands the owner's `xr` row through the item's one-hot (exact:
-  `sparse._expand`, one stacked MXU product of the three bfloat16 pieces),
+  `walk.expand`, one stacked MXU product of the three bfloat16 pieces),
   adds the neighbour's gathered `xl`, transposed (a lane an entry), applies
   `leaky` and weighs by `a_d` on the VPU, and sums over the sublanes
-  (`sparse._entry_dot`: a float32 multiplication an element and a float32 sum
+  (`walk.entry_dot`: a float32 multiplication an element and a float32 sum
   in an order the source fixes). The score goes into the entries' state, the
   row where GAT's walks keep `z`; `z` itself, W floats an entry, is made
   again where the backward pass needs it rather than written.
 - `_softmax`, `_sum` and `_edge_dot` are GAT's own kernel bodies
-  (`sparse._attention_softmax_kernel`, `_attention_sum_kernel`,
-  `_attention_edge_dot_kernel`) under this family's names; the softmax walk,
+  (`sparse_gat.attention_softmax_kernel`, `attention_sum_kernel`,
+  `attention_edge_dot_kernel`) under this family's names; the softmax walk,
   which makes `leaky(z)` of the stored row, is handed a slope of 1, so it
   reads the stored score as it is.
 - `a` is a parameter, the same for every entry of a direction. A walk reads
@@ -61,7 +61,7 @@ forward and backward, each a Mosaic kernel the device trace names:
     to the bit the forward's; the neighbour's max, sum and c (in the second
     table) its alpha and `d score`; its `d alpha` is `<xl of the owner, g of
     the neighbour>`. The two W-row results, `d xr` and `d xl`, go through the
-    one-hot as ONE stacked `[2W, block]` sum (`sparse._reduce`: three passes);
+    one-hot as ONE stacked `[2W, block]` sum (`walk.reduce`: three passes);
   - to the PARAMETER, `d a_d = sum_e d score_e leaky(z_e)` over every entry of
     direction d: each item sums its own entries' terms along the lanes (no
     MXU) into the first two columns of its tile's `[W, 128]` block, and XLA
@@ -71,11 +71,7 @@ forward and backward, each a Mosaic kernel the device trace names:
 
 Off the TPU the same mathematics is plain XLA over the plan's sorted entries
 (`_gatv2_xla`), differentiated by JAX: the CPU's path and the tests' oracle.
-`sparse.planned_impl()` picks, by platform alone.
-
-A module of its own, as `ops/sparse_gated.py` and `ops/sparse_pna.py` are: a
-Mosaic kernel's file and line are in the compile cache's key, and nothing
-here moves a line of another kernel's file.
+`walk.planned_impl()` picks, by platform alone.
 """
 from __future__ import annotations
 
@@ -86,34 +82,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from kmamiz_tpu.ops import sparse
-from kmamiz_tpu.ops.sparse import (
-    _NEG,
-    ATT_ROWS,
-    ATT_SPARE,
-    PLAN_NODE_TILE,
-    ROW_ALPHA,
-    ROW_DALPHA,
-    ROW_Z,
-    EdgePlan,
-    _add_row,
-    _attention_edge_dot_kernel,
-    _attention_softmax_kernel,
-    _attention_sum_kernel,
-    _by_direction,
-    _entry_dot,
-    _entry_state,
-    _expand,
-    _gather_rows,
-    _item,
-    _leaky,
-    _node_rows,
-    _node_table,
-    _node_tiles,
-    _pad_to,
-    _reduce,
-    _row,
-    _walk_call,
+from kmamiz_tpu.ops import walk
+from kmamiz_tpu.ops.edge_plan import PLAN_NODE_TILE, EdgePlan, pad_to, plan_tiles
+from kmamiz_tpu.ops.sparse_gat import attention_edge_dot_kernel, attention_softmax_kernel, attention_sum_kernel
+from kmamiz_tpu.ops.walk import (
+    ATT_ROWS, ATT_SPARE, NEG, ROW_ALPHA, ROW_DALPHA, ROW_Z, add_row, by_direction, entry_dot, entry_state, expand,
+    gather_rows, item, leaky, node_rows, node_table, reduce, row, walk_call,
 )
 
 
@@ -127,21 +101,21 @@ def _score_kernel(
     tile_ref, block_ref, flag_ref, state_ref, msg_ref, orow_ref, a_ref,
     next_ref, top_ref, *, width: int, leak: float,
 ):
-    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (top_ref,), _NEG)
+    real, one_hot, d = item(tile_ref, block_ref, flag_ref, state_ref, next_ref, (top_ref,), NEG)
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
         x = msg_ref[...].T  # xl, then xr, of the neighbour: a lane an entry
-        own = _expand(orow_ref[...], hot)  # xr of the owner, and a row of ones
+        own = expand(orow_ref[...], hot)  # xr of the owner, and a row of ones
         inside = own[width : width + 1] > 0.5
         z = x[:width] + own[:width]
-        score = _entry_dot(_by_direction(d, *_a_rows(a_ref)), _leaky(z, leak))
-        _add_row(next_ref, ROW_Z, jnp.where(inside, score, 0.0))
+        score = entry_dot(by_direction(d, *_a_rows(a_ref)), leaky(z, leak))
+        add_row(next_ref, ROW_Z, jnp.where(inside, score, 0.0))
         tops = []
         for k in (0, 1):
-            mine = jnp.where(inside & (d == k), score, _NEG)
-            tops.append(jnp.max(jnp.where(one_hot, mine, _NEG), axis=1, keepdims=True))
+            mine = jnp.where(inside & (d == k), score, NEG)
+            tops.append(jnp.max(jnp.where(one_hot, mine, NEG), axis=1, keepdims=True))
         lane = jax.lax.broadcasted_iota(jnp.int32, top_ref.shape, 1)
         top_ref[...] = jnp.maximum(top_ref[...], jnp.where(lane == 0, tops[0], tops[1]))
 
@@ -150,7 +124,7 @@ def _backward_kernel(
     tile_ref, block_ref, flag_ref, state_ref, msg_ref, gmsg_ref, orow_ref, a_ref,
     dx_ref, da_ref, *, width: int, leak: float,
 ):
-    real, one_hot, d = _item(tile_ref, block_ref, flag_ref, state_ref, None, (dx_ref, da_ref))
+    real, one_hot, d = item(tile_ref, block_ref, flag_ref, state_ref, None, (dx_ref, da_ref))
 
     @pl.when(real)
     def _walk():
@@ -159,17 +133,17 @@ def _backward_kernel(
         x = msg_ref[...].T  # xl, then xr, of the neighbour
         y = gmsg_ref[...].T  # g of the neighbour, then its max0 max1 sum0 sum1 c0 c1
         nbr = y[w : w + ATT_ROWS]
-        own = _expand(orow_ref[...], hot)  # xr and xl of the owner, its c0 c1, a row of ones
+        own = expand(orow_ref[...], hot)  # xr and xl of the owner, its c0 c1, a row of ones
         inside = own[2 * w + 2 : 2 * w + 3] > 0.5
         a_0, a_1 = _a_rows(a_ref)
         # this entry's own softmax: d score = alpha (d alpha - c), d z = d score a_d leaky'(z)
         z = x[:w] + own[:w]
         slope = jnp.where(z >= 0, 1.0, leak)
-        c = _by_direction(d, own[2 * w : 2 * w + 1], own[2 * w + 1 : 2 * w + 2])
-        dscore = jnp.where(inside, _row(state_ref, ROW_ALPHA) * (_row(state_ref, ROW_DALPHA) - c), 0.0)
-        dz = dscore * _by_direction(d, a_0, a_1) * slope
+        c = by_direction(d, own[2 * w : 2 * w + 1], own[2 * w + 1 : 2 * w + 2])
+        dscore = jnp.where(inside, row(state_ref, ROW_ALPHA) * (row(state_ref, ROW_DALPHA) - c), 0.0)
+        dz = dscore * by_direction(d, a_0, a_1) * slope
         # d a_d: the item's entries of direction d, summed along the lanes into column d of the tile's block
-        terms = dscore * _leaky(z, leak)
+        terms = dscore * leaky(z, leak)
         lane = jax.lax.broadcasted_iota(jnp.int32, da_ref.shape, 1)
         da_ref[...] += jnp.where(
             lane == 0, jnp.sum(jnp.where(d == 0, terms, 0.0), axis=1, keepdims=True),
@@ -177,22 +151,22 @@ def _backward_kernel(
         )
         # its mirror's, in the other direction: xl of the owner and xr of the neighbour make its z, the rest is
         # the neighbour's (rows max0 max1 sum0 sum1 c0 c1)
-        a_m = _by_direction(d, a_1, a_0)
+        a_m = by_direction(d, a_1, a_0)
         zm = own[w : 2 * w] + x[w : 2 * w]
-        score_m = _entry_dot(a_m, _leaky(zm, leak))
-        shift = _by_direction(d, nbr[1:2], nbr[0:1])
-        total = jnp.maximum(_by_direction(d, nbr[3:4], nbr[2:3]), 1e-30)
-        cm = _by_direction(d, nbr[5:6], nbr[4:5])
+        score_m = entry_dot(a_m, leaky(zm, leak))
+        shift = by_direction(d, nbr[1:2], nbr[0:1])
+        total = jnp.maximum(by_direction(d, nbr[3:4], nbr[2:3]), 1e-30)
+        cm = by_direction(d, nbr[5:6], nbr[4:5])
         alpha_m = jnp.where(inside, jnp.exp(jnp.clip(score_m - shift, -60.0, 0.0)) / total, 0.0)
-        dscore_m = alpha_m * (_entry_dot(own[w : 2 * w], y[:w]) - cm)  # d alpha: <xl of the owner, g of the neighbour>
+        dscore_m = alpha_m * (entry_dot(own[w : 2 * w], y[:w]) - cm)  # d alpha: <xl of the owner, g of the neighbour>
         dxl = alpha_m * y[:w] + dscore_m * a_m * jnp.where(zm >= 0, 1.0, leak)
-        dx_ref[...] += _reduce(jnp.concatenate([dz, dxl], axis=0), hot)  # [d xr | d xl] of the tile's nodes
+        dx_ref[...] += reduce(jnp.concatenate([dz, dxl], axis=0), hot)  # [d xr | d xl] of the tile's nodes
 
 
 def _shapes(plan: EdgePlan, width: int):
     """(nodes the tiles cover, the padded width, lanes of the forward table, of the backward's)."""
-    w = _pad_to(width, 8)
-    return _node_tiles(plan) * PLAN_NODE_TILE, w, _pad_to(2 * w, 128), _pad_to(w + ATT_SPARE, 128)
+    w = pad_to(width, 8)
+    return plan_tiles(plan) * PLAN_NODE_TILE, w, pad_to(2 * w, 128), pad_to(w + ATT_SPARE, 128)
 
 
 def _padded(x, w: int):
@@ -215,27 +189,27 @@ def _tile_columns(table):
 def _gatv2_pallas_fwd(plan: EdgePlan, xl, xr, a, leak: float, interpret: bool):
     n, width = xl.shape
     nodes, w, lanes, _ = _shapes(plan, width)
-    msg = _gather_rows(_node_table(nodes, lanes, _padded(xl, w), xr), plan.neighbour)
-    state, top = _walk_call(
+    msg = gather_rows(node_table(nodes, lanes, _padded(xl, w), xr), plan.neighbour)
+    state, top = walk_call(
         plan, partial(_score_kernel, width=w, leak=leak), "planned_gatv2_score",
         [
-            ("entry", _entry_state(plan)), ("message", msg),
-            ("node_rows", _node_table(nodes, w + ATT_ROWS, _padded(xr, w), jnp.ones((n, 1), jnp.float32)).T),
+            ("entry", entry_state(plan)), ("message", msg),
+            ("node_rows", node_table(nodes, w + ATT_ROWS, _padded(xr, w), jnp.ones((n, 1), jnp.float32)).T),
             ("node_rows", _a_table(a, nodes, w)),
         ],
         [("entry", ATT_ROWS), ("node", 2)], interpret,
     )
-    top = jnp.where(top > _NEG / 2, top, 0.0)[:n]  # an empty run shifts by 0
-    state, total = _walk_call(
-        plan, partial(_attention_softmax_kernel, leak=1.0),  # slope 1: the stored score as it is
+    top = jnp.where(top > NEG / 2, top, 0.0)[:n]  # an empty run shifts by 0
+    state, total = walk_call(
+        plan, partial(attention_softmax_kernel, leak=1.0),  # slope 1: the stored score as it is
         "planned_gatv2_softmax",
-        [("entry", state), ("node_rows", _node_rows(nodes, top[:, 0], top[:, 1]))],
+        [("entry", state), ("node_rows", node_rows(nodes, top[:, 0], top[:, 1]))],
         [("entry", ATT_ROWS), ("node_rows", ATT_ROWS)], interpret,
     )
     total = total[:2, :n].T  # [n, 2]
-    state, out = _walk_call(
-        plan, _attention_sum_kernel, "planned_gatv2_sum",
-        [("entry", state), ("node_rows", _node_rows(nodes, total[:, 0], total[:, 1])), ("message", msg)],
+    state, out = walk_call(
+        plan, attention_sum_kernel, "planned_gatv2_sum",
+        [("entry", state), ("node_rows", node_rows(nodes, total[:, 0], total[:, 1])), ("message", msg)],
         [("entry", ATT_ROWS), ("node_rows", lanes)], interpret,
     )
     saved = (xl, xr, a, msg, state, top, total)
@@ -247,18 +221,18 @@ def _gatv2_pallas_bwd(plan: EdgePlan, leak: float, interpret: bool, saved, g):
     n, width = xl.shape
     nodes, w, lanes, g_lanes = _shapes(plan, width)
     g = g.astype(jnp.float32)
-    state, c = _walk_call(
-        plan, _attention_edge_dot_kernel, "planned_gatv2_edge_dot",
-        [("entry", state), ("message", msg), ("node_rows", _node_table(nodes, lanes, g).T)],
+    state, c = walk_call(
+        plan, attention_edge_dot_kernel, "planned_gatv2_edge_dot",
+        [("entry", state), ("message", msg), ("node_rows", node_table(nodes, lanes, g).T)],
         [("entry", ATT_ROWS), ("node_rows", ATT_ROWS)], interpret,
     )
     c = c[:2, :n].T  # [n, 2]: the sum of alpha * d alpha over each run
-    g_msg = _gather_rows(_node_table(nodes, g_lanes, _padded(g, w), top, total, c), plan.neighbour)
-    dx, da = _walk_call(
+    g_msg = gather_rows(node_table(nodes, g_lanes, _padded(g, w), top, total, c), plan.neighbour)
+    dx, da = walk_call(
         plan, partial(_backward_kernel, width=w, leak=leak), "planned_gatv2_backward",
         [
             ("entry", state), ("message", msg), ("message", g_msg),
-            ("node_rows", _node_table(
+            ("node_rows", node_table(
                 nodes, 2 * w + ATT_ROWS, _padded(xr, w), _padded(xl, w), c, jnp.ones((n, 1), jnp.float32)
             ).T),
             ("node_rows", _a_table(a, nodes, w)),
@@ -297,13 +271,13 @@ def _gatv2_xla(plan: EdgePlan, xl, xr, a, leak: float):
     product summed over the width, as the walks and the plain reference make
     it, not a matrix-vector product."""
     n = xl.shape[0]
-    nodes = _node_tiles(plan) * PLAN_NODE_TILE
+    nodes = plan_tiles(plan) * PLAN_NODE_TILE
     owner, nbr, d = plan.owner[0], plan.neighbour, plan.direction[0]
     real = owner < nodes
     with jax.named_scope("reduce"):
         with jax.named_scope("gather"):
             rows, mine = xl[nbr], xr[jnp.minimum(owner, n - 1)]
-        score = jnp.sum(_leaky(rows + mine, leak) * a[d], axis=-1)
+        score = jnp.sum(leaky(rows + mine, leak) * a[d], axis=-1)
         run = jnp.where(real, owner * 2 + d, 2 * nodes)  # ascending
         neg = jnp.finfo(score.dtype).min
         top = jax.ops.segment_max(
@@ -326,13 +300,11 @@ def planned_gatv2(plan: EdgePlan, xl, xr, a, leak: float = 0.2, impl: Optional[s
     over the entries of one (i, d). `a` is `[2, W]`, a row per direction (0:
     edges out of the owner, 1: edges into it). Empty runs and the exponent
     are treated as `gat._segment_softmax` treats them. `impl` as for
-    `sparse.planned_neighbor_sum`. Counted in `sparse.route_stats()`
+    `sparse_sum.planned_neighbor_sum`. Counted in `sparse.route_stats()`
     `["planned"]`, as every reduction over a plan is, and in `["gatv2"]`
     (trace time)."""
-    with sparse._route_lock:
-        sparse._route_counts["planned"] += 1
-        sparse._route_counts["gatv2"] += 1
-    impl = impl or sparse.planned_impl()
+    walk.count("planned", "gatv2")
+    impl = impl or walk.planned_impl()
     if impl == "xla":
         return _gatv2_xla(plan, xl, xr, a, leak)
     return _gatv2_pallas(plan, xl, xr, a, leak, impl == "pallas_interpret")

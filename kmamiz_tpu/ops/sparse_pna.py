@@ -1,6 +1,6 @@
 """The multi-aggregate on the edge plan: PNA's sum, sum of squares, maximum and minimum.
 
-Per plan entry e = (owner o, neighbour n) of `ops/sparse.py`'s `EdgePlan` and a
+Per plan entry e = (owner o, neighbour n) of `ops/edge_plan.py`'s `EdgePlan` and a
 table of message rows `m [N, W]`, four tables come out of ONE row gather and ONE
 walk of the plan's items:
 
@@ -10,7 +10,7 @@ walk of the plan's items:
 Neighbourhood Aggregation (Corso et al., arXiv:2004.05718; `models/pna.py`)
 makes its mean, standard deviation, maximum and minimum of. Every other
 reduction over the plan is a sum through the item's one-hot on the MXU
-(`sparse.planned_neighbor_sum`, `planned_attention`'s `_sum`,
+(`sparse_sum.planned_neighbor_sum`, `planned_attention`'s `_sum`,
 `sparse_gated.planned_gated_sum`). A maximum is not a sum.
 
 On the TPU it is four row gathers and two walks, each a Mosaic kernel that the
@@ -28,7 +28,7 @@ device trace names:
   are the upper half's own (`(-m)^2 = m^2`), made on the VPU where they are
   summed. Sum and sum of squares go through the one-hot as the siblings' sums
   do: the block transposed (a lane an entry), the exact split of a float32 in
-  three bfloat16 pieces, three MXU passes (`sparse._reduce`), `[sum | sq]` as
+  three bfloat16 pieces, three MXU passes (`walk.reduce`), `[sum | sq]` as
   one `[128, nodes]` table of node rows.
 - The maximum cannot go through the MXU. The plan's entries are sorted by
   owner, so an owner's entries are consecutive lanes of the transposed block:
@@ -75,7 +75,7 @@ device trace names:
   makes of a block before its one-hot (the transposition, the squares, the
   running maximum, the splits into bfloat16 pieces) depends on the block
   alone. The block's first item makes it, uses it and keeps it in VMEM
-  scratch (`_walk_call`'s `scratch`, counted in
+  scratch (`walk_call`'s `scratch`, counted in
   `sparse.route_stats()["block_arrays"]`); the block's other items read it
   there: the same operations in the same order, so the same bits. The
   backward walk's three transpositions a block are left where they are: kept
@@ -84,11 +84,7 @@ device trace names:
 Off the TPU the same mathematics is plain XLA over the plan's sorted entries
 (`_aggregate_xla`: `jax.ops.segment_sum`, `segment_max`, `segment_min`),
 differentiated by JAX: the CPU's path and the tests' oracle.
-`sparse.planned_impl()` picks, by platform alone.
-
-A module of its own, as `ops/sparse_gated.py` is: a Mosaic kernel's file and
-line are in the compile cache's key, and nothing here moves a line of another
-kernel's file.
+`walk.planned_impl()` picks, by platform alone.
 """
 from __future__ import annotations
 
@@ -100,34 +96,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kmamiz_tpu.ops import sparse
-from kmamiz_tpu.ops.sparse import (
-    _NEG,
-    _NT,
-    ATT_ROWS,
-    PLAN_NODE_TILE,
-    ROW_OWNER,
-    EdgePlan,
-    _entry_state,
-    _expand,
-    _gather_rows,
-    _item,
-    _mxu,
-    _new_block,
-    _node_tiles,
-    _pad_to,
-    _reduce,
-    _row,
-    _rows,
-    _split3,
-    _walk_call,
+from kmamiz_tpu.ops import walk
+from kmamiz_tpu.ops.edge_plan import PLAN_NODE_TILE, EdgePlan, ShardPlan, pad_to, plan_tiles
+from kmamiz_tpu.ops.walk import (
+    ATT_ROWS, NEG, NT, ROW_OWNER, entry_state, expand, gather_rows, halves, item, mxu, new_block, reduce, row, rows,
+    split3, uncounted, walk_call,
 )
-from kmamiz_tpu.ops.sparse_gated import _halves
 
 
 def _owner(state):
     """[1, block] int32: the owner of each entry of a block of the entries' state."""
-    return jax.lax.bitcast_convert_type(_row(state, ROW_OWNER), jnp.int32)
+    return jax.lax.bitcast_convert_type(row(state, ROW_OWNER), jnp.int32)
 
 
 def _running_max(x, state):
@@ -141,26 +120,26 @@ def _running_max(x, state):
     s = 1
     while s < be:
         same = (_owner(pltpu.roll(state, s, axis=1)) == owner) & (lane >= s)  # the roll wraps: a hub fills a block
-        x = jnp.maximum(x, jnp.where(same, pltpu.roll(x, s, axis=1), _NEG))
+        x = jnp.maximum(x, jnp.where(same, pltpu.roll(x, s, axis=1), NEG))
         s *= 2
     last = (_owner(pltpu.roll(state, be - 1, axis=1)) != owner) | (lane == be - 1)
     return x, last
 
 
 def _pieces(rows):
-    """[R, block] float32 entry rows as `_reduce` splits them: the three
-    bfloat16 pieces, in the order `_reduce` sums them."""
-    return tuple(reversed(_split3(rows)))
+    """[R, block] float32 entry rows as `reduce` splits them: the three
+    bfloat16 pieces, in the order `reduce` sums them."""
+    return tuple(reversed(split3(rows)))
 
 
 def _reduce_pieces(pieces, hot):
-    """`_reduce` of rows already split (`_pieces`): the same three passes,
+    """`reduce` of rows already split (`_pieces`): the same three passes,
     summed in the same order, so the same bits."""
-    return sum(_mxu(piece, hot, _NT) for piece in pieces)
+    return sum(mxu(piece, hot, NT) for piece in pieces)
 
 
 def _forward_block(half: int, counted: bool):
-    """What the forward walk keeps of an edge block (`_walk_call`'s `scratch`):
+    """What the forward walk keeps of an edge block (`walk_call`'s `scratch`):
     the three pieces of the `[sum | sq]` entry rows, the three of each owner's
     running maximum on its last entry, the last entries as a row table, and
     where the ties are counted `[m | -m]` itself."""
@@ -179,18 +158,18 @@ def _aggregate_kernel(
     cells' plan). Made in a region of its own and read back by every item,
     the same work cost the walk 7% more on the v5e (PERF.md, section 6)."""
     ties, block = (refs[:1], refs[1:]) if counted else ((), refs)
-    real, one_hot, _d = _item(tile_ref, block_ref, flag_ref, state_ref, None, (sums_ref, *ties))
-    new_block = _new_block(block_ref)
+    real, one_hot, _d = item(tile_ref, block_ref, flag_ref, state_ref, None, (sums_ref, *ties))
+    first = new_block(block_ref)
 
     @pl.when(flag_ref[pl.program_id(0)] == 1)
     def _new_tile():
-        tops_ref[...] = jnp.full_like(tops_ref, _NEG)
+        tops_ref[...] = jnp.full_like(tops_ref, NEG)
 
-    def walk(sum_pieces, top_pieces, last_rows, x):
+    def use(sum_pieces, top_pieces, last_rows, x):
         hot = one_hot.astype(jnp.bfloat16)
         sums_ref[...] += _reduce_pieces(sum_pieces, hot)  # [sum | sum of squares]
         # the owners this block holds: each has ONE last entry in it
-        held = _mxu(last_rows.astype(jnp.bfloat16), hot, _NT)[0:1] > 0.5
+        held = mxu(last_rows.astype(jnp.bfloat16), hot, NT)[0:1] > 0.5
         top = _reduce_pieces(top_pieces, hot)
         old = tops_ref[...]
         new = jnp.where(held, jnp.maximum(old, top), old)
@@ -198,47 +177,44 @@ def _aggregate_kernel(
         if counted:
             # the block's entries at their owner's maximum OF THE BLOCK, merged as the maximum is (the
             # module's docstring); an owner the block does not hold counts 0 there and keeps its own
-            count = _mxu((x == _expand(top, hot)).astype(jnp.float32).astype(jnp.bfloat16), hot, _NT)
+            count = mxu((x == expand(top, hot)).astype(jnp.float32).astype(jnp.bfloat16), hot, NT)
             ties[0][...] = jnp.where(old == new, ties[0][...], 0.0) + jnp.where(top == new, count, 0.0)
 
-    @pl.when(real & new_block)
+    @pl.when(real & first)
     def _first_item():
         x = msg_ref[...].T  # [m | -m] of the neighbour, a lane an entry
         upper = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) < half
         run, last = _running_max(x, state_ref[...])
         sum_pieces, top_pieces = _pieces(jnp.where(upper, x, x * x)), _pieces(jnp.where(last, run, 0.0))
-        made = (*sum_pieces, *top_pieces, _rows(last.astype(jnp.float32))) + ((x,) if counted else ())
+        made = (*sum_pieces, *top_pieces, rows(last.astype(jnp.float32))) + ((x,) if counted else ())
         for ref, value in zip(block, made, strict=True):
             ref[...] = value
-        walk(sum_pieces, top_pieces, made[6], x)
+        use(sum_pieces, top_pieces, made[6], x)
 
-    products = sparse._mxu_calls.n
-
-    @pl.when(real & jnp.logical_not(new_block))
-    def _later_item():
-        kept = [ref[...] for ref in block]
-        walk(tuple(kept[0:3]), tuple(kept[3:6]), kept[6], kept[7] if counted else None)
-
-    sparse._mxu_calls.n = products  # an item runs one of the two bodies: its products are counted once
+    with uncounted():  # an item runs one of the two bodies: its products are counted once
+        @pl.when(real & jnp.logical_not(first))
+        def _later_item():
+            kept = [ref[...] for ref in block]
+            use(tuple(kept[0:3]), tuple(kept[3:6]), kept[6], kept[7] if counted else None)
 
 
 def _aggregate_backward_kernel(
     tile_ref, block_ref, flag_ref, state_ref, nsum_ref, nshare_ref, ntop_ref, mrow_ref, dm_ref, *, half: int,
 ):
-    real, one_hot, _d = _item(tile_ref, block_ref, flag_ref, state_ref, None, (dm_ref,))
+    real, one_hot, _d = item(tile_ref, block_ref, flag_ref, state_ref, None, (dm_ref,))
 
     @pl.when(real)
     def _walk():
         hot = one_hot.astype(jnp.bfloat16)
-        own = _expand(mrow_ref[...], hot)  # [m | -m] of the entry's owner: the mirror's neighbour
+        own = expand(mrow_ref[...], hot)  # [m | -m] of the entry's owner: the mirror's neighbour
         g = nsum_ref[...].T  # [g_sum | g_sq] of the neighbour: the mirror's owner
         hit = jnp.where(own == ntop_ref[...].T, nshare_ref[...].T, 0.0)  # its [g_max | g_min] / ties, where m is its extreme
-        dm_ref[...] += _reduce(g[:half] + 2.0 * own[:half] * g[half:] + hit[:half] + hit[half:], hot)
+        dm_ref[...] += reduce(g[:half] + 2.0 * own[:half] * g[half:] + hit[:half] + hit[half:], hot)
 
 
 def _shapes(plan: EdgePlan, width: int) -> Tuple[int, int]:
     """(nodes the tiles cover, lanes of one half of a gathered row)."""
-    return _node_tiles(plan) * PLAN_NODE_TILE, _pad_to(2 * width, 128) // 2
+    return plan_tiles(plan) * PLAN_NODE_TILE, pad_to(2 * width, 128) // 2
 
 
 def _held(plan: EdgePlan, n: int):
@@ -251,11 +227,11 @@ def _aggregate_pallas_fwd(plan: EdgePlan, m, interpret: bool, counted: bool):
     their ties): the primal counts nothing, the forward rule does."""
     n, width = m.shape
     nodes, half = _shapes(plan, width)
-    msg = _gather_rows(_halves(nodes, half, m, -m), plan.neighbour)  # [L, 2 half], float32
-    sums, tops, *ties = _walk_call(
+    msg = gather_rows(halves(nodes, half, m, -m), plan.neighbour)  # [L, 2 half], float32
+    sums, tops, *ties = walk_call(
         plan, partial(_aggregate_kernel, half=half, counted=counted),
         "planned_aggregate_counted" if counted else "planned_aggregate",
-        [("entry", _entry_state(plan)), ("message", msg)],
+        [("entry", entry_state(plan)), ("message", msg)],
         [("node_rows", 2 * half)] * (3 if counted else 2), interpret, _forward_block(half, counted),
     )
     held = _held(plan, n)
@@ -276,18 +252,18 @@ def _aggregate_pallas_bwd(plan: EdgePlan, interpret: bool, saved, cotangents):
     ties = jnp.maximum(ties, 1.0)  # an owner with no entry shares nothing out
     # one table at a time in the fast memory (the module's docstring): a table
     # is made when the gather before it is done, the walk's own operand last
-    nsum = _gather_rows(_halves(nodes, half, g_sum, g_sq), plan.neighbour)
+    nsum = gather_rows(halves(nodes, half, g_sum, g_sq), plan.neighbour)
     nsum, g_max, g_min, ties = jax.lax.optimization_barrier((nsum, g_max, g_min, ties))
     shares = (g_max / ties[:width, :n].T, g_min / ties[half : half + width, :n].T)
-    nshare = _gather_rows(_halves(nodes, half, *shares), plan.neighbour)
+    nshare = gather_rows(halves(nodes, half, *shares), plan.neighbour)
     nshare, tops = jax.lax.optimization_barrier((nshare, tops))
-    ntop = _gather_rows(tops.T, plan.neighbour)
+    ntop = gather_rows(tops.T, plan.neighbour)
     ntop, m = jax.lax.optimization_barrier((ntop, m))
-    dm = _walk_call(
+    dm = walk_call(
         plan, partial(_aggregate_backward_kernel, half=half), "planned_aggregate_backward",
         [
-            ("entry", _entry_state(plan)), ("message", nsum), ("message", nshare), ("message", ntop),
-            ("node_rows", _halves(nodes, half, m, -m).T),
+            ("entry", entry_state(plan)), ("message", nsum), ("message", nshare), ("message", ntop),
+            ("node_rows", halves(nodes, half, m, -m).T),
         ],
         [("node_rows", half)], interpret,
     )[0]
@@ -328,7 +304,7 @@ def _aggregate_xla(plan: EdgePlan, m):
     splits a shared maximum equally): the path off the TPU, and the oracle of
     the kernels. An empty segment's maximum is -inf, and is masked by the degree."""
     n = m.shape[0]
-    nodes = _node_tiles(plan) * PLAN_NODE_TILE
+    nodes = plan_tiles(plan) * PLAN_NODE_TILE
     owner = plan.owner[0]
     held = jnp.pad(plan.degree, (0, nodes + 1 - plan.degree.shape[0]))[:, None] > 0
     with jax.named_scope("reduce"):  # the phases of the kernels' path (docs/OBSERVABILITY.md), on this one too
@@ -347,16 +323,15 @@ def planned_aggregate(plan: EdgePlan, m, impl: Optional[str] = None):
     `[N, W]`, over the entries (o, n) of o, a multiset: an endpoint that is
     both caller and callee of o makes two. An owner with no entry reads 0 in
     all four. A maximum that several entries share splits its gradient among
-    them equally. `impl` as for `sparse.planned_neighbor_sum`. Counted in
+    them equally. `impl` as for `sparse_sum.planned_neighbor_sum`. Counted in
     `sparse.route_stats()["planned"]` (trace time)."""
-    if isinstance(plan, sparse.ShardPlan):
+    if isinstance(plan, ShardPlan):
         raise NotImplementedError(
             "a maximum over an owner's entries knows no mesh axis yet: a node-sharded plan meets them "
             "in pieces, a source chip each, and has to merge a running maximum across the sources"
         )
-    with sparse._route_lock:
-        sparse._route_counts["planned"] += 1
-    impl = impl or sparse.planned_impl()
+    walk.count("planned")
+    impl = impl or walk.planned_impl()
     if impl == "xla":
         return _aggregate_xla(plan, m)
     return _aggregate_pallas(plan, m, impl == "pallas_interpret")

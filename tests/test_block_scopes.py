@@ -350,16 +350,16 @@ def test_the_chips_own_program_reads_under_the_programs_names(one_chip, head):
     collectives: `tests/test_edge_plan.py::test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes`.)"""
     from jax.experimental.compilation_cache import compilation_cache
 
-    from kmamiz_tpu.ops import sparse
+    from kmamiz_tpu.ops import edge_plan, walk
 
     model, kernels = KERNELS[head]
     slots, nb, eb, width = 432, 131072, 524288, 18
-    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    entries, _tiles, items = edge_plan.plan_shapes(nb, eb)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    plan = sparse.EdgePlan(
+    plan = edge_plan.EdgePlan(
         owner=arg((1, entries), jnp.int32), neighbour=arg((entries,), jnp.int32), degree=arg((nb,), jnp.float32),
         item_tile=arg((items,), jnp.int32), item_block=arg((items,), jnp.int32), item_flag=arg((items,), jnp.int32),
         direction=arg((1, entries), jnp.int32), mean_log_degree=arg((), jnp.float32),
@@ -370,7 +370,7 @@ def test_the_chips_own_program_reads_under_the_programs_names(one_chip, head):
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
     compilation_cache.reset_cache()
-    real_impl, sparse.planned_impl = sparse.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
+    real_impl, walk.planned_impl = walk.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
     try:
         text = stacked.epoch_runner(model, 1e-2, 10.0).fn.lower(
             whole(params), whole(opt_state), arg((slots, nb, width), jnp.float32),
@@ -378,7 +378,7 @@ def test_the_chips_own_program_reads_under_the_programs_names(one_chip, head):
             arg((eb,), jnp.int32), arg((eb,), jnp.int32), arg((eb,), jnp.bool_), 1, plan,
         ).compile().as_text()
     finally:
-        sparse.planned_impl = real_impl
+        walk.planned_impl = real_impl
         stacked.epoch_runner.cache_clear()
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()

@@ -1,4 +1,4 @@
-"""The per-dataset edge plan and the planned neighbour sum (ops/sparse.py):
+"""The per-dataset edge plan and the planned neighbour sum (ops/edge_plan.py, ops/sparse_sum.py):
 the topology sorted once by owner, a tiled reduction over it in plain XLA
 and as a Pallas kernel (interpreted here), its own VJP, and the wiring into
 graphsage.forward, the stack and the epoch block."""
@@ -13,10 +13,10 @@ import pytest
 
 from kmamiz_tpu.core import programs
 from kmamiz_tpu.models import gat, graphsage, stacked, trainer
-from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.ops import edge_plan, sparse, sparse_gat, sparse_sum, walk
 from kmamiz_tpu.telemetry import REGISTRY
 
-TN, BE = sparse.PLAN_NODE_TILE, sparse.PLAN_EDGE_BLOCK
+TN, BE = edge_plan.PLAN_NODE_TILE, edge_plan.PLAN_EDGE_BLOCK
 IMPLS = ("xla", "pallas_interpret")
 
 
@@ -89,8 +89,8 @@ class TestBuildEdgePlan:
     @pytest.mark.parametrize("name", CASES)
     def test_shapes_are_a_function_of_the_buckets(self, name):
         src, dst, mask, nb = _case(name)
-        plan, entries, items = sparse.build_edge_plan(src, dst, mask, nb)
-        total, node_tiles, bound = sparse.plan_shapes(nb, src.shape[0])
+        plan, entries, items = edge_plan.build_edge_plan(src, dst, mask, nb)
+        total, node_tiles, bound = edge_plan.plan_shapes(nb, src.shape[0])
         assert plan.owner.shape == (1, total) and plan.neighbour.shape == (total,)
         assert plan.degree.shape == (nb,) and plan.degree.dtype == np.float32
         assert plan.item_tile.shape == plan.item_block.shape == plan.item_flag.shape == (bound,)
@@ -101,14 +101,14 @@ class TestBuildEdgePlan:
     @pytest.mark.parametrize("name", CASES)
     def test_degree_equals_neighbor_degree_exactly(self, name):
         src, dst, mask, nb = _case(name)
-        plan, _, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, _, _ = edge_plan.build_edge_plan(src, dst, mask, nb)
         want = graphsage.neighbor_degree(nb, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask))
         np.testing.assert_array_equal(plan.degree, np.asarray(want))
 
     @pytest.mark.parametrize("name", CASES)
     def test_every_real_edge_appears_once_per_direction_sorted_by_owner(self, name):
         src, dst, mask, nb = _case(name)
-        plan, entries, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, entries, _ = edge_plan.build_edge_plan(src, dst, mask, nb)
         owner, neighbour = plan.owner[0], plan.neighbour
         assert (np.diff(owner) >= 0).all()
         assert (owner[entries:] >= -(-nb // TN) * TN).all()  # parked past every tile
@@ -122,7 +122,7 @@ class TestBuildEdgePlan:
     @pytest.mark.parametrize("name", CASES)
     def test_work_list_covers_every_entry_and_every_tile(self, name):
         src, dst, mask, nb = _case(name)
-        plan, entries, items = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, entries, items = edge_plan.build_edge_plan(src, dst, mask, nb)
         tile, block, flag = plan.item_tile, plan.item_block, plan.item_flag
         assert (np.diff(tile) >= 0).all()
         assert (flag[:items] >= 0).all() and (flag[items:] == -1).all()
@@ -141,17 +141,17 @@ class TestBuildEdgePlan:
 
     def test_a_heavy_owner_spans_blocks_and_an_empty_tile_still_has_an_item(self):
         src, dst, mask, nb = _case("heavier_than_a_block")
-        plan, _, items = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, _, items = edge_plan.build_edge_plan(src, dst, mask, nb)
         assert plan.degree[7] > 2 * BE and items >= 3  # one tile, at least three blocks
         src, dst, mask, nb = _case("tile_with_no_edges")
-        plan, _, items = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, _, items = edge_plan.build_edge_plan(src, dst, mask, nb)
         assert plan.degree[TN : 3 * TN].sum() == 0
         assert {1, 2} <= set(plan.item_tile[:items].tolist())
 
     def test_an_edge_out_of_the_bucket_contributes_nothing(self):
         src = np.array([0, 1, 99, 2], np.int32)
         dst = np.array([1, 2, 3, -1], np.int32)
-        plan, entries, _ = sparse.build_edge_plan(src, dst, np.ones(4, bool), 8)
+        plan, entries, _ = edge_plan.build_edge_plan(src, dst, np.ones(4, bool), 8)
         assert entries == 4
         np.testing.assert_array_equal(plan.degree, [1, 2, 1, 0, 0, 0, 0, 0])
 
@@ -162,9 +162,9 @@ class TestPlannedNeighborSum:
     @pytest.mark.parametrize("name", CASES)
     def test_against_segment_sum(self, name, width, impl):
         src, dst, mask, nb = _case(name)
-        plan, _, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, _, _ = edge_plan.build_edge_plan(src, dst, mask, nb)
         h = jnp.asarray(np.random.default_rng(1).normal(size=(nb, width)).astype(np.float32))
-        got = np.asarray(sparse.planned_neighbor_sum(_device(plan), h, impl))
+        got = np.asarray(sparse_sum.planned_neighbor_sum(_device(plan), h, impl))
         want = np.asarray(_legacy_sum(h, src, dst, mask))
         assert got.shape == want.shape and got.dtype == want.dtype
         # the same float32 addends in another order: rounding of partial
@@ -184,12 +184,12 @@ class TestPlannedNeighborSum:
         passes one after the other, so the last bits may differ. Two runs of
         either give the same bits."""
         src, dst, mask, nb = _case(name)
-        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, mask, nb)[0])
         h = jnp.asarray(np.random.default_rng(2).normal(size=(nb, width)).astype(np.float32))
-        a, b = (np.asarray(sparse.planned_neighbor_sum(plan, h, impl)) for impl in IMPLS)
+        a, b = (np.asarray(sparse_sum.planned_neighbor_sum(plan, h, impl)) for impl in IMPLS)
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * max(float(np.abs(a).max()), 1.0))
         for impl, first in zip(IMPLS, (a, b)):
-            again = np.asarray(sparse.planned_neighbor_sum(plan, h, impl))
+            again = np.asarray(sparse_sum.planned_neighbor_sum(plan, h, impl))
             np.testing.assert_array_equal(again, first)
 
     @pytest.mark.parametrize("name", ("masked", "heavier_than_a_block"))
@@ -198,12 +198,12 @@ class TestPlannedNeighborSum:
         sum is the sum the kernel makes of that column alone. (XLA's matrix
         product on a CPU blocks by the width, so its last bit is not.)"""
         src, dst, mask, nb = _case(name)
-        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, mask, nb)[0])
         slots = np.random.default_rng(6).normal(size=(7, nb, 18)).astype(np.float32)
         table = jnp.asarray(np.moveaxis(slots, 0, 1).reshape(nb, 126))
-        packed = np.asarray(sparse.planned_neighbor_sum(plan, table, "pallas_interpret"))
+        packed = np.asarray(sparse_sum.planned_neighbor_sum(plan, table, "pallas_interpret"))
         for j in (0, 3, 6):
-            alone = sparse.planned_neighbor_sum(plan, jnp.asarray(slots[j]), "pallas_interpret")
+            alone = sparse_sum.planned_neighbor_sum(plan, jnp.asarray(slots[j]), "pallas_interpret")
             np.testing.assert_array_equal(packed[:, j * 18 : (j + 1) * 18], np.asarray(alone))
 
     def test_products_are_float32_exact(self):
@@ -212,25 +212,25 @@ class TestPlannedNeighborSum:
         n = 256
         src = np.arange(0, n, 2, dtype=np.int32)
         dst = src + 1
-        plan = _device(sparse.build_edge_plan(src, dst, np.ones(n // 2, bool), n)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, np.ones(n // 2, bool), n)[0])
         h = jnp.asarray((np.random.default_rng(3).random((n, 64)) + 1.0).astype(np.float32))
         swapped = np.asarray(h).reshape(n // 2, 2, 64)[:, ::-1].reshape(n, 64)
         for impl in IMPLS:
             np.testing.assert_array_equal(
-                np.asarray(sparse.planned_neighbor_sum(plan, h, impl)), swapped
+                np.asarray(sparse_sum.planned_neighbor_sum(plan, h, impl)), swapped
             )
 
     @pytest.mark.parametrize("impl", IMPLS)
     def test_vjp_is_the_forward_and_matches_autodiff_of_the_legacy_sum(self, impl):
         src, dst, mask, nb = _case("masked")
-        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, mask, nb)[0])
         rng = np.random.default_rng(4)
         h = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
         ct = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
-        out, pull = jax.vjp(lambda x: sparse.planned_neighbor_sum(plan, x, impl), h)
+        out, pull = jax.vjp(lambda x: sparse_sum.planned_neighbor_sum(plan, x, impl), h)
         (got,) = pull(ct)
         np.testing.assert_array_equal(
-            np.asarray(got), np.asarray(sparse.planned_neighbor_sum(plan, ct, impl))
+            np.asarray(got), np.asarray(sparse_sum.planned_neighbor_sum(plan, ct, impl))
         )
         _, pull_legacy = jax.vjp(lambda x: _legacy_sum(x, src, dst, mask), h)
         np.testing.assert_allclose(
@@ -239,9 +239,9 @@ class TestPlannedNeighborSum:
 
     def test_no_scatter_over_edge_rows_is_left_forward_or_backward(self):
         src, dst, mask, nb = _case("random")
-        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, mask, nb)[0])
         h = jnp.ones((nb, 18), jnp.float32)
-        loss = lambda x: (sparse.planned_neighbor_sum(plan, x, "xla") ** 2).sum()  # noqa: E731
+        loss = lambda x: (sparse_sum.planned_neighbor_sum(plan, x, "xla") ** 2).sum()  # noqa: E731
         text = jax.jit(jax.grad(loss)).lower(h).as_text()
         entries = plan.neighbour.shape[0]
         for line in text.splitlines():
@@ -250,19 +250,19 @@ class TestPlannedNeighborSum:
 
     def test_counts_in_route_stats(self):
         src, dst, mask, nb = _case("padded")
-        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, mask, nb)[0])
         assert sparse.route_stats()["planned"] == 0
-        sparse.planned_neighbor_sum(plan, jnp.ones((nb, 4)), "xla")
+        sparse_sum.planned_neighbor_sum(plan, jnp.ones((nb, 4)), "xla")
         assert sparse.route_stats()["planned"] == 1
         sparse.reset_for_tests()
         assert sparse.route_stats()["planned"] == 0
 
     def test_no_value_of_the_backend_knob_selects_the_reducer(self, monkeypatch):
-        assert sparse.planned_impl() == "xla"  # a CPU, no knob
+        assert walk.planned_impl() == "xla"  # a CPU, no knob
         for value in sparse._VALID_BACKENDS:
             monkeypatch.setenv("KMAMIZ_SPARSE", value)
             sparse.reset_for_tests()
-            assert sparse.planned_impl() == "xla"
+            assert walk.planned_impl() == "xla"
 
 
 def _params(num_features, hidden=8, num_nodes=0, seed=0):
@@ -276,7 +276,7 @@ class TestForwardWithAPlan:
     @pytest.mark.parametrize("embeddings", (False, True), ids=("features", "embeddings"))
     def test_loss_and_gradient_match_todays_forward(self, plan_reducer, embeddings):
         src, dst, mask, nb = _case("masked")
-        plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, mask, nb)[0])
         rng = np.random.default_rng(5)
         x = jnp.asarray(rng.normal(size=(nb, 18)).astype(np.float32))
         tl = jnp.asarray(rng.normal(size=nb).astype(np.float32))
@@ -356,14 +356,14 @@ class TestStackCarriesThePlan:
     def test_the_stack_holds_the_plan_of_its_padded_edge_list(self):
         ds = _dataset()
         st = stacked.stack_dataset(ds)
-        want, entries, items = sparse.build_edge_plan(
+        want, entries, items = edge_plan.build_edge_plan(
             np.asarray(st.src), np.asarray(st.dst), np.asarray(st.edge_mask), st.bucket_nodes
         )
         assert (st.plan_entries, st.plan_items) == (entries, items)
         assert entries == 2 * int(np.asarray(ds.edge_mask).sum())
         for got, w in zip(st.plan, want):
             np.testing.assert_array_equal(np.asarray(got), w)
-        shapes = sparse.plan_shapes(st.bucket_nodes, st.bucket_edges)
+        shapes = edge_plan.plan_shapes(st.bucket_nodes, st.bucket_edges)
         assert st.plan.owner.shape == (1, shapes[0]) and st.plan.item_tile.shape == (shapes[2],)
 
     def test_builds_and_hits_are_counted_and_datasets_over_one_graph_share_a_plan(self):
@@ -673,7 +673,7 @@ def one_chip(topo):
 
 
 def _described_plan(arg, nb, entries, items):
-    return sparse.EdgePlan(
+    return edge_plan.EdgePlan(
         owner=arg((1, entries), jnp.int32), neighbour=arg((entries,), jnp.int32),
         degree=arg((nb,), jnp.float32), item_tile=arg((items,), jnp.int32),
         item_block=arg((items,), jnp.int32), item_flag=arg((items,), jnp.int32),
@@ -686,7 +686,7 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_chip, width):
     from jax.experimental.compilation_cache import compilation_cache
 
     nb, eb = 131072, 524288
-    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    entries, _tiles, items = edge_plan.plan_shapes(nb, eb)
     assert (entries, items) == (1048576, 1024 + 2048)
 
     def arg(shape, dtype):
@@ -698,7 +698,7 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_chip, width):
     compilation_cache.reset_cache()
     try:
         compiled = (
-            jax.jit(lambda p, h: sparse.planned_neighbor_sum(p, h, "pallas"))
+            jax.jit(lambda p, h: sparse_sum.planned_neighbor_sum(p, h, "pallas"))
             .lower(plan, arg((nb, width), jnp.float32))
             .compile()
         )
@@ -720,13 +720,13 @@ def test_attention_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, wid
     from jax.experimental.compilation_cache import compilation_cache
 
     nb, eb = 131072, 524288
-    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    entries, _tiles, items = edge_plan.plan_shapes(nb, eb)
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(hw, s, t, plan):
-        return (sparse.planned_attention(plan, hw, s, t, 0.2, "pallas") ** 2).sum()
+        return (sparse_gat.planned_attention(plan, hw, s, t, 0.2, "pallas") ** 2).sum()
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -760,7 +760,7 @@ def test_gated_sum_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, wid
     from kmamiz_tpu.ops import sparse_gated
 
     nb, eb = 131072, 524288
-    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    entries, _tiles, items = edge_plan.plan_shapes(nb, eb)
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -811,7 +811,7 @@ def test_aggregate_kernels_compile_for_the_v5e_at_the_cells_shapes(one_chip, wid
     from kmamiz_tpu.ops import sparse_pna
 
     nb, eb = 131072, 524288
-    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    entries, _tiles, items = edge_plan.plan_shapes(nb, eb)
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -856,7 +856,7 @@ def test_gatv2_walks_compile_for_the_v5e_at_the_cells_shapes(one_chip, width):
     from kmamiz_tpu.ops import sparse_gatv2
 
     nb, eb = 131072, 524288
-    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    entries, _tiles, items = edge_plan.plan_shapes(nb, eb)
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -897,7 +897,7 @@ def test_the_aggregate_primal_compiles_for_the_v5e_and_counts_no_ties(one_chip):
     from kmamiz_tpu.ops import sparse_pna
 
     nb, eb = 131072, 524288
-    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    entries, _tiles, items = edge_plan.plan_shapes(nb, eb)
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -935,7 +935,7 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
     mesh = Mesh(np.asarray(topo.devices), ("nodes",))
     slots, nb, eb, width, shards = 432, 524288, 2097152, 18, 4
     rows = nb // shards
-    entries, _tiles, items = sparse.plan_shapes(rows, eb // shards // shards)
+    entries, _tiles, items = edge_plan.plan_shapes(rows, eb // shards // shards)
     assert (entries, items) == (262144, 1024 + 512)  # a quarter of the 100k cell's entries a source
 
     def arg(shape, dtype, spec=P()):
@@ -953,7 +953,7 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # a described chip reads none back
     compilation_cache.reset_cache()
-    real_impl, sparse.planned_impl = sparse.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
+    real_impl, walk.planned_impl = walk.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
     try:
         compiled = stacked.node_sharded_epoch_runner(graphsage, 1e-2, 10.0, mesh).fn.lower(
             whole(params), whole(opt_state), arg((slots, nb, width), jnp.float32, cut),
@@ -961,7 +961,7 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
             arg((eb,), jnp.int32), arg((eb,), jnp.int32), arg((eb,), jnp.bool_), 1, plan,
         ).compile()
     finally:
-        sparse.planned_impl = real_impl
+        walk.planned_impl = real_impl
         stacked.node_sharded_epoch_runner.cache_clear()
         stacked.epoch_runner.cache_clear()
         jax.config.update("jax_enable_compilation_cache", was)
@@ -1000,25 +1000,20 @@ def test_node_sharded_block_compiles_for_the_v5e_host_at_the_cells_shapes(topo):
 # -- the one-chip blocks stay the programs they were ---------------------------
 
 #: sha256 of each one-chip epoch block as it LOWERS for a described v5e at the cells' shapes (`_block_fingerprint`).
-#: A Mosaic kernel's source locations are in its bytecode and so in the compile cache's key: a PR that moves a line at
-#: or above a kernel of `ops/sparse.py`, `ops/sparse_gated.py`, a head's forward or the block's body in `models/stacked.py`
-#: changes these, pays a cold compile in every one-chip cell (13 s in PR 30) and says so (PR 37: the named scopes in
-#: the block, the heads and the reductions moved lines in all of them); one that means to leave the one-chip path
-#: alone (PR 35, PR 36: the sharded path) keeps them. PR 38 changed GAT's and STLGT's walks (their per-entry dot
-#: products) and kept GraphSAGE's. PR 39 gave the plan a leaf (`mean_log_degree`) and so moved every kernel of
-#: `ops/sparse.py` two lines down: all three changed, GAT's and STLGT's in their locations alone, GraphSAGE's also by
-#: one scalar that its slot group's loops hand on with the plan (the compiled block is the parent's instruction for
-#: instruction, compared in PR 39); `pna` is new. PR 40 changed `pna`'s alone (`ops/sparse_pna.py`: the ties counted in
-#: the forward walk, one walk fewer). `gatv2` came in its own file and left the other four as they were. The update's
-#: product over the four aggregates alone (`pna._update`) changed `pna`'s alone. PNA's walks keeping an edge block's own
-#: work in VMEM scratch (`_walk_call`'s `scratch`, added in place: no line of `ops/sparse.py` moved) changed `pna`'s
-#: alone. The failing assertion prints the new value.
+#: A Mosaic kernel's source locations are in its bytecode and so in the compile cache's key: a change that moves a line
+#: at or above a kernel of `ops/`, a head's forward or the block's body in `models/stacked.py` changes these, pays a
+#: cold compile in every one-chip cell (13 s in PR 30) and says so; one that means to leave the one-chip path alone
+#: (PR 35, PR 36: the sharded path) keeps them. Since the kernel layer was split into a module per decision
+#: (`ops/edge_plan.py`, `ops/walk.py`, `ops/sparse_sum.py`, `ops/sparse_gat.py` and the three heads' files), an edit
+#: moves the kernels of its own module and of the modules that call it only. That split moved every kernel's file and
+#: line and nothing else: each block's lowered text with the kernels' bytecode read without its locations stayed the
+#: same to the bit. The failing assertion prints the new value.
 ONE_CHIP_BLOCKS = {
-    "graphsage": "3cf6ea2861cdd0862ac02579c2d28aed16fd6a901eabcf7fa37d751c8433e982",
-    "gat": "21cc8349f75ef8676b9eabc9a03a4af0aa7c195d7cfb616f0290854bc641defa",
-    "stlgt": "191c1ef0f1b612b63642205f0b64b05a82871602b6030360a4a3393929436f70",
-    "pna": "64c79a1aea080b56adef8d51933527cee346bfad1e961a9dce94bdb4ddcadec5",
-    "gatv2": "2014547782fb49c992aa04ff65d94525ed145b94b1f311210b0b58bafd6fba27",
+    "graphsage": "8cd3fde239c21167de212d4a71e0c464eeb30f05a9c7af71ac2a38dbe20c03ba",
+    "gat": "782380c2ffbdae47d59be3754f0598df45fb5b31fe422fc459c3a43b4aaa70dd",
+    "stlgt": "8e77bec535a0bbe0a7124c3fecf63fe69e616030230f549f8288e41250b2a292",
+    "pna": "c48bc933058372e3f0daea666762252499c311468770c515a8c443744bf2d409",
+    "gatv2": "4dbe081f4a5c4fffc7206205c6a494e1e79d10397a2c5f23b5f2fc54d825393f",
 }
 
 
@@ -1054,7 +1049,7 @@ def _lower_one_chip_block(one_chip, model):
     """A one-chip cell's epoch block lowered for a described v5e at the cells' shapes (432 slots, 131,072 nodes, a plan
     of 1,048,576 entries)."""
     slots, nb, eb, width = 432, 131072, 524288, 18
-    entries, _tiles, items = sparse.plan_shapes(nb, eb)
+    entries, _tiles, items = edge_plan.plan_shapes(nb, eb)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -1062,8 +1057,8 @@ def _lower_one_chip_block(one_chip, model):
     params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), hidden=64, num_features=width))
     opt_state = jax.eval_shape(lambda p: model.make_optimizer(1e-2).init(p), params)
     whole = lambda tree: jax.tree_util.tree_map(lambda a: arg(a.shape, a.dtype), tree)  # noqa: E731
-    real_impl, sparse.planned_impl = sparse.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
-    # a helper two families share (`_weighted_sum`) keeps the call stack of whoever traced it FIRST in the process, and
+    real_impl, walk.planned_impl = walk.planned_impl, lambda: "pallas"  # `jax.default_backend()` sees the CPU
+    # a helper two families share (`walk.weighted_sum`) keeps the call stack of whoever traced it FIRST in the process, and
     # that stack is in the kernel's bytecode: trace afresh, as a process that refreshes one head does
     jax.clear_caches()
     try:
@@ -1073,7 +1068,7 @@ def _lower_one_chip_block(one_chip, model):
             arg((eb,), jnp.int32), arg((eb,), jnp.int32), arg((eb,), jnp.bool_), 1, _described_plan(arg, nb, entries, items),
         )
     finally:
-        sparse.planned_impl = real_impl
+        walk.planned_impl = real_impl
         stacked.epoch_runner.cache_clear()
 
 

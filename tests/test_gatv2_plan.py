@@ -18,7 +18,7 @@ from benchmarks.reference import check
 from benchmarks.reference import gatv2 as reference_gatv2
 from benchmarks.reference import train as reference_train
 from kmamiz_tpu.models import checkpoint, common, gatv2, stacked, trainer
-from kmamiz_tpu.ops import sparse, sparse_gatv2
+from kmamiz_tpu.ops import edge_plan, sparse, sparse_gatv2
 from kmamiz_tpu.telemetry.tracing import TRACER
 
 from test_edge_plan import BE, _case
@@ -62,7 +62,7 @@ class TestPlannedGatv2:
     @pytest.mark.parametrize("name,width", [("hub_and_isolated", 8), ("heavier_than_a_block", 64), ("two_tiles", 12)])
     def test_value_and_every_cotangent_match_the_plain_reference(self, name, width, impl):
         src, dst, mask, nb = _topology(name)
-        host, entries, _items = sparse.build_edge_plan(src, dst, mask, nb)
+        host, entries, _items = edge_plan.build_edge_plan(src, dst, mask, nb)
         owner, direction = host.owner[0, :entries], host.direction[0, :entries]
         if name == "hub_and_isolated":
             # an empty run in each direction: an endpoint that only calls, one that is only called, and some with neither
@@ -92,7 +92,7 @@ class TestPlannedGatv2:
     @pytest.mark.parametrize(
         "differentiated,want",
         [
-            # an expand each; `_reduce`'s three passes in the other four
+            # an expand each; `walk.reduce`'s three passes in the other four
             (True, {f"planned_gatv2_{w}": 1 if w == "score" else 4 for w in WALKS}),
             (False, {"planned_gatv2_score": 1, "planned_gatv2_softmax": 4, "planned_gatv2_sum": 4}),
         ],

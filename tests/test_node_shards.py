@@ -1,6 +1,6 @@
 """A history that one device cannot hold: the stack cut by NODES over the
-local devices (models/stacked.py), a plan a shard (ops/sparse.build_shard_plans),
-the neighbour table all-gathered (ops/sparse.sharded_neighbor_sum), the epoch
+local devices (models/stacked.py), a plan a shard (ops/edge_plan.build_shard_plans),
+the neighbour table all-gathered (ops/sparse_sum.sharded_neighbor_sum), the epoch
 block under `shard_map` with the one-device block's own body. On the eight
 host devices `conftest.py` gives, at a small size: 1,000 endpoints in a 1,024
 bucket, four shards of 256 rows, a hub whose entries weigh a shard down, and
@@ -24,7 +24,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from kmamiz_tpu.models import common, gat, gatv2, graphsage, pna, stacked, trainer
-from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.ops import edge_plan, sparse, sparse_sum
 from kmamiz_tpu.parallel import mesh as mesh_mod
 from kmamiz_tpu.telemetry.tracing import TRACER
 
@@ -87,7 +87,7 @@ def _laid_out(name, shards, width=8, seed=1):
     random table, as the whole `[Nb, W]` in the plans' row order and as the
     nodes' own `[N, W]`."""
     src, dst, mask, n, nb = _graph(name)
-    plans, cuts, entries, items = sparse.build_shard_plans(src, dst, mask, n, nb, shards)
+    plans, cuts, entries, items = edge_plan.build_shard_plans(src, dst, mask, n, nb, shards)
     h = np.random.default_rng(seed).normal(size=(n, width)).astype(np.float32)
     table = np.zeros((nb, width), np.float32)
     table[_rows(cuts, n, nb // shards)] = h
@@ -108,11 +108,12 @@ def _first(plans):
 
 
 class TestShardPlans:
-    @pytest.mark.parametrize("name", GRAPHS)
+    @pytest.mark.parametrize("name", GRAPHS + ("roomy_hub",))
     def test_one_shard_is_the_unsharded_plan(self, name):
+        """Every leaf, the item list among them: one function makes the items of both (a hub's too)."""
         src, dst, mask, n, nb = _graph(name)
-        plans, cuts, entries, items = sparse.build_shard_plans(src, dst, mask, n, nb, 1)
-        whole, n_entries, n_items = sparse.build_edge_plan(src, dst, mask, nb)
+        plans, cuts, entries, items = edge_plan.build_shard_plans(src, dst, mask, n, nb, 1)
+        whole, n_entries, n_items = edge_plan.build_edge_plan(src, dst, mask, nb)
         assert cuts.tolist() == [0, n] and entries == [n_entries] and items == [n_items]
         for got, want in zip(plans, whole):
             assert got.shape == (1,) + want.shape and got.dtype == want.dtype
@@ -125,7 +126,7 @@ class TestShardPlans:
         the shard their neighbour lives on: every real entry in exactly one
         (owner, source) sub-plan, the neighbour a row of that source's table."""
         src, dst, mask, n, nb = _graph(name)
-        plans, cuts, entries, items = sparse.build_shard_plans(src, dst, mask, n, nb, shards)
+        plans, cuts, entries, items = edge_plan.build_shard_plans(src, dst, mask, n, nb, shards)
         rows = nb // shards
         row_of = _rows(cuts, n, rows)
         assert cuts[0] == 0 and cuts[-1] == n and (np.diff(cuts) >= 0).all() and (np.diff(cuts) <= rows).all()
@@ -136,7 +137,7 @@ class TestShardPlans:
         assert len(entries) == len(items) == shards * shards  # flat, owner-major
         assert plans.degree.shape == (shards, rows) and plans.neighbour.shape[:2] == (shards, shards)
         got = []
-        for i, one in enumerate(sparse.sub_plans(plans)):
+        for i, one in enumerate(edge_plan.sub_plans(plans)):
             d, source = divmod(i, shards)
             k, held = entries[i], cuts[d + 1] - cuts[d]
             owner, neighbour = one.owner[0], one.neighbour
@@ -148,7 +149,7 @@ class TestShardPlans:
             assert (one.item_flag[: items[i]] >= 0).all() and (one.item_flag[items[i]:] == -1).all()
             np.testing.assert_array_equal(one.degree, plans.degree[d])  # a shard's whole degree, whatever the source
             if k == 0:  # nothing of this source for this owner: a plan that writes zeros
-                zeros = sparse._planned_reduce_xla(
+                zeros = sparse_sum._planned_reduce_xla(
                     jax.tree_util.tree_map(jnp.asarray, one), jnp.ones((owner.shape[0], 4), jnp.float32))
                 assert zeros.shape[0] >= rows and not np.asarray(zeros).any()
         assert sorted(got) == want and sum(entries) == 2 * int(mask.sum())
@@ -156,16 +157,16 @@ class TestShardPlans:
         for d in range(shards):  # the sources' entries add up to the owner's, and to its degree
             assert by_owner[d] == plans.degree[d].sum() == sum(1 for o, _n, _d in want if o // rows == d)
         # one shape, a function of the buckets while the fullest pair fits it, wider by eighths where a hub does not let it
-        base, tiles, n_items = sparse.plan_shapes(rows, src.shape[0] // shards // shards)
-        step = max(base // 8, sparse.PLAN_EDGE_BLOCK)
+        base, tiles, n_items = edge_plan.plan_shapes(rows, src.shape[0] // shards // shards)
+        step = max(base // 8, edge_plan.PLAN_EDGE_BLOCK)
         width = plans.owner.shape[3]
-        assert plans.owner.shape == (shards, shards, 1, width) and plans.item_tile.shape == (shards, shards, tiles + width // sparse.PLAN_EDGE_BLOCK)
+        assert plans.owner.shape == (shards, shards, 1, width) and plans.item_tile.shape == (shards, shards, tiles + width // edge_plan.PLAN_EDGE_BLOCK)
         assert width == max(base, -(-max(entries) // step) * step)
         assert (width > base) == (name == "full_bucket")  # 40 nodes draw every edge: a pair outgrows the base
 
     def test_the_cuts_follow_the_entries_not_the_nodes(self):
         src, dst, mask, n, nb = _graph("roomy_hub")
-        _plans, cuts, by_source, _items = sparse.build_shard_plans(src, dst, mask, n, nb, 4)
+        _plans, cuts, by_source, _items = edge_plan.build_shard_plans(src, dst, mask, n, nb, 4)
         entries = np.reshape(by_source, (4, 4)).sum(axis=1).tolist()  # an owner's, over its sources
         degree = np.bincount(np.concatenate([src[mask], dst[mask]]), minlength=n)
         assert degree.max() > 600 and np.diff(cuts).max() < 256
@@ -176,13 +177,13 @@ class TestShardPlans:
     def test_a_range_never_outgrows_its_rows_and_the_rest_still_fits(self):
         degree = np.zeros(512, np.int64)
         degree[:40] = 100  # all the entries in the first forty nodes of a bucket without a spare row
-        cuts = sparse.shard_cuts(degree, 4, 128)
+        cuts = edge_plan.shard_cuts(degree, 4, 128)
         assert cuts.tolist() == [0, 128, 256, 384, 512]
-        cuts = sparse.shard_cuts(np.ones(300, np.int64), 4, 128)
+        cuts = edge_plan.shard_cuts(np.ones(300, np.int64), 4, 128)
         assert cuts.tolist() == [0, 75, 150, 225, 300]
-        assert sparse.shard_cuts(np.zeros(10, np.int64), 4, 128).tolist() == [0, 2, 5, 7, 10]
+        assert edge_plan.shard_cuts(np.zeros(10, np.int64), 4, 128).tolist() == [0, 2, 5, 7, 10]
         with pytest.raises(ValueError, match="do not fit"):
-            sparse.shard_cuts(np.ones(600, np.int64), 4, 128)
+            edge_plan.shard_cuts(np.ones(600, np.int64), 4, 128)
 
 
 # -- the sharded sum -------------------------------------------------------------
@@ -195,13 +196,13 @@ class TestShardedSum:
         sum one device makes of the whole table, and the exact one."""
         (src, dst, mask, n, nb), plans, cuts, h, table = _laid_out(name, 4)
         sharded = _over_the_mesh(
-            4, lambda p, rows: sparse.planned_neighbor_sum(sparse.ShardPlan(_first(p), AXIS), rows), P(AXIS), P(AXIS)
+            4, lambda p, rows: sparse_sum.planned_neighbor_sum(edge_plan.ShardPlan(_first(p), AXIS), rows), P(AXIS), P(AXIS)
         )
         got = np.asarray(jax.jit(sharded)(plans, jnp.asarray(table)))
         assert got.shape == table.shape
         assert sparse.route_stats()["sharded"] == sparse.route_stats()["planned"] == 1
-        one_plan, *_ = sparse.build_shard_plans(src, dst, mask, n, nb, 1)
-        whole = np.asarray(sparse.planned_neighbor_sum(_first(jax.tree_util.tree_map(jnp.asarray, one_plan)), jnp.asarray(np.pad(h, ((0, nb - n), (0, 0))))))
+        one_plan, *_ = edge_plan.build_shard_plans(src, dst, mask, n, nb, 1)
+        whole = np.asarray(sparse_sum.planned_neighbor_sum(_first(jax.tree_util.tree_map(jnp.asarray, one_plan)), jnp.asarray(np.pad(h, ((0, nb - n), (0, 0))))))
         row_of = _rows(cuts, n, nb // 4)
         np.testing.assert_allclose(got[row_of], whole[:n], rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(got[row_of], _exact_sum(h, src, dst, mask), rtol=1e-5, atol=1e-5)
@@ -217,9 +218,9 @@ class TestShardedSum:
         g = np.random.default_rng(5).normal(size=table.shape).astype(np.float32)
 
         def pulled_back(p, rows, ct):
-            plan = sparse.ShardPlan(_first(p), AXIS)
-            out, vjp = jax.vjp(lambda r: sparse.planned_neighbor_sum(plan, r), rows)
-            return jnp.stack([out, vjp(ct)[0], sparse.planned_neighbor_sum(plan, ct)])[None]
+            plan = edge_plan.ShardPlan(_first(p), AXIS)
+            out, vjp = jax.vjp(lambda r: sparse_sum.planned_neighbor_sum(plan, r), rows)
+            return jnp.stack([out, vjp(ct)[0], sparse_sum.planned_neighbor_sum(plan, ct)])[None]
 
         out, d_rows, forward_of_ct = np.moveaxis(np.asarray(
             jax.jit(_over_the_mesh(4, pulled_back, P(AXIS), P(AXIS), P(AXIS)))(plans, jnp.asarray(table), jnp.asarray(g))
@@ -240,10 +241,10 @@ class TestShardedSum:
         for d in (0, 3):
             total = np.zeros((rows, 18), np.float32)
             for source in range(4):
-                one = sparse._source_plan(jax.tree_util.tree_map(lambda a: a[d], plans), source)
+                one = edge_plan.source_plan(jax.tree_util.tree_map(lambda a: a[d], plans), source)
                 mine = jnp.asarray(table[source * rows : (source + 1) * rows])
-                got = np.asarray(sparse.planned_neighbor_sum(one, mine, "pallas_interpret"))
-                again = np.asarray(sparse.planned_neighbor_sum(one, mine, "xla"))
+                got = np.asarray(sparse_sum.planned_neighbor_sum(one, mine, "pallas_interpret"))
+                again = np.asarray(sparse_sum.planned_neighbor_sum(one, mine, "xla"))
                 assert got.shape == (rows, 18)
                 np.testing.assert_allclose(got, again, rtol=1e-5, atol=1e-5)
                 total += got
@@ -257,7 +258,7 @@ class TestShardedSum:
         gathers from four tables, four reductions, the parts added."""
         (src, dst, mask, n, nb), plans, cuts, h, table = _laid_out("hub", 4, width=18)
         sharded = _over_the_mesh(
-            4, lambda p, rows: sparse.planned_neighbor_sum(sparse.ShardPlan(_first(p), AXIS), rows, impl), P(AXIS), P(AXIS)
+            4, lambda p, rows: sparse_sum.planned_neighbor_sum(edge_plan.ShardPlan(_first(p), AXIS), rows, impl), P(AXIS), P(AXIS)
         )
         lowered = jax.jit(sharded).lower(plans, jnp.asarray(table)).as_text()
         assert lowered.count("stablehlo.all_gather") == 1 and lowered.count("optimization_barrier") == 4
@@ -268,7 +269,7 @@ class TestShardedSum:
         (_g, plans, _cuts, _h, table) = _laid_out("hub", 4)
         fn = _over_the_mesh(
             4, lambda p, rows: sum(jax.value_and_grad(
-                lambda r: (sparse.planned_neighbor_sum(sparse.ShardPlan(_first(p), AXIS), r) ** 2).sum())(rows)),
+                lambda r: (sparse_sum.planned_neighbor_sum(edge_plan.ShardPlan(_first(p), AXIS), r) ** 2).sum())(rows)),
             P(AXIS), P(AXIS),
         )
         gathers = [ln for ln in jax.jit(fn).lower(plans, jnp.asarray(table)).as_text().splitlines() if "all_gather" in ln]
@@ -322,7 +323,7 @@ class TestShardedLoss:
 
         def share(params, f, tl, ta, nm, plan):
             def partial_loss(p):
-                lat, logit = graphsage.forward(p, f[0], None, None, None, plan=sparse.ShardPlan(_first(plan), AXIS))
+                lat, logit = graphsage.forward(p, f[0], None, None, None, plan=edge_plan.ShardPlan(_first(plan), AXIS))
                 w = nm[0].astype(jnp.float32)
                 count = jnp.maximum(jax.lax.psum(w.sum(), AXIS), 1.0)  # a count: no gradient goes through it
                 bce = optax.sigmoid_binary_cross_entropy(logit, ta[0])
@@ -353,7 +354,7 @@ class TestShardedLoss:
         params = graphsage.init_params(jax.random.PRNGKey(1), hidden=16, num_features=18)
 
         def device(params, f, tl, ta, nm, plan):
-            loss_fn = stacked.head_loss_fn(graphsage, 3.0, plan=sparse.ShardPlan(_first(plan), AXIS))
+            loss_fn = stacked.head_loss_fn(graphsage, 3.0, plan=edge_plan.ShardPlan(_first(plan), AXIS))
             (loss, (lat, ano)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, f[0], None, None, None, tl[0], ta[0], nm[0]
             )

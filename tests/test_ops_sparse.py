@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from kmamiz_tpu.analysis import guards
 from kmamiz_tpu.core import programs
 from kmamiz_tpu.graph.store import EndpointGraph
-from kmamiz_tpu.ops import scorers, sparse, window
+from kmamiz_tpu.ops import edge_plan, scorers, sparse, walk, window
 
 EXACT_LANES = (
     "instability_on",
@@ -250,10 +250,10 @@ class TestModelPlaneReductions:
             monkeypatch.setenv("KMAMIZ_SPARSE", env)
         sparse.reset_for_tests()
         monkeypatch.setattr(jax, "default_backend", lambda: platform)
-        assert sparse.planned_impl() == want
+        assert walk.planned_impl() == want
         if env is not None:  # and the knob off the TPU changes nothing either
             monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-            assert sparse.planned_impl() == "xla"
+            assert walk.planned_impl() == "xla"
 
     @pytest.mark.parametrize("nodes", (48, 2048, 4096))
     def test_neighbor_mean_with_a_plan_against_without(self, nodes):
@@ -264,7 +264,7 @@ class TestModelPlaneReductions:
         h, src, dst, mask = self._graph(nodes, nodes, 4 * nodes, 12)
         plan = jax.tree_util.tree_map(
             jnp.asarray,
-            sparse.build_edge_plan(
+            edge_plan.build_edge_plan(
                 np.asarray(src), np.asarray(dst), np.asarray(mask), nodes
             )[0],
         )

@@ -1,4 +1,4 @@
-"""The planned attention (ops/sparse.planned_attention): GAT's directed
+"""The planned attention (ops/sparse_gat.planned_attention): GAT's directed
 segment softmax, its weighted sum and their VJP over the stack's edge plan, in
 plain XLA and as the Pallas kernels (interpreted here), against the unsorted
 formulation of `gat._attend`, against the plain reference of the benchmark,
@@ -15,7 +15,7 @@ import pytest
 
 from benchmarks.reference import gat as reference_gat
 from kmamiz_tpu.models import common, gat, stacked, trainer
-from kmamiz_tpu.ops import sparse
+from kmamiz_tpu.ops import edge_plan, sparse, sparse_gat, sparse_sum, walk
 from kmamiz_tpu.telemetry.tracing import TRACER
 from test_edge_plan import BE, CASES, IMPLS, TN, _case, _dataset, _device
 
@@ -24,7 +24,7 @@ WIDTH = 16
 
 def _plan(name):
     src, dst, mask, nb = _case(name)
-    return src, dst, mask, nb, _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+    return src, dst, mask, nb, _device(edge_plan.build_edge_plan(src, dst, mask, nb)[0])
 
 
 def _inputs(nb, seed=1, width=WIDTH):
@@ -46,7 +46,7 @@ def _planned(hw, vectors, plan, impl):
     a_s, a_d, a_sr, a_dr = vectors
     s = jnp.stack([hw @ a_sr, hw @ a_s], axis=1)
     t = jnp.stack([hw @ a_dr, hw @ a_d], axis=1)
-    return sparse.planned_attention(plan, hw, s, t, gat.LEAK, impl)
+    return sparse_gat.planned_attention(plan, hw, s, t, gat.LEAK, impl)
 
 
 def _close(got, want, tol, what=""):
@@ -58,7 +58,7 @@ class TestTheDirectedPlan:
     @pytest.mark.parametrize("name", CASES)
     def test_entries_are_sorted_by_owner_then_direction_and_say_which_end_they_are(self, name):
         src, dst, mask, nb = _case(name)
-        plan, entries, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, entries, _ = edge_plan.build_edge_plan(src, dst, mask, nb)
         owner, nbr, d = plan.owner[0, :entries], plan.neighbour[:entries], plan.direction[0, :entries]
         assert set(np.unique(d).tolist()) <= {0, 1} and (plan.direction[0, entries:] == 0).all()
         assert (np.diff(owner.astype(np.int64) * 2 + d) >= 0).all()
@@ -74,7 +74,7 @@ class TestTheDirectedPlan:
         seen from its other end. The backward pass sums over mirrors in place
         of a permutation, so the pairing has to be one to one."""
         src, dst, mask, nb = _case(name)
-        plan, entries, _ = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, entries, _ = edge_plan.build_edge_plan(src, dst, mask, nb)
         owner, nbr, d = plan.owner[0, :entries], plan.neighbour[:entries], plan.direction[0, :entries]
         here = np.lexsort((d, nbr, owner))  # by (owner, neighbour, direction)
         there = np.lexsort((1 - d, owner, nbr))  # by (neighbour, owner, other direction)
@@ -88,7 +88,7 @@ class TestTheDirectedPlan:
     def test_the_block_of_consecutive_items_never_falls(self):
         for name in CASES:
             src, dst, mask, nb = _case(name)
-            plan, _, _ = sparse.build_edge_plan(src, dst, mask, nb)
+            plan, _, _ = edge_plan.build_edge_plan(src, dst, mask, nb)
             assert (np.diff(plan.item_block) >= 0).all(), name
 
     @pytest.mark.parametrize("name", CASES + ("hub", "shared_block", "three_by_three"))
@@ -97,11 +97,11 @@ class TestTheDirectedPlan:
         blocks ahead, and what `plan_blocks` counts: the real items' blocks
         start at 0 and rise by at most one, the no-ops repeat the last."""
         src, dst, mask, nb = _GRAPHS[name]() if name in _GRAPHS else _case(name)
-        plan, _, items = sparse.build_edge_plan(src, dst, mask, nb)
+        plan, _, items = edge_plan.build_edge_plan(src, dst, mask, nb)
         real = plan.item_block[:items]
         assert real[0] == 0 and set(np.diff(real).tolist()) <= {0, 1}
         assert (plan.item_block[items:] == real[-1]).all()
-        assert sparse.plan_blocks(plan, items) == len(np.unique(real)) == real[-1] + 1
+        assert edge_plan.plan_blocks(plan, items) == len(np.unique(real)) == real[-1] + 1
 
 
 def _hub_graph():
@@ -149,8 +149,8 @@ _GRAPHS = {"hub": _hub_graph, "shared_block": _shared_block_graph, "three_by_thr
 #: recomputed, not read). Those of d s and d t stood from PR 30 (89811fd) to
 #: PR 37 and are PR 38's now, recorded on its final tree (parent commit
 #: 4a46e2e): `d alpha = <g[i], hw[j]>` and the mirror's `<hw[i], g[j]>` are
-#: float32 sums of float32 products, by halves (`_entry_dot`), where `_dot6`
-#: kept a product to 2^-24 of the sum; `alpha`, `c` and `_reduce` as before
+#: float32 sums of float32 products, by halves (`walk.entry_dot`), where `_dot6`
+#: kept a product to 2^-24 of the sum; `alpha`, `c` and `walk.reduce` as before
 _DIGESTS = {
     "hub": ("aebc210415e35939", "c5139565ff963696", "6dbcbb9687956b8f", "bb48a2701f031b4a"),
     "shared_block": ("8e60f886afeae48d", "583ee12ca7e2e9fe", "4a6926adbe225e42", "cc246e6e1cc11a2c"),
@@ -161,11 +161,11 @@ _DIGEST_SEEDS = {"hub": 21, "shared_block": 22}
 def _direct(graph, seed, impl, width=WIDTH):
     """(value, d hw, d s, d t) of `planned_attention` itself on seeded inputs."""
     src, dst, mask, nb = graph
-    plan = _device(sparse.build_edge_plan(src, dst, mask, nb)[0])
+    plan = _device(edge_plan.build_edge_plan(src, dst, mask, nb)[0])
     rng = np.random.default_rng(seed)
     hw, ct = (jnp.asarray(rng.normal(size=(nb, width)).astype(np.float32)) for _ in range(2))
     s, t = (jnp.asarray(rng.normal(size=(nb, 2)).astype(np.float32)) for _ in range(2))
-    out, pull = jax.vjp(lambda h, s_, t_: sparse.planned_attention(plan, h, s_, t_, 0.2, impl), hw, s, t)
+    out, pull = jax.vjp(lambda h, s_, t_: sparse_gat.planned_attention(plan, h, s_, t_, 0.2, impl), hw, s, t)
     return [np.asarray(x) for x in (out, *pull(ct))]
 
 
@@ -174,7 +174,7 @@ def _float64_value_and_d_hw(graph, seed, width=WIDTH):
     softmax of each (owner, direction) run, `out[i] = sum alpha_e hw[j]` and
     `d hw[j] = sum alpha_e ct[i]` over the entries (i, j, d)."""
     src, dst, mask, nb = graph
-    plan, entries, _ = sparse.build_edge_plan(src, dst, mask, nb)
+    plan, entries, _ = edge_plan.build_edge_plan(src, dst, mask, nb)
     rng = np.random.default_rng(seed)
     hw, ct = (rng.normal(size=(nb, width)).astype(np.float32).astype(np.float64) for _ in range(2))
     s, t = (rng.normal(size=(nb, 2)).astype(np.float32).astype(np.float64) for _ in range(2))
@@ -222,7 +222,7 @@ class TestTheKernelsKeepTheirBits:
 
     def test_a_block_met_by_three_tiles_and_a_tile_that_meets_three_blocks(self):
         graph = _three_by_three_graph()
-        plan, _, items = sparse.build_edge_plan(*graph)
+        plan, _, items = edge_plan.build_edge_plan(*graph)
         tiles, blocks = plan.item_tile[:items], plan.item_block[:items]
         assert np.bincount(tiles).max() >= 3  # a tile by items of three blocks
         assert np.bincount(blocks).max() >= 3  # a block by items of three tiles
@@ -237,33 +237,33 @@ class TestTheKernelsKeepTheirBits:
         src, dst, mask, nb, plan = _plan("masked")
         hw, _, _ = _inputs(nb)
         s = t = jnp.zeros((nb, 2), jnp.float32)
-        _, saved = sparse._attention_pallas_fwd(plan, hw, s, t, 0.2, True)
+        _, saved = sparse_gat._attention_pallas_fwd(plan, hw, s, t, 0.2, True)
         entries = plan.neighbour.shape[0]
         per_entry = {a.shape: a for a in saved if entries in a.shape}
-        assert sorted(per_entry) == [(sparse.ATT_ROWS, entries), (entries, 128)]
+        assert sorted(per_entry) == [(walk.ATT_ROWS, entries), (entries, 128)]
         assert all(a.dtype == jnp.float32 for a in per_entry.values())
         assert len([a for a in saved if entries in a.shape]) == 2
-        state = np.asarray(per_entry[(sparse.ATT_ROWS, entries)])
+        state = np.asarray(per_entry[(walk.ATT_ROWS, entries)])
         real = 2 * int(mask.sum())
         # the blocks an item visits (no walk writes the others, none reads them)
         seen = (int(np.asarray(plan.item_block).max()) + 1) * BE
         state = state[:, :seen]
         # the plan's owner and direction ride in it as bits, untouched by three walks
-        np.testing.assert_array_equal(state[sparse.ROW_OWNER].view(np.int32), np.asarray(plan.owner)[0, :seen])
-        np.testing.assert_array_equal(state[sparse.ROW_DIR].view(np.int32), np.asarray(plan.direction)[0, :seen])
-        assert (state[sparse.ROW_ALPHA, :real] > 0).all() and (state[sparse.ROW_ALPHA, real:] == 0).all()
-        assert (state[sparse.ROW_DALPHA] == 0).all()  # the backward pass's row
+        np.testing.assert_array_equal(state[walk.ROW_OWNER].view(np.int32), np.asarray(plan.owner)[0, :seen])
+        np.testing.assert_array_equal(state[walk.ROW_DIR].view(np.int32), np.asarray(plan.direction)[0, :seen])
+        assert (state[walk.ROW_ALPHA, :real] > 0).all() and (state[walk.ROW_ALPHA, real:] == 0).all()
+        assert (state[walk.ROW_DALPHA] == 0).all()  # the backward pass's row
 
 
 def _item_dots(host_plan, items, owner_table, entry_rows_t_of):
-    """What a walk's items make of `sparse._entry_dot`, item by item as a
+    """What a walk's items make of `walk.entry_dot`, item by item as a
     kernel calls it: the tile's rows of the `[nodes, R]` owner table
     transposed and expanded through the item's one-hot in bfloat16, against
     the block's rows transposed (`entry_rows_t_of(block, d)`, `[R, block]`). Returns the entries' sums
     over the items `[L]` and every item's own `[1, block]` row with the mask of
     the entries its tile owns."""
     owner, d = np.asarray(host_plan.owner), np.asarray(host_plan.direction)
-    dot = jax.jit(lambda owner_rows_t, entry_rows_t, hot: sparse._entry_dot(sparse._expand(owner_rows_t, hot), entry_rows_t))
+    dot = jax.jit(lambda owner_rows_t, entry_rows_t, hot: walk.entry_dot(walk.expand(owner_rows_t, hot), entry_rows_t))
     total, per_item = np.zeros(owner.shape[1], np.float32), []
     for i in range(items):
         tile, block = int(host_plan.item_tile[i]), int(host_plan.item_block[i])
@@ -314,19 +314,19 @@ class TestTheEntryDot:
     @staticmethod
     def _operands(name, seed=26, width=64):
         graph = _GRAPHS[name]() if name in _GRAPHS else _case(name)
-        host, entries, items = sparse.build_edge_plan(*graph)
+        host, entries, items = edge_plan.build_edge_plan(*graph)
         plan, nb = _device(host), graph[3]
         rng = np.random.default_rng(seed)
         hw, g = (jnp.asarray(rng.normal(size=(nb, width)).astype(np.float32)) for _ in range(2))
         scalars = [jnp.asarray(rng.normal(size=(nb, 2)).astype(np.float32)) for _ in range(4)]
-        nodes, lanes = sparse._attention_shapes(plan, hw)
+        nodes, lanes = sparse_gat._attention_shapes(plan, hw)
         return host, entries, items, plan, hw, g, scalars, nodes, lanes
 
     @pytest.mark.parametrize("name", _DOT_GRAPHS)
     def test_edge_dots_product_of_g_of_the_owner_and_hw_of_the_neighbour(self, name):
         host, entries, items, plan, hw, g, scalars, nodes, lanes = self._operands(name)
-        msg = sparse._gather_rows(sparse._node_table(nodes, lanes, hw, scalars[0]), plan.neighbour)
-        got, _ = _item_dots(host, items, np.asarray(sparse._node_table(nodes, lanes, g)), _blocks_transposed(msg))
+        msg = walk.gather_rows(walk.node_table(nodes, lanes, hw, scalars[0]), plan.neighbour)
+        got, _ = _item_dots(host, items, np.asarray(walk.node_table(nodes, lanes, g)), _blocks_transposed(msg))
         own, nbr = host.owner[0, :entries], host.neighbour[:entries]
         oracle = np.asarray((g[own] * hw[nbr]).sum(axis=1))
         _dot_is_as_close_as_the_oracles(got[:entries], oracle, np.asarray(g)[own], np.asarray(hw)[nbr])
@@ -337,8 +337,8 @@ class TestTheEntryDot:
         """The block's rows are `[g | t, max, sum, c]` of the neighbour: the
         lanes past the width meet zeros of the owner's table."""
         host, entries, items, plan, hw, g, scalars, nodes, lanes = self._operands(name, seed=27)
-        msg = sparse._gather_rows(sparse._node_table(nodes, lanes, g, *scalars), plan.neighbour)
-        got, _ = _item_dots(host, items, np.asarray(sparse._node_table(nodes, lanes, hw)), _blocks_transposed(msg))
+        msg = walk.gather_rows(walk.node_table(nodes, lanes, g, *scalars), plan.neighbour)
+        got, _ = _item_dots(host, items, np.asarray(walk.node_table(nodes, lanes, hw)), _blocks_transposed(msg))
         own, nbr = host.owner[0, :entries], host.neighbour[:entries]
         oracle = np.asarray((hw[own] * g[nbr]).sum(axis=1))
         _dot_is_as_close_as_the_oracles(got[:entries], oracle, np.asarray(hw)[own], np.asarray(g)[nbr])
@@ -348,21 +348,21 @@ class TestTheEntryDot:
         product in its row of the state: the items' own, a block met by four
         tiles (`three_by_three`) summed from one product and three zeros."""
         host, entries, items, plan, hw, g, scalars, nodes, lanes = self._operands("three_by_three", width=WIDTH)
-        _, saved = sparse._attention_pallas_fwd(plan, hw, scalars[0], scalars[1], 0.2, True)
+        _, saved = sparse_gat._attention_pallas_fwd(plan, hw, scalars[0], scalars[1], 0.2, True)
         msg, state = saved[3], saved[4]
-        state, _c = sparse._walk_call(
-            plan, sparse._attention_edge_dot_kernel, "planned_attention_edge_dot",
-            [("entry", state), ("message", msg), ("node_rows", sparse._node_table(nodes, lanes, g).T)],
-            [("entry", sparse.ATT_ROWS), ("node_rows", sparse.ATT_ROWS)], True,
+        state, _c = walk.walk_call(
+            plan, sparse_gat.attention_edge_dot_kernel, "planned_attention_edge_dot",
+            [("entry", state), ("message", msg), ("node_rows", walk.node_table(nodes, lanes, g).T)],
+            [("entry", walk.ATT_ROWS), ("node_rows", walk.ATT_ROWS)], True,
         )
-        got, _ = _item_dots(host, items, np.asarray(sparse._node_table(nodes, lanes, g)), _blocks_transposed(msg))
-        np.testing.assert_array_equal(np.asarray(state)[sparse.ROW_DALPHA, :entries], got[:entries])
+        got, _ = _item_dots(host, items, np.asarray(walk.node_table(nodes, lanes, g)), _blocks_transposed(msg))
+        np.testing.assert_array_equal(np.asarray(state)[walk.ROW_DALPHA, :entries], got[:entries])
 
     @pytest.mark.parametrize("name", ("shared_block", "three_by_three"))
     def test_an_entry_of_another_tile_dots_to_exactly_zero_and_two_runs_give_the_same_bits(self, name):
         host, entries, items, plan, hw, g, scalars, nodes, lanes = self._operands(name, seed=28)
-        msg = sparse._gather_rows(sparse._node_table(nodes, lanes, hw, scalars[0]), plan.neighbour)
-        table = np.asarray(sparse._node_table(nodes, lanes, g))
+        msg = walk.gather_rows(walk.node_table(nodes, lanes, hw, scalars[0]), plan.neighbour)
+        table = np.asarray(walk.node_table(nodes, lanes, g))
         (first, per_item), (second, _) = (_item_dots(host, items, table, _blocks_transposed(msg)) for _ in range(2))
         np.testing.assert_array_equal(first.view(np.int32), second.view(np.int32))
         strangers = 0
@@ -411,7 +411,7 @@ class TestPlannedAttention:
         has_in = np.bincount(dst[mask], minlength=nb) > 0
         want = (has_out.astype(np.float32) + has_in.astype(np.float32))[:, None] * np.ones((1, 4), np.float32)
         for impl in IMPLS:
-            got = sparse.planned_attention(plan, jnp.ones((nb, 4), jnp.float32), s, t, 0.2, impl)
+            got = sparse_gat.planned_attention(plan, jnp.ones((nb, 4), jnp.float32), s, t, 0.2, impl)
             np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=2e-6)
 
     @pytest.mark.parametrize("impl", IMPLS)
@@ -420,7 +420,7 @@ class TestPlannedAttention:
         src = np.array([0, 0, 3, 0], np.int32)
         dst = np.array([1, 2, 1, 0], np.int32)
         mask = np.array([True, True, True, False])
-        plan = _device(sparse.build_edge_plan(src, dst, mask, 8)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, mask, 8)[0])
         hw, vectors, ct = _inputs(8, seed=4)
         got, pull = jax.vjp(lambda h, a: _planned(h, a, plan, impl), hw, vectors)
         want, pull_unsorted = jax.vjp(lambda h, a: _unsorted(h, a, src, dst, mask), hw, vectors)
@@ -438,7 +438,7 @@ class TestPlannedAttention:
         src = np.array([1, 2, 3, 0, 0, 0, 0, 0], np.int32)
         dst = np.array([5, 5, 5, 1, 0, 0, 0, 0], np.int32)
         mask = np.array([False, False, False, True, False, False, False, False])
-        plan = _device(sparse.build_edge_plan(src, dst, mask, 8)[0])
+        plan = _device(edge_plan.build_edge_plan(src, dst, mask, 8)[0])
         hw, vectors, ct = _inputs(8, seed=5)
         got, pull = jax.vjp(lambda h, a: _planned(h, a, plan, impl), hw, vectors)
         want, pull_unsorted = jax.vjp(lambda h, a: _unsorted(h, a, src, dst, mask), hw, vectors)
@@ -453,7 +453,7 @@ class TestPlannedAttention:
         its run of in-edges crosses five edge blocks, so its maximum, its sum
         and its weighted sum are carried from item to item."""
         src, dst, mask, n = _hub_graph()
-        host, entries, items = sparse.build_edge_plan(src, dst, mask, n)
+        host, entries, items = edge_plan.build_edge_plan(src, dst, mask, n)
         assert host.degree[3] == mask.shape[0] and items >= 6
         plan = _device(host)
         hw, vectors, ct = _inputs(n, seed=7)
@@ -495,19 +495,19 @@ class TestPlannedAttention:
     def test_counts_in_route_stats_beside_planned(self):
         _src, _dst, _mask, nb, plan = _plan("padded")
         assert sparse.route_stats()["attention"] == 0
-        sparse.planned_attention(plan, jnp.ones((nb, 4)), jnp.zeros((nb, 2)), jnp.zeros((nb, 2)), 0.2, "xla")
+        sparse_gat.planned_attention(plan, jnp.ones((nb, 4)), jnp.zeros((nb, 2)), jnp.zeros((nb, 2)), 0.2, "xla")
         stats = sparse.route_stats()
         assert stats["attention"] == 1 and stats["planned"] == 1
-        sparse.planned_neighbor_sum(plan, jnp.ones((nb, 4)), "xla")
+        sparse_sum.planned_neighbor_sum(plan, jnp.ones((nb, 4)), "xla")
         stats = sparse.route_stats()
         assert stats["attention"] == 1 and stats["planned"] == 2
         sparse.reset_for_tests()
         assert sparse.route_stats()["attention"] == 0
 
     def test_route_stats_counts_the_mxu_products_of_each_of_the_seven_walks(self):
-        """`_mxu` calls of each walk's kernel when it was last traced: an
-        expand is one, a `_reduce` or a weighted sum three (six until PR 34),
-        a per-entry dot product ONE since PR 38 (`_entry_dot`: the owner's
+        """`walk.mxu` calls of each walk's kernel when it was last traced: an
+        expand is one, a `walk.reduce` or a weighted sum three (six until PR 34),
+        a per-entry dot product ONE since PR 38 (`walk.entry_dot`: the owner's
         rows stacked through the one-hot; `_dot6` spent six), and none more
         where the rows an item expands anyway ride in it (`_backward`'s). The
         three `dot`s of `planned_neighbor_sum`'s kernel are its own and not
@@ -517,7 +517,7 @@ class TestPlannedAttention:
         _src, _dst, _mask, nb, plan = _plan("padded")
         assert sparse.route_stats()["mxu_products"] == {}
         hw, s, t = jnp.ones((nb, 4)), jnp.zeros((nb, 2)), jnp.zeros((nb, 2))
-        attention = lambda h, s_, t_: sparse.planned_attention(plan, h, s_, t_, 0.2, "pallas_interpret").sum()  # noqa: E731
+        attention = lambda h, s_, t_: sparse_gat.planned_attention(plan, h, s_, t_, 0.2, "pallas_interpret").sum()  # noqa: E731
         jax.eval_shape(attention, hw, s, t)
         forward = {"planned_attention_max": 1, "planned_attention_softmax": 4, "planned_attention_sum": 4}
         assert sparse.route_stats()["mxu_products"] == forward
@@ -528,8 +528,8 @@ class TestPlannedAttention:
         jax.eval_shape(jax.grad(gated, argnums=(0, 1, 2, 3)), hw, hw, hw, jnp.zeros((1,)))
         all_seven = {**gat_walks, "planned_gated_sum": 7, "planned_gated_backward": 8}
         assert sparse.route_stats()["mxu_products"] == all_seven
-        jax.eval_shape(lambda h: sparse.planned_neighbor_sum(plan, h, "pallas_interpret"), hw)
-        sparse.planned_attention(plan, hw, s, t, 0.2, "xla")  # XLA's formulation traces no kernel
+        jax.eval_shape(lambda h: sparse_sum.planned_neighbor_sum(plan, h, "pallas_interpret"), hw)
+        sparse_gat.planned_attention(plan, hw, s, t, 0.2, "xla")  # XLA's formulation traces no kernel
         assert sparse.route_stats()["mxu_products"] == all_seven
         sparse.reset_for_tests()
         assert sparse.route_stats()["mxu_products"] == {}
@@ -544,7 +544,7 @@ class TestPlannedAttention:
 
         def glue_only(plan_, _kernel, _name, inputs, outputs, _interpret):
             """A walk's outputs without its body, so that only XLA's glue lowers."""
-            nodes = sparse._node_tiles(plan_) * TN
+            nodes = edge_plan.plan_tiles(plan_) * TN
             shapes = {"entry": (None, entries), "node_rows": (None, nodes), "node": (nodes, None)}
             alive = sum(jnp.sum(a.astype(jnp.float32)) for _kind, a in inputs)
             return [
@@ -552,13 +552,13 @@ class TestPlannedAttention:
                 for kind, lanes in outputs
             ]
 
-        real = sparse._walk_call
-        sparse._walk_call = glue_only
+        real = sparse_gat.walk_call
+        sparse_gat.walk_call = glue_only
         try:
             loss = lambda h, a: (_planned(h, a, plan, "pallas_interpret") ** 2).sum()  # noqa: E731
             text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(hw, vectors).as_text()
         finally:
-            sparse._walk_call = real
+            sparse_gat.walk_call = real
         assert "scatter" not in text
         gathers = [line for line in text.splitlines() if "gather" in line and "stablehlo.gather" in line]
         assert len(gathers) == 2  # [hw | s] forward, [g | t, max, sum, c] backward
@@ -661,7 +661,7 @@ class TestGatTrainingThroughThePlan:
         src, dst = np.asarray(ds.src)[real], np.asarray(ds.dst)[real]
         runs = len(set(src.tolist())) + len(set(dst.tolist()))
         assert st.plan_runs == runs and st.plan_entries == 2 * int(real.sum())
-        assert st.plan_blocks == sparse.plan_blocks(st.plan, st.plan_items) == 1
+        assert st.plan_blocks == edge_plan.plan_blocks(st.plan, st.plan_items) == 1
         noted = {}
         for tb in TRACER.traces():
             for i, span in enumerate(tb.spans):
@@ -679,7 +679,7 @@ class TestGatTrainingThroughThePlan:
         plan's span, beside the entries they will walk."""
         _src, _dst, _mask, nb, plan = _plan("padded")
         hw, st_ = jnp.ones((nb, 4)), jnp.zeros((nb, 2))
-        attention = lambda h: sparse.planned_attention(plan, h, st_, st_, 0.2, "pallas_interpret").sum()  # noqa: E731
+        attention = lambda h: sparse_gat.planned_attention(plan, h, st_, st_, 0.2, "pallas_interpret").sum()  # noqa: E731
         jax.eval_shape(jax.grad(attention), hw)
         stacked.stack_dataset(_dataset())
         noted = [

@@ -17,7 +17,7 @@ import pytest
 from benchmarks.reference import pna as reference_pna
 from benchmarks.reference import train as reference_train
 from kmamiz_tpu.models import checkpoint, common, pna, stacked, trainer
-from kmamiz_tpu.ops import sparse, sparse_pna
+from kmamiz_tpu.ops import edge_plan, sparse, sparse_gat, sparse_pna, sparse_sum
 from kmamiz_tpu.telemetry.tracing import TRACER
 
 from test_edge_plan import BE, _case
@@ -191,7 +191,7 @@ class TestPlannedAggregate:
     )
     def test_values_and_the_gradient_against_float64_segment_reductions(self, name, width, impl):
         src, dst, mask, nb = _topology(name)
-        host, entries, _items = sparse.build_edge_plan(src, dst, mask, nb)
+        host, entries, _items = edge_plan.build_edge_plan(src, dst, mask, nb)
         m, weights = _messages(nb, width, seed=len(name))
         want, want_grad, (ties_top, _ties_bottom) = _float64_aggregates_and_gradient(host, entries, m, weights)
         assert (ties_top > 1).any()  # the case holds a shared maximum
@@ -200,7 +200,7 @@ class TestPlannedAggregate:
 
     def test_a_hubs_entries_span_several_blocks_and_its_maximum_is_merged(self):
         src, dst, mask, nb = _topology("heavier_than_a_block")
-        host, entries, items = sparse.build_edge_plan(src, dst, mask, nb)
+        host, entries, items = edge_plan.build_edge_plan(src, dst, mask, nb)
         hub_blocks = np.unique(np.flatnonzero(host.owner[0, :entries] == 7) // BE)
         assert hub_blocks.size >= 3 and items > 1
         m = np.zeros((nb, 8), np.float32)
@@ -213,7 +213,7 @@ class TestPlannedAggregate:
     @pytest.mark.parametrize("impl", IMPLS)
     def test_an_owner_with_no_entry_reads_zero_and_padding_enters_no_aggregate(self, impl):
         src, dst, mask, n, nb = _graph("hub_and_isolated")
-        host, entries, _items = sparse.build_edge_plan(src, dst, mask, nb)
+        host, entries, _items = edge_plan.build_edge_plan(src, dst, mask, nb)
         assert entries < host.owner.shape[1] and not mask.all()  # parked entries and masked edges
         m, weights = _messages(nb, 8)
         m = m.at[0].set(1e6)  # the row every parked entry gathers
@@ -249,7 +249,7 @@ class TestPlannedAggregate:
         with pytest.raises(Exception):  # Mosaic cannot target a CPU: nothing interprets silently
             jax.block_until_ready(sparse_pna.planned_aggregate(plan, m, "pallas"))
         with pytest.raises(NotImplementedError, match="merge a running maximum across the sources"):
-            sparse_pna.planned_aggregate(sparse.ShardPlan(plan, "nodes"), m)
+            sparse_pna.planned_aggregate(edge_plan.ShardPlan(plan, "nodes"), m)
 
     @pytest.mark.parametrize(
         "differentiated,want",
@@ -275,7 +275,7 @@ class TestPlannedAggregate:
         against the float64 count over the owner's whole run of entries; and the gradient they divide, against
         the float64 oracle's."""
         src, dst, mask, nb = _topology(name)
-        host, entries, _items = sparse.build_edge_plan(src, dst, mask, nb)
+        host, entries, _items = edge_plan.build_edge_plan(src, dst, mask, nb)
         m, weights = _messages(nb, 8, seed=len(name))
         if name == "maxima_in_later_blocks":
             m = _maxima_in_later_blocks(host, entries, np.asarray(m))
@@ -298,7 +298,7 @@ class TestABlocksWorkOnce:
         no-op items after the last block: the four aggregates, the ties and the gradient against float64 and
         against the XLA oracle."""
         src, dst, mask, nb = _topology(name)
-        host, entries, items = sparse.build_edge_plan(src, dst, mask, nb)
+        host, entries, items = edge_plan.build_edge_plan(src, dst, mask, nb)
         blocks = np.asarray(host.item_block)
         assert np.bincount(blocks[:items]).max() == TILES_A_BLOCK[name]  # the tiles the busiest block meets
         assert items < blocks.size and (blocks[items:] == blocks[items - 1]).all()  # the no-ops repeat the last block
@@ -345,8 +345,8 @@ class TestABlocksWorkOnce:
         src, dst, mask, nb = _topology("wide_bucket")
         plan = _plan(src, dst, mask, nb)
         h = jnp.ones((nb, 8))
-        jax.grad(lambda h: sparse.planned_neighbor_sum(plan, h, "pallas_interpret").sum())(h)
-        jax.grad(lambda h: sparse.planned_attention(plan, h, h[:, :2], h[:, :2], 0.2, "pallas_interpret").sum())(h)
+        jax.grad(lambda h: sparse_sum.planned_neighbor_sum(plan, h, "pallas_interpret").sum())(h)
+        jax.grad(lambda h: sparse_gat.planned_attention(plan, h, h[:, :2], h[:, :2], 0.2, "pallas_interpret").sum())(h)
         assert sparse.route_stats()["mxu_products"] and sparse.route_stats()["block_arrays"] == {}
 
     @pytest.mark.parametrize("n_nodes,share", ((100, 0.0), (150, 0.25)))
@@ -367,16 +367,16 @@ class TestThePlansDelta:
     @pytest.mark.parametrize("name", GRAPHS)
     def test_it_is_the_mean_log_degree_of_the_endpoints_that_have_a_neighbour(self, name):
         src, dst, mask, nb = _topology(name)
-        host = sparse.build_edge_plan(src, dst, mask, nb)[0]
+        host = edge_plan.build_edge_plan(src, dst, mask, nb)[0]
         degree = np.asarray(host.degree, np.float64)
         want = np.log1p(degree[degree > 0]).mean()
         assert float(host.mean_log_degree) == pytest.approx(want, rel=1e-6)
-        assert float(sparse.mean_log_degree(host.degree)) == pytest.approx(want, rel=1e-6)
+        assert float(edge_plan.mean_log_degree(host.degree)) == pytest.approx(want, rel=1e-6)
 
     def test_a_graph_without_an_edge_scales_by_one(self):
         src, dst, mask, nb = _case("empty")
-        assert float(sparse.build_edge_plan(src, dst, mask, nb)[0].mean_log_degree) == 1.0
-        assert float(sparse.mean_log_degree(np.zeros(8))) == 1.0
+        assert float(edge_plan.build_edge_plan(src, dst, mask, nb)[0].mean_log_degree) == 1.0
+        assert float(edge_plan.mean_log_degree(np.zeros(8))) == 1.0
 
     def test_the_rows_that_pad_a_node_bucket_do_not_move_a_forecast(self):
         """The served forward (models/serving.py) and the batched evaluation pad the nodes to a bucket and hand no

@@ -17,7 +17,7 @@ from kmamiz_tpu.core.spans import _pad_size
 from kmamiz_tpu.models import common, gat, graphsage, stacked, trainer
 from kmamiz_tpu.models.stlgt import model as stlgt
 from kmamiz_tpu.models.stlgt import trainer as stlgt_trainer
-from kmamiz_tpu.ops import sparse, sparse_gated
+from kmamiz_tpu.ops import edge_plan, sparse, sparse_gated, walk
 from kmamiz_tpu.telemetry.tracing import TRACER
 
 from test_edge_plan import BE, _case
@@ -63,7 +63,7 @@ GRAPHS = ("hub_and_isolated", "wide_bucket", "two_tiles")
 
 
 def _plan(src, dst, mask, nb):
-    return jax.tree_util.tree_map(jnp.asarray, sparse.build_edge_plan(src, dst, mask, nb)[0])
+    return jax.tree_util.tree_map(jnp.asarray, edge_plan.build_edge_plan(src, dst, mask, nb)[0])
 
 
 def _segment_bias(q, k, v, b_edge, src, dst, mask):
@@ -111,7 +111,7 @@ def _bound_on_dq_and_dk(plan, entries, q, k, v, b, w):
     """How far the KERNELS' float32 `d q` and `d k` of `(bias * w).sum()` can
     lie from the float64 ones, element by element, a priori: every rounding of
     the path counted at 2^-24 of what it rounds and carried forward to first
-    order, nothing measured. A per-entry dot product is `sparse._entry_dot`'s
+    order, nothing measured. A per-entry dot product is `walk.entry_dot`'s
     sum (12 roundings deep: the product, four halvings of 128 rows, seven adds
     over a vreg's sublanes); a sum over a node's entries through the one-hot
     is at most one rounding an entry and three for the pieces (`deg + 3`); an
@@ -183,7 +183,7 @@ class TestPlannedGatedSum:
         passed the oracle's in 7 at the parent and in 10 here, and no element
         of 120 runs passed 11% of the bound)."""
         src, dst, mask, _n, nb = _graph(name)
-        host, entries, _items = sparse.build_edge_plan(src, dst, mask, nb)
+        host, entries, _items = edge_plan.build_edge_plan(src, dst, mask, nb)
         plan = jax.tree_util.tree_map(jnp.asarray, host)
         q, k, v, b, w = _tables(nb, width, seed=2)
         exact = _float64_bias_and_gradients(host, entries, q, k, v, b, w)
@@ -267,14 +267,14 @@ class TestPlannedGatedSum:
 
 class TestTheGatedWalksEntryDots:
     """PR 38: the two gated walks' per-entry dot products through
-    `sparse._entry_dot`, on the operands the kernels hand it: the owner's
+    `walk.entry_dot`, on the operands the kernels hand it: the owner's
     `[q | k]` (forward) and `[v | g]` (backward) as node rows, the block's
     gathered rows transposed and laid under the half each entry reads."""
 
     @staticmethod
     def _operands(name, seed, width=64):
         graph = _GRAPHS[name]() if name in _GRAPHS else _case(name)
-        host, entries, items = sparse.build_edge_plan(*graph)
+        host, entries, items = edge_plan.build_edge_plan(*graph)
         plan = jax.tree_util.tree_map(jnp.asarray, host)
         q, k, v, _b, g = _tables(graph[3], width, seed=seed)
         nodes, half, _scale = sparse_gated._shapes(plan, width)
@@ -284,9 +284,9 @@ class TestTheGatedWalksEntryDots:
     @pytest.mark.parametrize("name", _DOT_GRAPHS)
     def test_the_gates_product_of_q_and_k_under_the_half_each_direction_reads(self, name):
         host, entries, items, plan, (q, k, _v, _g), nodes, half, own, nbr, caller = self._operands(name, 5)
-        nqk = np.asarray(sparse._gather_rows(sparse_gated._halves(nodes, half, q, k), plan.neighbour))
+        nqk = np.asarray(walk.gather_rows(walk.halves(nodes, half, q, k), plan.neighbour))
         got, _ = _item_dots(
-            host, items, np.asarray(sparse_gated._halves(nodes, half, q, k)),
+            host, items, np.asarray(walk.halves(nodes, half, q, k)),
             lambda block, d: sparse_gated._by_half(jnp.asarray(nqk[block * BE : (block + 1) * BE]), d, half),
         )
         mine, theirs = jnp.where(caller, q[own], k[own]), jnp.where(caller, k[nbr], q[nbr])
@@ -299,14 +299,14 @@ class TestTheGatedWalksEntryDots:
         """`<v[o], g[n]> + <g[o], v[n]>` in one sum of 128 terms: an edge's d
         gate from either of its entries."""
         host, entries, items, plan, (_q, _k, v, g), nodes, half, own, nbr, _caller = self._operands(name, 6)
-        ng = np.asarray(sparse._gather_rows(sparse_gated._halves(nodes, half, g, g[:, :1]), plan.neighbour))
-        nv = np.asarray(sparse._gather_rows(sparse_gated._halves(nodes, half, None, v), plan.neighbour))
+        ng = np.asarray(walk.gather_rows(walk.halves(nodes, half, g, g[:, :1]), plan.neighbour))
+        nv = np.asarray(walk.gather_rows(walk.halves(nodes, half, None, v), plan.neighbour))
 
         def g_over_v(block, _d):
             at = slice(block * BE, (block + 1) * BE)
             return sparse_gated._g_over_v(jnp.asarray(ng[at]).T, jnp.asarray(nv[at]).T, half)
 
-        got, _ = _item_dots(host, items, np.asarray(sparse_gated._halves(nodes, half, v, g)), g_over_v)
+        got, _ = _item_dots(host, items, np.asarray(walk.halves(nodes, half, v, g)), g_over_v)
         mine = jnp.concatenate([v[own], g[own]], axis=1)
         theirs = jnp.concatenate([g[nbr], v[nbr]], axis=1)
         oracle = np.asarray((mine * theirs).sum(axis=1))
@@ -321,7 +321,7 @@ class TestTheGatedWalksEntryDots:
         _out, saved = sparse_gated._gated_pallas_fwd(plan, q, k, v, b, True)
         nqk, state = np.asarray(saved[2]), np.asarray(saved[4])
         dots, _ = _item_dots(
-            host, items, np.asarray(sparse_gated._halves(nodes, half, q, k)),
+            host, items, np.asarray(walk.halves(nodes, half, q, k)),
             lambda block, d: sparse_gated._by_half(jnp.asarray(nqk[block * BE : (block + 1) * BE]), d, half),
         )
         a = jnp.asarray(dots[:entries]) * jnp.float32(64.0 ** -0.5) + b
